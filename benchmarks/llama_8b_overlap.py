@@ -25,7 +25,8 @@ program itself:
    this; on a pod with ``benchutil.latency_hiding_xla_flags()`` the same
    accounting upgrades to the scheduled start->done windows).
 3. Merge the fractions into the measured-components JSON
-   (``llama_8b_measured_r06.json``) and re-base the composed projection:
+   (``llama_8b_measured.json``, written by llama_8b_measured.py on the
+   chip) and re-base the composed projection:
 
        t_step = t_chip + (1 - f_tp) * t_tp + (1 - f_dp) * t_dp
 
@@ -35,8 +36,7 @@ program itself:
 Run (CPU by design, no TPU needed):
 
   PYTHONPATH=. python benchmarks/llama_8b_overlap.py \
-      [--buckets 8] [--out benchmarks/llama_8b_measured_r06.json] \
-      [--seed-from benchmarks/llama_8b_measured_r05.json]
+      [--buckets 8] [--out benchmarks/llama_8b_measured.json]
 """
 
 import argparse
@@ -483,12 +483,6 @@ def rebase_projection(result: dict) -> None:
     t_chip = comp["t_chip_s"]
     t_tp = ici["tp_allgather_reducescatter_s_per_step"]
     t_dp = ici["dp_neighbor_exchange_int8_s"]
-    # retire the r05 spread fields (rides in via the seeded r05 JSON):
-    # the projection is ONE defended number now
-    for stale in ("t_step_no_overlap_s", "t_step_full_overlap_s"):
-        comp.pop(stale, None)
-    for stale in ("no_overlap_s", "full_overlap_s"):
-        ici.pop(stale, None)
     comp["formula"] = (
         "t_chip = 32*(fwd+fwd_bwd) + embed + min(head, head_chunked) + "
         "opt; t_step = t_chip + (1-f_tp)*t_tp + (1-f_dp)*t_dp with f_* "
@@ -520,9 +514,7 @@ def main():
     ap.add_argument("--comm-mode", default="atc",
                     choices=["atc", "cta"])
     ap.add_argument("--out",
-                    default="benchmarks/llama_8b_measured_r17.json")
-    ap.add_argument("--seed-from",
-                    default="benchmarks/llama_8b_measured_r14.json")
+                    default="benchmarks/llama_8b_measured.json")
     ap.add_argument("--skip-epilogue", action="store_true",
                     help="skip the fused-vs-unfused epilogue "
                          "accounting (2 extra AOT compiles)")
@@ -535,9 +527,8 @@ def main():
     args = ap.parse_args()
 
     result = {}
-    src = args.out if os.path.exists(args.out) else args.seed_from
-    if os.path.exists(src):
-        with open(src) as fh:
+    if os.path.exists(args.out):
+        with open(args.out) as fh:
             result = json.load(fh)
     result["overlap"] = audit(args.buckets, args.comm_mode)
     if not args.skip_epilogue:

@@ -13,7 +13,6 @@ bluefog/torch/__init__.py:34-110); see ``bluefog_tpu.api`` for the
 flat op API and ``bluefog_tpu.topology`` for graph generators.
 """
 
-from bluefog_tpu import _compat  # noqa: F401  (installs jax API shims)
 from bluefog_tpu.version import __version__
 
 # Flat API re-exports (reference: bluefog/torch/__init__.py:34-110).

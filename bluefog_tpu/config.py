@@ -57,6 +57,7 @@ __all__ = [
     "chip_hbm_gbps_override",
     "environ_passthrough",
     "configure_host_platform",
+    "configure_compilation_cache",
 ]
 
 
@@ -557,3 +558,21 @@ def configure_host_platform(devices: int = 8) -> None:
         env["XLA_FLAGS"] = (
             flags + f" --xla_force_host_platform_device_count={devices}"
         ).strip()
+
+
+def configure_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already
+    uses it and nothing is changed; otherwise the cache lives at
+    ``<checkout>/.jax_cache``, derived from this package's own path — a
+    FIXED location, because the directory is part of what a later
+    process must agree on to hit.  Call before the first compile."""
+    path = _env("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

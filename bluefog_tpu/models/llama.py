@@ -387,6 +387,17 @@ class LlamaConfig:
             n_kv_heads=8, hidden_dim=14336, rope_theta=500000.0, **overrides)
 
     @staticmethod
+    def llama_1b(**overrides) -> "LlamaConfig":
+        """The repo's "1b" benchmark widths (TinyLlama-class: 32 query /
+        8 KV heads of 64, SwiGLU 5632): the largest configuration whose
+        batch-4 x 2048 SGD+momentum train step fits one 16 GB v5e chip.
+        The ONE definition examples/ and chip_smoke.py share."""
+        base = dict(vocab_size=32000, dim=2048, n_layers=16, n_heads=32,
+                    n_kv_heads=8, hidden_dim=5632, max_seq_len=8192)
+        base.update(overrides)
+        return LlamaConfig(**base)
+
+    @staticmethod
     def tiny(**overrides) -> "LlamaConfig":
         """Test-scale config."""
         base = dict(vocab_size=256, dim=64, n_layers=2, n_heads=4,

@@ -31,6 +31,7 @@ _LIB = os.path.join(_HERE, "libbf_native.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
+_rebuilt = False
 
 
 def _build() -> bool:
@@ -67,7 +68,7 @@ def _log_build_failure(detail: str):
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _build_failed
+    global _lib, _build_failed, _rebuilt
     with _lock:
         if _lib is not None:
             return _lib
@@ -76,9 +77,11 @@ def _load() -> Optional[ctypes.CDLL]:
         stale = (not os.path.exists(_LIB) or
                  os.path.getmtime(_LIB) < max(os.path.getmtime(s)
                                               for s in _SRCS))
-        if stale and not _build():
-            _build_failed = True
-            return None
+        if stale:
+            if not _build():
+                _build_failed = True
+                return None
+            _rebuilt = True
         try:
             lib = ctypes.CDLL(_LIB)
         except OSError:
@@ -120,6 +123,16 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> str:
+    """How this process got the library: ``"rebuilt"`` (compiled from
+    the committed ``.cc`` sources just now), ``"loaded"`` (an existing
+    build newer than the sources), or ``"unavailable"``.  The ``.so`` is
+    not a committed file, so a fresh checkout always reads "rebuilt"."""
+    if _load() is None:
+        return "unavailable"
+    return "rebuilt" if _rebuilt else "loaded"
 
 
 class NativeTimelineWriter:

@@ -204,8 +204,6 @@ def _analyzed(compiled):
         return rec, hlo
     _cache_misses += 1
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per device
-        cost = cost[0]
     rec = {
         "cost": cost or {},
         "collective_bytes": benchutil.hlo_collective_bytes(hlo),

@@ -57,7 +57,8 @@ def _fit_block(t: int, want: int) -> int:
 def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, scale: float, quantized: bool,
                    n_kv: int):
-    """Grid = (B, S blocks).  One batch element's [KV * rep, D] query
+    """Grid = (B, S blocks); ``idx_ref`` holds one cache position per
+    batch element (SMEM).  One batch element's [KV * rep, D] query
     tile is resident; its KV heads process as a STATIC in-kernel loop
     (one program per batch element instead of per (batch, kv) pair —
     per-program overhead amortizes over the kv heads, measured ~2x
@@ -67,6 +68,7 @@ def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
     O(rep * block_s), not O(block_s * D))."""
     sj = pl.program_id(1)
     n_s = pl.num_programs(1)
+    idx = idx_ref[pl.program_id(0)]
     q_all = q_ref[0].astype(jnp.float32)      # [KV * rep, D]
     heads, d = q_all.shape
     rep = heads // n_kv
@@ -106,7 +108,7 @@ def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
         # tail beyond is unwritten zeros and must be masked out
         pos = sj * block_s + jax.lax.broadcasted_iota(
             jnp.int32, (rep, block_s), 1)
-        s = jnp.where(pos <= idx_ref[0], s, _NEG_INF)
+        s = jnp.where(pos <= idx, s, _NEG_INF)
 
         sl = slice(kv * rep, (kv + 1) * rep)
         m, l, acc = m_ref[sl], l_ref[sl], acc_ref[sl]
@@ -143,7 +145,8 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
 def _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, *, block_s,
                  interpret):
     """q: [B, 1, n_q, D]; k_all/v_all: KV-HEAD-MAJOR [B, KV, S, D]
-    (int8 when quantized); ks_all/vs_all: [B, KV, S] f32 scales or None.
+    (int8 when quantized); ks_all/vs_all: [B, KV, S] f32 scales or None;
+    idx: the current position, one scalar for every row or [B] per row.
     Returns [B, 1, n_q, D] in q's dtype."""
     b, t, n_q, d = q.shape
     assert t == 1, "the fused decode kernel serves single-token steps"
@@ -161,7 +164,7 @@ def _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, *, block_s,
             "use decode_attn='xla'")
 
     q3 = q.reshape(b, n_q, d)  # kv-major head order matches the cache
-    idx1 = jnp.reshape(jnp.asarray(idx, jnp.int32), (1,))
+    idx_b = jnp.broadcast_to(jnp.asarray(idx, jnp.int32).reshape(-1), (b,))
 
     kv_spec = pl.BlockSpec((1, n_kv, block_s, d),
                            lambda bk, sj: (bk, 0, sj, 0))
@@ -174,15 +177,15 @@ def _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, *, block_s,
         pl.BlockSpec((1, n_q, d), lambda bk, sj: (bk, 0, 0)),
         kv_spec, kv_spec,
     ]
-    args = [idx1, q3, k_all, v_all]
+    args = [idx_b, q3, k_all, v_all]
     if quantized:
         in_specs += [scale_spec, scale_spec]
         args += [ks_all[..., None], vs_all[..., None]]
     else:
-        # scales unused; pass the idx scalar twice as cheap placeholders
+        # scales unused; pass the positions twice as cheap placeholders
         in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM),
                      pl.BlockSpec(memory_space=pltpu.SMEM)]
-        args += [idx1, idx1]
+        args += [idx_b, idx_b]
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=1.0 / d ** 0.5,
@@ -201,16 +204,53 @@ def _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, *, block_s,
     return out.reshape(b, 1, n_q, d)
 
 
+@functools.lru_cache(maxsize=None)
+def _row_batched(block_s: int, interpret: bool):
+    """``_decode_impl`` as ``call(idx, q, k_all, v_all[, ks_all,
+    vs_all])`` with its own ``vmap`` rule: a mapped axis of independent
+    rows FOLDS into the kernel's batch grid axis, each row keeping its
+    own position.  The serving engine maps the decode step over its
+    cache slots; Pallas's generic batching would instead batch the SMEM
+    position operand into a squeezed ``[slots, 1]`` block, which the
+    TPU lowering refuses (SMEM blocks must span the whole array)."""
+
+    @jax.custom_batching.custom_vmap
+    def call(idx, q, k_all, v_all, *scales):
+        ks_all, vs_all = scales or (None, None)
+        return _decode_impl(q, k_all, v_all, ks_all, vs_all, idx,
+                            block_s=block_s, interpret=interpret)
+
+    @call.def_vmap
+    def _fold(axis_size, in_batched, idx, *arrays):
+        idx_batched, *arrays_batched = in_batched
+        b = arrays[0].shape[1 if arrays_batched[0] else 0]
+
+        def fold(x, batched):
+            if not batched:
+                x = jnp.broadcast_to(x[None], (axis_size,) + x.shape)
+            return x.reshape((axis_size * b,) + x.shape[2:])
+
+        idx = jnp.asarray(idx, jnp.int32)
+        if not idx_batched:
+            idx = jnp.broadcast_to(idx[None], (axis_size,) + idx.shape)
+        idx = jnp.broadcast_to(idx.reshape(axis_size, -1), (axis_size, b))
+        out = call(idx.reshape(-1), *map(fold, arrays, arrays_batched))
+        return out.reshape((axis_size, b) + out.shape[1:]), True
+
+    return call
+
+
 def decode_attention(q, k_all, v_all, idx, *, block_s: int = 512,
                      interpret: Optional[bool] = None):
     """Fused GQA decode-attention step over a full-precision cache.
 
     q: [B, 1, n_q, D]; k_all/v_all: [B, KV, S, D] (cache layout/dtype);
-    idx: scalar current position.  Drop-in for the decode-step case of
-    ``models.llama._cached_attention`` (reference has no counterpart —
-    decode itself is a new capability, docs/parity.md)."""
-    return _decode_impl(q, k_all, v_all, None, None, idx, block_s=block_s,
-                        interpret=_auto_interpret(interpret))
+    idx: current position, a scalar or [B] per row.  Drop-in for the
+    decode-step case of ``models.llama._cached_attention`` (reference
+    has no counterpart — decode itself is a new capability,
+    docs/parity.md)."""
+    return _row_batched(block_s, _auto_interpret(interpret))(
+        idx, q, k_all, v_all)
 
 
 def decode_attention_int8(q, kq_all, ks_all, vq_all, vs_all, idx, *,
@@ -224,6 +264,5 @@ def decode_attention_int8(q, kq_all, ks_all, vq_all, vs_all, idx, *,
     models/llama.py).  Replaces the decode-step case of both
     ``_cached_attention_int8`` (whose probability re-quantization cost
     it the long-context crown) and the dequant-then-attend path."""
-    return _decode_impl(q, kq_all, vq_all, ks_all, vs_all, idx,
-                        block_s=block_s,
-                        interpret=_auto_interpret(interpret))
+    return _row_batched(block_s, _auto_interpret(interpret))(
+        idx, q, kq_all, vq_all, ks_all, vs_all)

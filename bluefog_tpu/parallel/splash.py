@@ -2,7 +2,8 @@
 
 Round-5 A/B at the 1B per-layer train shapes (benchmarks/splash_ab.py,
 v5e-1, [B=4, H=32, KV=8, S=2048, D=64] bf16, causal, chained-loop
-timing) measured ``jax.experimental.pallas.ops.tpu.splash_attention``
+timing; earlier installation, record removed) measured
+``jax.experimental.pallas.ops.tpu.splash_attention``
 with its fused one-pass dq/dk/dv backward at **6.37 ms fwd+bwd** per
 layer vs **8.72 ms** for our ``pallas_attention`` kernel (forward is a
 wash: 2.63 vs 2.71 ms — the win is the fused backward).  End-to-end
@@ -10,8 +11,8 @@ wash: 2.63 vs 2.71 ms — the win is the fused backward).  End-to-end
 +10.5% at 200M (50.0%)**, loss identical.  ``LlamaConfig(
 attn_impl="splash")`` opts the plain causal full-sequence train path
 into it; at the 8B tp8_seqshard shard shapes the whole-layer chain
-still favors our flash kernel (llama_8b_measured_r05.json sweep), so
-the 8B composition keeps ``flash``.
+still favored our flash kernel in that round's tile sweep (earlier
+installation, record removed), so the 8B composition keeps ``flash``.
 
 Our kernel remains the default and the only backend with an LSE output
 (ring/blockwise composition, ``flash_attention_with_lse``) and
@@ -35,38 +36,13 @@ import jax.numpy as jnp
 
 from bluefog_tpu.parallel.pallas_attention import _fit_block
 
-__all__ = ["splash_attention", "library_supports_head_dim"]
-
-
-@functools.lru_cache(maxsize=8)
-def library_supports_head_dim(d: int) -> bool:
-    """Whether the INSTALLED splash library kernel accepts ``head_dim=d``.
-
-    Older jax releases hard-require head_dim to be a whole 128-lane
-    multiple; newer ones pad narrower heads internally.  Probed by
-    abstractly tracing a tiny call (no compute), so callers and tests
-    can gate instead of tripping the library's NotImplementedError deep
-    inside a model trace."""
-    if d % 128 == 0:
-        return True
-    try:
-        with jax.enable_x64(False):
-            q = jax.ShapeDtypeStruct((1, 128, 1, d), jnp.float32)
-            jax.eval_shape(
-                lambda a, b, c: splash_attention(
-                    a, b, c, block_q=128, block_kv=128, interpret=True),
-                q, q, q)
-        return True
-    except NotImplementedError:
-        return False
+__all__ = ["splash_attention"]
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
     if interpret is not None:
         return interpret
     return jax.default_backend() != "tpu"
-
-
 
 
 @functools.lru_cache(maxsize=64)

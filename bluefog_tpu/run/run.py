@@ -9,7 +9,13 @@ launcher covers the two launch shapes:
 * **Local multi-process** (default): spawn ``-np`` processes on this host,
   each a ``jax.distributed`` member.  With ``--force-cpu-devices K`` each
   process simulates K CPU devices — the single-host stand-in for a pod,
-  used by the multi-process test suite (SURVEY.md §4).
+  used by the multi-process test suite (SURVEY.md §4).  Without it every
+  child is handed every chip of the host, and a chip belongs to one
+  process at a time: on one host with chips the supported layout is ONE
+  process driving all of them (no ``bfrun``).  This launcher's own
+  process imports the package and must initialize no backend, or it
+  would hold the chips its children need
+  (tests/test_chip_smoke.py::test_bfrun_parent_imports_initialize_no_backend).
 * **Multi-host, by hand**: run the same ``bfrun`` command on every host
   with ``--host-rank R --coordinator HOST0:PORT`` (or let the TPU
   platform's launcher set the env), matching how TPU pods start jobs.

@@ -32,6 +32,7 @@ import numpy as np
 from bluefog_tpu import models
 from bluefog_tpu.benchutil import (chip_hbm_bandwidth, device_fetch,
                                    fetch_overhead)
+from bluefog_tpu.config import configure_compilation_cache
 from bluefog_tpu.models import llama_generate, quantize_llama_params
 from bluefog_tpu.models.quant import QUANT_KERNELS
 
@@ -68,10 +69,7 @@ def make_config():
             vocab_size=32000, dim=1024, n_layers=12, n_heads=16,
             n_kv_heads=4, hidden_dim=2816, max_seq_len=8192, dtype=dtype,
             **extra)
-    return models.LlamaConfig(
-        vocab_size=32000, dim=2048, n_layers=16, n_heads=32,
-        n_kv_heads=8, hidden_dim=5632, max_seq_len=8192, dtype=dtype,
-        **extra)
+    return models.LlamaConfig.llama_1b(dtype=dtype, **extra)
 
 
 def stream_bytes_per_step(variables, cfg, batch_size) -> int:
@@ -105,6 +103,7 @@ def stream_bytes_per_step(variables, cfg, batch_size) -> int:
 
 
 def main():
+    configure_compilation_cache()
     cfg = make_config()
     model = models.Llama(cfg)
     rng = np.random.RandomState(0)

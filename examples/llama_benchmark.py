@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from bluefog_tpu import models
 from bluefog_tpu.benchutil import (chip_peak_flops, compiled_step_flops,
                                    device_fetch, fetch_overhead, mfu)
+from bluefog_tpu.config import configure_compilation_cache
 from bluefog_tpu.optim import functional as F
 from bluefog_tpu.topology import (
     ExponentialTwoGraph,
@@ -139,13 +140,12 @@ def make_config():
             vocab_size=32000, dim=1024, n_layers=12, n_heads=16,
             n_kv_heads=4, hidden_dim=2816, max_seq_len=8192, **base)
     if args.model == "1b":
-        return models.LlamaConfig(
-            vocab_size=32000, dim=2048, n_layers=16, n_heads=32,
-            n_kv_heads=8, hidden_dim=5632, max_seq_len=8192, **base)
+        return models.LlamaConfig.llama_1b(**base)
     return models.LlamaConfig.llama3_8b(**base)
 
 
 def main():
+    configure_compilation_cache()
     devices = jax.devices()
     n_total = len(devices)
     n_sp, n_tp, n_ep, n_pp = args.sp, args.tp, args.ep, args.pp

@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bluefog_tpu import models
 from bluefog_tpu.benchutil import device_fetch, fetch_overhead
+from bluefog_tpu.config import configure_compilation_cache
 from bluefog_tpu.optim import functional as F
 from bluefog_tpu.topology import (
     ExponentialTwoGraph,
@@ -49,6 +50,7 @@ args = parser.parse_args()
 
 
 def main():
+    configure_compilation_cache()
     devices = jax.devices()
     n = len(devices)
     mesh = Mesh(np.array(devices), ("bf",))

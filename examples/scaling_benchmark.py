@@ -27,6 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bluefog_tpu import models
 from bluefog_tpu.benchutil import device_fetch, fetch_overhead
+from bluefog_tpu.config import configure_compilation_cache
 from bluefog_tpu.optim import functional as F
 from bluefog_tpu.topology import (
     ExponentialTwoGraph,
@@ -139,6 +140,7 @@ def throughput(n_devices, dist_optimizer):
 
 
 def main():
+    configure_compilation_cache()
     n = len(jax.devices())
     base = throughput(1, "local")
     print(f"single-device baseline: {base:.1f} img/s")

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from bluefog_tpu import models, timeline
 from bluefog_tpu.benchutil import poisson_arrivals
+from bluefog_tpu.config import configure_compilation_cache
 from bluefog_tpu.serving import Request, RequestRejected, ServingEngine
 
 parser = argparse.ArgumentParser()
@@ -42,6 +43,7 @@ parser.add_argument("--timeline", default=None, metavar="PATH",
 
 
 def main():
+    configure_compilation_cache()
     args = parser.parse_args()
     cfg = models.LlamaConfig.tiny(dtype=jnp.float32)
     variables = models.Llama(cfg).init(jax.random.PRNGKey(1),

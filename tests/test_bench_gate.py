@@ -1,7 +1,8 @@
-"""The bench regression gate is wired into the driver flow (ISSUE 6):
-a committed pre-PR baseline + a smoke test that the gate actually
-gates — exit 1 on a synthetic regressed record, exit 0 on the real
-committed before/after pair.
+"""The bench regression gate (``benchutil.bench_compare`` /
+``bench_regression_gate``): exit 1 on a regressed record, exit 0 on a
+before/after pair that did not regress, over the shapes of record this
+repo emits.  The records are built here: a gate's baseline is a record
+taken on the same installation, and none is committed for this one yet.
 """
 
 import copy
@@ -11,7 +12,6 @@ import os
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BASELINE = os.path.join(REPO, "benchmarks", "bench_baseline.json")
 
 pytestmark = pytest.mark.perf
 
@@ -21,62 +21,104 @@ def _load(name):
         return json.load(fh)
 
 
-def test_committed_baseline_is_the_r05_record():
-    """The committed baseline IS the pre-ISSUE-6 driver record (r05
-    parsed line), so the driver-flow gate measures this PR's change
-    against the state it branched from."""
-    base = _load(os.path.join("benchmarks", "bench_baseline.json"))
-    r05 = _load("BENCH_r05.json")["parsed"]
-    assert base == r05
-    assert base["metric"] == "resnet50_train_images_per_sec_per_chip"
-    assert base["value"] > 0 and base["mfu"] > 0
+def _bench_line(value=2700.0, mfu=0.33):
+    """A ``bench.py`` output line."""
+    return {"metric": "resnet50_train_images_per_sec_per_chip",
+            "value": value, "unit": "img/s/chip", "vs_baseline": 10.0,
+            "mfu": mfu, "flops_per_step_per_device": 3.0e12,
+            "peak_tflops_per_chip": 197.0}
 
 
-def test_gate_exits_nonzero_on_synthetic_regression(capsys):
+def _driver_record(line):
+    """The wrapper a driver run stores the line in."""
+    return {"n": 1, "cmd": "python bench.py", "rc": 0,
+            "tail": json.dumps(line) + "\n", "parsed": line}
+
+
+def _audit_record():
+    """The 8B audit's sections (benchmarks/llama_8b_overlap.py) with the
+    fields the gate reads."""
+    return {
+        "epilogue": {"claims": {"cost_bytes_not_above_r11": True}},
+        "hierarchical": {
+            "flat": {"dcn_bytes_per_step": 4.0e9,
+                     "tp_overlap_fraction": 0.85},
+            "hierarchical": {"dcn_bytes_per_step": 2.0e9,
+                             "tp_overlap_fraction": 0.85},
+            "dcn_bytes_per_step": 2.0e9,
+            "tp_overlap_fraction": 0.85,
+            "claims": {"dcn_bytes_cut": True, "dcn_bytes_ratio": 0.5,
+                       "tp_overlap_defended": True},
+        },
+        "compressed": {
+            "dcn_bytes_per_step": 0.75e9,
+            "claims": {"dcn_bytes_vs_int8_only": 0.375,
+                       "dcn_bytes_halved": True},
+        },
+    }
+
+
+@pytest.fixture
+def baseline_path(tmp_path):
+    path = tmp_path / "bench_baseline.json"
+    path.write_text(json.dumps(_bench_line()))
+    return str(path)
+
+
+def test_headline_reads_the_driver_wrapper_like_the_raw_line():
+    """A driver record holds the bench line under ``"parsed"``; the gate
+    reads the same headline from either shape."""
+    from bluefog_tpu.benchutil import bench_headline
+
+    line = _bench_line()
+    head = bench_headline(_driver_record(line))
+    assert head == bench_headline(line)
+    assert head["value"] == line["value"] and head["mfu"] == line["mfu"]
+
+
+def test_gate_exits_nonzero_on_synthetic_regression(baseline_path, capsys):
     """A 20% throughput/MFU drop beyond the 5% tolerance fails the
     gate (bench.py exits 1 on a False gate result)."""
     from bluefog_tpu.benchutil import bench_regression_gate
 
-    regressed = copy.deepcopy(_load(
-        os.path.join("benchmarks", "bench_baseline.json")))
+    regressed = _bench_line()
     regressed["value"] *= 0.8
     regressed["mfu"] *= 0.8
-    ok = bench_regression_gate(regressed, BASELINE)
+    ok = bench_regression_gate(regressed, baseline_path)
     assert ok is False
     out = capsys.readouterr().out
     assert "REGRESSED" in out
 
 
-def test_gate_passes_on_real_before_after_pair(capsys):
-    """The real committed r04 -> r05 pair (2738.2 -> 2746.5 img/s/chip,
-    an improvement) passes the gate: exit 0."""
+def test_gate_passes_on_before_after_pair(baseline_path, capsys):
+    """A before/after pair of driver records inside the tolerance (a
+    0.3% improvement) passes the gate: exit 0."""
     from bluefog_tpu.benchutil import bench_compare
 
-    before = _load("BENCH_r04.json")
-    after = _load("BENCH_r05.json")
+    before = _driver_record(_bench_line(2738.2, 0.3343))
+    after = _driver_record(_bench_line(2746.5, 0.3353))
     ok, rows = bench_compare(after, before)
     assert ok is True
     assert rows and not any(r["regressed"] for r in rows)
-    # and the fresh record gates clean against the committed baseline
+    # and the fresh record gates clean against a baseline file
     from bluefog_tpu.benchutil import bench_regression_gate
 
-    assert bench_regression_gate(after, BASELINE) is True
+    assert bench_regression_gate(after, baseline_path) is True
 
 
 def test_bench_py_defaults_to_committed_baseline():
-    """A plain ``python bench.py`` (the driver's invocation) gates
-    against the committed baseline by default; ``--compare ''`` opts
-    out and an explicit path wins."""
+    """A plain ``python bench.py`` (the driver's invocation) compares
+    with nothing — no baseline is committed, a record from another
+    installation is not one — and ``--compare`` names a record."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
         "bench", os.path.join(REPO, "bench.py"))
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
-    args = bench.parse_args([])
-    assert args.compare == bench.DEFAULT_BASELINE
-    assert os.path.exists(args.compare)
-    assert bench.parse_args(["--compare", ""]).compare is None
+    assert bench.parse_args([]).compare is None
+    assert not os.path.exists(
+        os.path.join(REPO, "benchmarks", "bench_baseline.json"))
     assert bench.parse_args(["--compare", "x.json"]).compare == "x.json"
 
 
@@ -183,33 +225,26 @@ def test_chaos_bench_defaults_and_baseline():
 
 
 # --------------------------------------------------------------------- #
-# hierarchical-exchange baseline (ISSUE 11): the 8B audit's flat-vs-
-# two-level record joins the gate flow — DCN bytes/step is a gated
+# hierarchical-exchange audit (ISSUE 11): the 8B audit's flat-vs-
+# two-level section joins the gate flow — DCN bytes/step is a gated
 # lower-is-better headline, so a schedule change that silently re-
 # inflates the inter-machine wire fails the compare
 # --------------------------------------------------------------------- #
 @pytest.mark.hier
-def test_hierarchical_audit_baseline_is_committed_and_defended():
-    """The committed r14 record carries the hierarchical audit with
-    every machine-checked claim true: DCN bytes/step halved vs the
-    flat exchange at the same guard+health+int8 config, tp overlap
-    still defended, cost-model overhead bounded, and the r11-layout
-    epilogue record not regressed."""
-    base = _load(os.path.join("benchmarks",
-                              "llama_8b_measured_r14.json"))
-    hier = base["hierarchical"]
-    assert all(v is True for k, v in hier["claims"].items()
-               if isinstance(v, bool)), hier["claims"]
-    assert hier["claims"]["dcn_bytes_ratio"] <= 0.75
-    assert (hier["hierarchical"]["dcn_bytes_per_step"]
-            < hier["flat"]["dcn_bytes_per_step"])
-    assert base["epilogue"]["claims"]["cost_bytes_not_above_r11"] is True
-    # the gate sees the hierarchical headline fields
-    from bluefog_tpu.benchutil import bench_headline
+def test_hierarchical_audit_fields_are_gated():
+    """The gate reads the hierarchical audit's headline fields out of
+    its section, and an audit record gates clean against itself."""
+    from bluefog_tpu.benchutil import bench_compare, bench_headline
 
+    base = _audit_record()
     head = bench_headline(base)
-    assert "hierarchical.dcn_bytes_per_step" in head
-    assert "hierarchical.tp_overlap_fraction" in head
+    assert head["hierarchical.dcn_bytes_per_step"] == 2.0e9
+    assert head["hierarchical.tp_overlap_fraction"] == 0.85
+    # booleans and nested legs are not headlines
+    assert not any(k.startswith("epilogue.") for k in head)
+    assert "hierarchical.dcn_bytes_cut" not in head
+    ok, rows = bench_compare(base, base)
+    assert ok is True and rows
 
 
 @pytest.mark.hier
@@ -219,8 +254,7 @@ def test_gate_catches_dcn_byte_regression(capsys):
     lower is better for dcn_bytes_per_step."""
     from bluefog_tpu.benchutil import bench_compare
 
-    base = _load(os.path.join("benchmarks",
-                              "llama_8b_measured_r14.json"))
+    base = _audit_record()
     regressed = copy.deepcopy(base)
     regressed["hierarchical"]["dcn_bytes_per_step"] *= 2.0
     regressed["hierarchical"]["tp_overlap_fraction"] *= 0.5
@@ -229,8 +263,10 @@ def test_gate_catches_dcn_byte_regression(capsys):
     bad = {r["name"] for r in rows if r["regressed"]}
     assert "hierarchical.dcn_bytes_per_step" in bad
     assert "hierarchical.tp_overlap_fraction" in bad
-    # ... and the committed record gates clean against itself
-    ok2, _ = bench_compare(base, base)
+    # ... and a halved wire is an improvement, never a failure
+    better = copy.deepcopy(base)
+    better["hierarchical"]["dcn_bytes_per_step"] *= 0.5
+    ok2, _ = bench_compare(better, base)
     assert ok2 is True
 
 
@@ -372,34 +408,15 @@ def test_gate_catches_no_adaptation_regression(capsys):
 # wire (k drift, mask packing, scale width) fails the compare
 # --------------------------------------------------------------------- #
 @pytest.mark.hier
-def test_compressed_audit_baseline_is_committed_and_defended():
-    """The committed r17 record carries the compressed-mixing audit
-    with every machine-checked claim true: every lowered permute
-    payload byte-exact against the mix_wire_layout prediction, DCN
-    bytes/step at most HALF the r14 int8-only hierarchical record at
-    the same layout, and the live ratio swap aval-invariant (the
-    zero-recompile property)."""
-    base = _load(os.path.join("benchmarks",
-                              "llama_8b_measured_r17.json"))
-    comp = base["compressed"]
-    claims = comp["claims"]
-    assert claims["predicted_collectives_byte_exact"] is True
-    assert claims["contract_problems"] == []
-    assert claims["ratio_swap_avals_unchanged"] is True
-    assert claims["dcn_bytes_halved"] is True
-    assert claims["dcn_bytes_vs_int8_only"] <= 0.5
-    r14 = _load(os.path.join("benchmarks",
-                             "llama_8b_measured_r14.json"))
-    assert (comp["dcn_bytes_per_step"] <= 0.5 *
-            r14["hierarchical"]["hierarchical"]["dcn_bytes_per_step"])
-    # ... and the r17 record does not regress the r14 hierarchical leg
-    assert (base["hierarchical"]["hierarchical"]["dcn_bytes_per_step"]
-            <= r14["hierarchical"]["hierarchical"]["dcn_bytes_per_step"])
-    # the gate sees the compressed headline field
+def test_compressed_audit_fields_are_gated():
+    """The gate reads the compressed-mixing audit's DCN bytes/step out
+    of its section, beside the hierarchical leg's in the same record."""
     from bluefog_tpu.benchutil import bench_headline
 
-    head = bench_headline(base)
-    assert "compressed.dcn_bytes_per_step" in head
+    head = bench_headline(_audit_record())
+    assert head["compressed.dcn_bytes_per_step"] == 0.75e9
+    assert (head["compressed.dcn_bytes_per_step"]
+            <= 0.5 * head["hierarchical.dcn_bytes_per_step"])
 
 
 # --------------------------------------------------------------------- #
@@ -503,8 +520,7 @@ def test_gate_catches_compressed_wire_regression(capsys):
     better for compressed.dcn_bytes_per_step."""
     from bluefog_tpu.benchutil import bench_compare
 
-    base = _load(os.path.join("benchmarks",
-                              "llama_8b_measured_r17.json"))
+    base = _audit_record()
     regressed = copy.deepcopy(base)
     regressed["compressed"]["dcn_bytes_per_step"] *= 2.0
     ok, rows = bench_compare(regressed, base, tolerance=0.25)

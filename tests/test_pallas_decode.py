@@ -65,6 +65,38 @@ def test_decode_attention_int8_matches_dequant_reference():
                                atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("quantized", [False, True])
+def test_vmap_over_rows_folds_into_the_kernel_batch(quantized):
+    """The serving engine maps the decode step over its cache slots,
+    every slot at its OWN position: the kernel's vmap rule folds the
+    mapped axis into its batch grid axis (one launch, per-row
+    positions) and must equal a Python loop of single-slot calls."""
+    slots, n_kv, rep, s, d = 3, 2, 2, 64, 16
+    positions = jnp.asarray([0, 17, 63], jnp.int32)
+    rng = np.random.RandomState(4)
+    q = jnp.asarray(rng.randn(slots, 1, 1, n_kv * rep, d), jnp.float32)
+    k, v = _rand_cache(slots, n_kv, s, d, seed=5)
+    cache = (k[:, None], v[:, None])          # [slots, B=1, KV, S, D]
+    if quantized:
+        (kq, ks), (vq, vs) = _amax_quantize(cache[0]), _amax_quantize(
+            cache[1])
+        operands = (kq, ks[..., 0], vq, vs[..., 0])
+        kernel = decode_attention_int8
+    else:
+        operands, kernel = cache, decode_attention
+
+    def one(q, idx, *ops):
+        return kernel(q, *ops, idx)
+
+    mapped = jax.jit(jax.vmap(one))(q, positions, *operands)
+    jaxpr = str(jax.make_jaxpr(jax.vmap(one))(q, positions, *operands))
+    assert jaxpr.count("pallas_call") == 1
+    for i in range(slots):
+        alone = one(q[i], positions[i], *[op[i] for op in operands])
+        np.testing.assert_array_equal(np.asarray(mapped[i]),
+                                      np.asarray(alone))
+
+
 def test_decode_attention_blocked_softmax_is_stable():
     """Online softmax across S blocks == one-shot softmax (block_s
     smaller than S exercises the flash recurrence)."""

@@ -1,0 +1,144 @@
+"""The main path's Pallas kernels, compiled by the TPU compiler for a
+DESCRIBED v5e:2x2 topology (no chip attached) at the "1b" Llama widths
+chip_smoke.py runs: 32 query / 8 KV heads of 64, batch 4 x 2048 for
+training, 8 slots x 1024 (bf16) and 2048 (int8) cache positions for
+decode.  Interpret mode cannot see what these catch — a block shape or a
+memory space Mosaic refuses — and each costs a second or two and no chip
+time.  Nothing executes: a pass says the kernel compiles, not that it is
+right (tests/test_pallas_*.py hold the numerics, chip_smoke.py the chip).
+
+The whole file skips where the topology cannot be described (no libtpu).
+The suite's conftest turns x64 on; the chip runs without it and the
+splash library kernel refuses it, so every test here scopes it off.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from bluefog_tpu import models
+from bluefog_tpu.models import generate
+from bluefog_tpu.parallel import pallas_decode
+from bluefog_tpu.parallel.pallas_attention import flash_attention
+from bluefog_tpu.parallel.splash import splash_attention
+from bluefog_tpu.serving import engine
+from bluefog_tpu.serving.kv_pool import SlotPool
+
+CFG = models.LlamaConfig.llama_1b(dtype=jnp.bfloat16)
+BATCH, SEQ, SLOTS = 4, 2048, 8
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu, or it cannot describe a chip
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_x64():
+    with jax.enable_x64(False):
+        yield
+
+
+def _compiled_text(fn, *shapes, chip, **static):
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        shapes)
+    return jax.jit(fn, static_argnames=tuple(static)).lower(
+        *args, **static).compile().as_text()
+
+
+def _qkv(heads=CFG.n_heads):
+    q = jax.ShapeDtypeStruct((BATCH, SEQ, heads, CFG.head_dim),
+                             jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((BATCH, SEQ, CFG.n_kv_heads, CFG.head_dim),
+                              jnp.bfloat16)
+    return q, kv, kv
+
+
+def _loss_grads(attend):
+    def loss(q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32) ** 2)
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_flash_kernel_compiles_for_v5e(chip, backward):
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    text = _compiled_text(_loss_grads(attend) if backward else attend,
+                          *_qkv(), chip=chip)
+    assert "tpu_custom_call" in text
+
+
+def test_splash_backward_compiles_for_v5e(chip):
+    def attend(q, k, v):
+        return splash_attention(q, k, v, causal=True, interpret=False)
+
+    assert "tpu_custom_call" in _compiled_text(_loss_grads(attend),
+                                               *_qkv(), chip=chip)
+
+
+@pytest.mark.parametrize("kv_quant,positions", [("none", 1024),
+                                                ("int8", 2048)])
+def test_decode_kernel_compiles_for_v5e(chip, kv_quant, positions):
+    q = jax.ShapeDtypeStruct((SLOTS, 1, CFG.n_heads, CFG.head_dim),
+                             jnp.bfloat16)
+    idx = jax.ShapeDtypeStruct((), jnp.int32)
+    shape = (SLOTS, CFG.n_kv_heads, positions, CFG.head_dim)
+    if kv_quant == "int8":
+        kv = jax.ShapeDtypeStruct(shape, jnp.int8)
+        scale = jax.ShapeDtypeStruct(shape[:-1], jnp.float32)
+        text = _compiled_text(
+            lambda q, k, ks, v, vs, i: pallas_decode.decode_attention_int8(
+                q, k, ks, v, vs, i, interpret=False),
+            q, kv, scale, kv, scale, idx, chip=chip)
+    else:
+        kv = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        text = _compiled_text(
+            lambda q, k, v, i: pallas_decode.decode_attention(
+                q, k, v, i, interpret=False),
+            q, kv, kv, idx, chip=chip)
+    assert "tpu_custom_call" in text
+
+
+def test_engine_decode_step_holds_the_kernel_on_v5e(chip, monkeypatch):
+    """The serving engine's resident decode step maps the model over
+    its slots, each at its own position: the program the TPU lowering
+    refused before the kernel learned to fold a mapped axis.  Two
+    layers (depth repeats the same kernel call); the model asks the
+    backend whether to interpret, which is ``cpu`` here, so the test
+    answers for it."""
+    monkeypatch.setattr(pallas_decode, "_auto_interpret",
+                        lambda interpret: False)
+    cfg = generate.decode_config(
+        models.LlamaConfig.llama_1b(dtype=jnp.bfloat16, n_layers=2), 1024,
+        decode_attn="pallas")
+    variables = jax.eval_shape(
+        lambda: models.Llama(cfg).init(jax.random.PRNGKey(0),
+                                       jnp.zeros((1, 1), jnp.int32)))
+    pool = jax.eval_shape(lambda: SlotPool(cfg, SLOTS, 1024).cache)
+
+    def slots(dtype, *tail):
+        return jax.ShapeDtypeStruct((SLOTS,) + tail, dtype)
+
+    text = _compiled_text(
+        engine._decode_step_prog.__wrapped__, variables["params"], pool,
+        slots(jnp.int32), slots(bool), slots(jnp.uint32, 2),
+        slots(jnp.int32), slots(jnp.float32), chip=chip, cfg=cfg,
+        horizon=1)
+    assert "tpu_custom_call" in text
