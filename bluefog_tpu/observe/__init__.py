@@ -13,6 +13,10 @@ subsystem extends that stance to metrics and per-op cost attribution):
   per-thread tracks; the serving engine, resilience runner, eager op
   API, and ``build_train_step`` wrappers publish here, and
   ``timeline.py`` is a thin Chrome-trace exporter over it;
+* :mod:`~bluefog_tpu.observe.compiles` — one ``jax.monitoring``
+  listener, registered here: ``bf_compile_seconds_total{stage=}``,
+  ``bf_compiles_total``, ``bf_compile_cache_misses_total`` and a
+  ``compile.<fun_name>`` instant a backend compile;
 * :mod:`~bluefog_tpu.observe.stepprof` — ``profile_step`` returns a
   :class:`StepProfile` (FLOPs, per-collective bytes, overlap windows,
   MFU) from XLA's own view of the compiled module;
@@ -32,6 +36,7 @@ from bluefog_tpu.observe.registry import (Counter, Gauge, Histogram,
                                           MetricsRegistry, enabled,
                                           get_registry, percentile)
 from bluefog_tpu.observe.tracer import Tracer, get_tracer, publish_tracer
+from bluefog_tpu.observe import compiles as _compiles
 from bluefog_tpu.observe.stepprof import (StepProfile, hlo_op_breakdown,
                                           profile_step,
                                           verify_collective_contract)
@@ -55,6 +60,8 @@ __all__ = [
     "BlackBox", "DecisionEvent", "explain", "get_blackbox",
     "record_decision",
 ]
+
+_compiles.install()
 
 # The decision flight recorder resolves lazily: its module reaches
 # into bluefog_tpu.sim for the canonical byte-stable formatting, and

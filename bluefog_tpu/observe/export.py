@@ -78,9 +78,12 @@ def prometheus_text(registry=None) -> str:
 
 def _jsonl(events, pid: int) -> str:
     lines = []
-    for phase, name, track, ts in events:
-        lines.append(json.dumps({"ph": phase, "name": name, "track": track,
-                                 "ts_us": round(ts, 3), "pid": pid}))
+    for phase, name, track, ts, args in events:
+        rec = {"ph": phase, "name": name, "track": track,
+               "ts_us": round(ts, 3), "pid": pid}
+        if args:
+            rec["args"] = dict(args)
+        lines.append(json.dumps(rec))
     return "\n".join(lines) + ("\n" if lines else "")
 
 

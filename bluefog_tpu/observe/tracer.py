@@ -23,6 +23,16 @@ Spans nest per **track** (the Chrome-trace ``tid``): ``begin`` pushes,
 renders as stacked bars.  ``span()`` is the context-manager form; with
 no explicit track it uses the calling thread's name, so concurrent
 producers get separate rows for free.
+
+``span()`` is also the ONE bridge into the profiler's trace: it enters
+a ``jax.profiler.TraceAnnotation("bf.<track>.<name>", **args)``, so
+that whenever a profiler session runs (``jax.profiler.start_trace``)
+the program's spans lie on ``/host:CPU`` of the same ``*.xplane.pb`` as
+the device's ``XLA Ops``, on one clock, nested by containment.  With no
+session running the annotation is a flag test.  ``begin``/``end`` pairs
+are NOT bridged: an annotation must begin and end on one thread, in
+order, and the eager ops' handles and the per-request tracks close out
+of order or on another thread — they stay in the ring and the sinks.
 """
 
 from __future__ import annotations
@@ -30,17 +40,89 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Callable, Dict, List, Optional
 
 from bluefog_tpu.observe.registry import enabled, get_registry
 
-__all__ = ["Tracer", "get_tracer", "publish_tracer", "effective_tracer"]
+__all__ = ["Tracer", "Span", "get_tracer", "publish_tracer",
+           "effective_tracer"]
 
 #: consecutive ``record()`` failures after which a sink is detached —
 #: a persistently broken sink (full disk, closed pipe) must not keep
 #: throwing inside every producer's span emission
 SINK_ERROR_LIMIT = 3
+
+#: prefix of every span the tracer writes into the profiler's trace
+PROFILER_PREFIX = "bf."
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str, args: dict):
+    """``jax.profiler.TraceAnnotation`` (imported on first use: the
+    observe layer comes up before anything needs the profiler)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **args)
+
+
+class Span:
+    """What ``Tracer.span()`` returns: the context manager of one span.
+    Entered, it holds the span's two stamps on the tracer's clock
+    (microseconds, as in the ring; ``end_us`` is set when the block is
+    left), so that a producer that wants the span's duration reads it
+    here instead of reading the clock a second time; and ``set()``, for
+    arguments known only when the work is done.  A plain class, not a
+    generator: the serving engine enters seven a step.  With no tracer
+    (observe off, no timeline) it records nothing and takes the two
+    stamps itself."""
+
+    __slots__ = ("begin_us", "end_us", "_tracer", "_track", "_name",
+                 "_args", "_annotation")
+
+    def __init__(self, tracer: Optional["Tracer"], track: str, name: str,
+                 args: dict):
+        self.begin_us = self.end_us = 0.0
+        self._tracer = tracer
+        self._track = track
+        self._name = name
+        self._args = args
+        self._annotation = None
+
+    def __enter__(self) -> "Span":
+        tracer = self._tracer
+        if tracer is None:
+            self.begin_us = time.perf_counter() * 1e6
+            return self
+        self._annotation = annotation = _annotation(
+            f"{PROFILER_PREFIX}{self._track}.{self._name}", self._args)
+        annotation.__enter__()
+        # the ring holds the dict itself: what set() adds shows there
+        self.begin_us = tracer._begin(self._track, self._name, self._args)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        tracer = self._tracer
+        if tracer is None:
+            self.end_us = time.perf_counter() * 1e6
+            return False
+        self.end_us = tracer.end(self._track)
+        self._annotation.__exit__(*exc)
+        return False
+
+    def set(self, **args) -> None:
+        """Add arguments to the open span, in the ring and (if a
+        profiler session runs) in the profiler's event."""
+        self._args.update(args)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**args)
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_us - self.begin_us) * 1e-6
 
 
 class Tracer:
@@ -93,21 +175,29 @@ class Tracer:
     def _now_us(self) -> float:
         return (self._clock() - self._t0) * 1e6
 
-    def _emit_locked(self, phase: str, name: str, track: str) -> None:
-        """Append + fan out; the CALLER holds ``self._lock`` — span
+    def _emit_locked(self, phase: str, name: str, track: str,
+                     args: Optional[dict] = None) -> float:
+        """Append + fan out, and return the event's stamp (us); the
+        CALLER holds ``self._lock`` — span
         bookkeeping and event emission must be one atomic step (two
         lock acquisitions would let a concurrent producer interleave an
         E between a track's bookkeeping and its B record), and the
         native timeline writer is a single-producer ring, so sink
         fan-out must stay serialized too (the pre-tracer Timeline held
         the same lock around its writer)."""
-        self._events.append((phase, name, track, self._now_us()))
+        ts = self._now_us()
+        self._events.append((phase, name, track, ts, args))
         self._n_emitted += 1
-        # sink fan-out is fault-isolated: one raising sink must not
-        # break span emission for the producers (or starve the other
-        # sinks), and the per-thread span stack stays consistent
-        # because the event was already buffered above.  A sink that
-        # fails SINK_ERROR_LIMIT times in a row is detached.
+        if self._sinks:
+            self._fan_out_locked(phase, name, track)
+        return ts
+
+    def _fan_out_locked(self, phase: str, name: str, track: str) -> None:
+        """Hand the event to every sink, fault-isolated: one raising
+        sink must not break span emission for the producers (or starve
+        the other sinks), and the per-thread span stack stays
+        consistent because the event was already buffered.  A sink that
+        fails SINK_ERROR_LIMIT times in a row is detached."""
         for sink in list(self._sinks):
             try:
                 sink.record(name, track, phase)
@@ -127,21 +217,32 @@ class Tracer:
                 self._sink_errors.pop(id(sink), None)
 
     # -- spans --------------------------------------------------------- #
-    def begin(self, track: str, name: str) -> None:
+    def begin(self, track: str, name: str) -> float:
         """Open a span named ``name`` on ``track`` (nested within the
-        track's currently-open span, if any)."""
+        track's currently-open span, if any).  Returns the B event's
+        stamp (us).  Ring and sinks only: see the module docstring for
+        what reaches the profiler."""
+        return self._begin(track, name, None)
+
+    def _begin(self, track: str, name: str, args: Optional[dict]) -> float:
         with self._lock:
-            self._open_spans.setdefault(track, []).append(name)
-            self._emit_locked("B", name, track)
+            spans = self._open_spans.get(track)
+            if spans is None:
+                self._open_spans[track] = [name]
+            else:
+                spans.append(name)
+            ts = self._emit_locked("B", name, track, args)
         stack = getattr(self._tls, "stack", None)
         if stack is None:
             stack = self._tls.stack = []
         stack.append((track, name))
+        return ts
 
-    def end(self, track: str) -> None:
+    def end(self, track: str) -> float:
         """Close the innermost open span on ``track`` (a no-op end on a
         track with no open span still records the E event so a foreign
-        B/E producer — the flat timeline API — stays balanced)."""
+        B/E producer — the flat timeline API — stays balanced).
+        Returns the E event's stamp (us)."""
         with self._lock:
             spans = self._open_spans.get(track)
             if spans:
@@ -152,7 +253,7 @@ class Tracer:
                 # keeping them would leak one dict entry per request
                 # for the life of the default-on global tracer
                 self._open_spans.pop(track, None)
-            self._emit_locked("E", "", track)
+            ts = self._emit_locked("E", "", track)
         stack = getattr(self._tls, "stack", None)
         if stack:
             # remove the INNERMOST entry for that track — producers
@@ -165,6 +266,7 @@ class Tracer:
                 if stack[i][0] == track:
                     del stack[i]
                     break
+        return ts
 
     def _prune_stale_locked(self, stack) -> None:
         """Drop trailing thread-local entries whose track has NO open
@@ -188,23 +290,21 @@ class Tracer:
             self._prune_stale_locked(stack)
         return stack[-1] if stack else None
 
-    def instant(self, name: str, track: str = "") -> None:
-        """A zero-duration marker event."""
+    def instant(self, name: str, track: str = "", **args) -> None:
+        """A zero-duration marker event (ring and sinks only)."""
         with self._lock:
-            self._emit_locked("i", name, track)
+            self._emit_locked("i", name, track, args or None)
 
-    @contextmanager
-    def span(self, track: Optional[str], name: str):
+    def span(self, track: Optional[str], name: str, **args) -> Span:
         """``with tracer.span("serving", "decode"): ...`` — the span
         covers the block; ``track=None`` uses the calling thread's name
-        (per-thread tracks)."""
+        (per-thread tracks).  Also written into the profiler's trace as
+        ``bf.<track>.<name>`` with ``args`` as the event's stats (the
+        module docstring says why only this form is).  The ``with``
+        yields the :class:`Span`."""
         if track is None:
             track = threading.current_thread().name
-        self.begin(track, name)
-        try:
-            yield
-        finally:
-            self.end(track)
+        return Span(self, track, name, args)
 
     def open_depth(self, track: str) -> int:
         """Current span-nesting depth on ``track`` (tests; a balanced
@@ -221,27 +321,31 @@ class Tracer:
             return self._n_emitted - len(self._events)
 
     def events(self) -> List[tuple]:
-        """The buffered ``(phase, name, track, ts_us)`` tuples, oldest
-        first."""
+        """The buffered ``(phase, name, track, ts_us, args)`` tuples,
+        oldest first (``args``: the span's or instant's keyword
+        arguments as a dict, empty or ``None`` where it has none)."""
         with self._lock:
             return list(self._events)
 
     @staticmethod
     def chrome_events(events: List[tuple], pid: int = 0) -> List[dict]:
-        """Format ``(phase, name, track, ts_us)`` tuples as Chrome-trace
-        JSON records — the same shape the timeline file writers stream
-        (``ph``/``ts``/``pid``/``tid``; instants carry ``s: "p"``)."""
+        """Format ``(phase, name, track, ts_us, args)`` tuples as
+        Chrome-trace JSON records — the same shape the timeline file
+        writers stream (``ph``/``ts``/``pid``/``tid``; instants carry
+        ``s: "p"``), plus ``args`` where the event has any."""
         out = []
-        for phase, name, track, ts in events:
+        for phase, name, track, ts, args in events:
             if phase == "B":
-                out.append({"name": name, "cat": track, "ph": "B",
-                            "ts": ts, "pid": pid, "tid": track})
+                rec = {"name": name, "cat": track, "ph": "B",
+                       "ts": ts, "pid": pid, "tid": track}
             elif phase == "E":
-                out.append({"ph": "E", "ts": ts, "pid": pid,
-                            "tid": track})
+                rec = {"ph": "E", "ts": ts, "pid": pid, "tid": track}
             else:
-                out.append({"name": name, "ph": "i", "ts": ts,
-                            "pid": pid, "s": "p"})
+                rec = {"name": name, "ph": "i", "ts": ts,
+                       "pid": pid, "s": "p"}
+            if args:
+                rec["args"] = dict(args)
+            out.append(rec)
         return out
 
     def to_chrome_trace(self) -> List[dict]:

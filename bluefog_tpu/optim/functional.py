@@ -386,6 +386,35 @@ def _make_health_vector(loss, grad_sq, updates, consensus,
         consensus=jnp.asarray(consensus, jnp.float32))
 
 
+# Named scopes of the compiled step: metadata only (the optimized HLO
+# with ``metadata={...}`` stripped is what it was), so that a device
+# trace can bill each operation to a part of the step by its ``tf_op``
+# path instead of by an HLO number.  JAX itself writes ``jvp(...)`` and
+# ``transpose(jvp(...))`` below ``bf.forward_backward``: the
+# forward/backward split.  Where scopes nest (the bucketed ATC engine
+# applies each bucket's update inside the exchange), the innermost
+# names the work.
+SCOPE_FORWARD_BACKWARD = "bf.forward_backward"
+SCOPE_OPTIMIZER = "bf.optimizer"
+SCOPE_EXCHANGE = "bf.exchange"
+
+
+def _opt_update(optimizer, grads, opt_state, params):
+    with jax.named_scope(SCOPE_OPTIMIZER):
+        return optimizer.update(grads, opt_state, params)
+
+
+def _apply_updates(params, updates):
+    with jax.named_scope(SCOPE_OPTIMIZER):
+        return optax.apply_updates(params, updates)
+
+
+def _allreduce_grads(grads, axis_name):
+    with jax.named_scope(SCOPE_EXCHANGE):
+        return jax.tree.map(
+            lambda g: C.allreduce(g, axis_name, average=True), grads)
+
+
 def _loss_and_grads(loss_fn, has_aux, sp_axis, pp_axis, param_specs,
                     params, aux, batch):
     """Forward+backward with the cross-axis reductions every builder
@@ -394,12 +423,13 @@ def _loss_and_grads(loss_fn, has_aux, sp_axis, pp_axis, param_specs,
     masked loss and restores pp-replicated leaves' gradients (the
     layer stacks sharded over pp got exact stage-local gradients
     through the reversed ppermutes — no reduction for those)."""
-    if has_aux:
-        (loss, new_aux), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, aux, batch)
-    else:
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        new_aux = aux
+    with jax.named_scope(SCOPE_FORWARD_BACKWARD):
+        if has_aux:
+            (loss, new_aux), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, aux, batch)
+        else:
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            new_aux = aux
     if sp_axis is not None:
         grads = lax.pmean(grads, sp_axis)
         loss = lax.pmean(loss, sp_axis)
@@ -700,7 +730,7 @@ def _bucketed_apply_combine_fn(spec: CommSpec, axis_name: str,
         for bi, g in enumerate(groups):
             fresh = list(leaves)
             for i in g:
-                fresh[i] = optax.apply_updates(leaves[i], upd_leaves[i])
+                fresh[i] = _apply_updates(leaves[i], upd_leaves[i])
             buf = _pack_bucket(fresh, g)
             wk = jax.random.fold_in(key, bi) if wire else None
             if hierarchical_local_size is not None:
@@ -760,7 +790,9 @@ def _observed_step(step_fn: Callable, labels: dict,
     """Host-side observability wrapper for a built train step: each
     dispatch increments ``bf_train_steps_total{comm_mode,overlap,
     guarded}`` and runs inside a ``train_step`` span on the ``train``
-    track.  Everything happens OUTSIDE the traced program — the wrapper
+    track (``bf.train.train_step`` in a profiler trace), the edge
+    accounting in a ``record_edges`` span inside it.  Everything happens
+    OUTSIDE the traced program — the wrapper
     calls the same jitted executable, so jit cache sizes and step
     outputs are bit-identical with ``BLUEFOG_OBSERVE`` on or off
     (asserted in tests/test_observe.py).  The span measures host
@@ -839,9 +871,13 @@ def _observed_step(step_fn: Callable, labels: dict,
         observe.get_registry().counter(
             "bf_train_steps_total", "train-step dispatches",
             **labels).inc()
-        if edge_traffic is not None:
-            record_edges(args)
         with tr.span("train", "train_step"):
+            if edge_traffic is not None:
+                # a span of its own: with a device scalar for ``step``
+                # the accounting costs one device-to-host read a
+                # dispatch, which a trace can now show
+                with tr.span("train", "record_edges"):
+                    record_edges(args)
             return step_fn(*args, **kwargs)
 
     return step
@@ -1031,7 +1067,7 @@ def _build_fused_train_step(
                 g = list(b.leaves)
                 fresh = list(leaves)
                 for i in g:
-                    fresh[i] = optax.apply_updates(leaves[i],
+                    fresh[i] = _apply_updates(leaves[i],
                                                    upd_leaves[i])
                 pre = _pack_bucket(fresh, g)
                 out, upd = _bucket_exchange(pre, spec, key, b, w,
@@ -1101,6 +1137,7 @@ def _build_fused_train_step(
     ps_branches = [_fused_push_sum_branch(s) for s in specs] \
         if comm_mode == "push_sum" else []
 
+    @jax.named_scope(SCOPE_EXCHANGE)
     def fused_combine(params, step, comm_weights, mix_state):
         if not branches:
             return params, zero(), mix_state
@@ -1155,10 +1192,11 @@ def _build_fused_train_step(
             return (jax.tree_util.tree_unflatten(treedef, out), cons,
                     mix_state)
 
+    @jax.named_scope(SCOPE_EXCHANGE)
     def fused_apply_then_combine(params, updates, step, comm_weights,
                                  mix_state):
         if not ac_branches:
-            return (optax.apply_updates(params, updates), zero(),
+            return (_apply_updates(params, updates), zero(),
                     mix_state)
 
         def run(operand):
@@ -1182,11 +1220,12 @@ def _build_fused_train_step(
             # collectives (and their epilogue stages) are skipped
             return lax.cond(
                 step % k_comm == 0, run,
-                lambda op: (optax.apply_updates(op[0], op[1]), zero(),
+                lambda op: (_apply_updates(op[0], op[1]), zero(),
                             op[2]),
                 (params, updates, mix_state))
         return run((params, updates, mix_state))
 
+    @jax.named_scope(SCOPE_EXCHANGE)
     def fused_push_sum(params, ps, step):
         def run(operand):
             if len(ps_branches) == 1:
@@ -1222,21 +1261,20 @@ def _build_fused_train_step(
             # (guarded note: the allreduce mixes GRADIENTS, so one
             # rank's NaN reaches every rank — the guard skips globally;
             # the neighbor modes contain the blast radius)
-            grads = jax.tree.map(
-                lambda g: C.allreduce(g, axis_name, average=True), grads)
+            grads = _allreduce_grads(grads, axis_name)
         if comm_mode == "push_sum":
             base_state, ps = opt_state
             params, ps, cons = fused_push_sum(params, ps, step)
-            updates, base_state = optimizer.update(grads, base_state,
+            updates, base_state = _opt_update(optimizer, grads, base_state,
                                                    params)
-            params = optax.apply_updates(params, updates)
+            params = _apply_updates(params, updates)
             hv = _fused_health(loss, grad_sq, updates, groups, cons,
                                None) if want_health else None
             return params, new_aux, (base_state, ps), loss, None, hv
         if comm_mode == "cta":
             params, cons, mix_state = fused_combine(
                 params, step, comm_weights, mix_state)
-        updates, new_opt = optimizer.update(grads, opt_state, params)
+        updates, new_opt = _opt_update(optimizer, grads, opt_state, params)
         skipped = None
         if guarded:
             ok = _grouped_all_finite(
@@ -1248,7 +1286,7 @@ def _build_fused_train_step(
                 return jnp.where(ok, new, old)
 
             params = jax.tree.map(
-                pick, optax.apply_updates(params, updates), params)
+                pick, _apply_updates(params, updates), params)
             new_aux = jax.tree.map(pick, new_aux, aux)
             new_opt = jax.tree.map(pick, new_opt, opt_state)
             if comm_mode == "atc":
@@ -1260,7 +1298,7 @@ def _build_fused_train_step(
                 params, cons, mix_state = fused_apply_then_combine(
                     params, updates, step, comm_weights, mix_state)
             else:
-                params = optax.apply_updates(params, updates)
+                params = _apply_updates(params, updates)
                 if comm_mode == "atc":
                     params, cons, mix_state = fused_combine(
                         params, step, comm_weights, mix_state)
@@ -1917,6 +1955,7 @@ def build_train_step(
     ] if comm_mode == "push_sum" else []
     k_comm = int(num_steps_per_communication)
 
+    @jax.named_scope(SCOPE_EXCHANGE)
     def combine(params, step):
         if not branches:
             return params
@@ -1936,6 +1975,7 @@ def build_train_step(
             return lax.cond(step % k_comm == 0, run, lambda p: p, params)
         return run(params)
 
+    @jax.named_scope(SCOPE_EXCHANGE)
     def combine_push_sum(params, ps, step):
         def run(operand):
             params, ps = operand
@@ -1964,13 +2004,14 @@ def build_train_step(
                             (params, ps))
         return run((params, ps))
 
+    @jax.named_scope(SCOPE_EXCHANGE)
     def apply_then_combine(params, updates, step):
         """ATC overlap engine: the interleaved per-bucket apply+combine
         (see _bucketed_apply_combine_fn).  Off-cycle steps under
         num_steps_per_communication still apply the optax update —
         only the collectives are skipped (lax.cond, like combine())."""
         if not ac_branches:
-            return optax.apply_updates(params, updates)
+            return _apply_updates(params, updates)
 
         def run(operand):
             params, updates = operand
@@ -1983,7 +2024,7 @@ def build_train_step(
 
         if k_comm > 1:
             return lax.cond(step % k_comm == 0, run,
-                            lambda op: optax.apply_updates(op[0], op[1]),
+                            lambda op: _apply_updates(op[0], op[1]),
                             (params, updates))
         return run((params, updates))
 
@@ -1996,16 +2037,16 @@ def build_train_step(
         grad_sq = _tree_sq_sum(grads) if health is not None else None
         consensus = jnp.zeros((), jnp.float32)
         if comm_mode == "gradient_allreduce":
-            grads = jax.tree.map(
-                lambda g: C.allreduce(g, axis_name, average=True), grads)
+            grads = _allreduce_grads(grads, axis_name)
         if comm_mode == "push_sum":
             base_state, ps = opt_state
             pre = params
             params, ps = combine_push_sum(params, ps, step)
             if health is not None and health.consensus:
                 consensus = _tree_distance(pre, params)
-            updates, base_state = optimizer.update(grads, base_state, params)
-            params = optax.apply_updates(params, updates)
+            updates, base_state = _opt_update(
+                optimizer, grads, base_state, params)
+            params = _apply_updates(params, updates)
             hv = (_make_health_vector(loss, grad_sq, updates, consensus)
                   if health is not None else None)
             return params, new_aux, (base_state, ps), loss, hv
@@ -2014,17 +2055,17 @@ def build_train_step(
             params = combine(params, step)
             if health is not None and health.consensus:
                 consensus = _tree_distance(pre, params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
+        updates, opt_state = _opt_update(optimizer, grads, opt_state, params)
         if atc_bucketed:
             new_params = apply_then_combine(params, updates, step)
             if health is not None and health.consensus:
                 # the per-bucket applies inside apply_then_combine are
                 # the same pure arithmetic — XLA CSEs the duplicate
-                applied = optax.apply_updates(params, updates)
+                applied = _apply_updates(params, updates)
                 consensus = _tree_distance(applied, new_params)
             params = new_params
         else:
-            params = optax.apply_updates(params, updates)
+            params = _apply_updates(params, updates)
             if comm_mode == "atc":
                 pre = params
                 params = combine(params, step)
@@ -2153,6 +2194,7 @@ def _build_guarded_train_step(
         for s in specs
     ] if neighbor else []
 
+    @jax.named_scope(SCOPE_EXCHANGE)
     def combine(params, step, comm_weights):
         if not wbranches:
             return params
@@ -2183,14 +2225,14 @@ def _build_guarded_train_step(
             # reaches every rank's update — the guard then skips
             # globally (all ranks keep their state).  The neighbor
             # modes contain the blast radius to the faulty rank.
-            grads = jax.tree.map(
-                lambda g: C.allreduce(g, axis_name, average=True), grads)
+            grads = _allreduce_grads(grads, axis_name)
         if comm_mode == "cta":
             pre = params
             params = combine(params, step, comm_weights)
             if health is not None and health.consensus:
                 consensus = _tree_distance(pre, params)
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
+        updates, new_opt_state = _opt_update(
+            optimizer, grads, opt_state, params)
         ok = _all_finite(loss, updates)
 
         # The skip guard: a per-rank conditional over pure arithmetic
@@ -2208,7 +2250,7 @@ def _build_guarded_train_step(
         def pick(new, old):
             return jnp.where(ok, new, old)
 
-        params = jax.tree.map(pick, optax.apply_updates(params, updates),
+        params = jax.tree.map(pick, _apply_updates(params, updates),
                               params)
         out_aux = jax.tree.map(pick, new_aux, aux)
         out_opt = jax.tree.map(pick, new_opt_state, opt_state)

@@ -219,6 +219,7 @@ def _flash_fwd_impl(q, k, v, q_offset, kv_offset, *, causal, scale,
     grid = (b * h, t_q // block_q, t_k // block_k)
     out, lse = pl.pallas_call(
         functools.partial(_kernel, causal=causal, scale=scale, offs=offs),
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -393,6 +394,7 @@ def _flash_bwd_impl(q, k, v, out, lse, do, q_offset, kv_offset, *, causal,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
                           offs=offs),
+        name="flash_bwd_dq",
         grid=(b * h, t_q // block_q, t_k // block_k),
         in_specs=[smem, smem, q_spec,
                   pl.BlockSpec((1, block_k, d), kv_index),
@@ -424,6 +426,7 @@ def _flash_bwd_impl(q, k, v, out, lse, do, q_offset, kv_offset, *, causal,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
                           group=group, offs=offs),
+        name="flash_bwd_dkv",
         grid=(b * h_kv, t_k // block_k, (t_q // block_q) * group),
         in_specs=[smem, smem,
                   pl.BlockSpec((1, block_q, d), q_row),

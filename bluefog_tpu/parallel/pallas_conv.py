@@ -128,6 +128,7 @@ def conv1x1_backward(x2d: jax.Array, dy2d: jax.Array, w: jax.Array,
     grid = (n // tn,)
     dx, dw = pl.pallas_call(
         _bwd_kernel,
+        name="conv1x1_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((tn, ci), lambda i: (i, 0)),

@@ -190,6 +190,7 @@ def _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, *, block_s,
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=1.0 / d ** 0.5,
                           quantized=quantized, n_kv=n_kv),
+        name="decode_attn",
         grid=(b, s_len // block_s),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, n_q, d), lambda bk, sj: (bk, 0, 0)),

@@ -57,7 +57,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
-from functools import partial
+from functools import lru_cache, partial
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -149,6 +149,16 @@ class Request:
         self._cancel = False
         self._prefix_keys = None
         return self
+
+
+@lru_cache(maxsize=4096)
+def _rng_key(seed: int) -> np.ndarray:
+    """A request's sampling key as host words, made once a seed and not
+    once a slot a decode step: ``jax.random.PRNGKey`` is two tiny device
+    programs and the read back waits for both, 2 ms a slot on a TPU and
+    a time that differs from one process to the next (the traced
+    ``decode_inputs`` phase showed it).  Callers copy, never write."""
+    return np.asarray(jax.random.PRNGKey(seed))
 
 
 def _sample(logits, key, temp):
@@ -591,10 +601,53 @@ class ServingEngine:
     def step(self) -> bool:
         """One engine iteration: shed/cancel, admit + one prefill chunk,
         one decode step over all active slots.  Returns True while there
-        is live work (queued, prefilling, or decoding)."""
-        t_step = time.perf_counter()  # real wall time (the injected
-        # clock may be virtual) — feeds the fleet step-time view
+        is live work (queued, prefilling, or decoding).
+
+        The whole iteration is one ``step`` span on the ``engine`` track
+        with its phases inside — ``admit``, ``prefill_chunk``,
+        ``decode_inputs``, ``decode_dispatch``, ``token_fetch``,
+        ``emit`` (``bf.engine.*`` in a profiler trace): per step, never
+        per token or per slot."""
         now = self.clock()
+        with self.metrics.span("step") as step_span:
+            self._step_phases(now)
+        # the step's wall time is the span's own two stamps (real time:
+        # the injected clock may be virtual) — feeds the fleet
+        # step-time view
+        self.metrics.on_step(self.pool.occupancy(),
+                             self.scheduler.queue_depth,
+                             step_span.seconds, now=now)
+        return bool(self._running or self._admitting
+                    or self.scheduler.queue_depth)
+
+    def _step_phases(self, now: float) -> None:
+        span = self.metrics.span
+        # 1-4. shedding, cancellations, admission + chunked prefill,
+        #      bounded by the per-step chunk budget (prefill work is
+        #      what stalls running decodes, so IT is what gets budgeted
+        #      — not admissions).  With the default budget of one the
+        #      admit span runs once; a larger budget admits again after
+        #      a prefill that finished inside the step.
+        with span("admit") as admit_span:
+            self._shed(now)
+            admit_span.set(admitted=self._admit(now))
+        chunks = 0
+        while self._admitting is not None and chunks < self.prefill_budget:
+            self._prefill_one_chunk(self._admitting)
+            chunks += 1
+            if self._admitting is None and chunks < self.prefill_budget:
+                with span("admit") as admit_span:
+                    admit_span.set(admitted=self._admit(now))
+        # 5. one decode token for every active slot
+        decoding = {s: r for s, r in self._running.items()
+                    if r.state == DECODE}
+        if decoding:
+            if self._spec is not None:
+                self._spec_decode_step(decoding)
+            else:
+                self._decode_step(decoding)
+
+    def _shed(self, now: float) -> None:
         # 1. deadline shedding in the queue (zero device cost)
         for req in self.scheduler.expire(now):
             req.state = CANCELLED
@@ -608,61 +661,45 @@ class ServingEngine:
             if req._cancel or (req.deadline is not None
                                and now >= req.deadline):
                 self._retire(req, CANCELLED, now)
-        # 3+4. admission + chunked prefill, bounded by the per-step
-        #      chunk budget (prefill work is what stalls running
-        #      decodes, so IT is what gets budgeted — not admissions)
-        chunks = 0
-        while chunks < self.prefill_budget:
-            if self._admitting is None:
-                if self._draining:
-                    break  # drain(): the current prefill finishes, but
-                    # nothing new leaves the queue
-                if self.pool.n_free == 0:
-                    break
-                req = self.scheduler.admit(now)
-                if req is None:
-                    break
-                req.slot = self.pool.alloc()
-                if self._draft_pool is not None:
-                    dslot = self._draft_pool.alloc()
-                    assert dslot == req.slot, (dslot, req.slot)
-                self.metrics.on_admit(req.rid, now)
-                # a failed-over request resumes with emitted tokens: its
-                # prefill region is (prompt ‖ tokens)[:-1] — the same
-                # chunk grid the original prefill stashed, so the replay
-                # restores cached chunks and computes only the tail
-                n_ctx = req.prompt.size + len(req.tokens)
-                if n_ctx > 1:
-                    self._restore_prefix(req)  # no-op without the cache
-                    if req._prefill_pos >= n_ctx - 1:
-                        # the whole prefill region came out of the
-                        # prefix cache — straight to decode, zero
-                        # prefill compute spent
-                        req.state = DECODE
-                        self._running[req.slot] = req
-                        continue
+
+    def _admit(self, now: float) -> int:
+        """Give slots to queued requests until one needs a prefill (it
+        becomes ``_admitting``) or none can be admitted.  Returns the
+        number admitted."""
+        admitted = 0
+        while self._admitting is None:
+            if self._draining:
+                break  # drain(): the current prefill finishes, but
+                # nothing new leaves the queue
+            if self.pool.n_free == 0:
+                break
+            req = self.scheduler.admit(now)
+            if req is None:
+                break
+            req.slot = self.pool.alloc()
+            if self._draft_pool is not None:
+                dslot = self._draft_pool.alloc()
+                assert dslot == req.slot, (dslot, req.slot)
+            self.metrics.on_admit(req.rid, now)
+            admitted += 1
+            # a failed-over request resumes with emitted tokens: its
+            # prefill region is (prompt ‖ tokens)[:-1] — the same
+            # chunk grid the original prefill stashed, so the replay
+            # restores cached chunks and computes only the tail
+            n_ctx = req.prompt.size + len(req.tokens)
+            if n_ctx > 1:
+                self._restore_prefix(req)  # no-op without the cache
+                if req._prefill_pos < n_ctx - 1:
                     req.state = PREFILL
                     self._admitting = req
-                else:  # single-token prompt: nothing to prefill — the
-                    # decode step consumes the whole prompt directly
-                    req.state = DECODE
-                    self._running[req.slot] = req
-                    continue
-            self._prefill_one_chunk(self._admitting)
-            chunks += 1
-        # 5. one decode token for every active slot
-        decoding = {s: r for s, r in self._running.items()
-                    if r.state == DECODE}
-        if decoding:
-            if self._spec is not None:
-                self._spec_decode_step(decoding)
-            else:
-                self._decode_step(decoding)
-        self.metrics.on_step(self.pool.occupancy(),
-                             self.scheduler.queue_depth,
-                             time.perf_counter() - t_step, now=now)
-        return bool(self._running or self._admitting
-                    or self.scheduler.queue_depth)
+                    break
+                # the whole prefill region came out of the prefix cache
+                # — straight to decode, zero prefill compute spent
+            # (a single-token prompt has nothing to prefill either: the
+            # decode step consumes the whole prompt directly)
+            req.state = DECODE
+            self._running[req.slot] = req
+        return admitted
 
     def run(self, max_steps: int = 100_000) -> None:
         """Drive :meth:`step` until idle (drain the queue and every
@@ -864,32 +901,35 @@ class ServingEngine:
         # split the one-shot path computes inside one big call)
         c = self.prefill_chunk
         pos = req._prefill_pos
-        ctx = self._context(req)
-        n_prefill = ctx.size - 1
-        valid = min(c, n_prefill - pos)
-        chunk = np.zeros((1, c), np.int32)
-        chunk[0, :valid] = ctx[pos:pos + valid]
-        chunk = jnp.asarray(chunk)
-        self.pool.cache = _prefill_chunk_prog(
-            self._params, self.pool.cache, jnp.int32(req.slot),
-            chunk, jnp.int32(valid), cfg=self.cfg)
-        if self._draft_pool is not None:
-            # the draft model needs the SAME context in its own cache;
-            # its chunk rides the target's budget slot (one admission
-            # unit of work, two trees)
-            self._draft_pool.cache = _prefill_chunk_prog(
-                self._draft_params, self._draft_pool.cache,
-                jnp.int32(req.slot), chunk, jnp.int32(valid),
-                cfg=self.draft_cfg)
-        self.metrics.on_prefill_chunk()
-        if (valid == c and req._prefix_keys
-                and pos // c < len(req._prefix_keys)):
-            # a FULL cold chunk just landed on the chunk grid — stash
-            # its K/V while it provably matches the chain hash
-            key = req._prefix_keys[pos // c]
-            self.pool.stash_chunk(req.slot, key, pos)
+        with self.metrics.span("prefill_chunk", rid=req.rid,
+                               slot=req.slot) as chunk_span:
+            ctx = self._context(req)
+            n_prefill = ctx.size - 1
+            valid = min(c, n_prefill - pos)
+            chunk_span.set(tokens=int(valid))
+            chunk = np.zeros((1, c), np.int32)
+            chunk[0, :valid] = ctx[pos:pos + valid]
+            chunk = jnp.asarray(chunk)
+            self.pool.cache = _prefill_chunk_prog(
+                self._params, self.pool.cache, jnp.int32(req.slot),
+                chunk, jnp.int32(valid), cfg=self.cfg)
             if self._draft_pool is not None:
-                self._draft_pool.stash_chunk(req.slot, key, pos)
+                # the draft model needs the SAME context in its own
+                # cache; its chunk rides the target's budget slot (one
+                # admission unit of work, two trees)
+                self._draft_pool.cache = _prefill_chunk_prog(
+                    self._draft_params, self._draft_pool.cache,
+                    jnp.int32(req.slot), chunk, jnp.int32(valid),
+                    cfg=self.draft_cfg)
+            self.metrics.on_prefill_chunk(int(valid))
+            if (valid == c and req._prefix_keys
+                    and pos // c < len(req._prefix_keys)):
+                # a FULL cold chunk just landed on the chunk grid —
+                # stash its K/V while it provably matches the chain hash
+                key = req._prefix_keys[pos // c]
+                self.pool.stash_chunk(req.slot, key, pos)
+                if self._draft_pool is not None:
+                    self._draft_pool.stash_chunk(req.slot, key, pos)
         req._prefill_pos = pos + valid
         if req._prefill_pos < n_prefill:
             return  # more chunks to go; decodes keep running meanwhile
@@ -897,7 +937,9 @@ class ServingEngine:
         self._running[req.slot] = req
         req.state = DECODE
 
-    def _decode_step(self, decoding: Dict[int, Request]) -> None:
+    def _decode_inputs(self, decoding: Dict[int, Request]) -> tuple:
+        """The decode programs' per-slot operands, on the device:
+        (tokens, active, rng keys, token counts, temperatures)."""
         cap = self.pool.capacity
         toks = np.zeros((cap,), np.int32)
         active = np.zeros((cap,), bool)
@@ -910,71 +952,71 @@ class ServingEngine:
             # afterwards the request's own stream feeds back
             toks[slot] = req.tokens[-1] if req.tokens else req.prompt[-1]
             active[slot] = True
-            keys[slot] = np.asarray(jax.random.PRNGKey(req.seed))
+            keys[slot] = _rng_key(req.seed)
             counts[slot] = len(req.tokens)
             temps[slot] = req.temperature
-        self.pool.cache, hist = _decode_step_prog(
-            self._params, self.pool.cache, jnp.asarray(toks),
-            jnp.asarray(active), jnp.asarray(keys), jnp.asarray(counts),
-            jnp.asarray(temps), cfg=self.cfg,
-            horizon=self.decode_horizon)
-        hist = np.asarray(hist)  # the per-step host sync: tokens stream
-        now = self.clock()
-        for slot, req in decoding.items():
-            for j in range(self.decode_horizon):
-                first = not req.tokens
-                req.tokens.append(int(hist[j, slot]))
-                if first:
-                    self.metrics.on_first_token(req.rid, now)
-                else:
-                    self.metrics.on_token(req.rid, now)
-                if self._maybe_finish(req):
-                    break  # surplus horizon tokens for a retired slot
-                    # are discarded (its cache is zeroed on free)
+        return (jnp.asarray(toks), jnp.asarray(active), jnp.asarray(keys),
+                jnp.asarray(counts), jnp.asarray(temps))
+
+    def _emit(self, decoding: Dict[int, Request], tokens_of) -> int:
+        """The per-token loop of a decode step: append each slot's run
+        (``tokens_of(slot)``), publish, finish and retire.  A run stops
+        at a retirement: surplus horizon or accepted tokens for a
+        retired slot are discarded (its cache index is reset on free, so
+        their cache writes are unobservable).  Returns the tokens
+        emitted."""
+        with self.metrics.span("emit") as emit_span:
+            now = self.clock()
+            emitted = 0
+            for slot, req in decoding.items():
+                for token in tokens_of(slot):
+                    first = not req.tokens
+                    req.tokens.append(int(token))
+                    emitted += 1
+                    if first:
+                        self.metrics.on_first_token(req.rid, now)
+                    else:
+                        self.metrics.on_token(req.rid, now)
+                    if self._maybe_finish(req):
+                        break
+            emit_span.set(tokens=emitted)
+        return emitted
+
+    def _decode_step(self, decoding: Dict[int, Request]) -> None:
+        span = self.metrics.span
+        with span("decode_inputs", slots=len(decoding)):
+            operands = self._decode_inputs(decoding)
+        with span("decode_dispatch"):
+            self.pool.cache, hist = _decode_step_prog(
+                self._params, self.pool.cache, *operands, cfg=self.cfg,
+                horizon=self.decode_horizon)
+        with span("token_fetch"):
+            hist = np.asarray(hist)  # [horizon, cap] — the per-step host
+            # sync: tokens stream
+        self._emit(decoding, lambda slot: hist[:, slot])
+        self.metrics.on_decode_step(len(decoding))
 
     def _spec_decode_step(self, decoding: Dict[int, Request]) -> None:
         """The speculative twin of :meth:`_decode_step`: one resident
         draft/verify program advances every active slot by 1 to
         ``lookahead+1`` tokens.  The host appends each slot's emitted
-        run with the same EOS/budget truncation the plain path applies —
-        surplus accepted tokens past a retirement are discarded (the
-        freed slot's index reset makes their cache writes
-        unobservable)."""
-        cap = self.pool.capacity
-        toks = np.zeros((cap,), np.int32)
-        active = np.zeros((cap,), bool)
-        keys = np.zeros((cap, 2), np.uint32)
-        counts = np.zeros((cap,), np.int32)
-        temps = np.zeros((cap,), np.float32)
-        for slot, req in decoding.items():
-            toks[slot] = req.tokens[-1] if req.tokens else req.prompt[-1]
-            active[slot] = True
-            keys[slot] = np.asarray(jax.random.PRNGKey(req.seed))
-            counts[slot] = len(req.tokens)
-            temps[slot] = req.temperature
-        (self.pool.cache, self._draft_pool.cache, hist,
-         n_emit) = _spec_step_prog(
-            self._params, self._draft_params, self.pool.cache,
-            self._draft_pool.cache, jnp.asarray(toks),
-            jnp.asarray(active), jnp.asarray(keys), jnp.asarray(counts),
-            jnp.asarray(temps), cfg_t=self.cfg, cfg_d=self.draft_cfg,
-            k=self._spec.lookahead)
-        hist = np.asarray(hist)      # [cap, lookahead+1]
-        n_emit = np.asarray(n_emit)  # [cap]
-        now = self.clock()
-        emitted = 0
-        for slot, req in decoding.items():
-            for j in range(int(n_emit[slot])):
-                first = not req.tokens
-                req.tokens.append(int(hist[slot, j]))
-                emitted += 1
-                if first:
-                    self.metrics.on_first_token(req.rid, now)
-                else:
-                    self.metrics.on_token(req.rid, now)
-                if self._maybe_finish(req):
-                    break  # surplus accepted tokens for a retired slot
-                    # are discarded (index reset on free)
+        run with the same EOS/budget truncation the plain path
+        applies."""
+        span = self.metrics.span
+        with span("decode_inputs", slots=len(decoding)):
+            operands = self._decode_inputs(decoding)
+        with span("decode_dispatch"):
+            (self.pool.cache, self._draft_pool.cache, hist,
+             n_emit) = _spec_step_prog(
+                self._params, self._draft_params, self.pool.cache,
+                self._draft_pool.cache, *operands, cfg_t=self.cfg,
+                cfg_d=self.draft_cfg, k=self._spec.lookahead)
+        with span("token_fetch"):
+            hist = np.asarray(hist)      # [cap, lookahead+1]
+            n_emit = np.asarray(n_emit)  # [cap]
+        emitted = self._emit(
+            decoding, lambda slot: hist[slot, :int(n_emit[slot])])
+        self.metrics.on_decode_step(len(decoding))
         self.metrics.on_spec_step(len(decoding), emitted)
 
     def _maybe_finish(self, req: Request) -> bool:

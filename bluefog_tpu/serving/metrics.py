@@ -23,7 +23,11 @@ Everything is published twice, through the unified observability layer
   request (``admission -> prefill -> decode -> retire``), which the
   Chrome-trace timeline exports when started: load a timeline in
   chrome://tracing and the continuous-batching interleaving is visible
-  directly — staggered prefills riding between decode steps.
+  directly — staggered prefills riding between decode steps — and one
+  ``engine`` track with a ``step`` span per :meth:`ServingEngine.step`
+  and its phases inside (:meth:`ServingMetrics.span`), which
+  ``Tracer.span`` also writes into the profiler's trace as
+  ``bf.engine.*``.
 
 ``summary()`` keeps its original dict shape (the operator dashboard the
 serving tests and bench consume); ``BLUEFOG_OBSERVE=0`` stops the
@@ -46,6 +50,9 @@ from bluefog_tpu.observe.registry import percentile  # noqa: F401  (moved
 # to observe/registry.py; re-exported here for backward compatibility)
 
 __all__ = ["ServingMetrics", "percentile"]
+
+#: the track of the engine's own spans (``bf.engine.*`` in a profile)
+ENGINE_TRACK = "engine"
 
 
 class _RequestRecord:
@@ -99,6 +106,14 @@ class ServingMetrics:
     def _tracer(self):
         return obs_tracer.effective_tracer(timeline_mod.get_timeline())
 
+    def span(self, name: str, **args):
+        """A span of the engine's own work (``step`` and its phases) on
+        the ``engine`` track; yields the tracer's ``Span``."""
+        # with no tracer (observe off, no timeline) the Span records
+        # nothing and only takes its two stamps: the step's wall time
+        # still feeds an explicit registry
+        return obs_tracer.Span(self._tracer(), ENGINE_TRACK, name, args)
+
     def _span(self, rid, activity: Optional[str]):
         """Close the request's open span and (unless retiring) open the
         next lifecycle phase on its per-request track — on the tracer
@@ -131,8 +146,13 @@ class ServingMetrics:
                         "requests refused (backpressure or too long)").inc()
 
     def on_admit(self, rid, now: float):
-        self._req[rid].admit_t = now
+        rec = self._req[rid]
+        rec.admit_t = now
         self._span(rid, "prefill")
+        reg = self._reg()
+        if reg is not None:
+            reg.histogram("bf_serving_queue_wait_seconds",
+                          "submit -> slot").observe(now - rec.submit_t)
 
     def on_first_token(self, rid, now: float):
         rec = self._req[rid]
@@ -184,15 +204,31 @@ class ServingMetrics:
             reg.counter("bf_serving_failovers_total",
                         "requests handed off to another replica").inc()
 
-    def on_prefill_chunk(self):
-        """One cold prefill chunk ran (a model forward over one chunk).
-        Together with :meth:`on_prefix_restore` this splits prompt
-        coverage into compute vs copy."""
+    def on_prefill_chunk(self, n_tokens: int):
+        """One cold prefill chunk ran (a model forward over one chunk)
+        with ``n_tokens`` valid positions; the rest of the chunk's
+        width was padding.  Together with :meth:`on_prefix_restore`
+        this splits prompt coverage into compute vs copy."""
         self.n_prefill_chunks += 1
         reg = self._reg()
         if reg is not None:
             reg.counter("bf_serving_prefill_chunks_total",
                         "cold prefill chunks computed").inc()
+            reg.counter("bf_serving_prefill_tokens_total",
+                        "valid positions in cold prefill chunks"
+                        ).inc(n_tokens)
+
+    def on_decode_step(self, n_slots: int):
+        """One decode program call (plain or speculative) advanced
+        ``n_slots`` active slots: slots / steps is the batch size a
+        decode step."""
+        reg = self._reg()
+        if reg is not None:
+            reg.counter("bf_serving_decode_steps_total",
+                        "decode program calls").inc()
+            reg.counter("bf_serving_decode_slots_total",
+                        "active slots summed over decode program calls"
+                        ).inc(n_slots)
 
     def on_prefix_restore(self, rid, n_chunks: int, n_tokens: int):
         """``n_chunks`` cached K/V chunks (``n_tokens`` prompt tokens)
@@ -216,23 +252,15 @@ class ServingMetrics:
 
     def on_spec_step(self, n_active: int, n_emitted: int):
         """One speculative decode step over ``n_active`` slots emitted
-        ``n_emitted`` tokens total (per-token accounting still flows
-        through ``on_first_token``/``on_token``; this records the
-        accepted-tokens-per-step ratio speculation is judged by)."""
+        ``n_emitted`` tokens total: the accepted-tokens-per-step ratio
+        speculation is judged by, in :meth:`summary`.  The registry has
+        it already — per-token accounting flows through
+        ``on_first_token``/``on_token`` and the step's slots through
+        :meth:`on_decode_step`, so it is ``bf_serving_tokens_total`` over
+        ``bf_serving_decode_slots_total``."""
         self.n_spec_steps += 1
         self.n_spec_active += n_active
         self.n_spec_emitted += n_emitted
-        reg = self._reg()
-        if reg is not None:
-            reg.counter("bf_serving_spec_steps_total",
-                        "speculative decode steps").inc()
-            reg.counter("bf_serving_spec_emitted_total",
-                        "tokens emitted by speculative steps"
-                        ).inc(n_emitted)
-            if n_active:
-                reg.gauge("bf_serving_spec_accepted_per_step",
-                          "tokens emitted per active slot, last step"
-                          ).set(n_emitted / n_active)
 
     def on_step(self, occupancy: float, queue_depth: int,
                 step_seconds: Optional[float] = None,

@@ -87,6 +87,8 @@ MODULES = [
      "span tracer: nested spans, instants, per-thread tracks"),
     ("bluefog_tpu.observe.stepprof",
      "HLO-attributed step profiler (profile_step / StepProfile)"),
+    ("bluefog_tpu.observe.compiles",
+     "compile accounting: one jax.monitoring listener -> counters + instants"),
     ("bluefog_tpu.observe.export",
      "exporters: Prometheus text, JSONL events, Chrome trace, snapshot"),
     ("bluefog_tpu.observe.fleet",

@@ -1,0 +1,14 @@
+"""Host time of the engine's ``emit`` phase (the per-token loop: append, metrics, finish and retire)
+summed within one ``bf.engine.step``, median over the steps of the
+traced stretch in which it ran; the reader prints every phase, and the
+step's self time (what no phase covers)."""
+
+from perfbench.harness import program_trace as pt
+
+PHASE = "emit"
+
+
+def reduce(trace, spans, ctx):
+    if not pt.on_chip():
+        return None
+    return pt.engine_phase_ms(__file__, trace, PHASE)

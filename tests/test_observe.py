@@ -164,6 +164,86 @@ def test_tracer_ring_buffer_bounds_memory():
     assert tr.events()[0][1] == "e12"  # oldest fell off first
 
 
+def test_span_arguments_ride_the_ring_and_the_chrome_trace():
+    """``span(**args)`` / ``Span.set`` / ``instant(**args)`` keep their
+    arguments as the event's fifth field and ``chrome_events`` writes
+    them as ``args``; a span's two stamps give its duration; the sink
+    protocol is unchanged (name, tid, phase)."""
+    t = [0.0]
+    tr = Tracer(clock=lambda: t[0])
+    seen = []
+
+    class Sink:
+        def record(self, name, tid, phase):
+            seen.append((name, tid, phase))
+
+    tr.add_sink(Sink())
+    with tr.span("engine", "step", rid=7) as sp:
+        t[0] = 0.25
+        sp.set(tokens=3)
+    tr.instant("compile.f", "compile", cause="test")
+    tr.begin("request.1", "admission")
+    tr.end("request.1")
+    assert sp.seconds == pytest.approx(0.25)
+    ev = tr.events()
+    assert [e[0] for e in ev] == ["B", "E", "i", "B", "E"]
+    assert ev[0][4] == {"rid": 7, "tokens": 3} and not ev[1][4]
+    assert ev[2][4] == {"cause": "test"} and not ev[3][4]
+    chrome = tr.to_chrome_trace()
+    assert chrome[0]["args"] == {"rid": 7, "tokens": 3}
+    assert chrome[2]["args"] == {"cause": "test"}
+    assert "args" not in chrome[1] and "args" not in chrome[3]
+    assert seen[0] == ("step", "engine", "B") and len(seen) == 5
+    assert '"args": {"rid": 7, "tokens": 3}' in \
+        observe.jsonl_events(tr).splitlines()[0]
+
+
+def test_spans_lie_in_the_profilers_trace_and_begin_end_do_not(tmp_path):
+    """``Tracer.span`` run under ``jax.profiler.start_trace`` leaves
+    ``bf.<track>.<name>`` events on the host plane of the xplane,
+    nested as entered and with their arguments; ``begin``/``end`` pairs
+    (the per-request tracks, the eager ops' handles) leave none."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tr.span("engine", "step"):
+            with tr.span("engine", "admit") as sp:
+                sp.set(admitted=2)
+            with tr.span("engine", "prefill_chunk", rid=5, slot=1):
+                pass
+        tr.begin("request.5", "admission")
+        tr.end("request.5")
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if "admission" in e.name:
+                    found[e.name] = None
+                if e.name.startswith("bf."):
+                    assert plane.name.startswith("/host:")
+                    found[e.name] = (e.start_ns,
+                                     e.start_ns + e.duration_ns,
+                                     dict(e.stats))
+    assert sorted(found) == ["bf.engine.admit", "bf.engine.prefill_chunk",
+                             "bf.engine.step"]
+    s0, s1, _ = found["bf.engine.step"]
+    a0, a1, a_args = found["bf.engine.admit"]
+    c0, c1, c_args = found["bf.engine.prefill_chunk"]
+    assert s0 <= a0 <= a1 <= c0 <= c1 <= s1
+    assert a_args == {"admitted": 2} and c_args == {"rid": 5, "slot": 1}
+
+
 def test_chrome_trace_round_trip(tmp_path):
     """Spans published through a tracer stream to the timeline file
     sink AND serialize identically from the in-memory buffer — the
@@ -412,16 +492,77 @@ def test_profile_step_reproduces_overlap_accounting():
         acc["bytes_total"]
 
 
-def test_observe_toggle_leaves_compiled_programs_untouched(monkeypatch):
+def _scoped_step(mesh, kind):
+    """One step of each builder ``build_train_step`` can return, all of
+    which carry the ``bf.*`` named scopes: ``(step, args)``."""
+    from bluefog_tpu.optim import functional as F
+    from bluefog_tpu.topology.dynamic import one_peer_dynamic_schedule
+
+    if kind == "bucketed":
+        step, params, ostate, batch = _bucketed_step(mesh)
+        return step, (params, ostate, batch)
+    base = {"w": jnp.eye(16) * 0.5, "v": jnp.ones((16, 4)) * 0.1}
+
+    def loss_fn(params, batch):
+        return jnp.mean((jnp.tanh(batch @ params["w"]) @ params["v"]) ** 2)
+
+    opt = optax.adamw(1e-2)
+    kw = {"none": dict(comm_mode="none"),
+          "atc": dict(comm_mode="atc",
+                      schedule=one_peer_dynamic_schedule(N)),
+          "guarded": dict(comm_mode="atc",
+                          schedule=one_peer_dynamic_schedule(N),
+                          guard=F.GuardConfig())}[kind]
+    step = F.build_train_step(loss_fn, opt, mesh, donate=False, **kw)
+    params = F.rank_major(base, mesh)
+    ostate = F.rank_major(opt.init(base), mesh)
+    batch = jax.device_put(np.ones((N, 8, 16), np.float32) * 0.3,
+                           NamedSharding(mesh, P("bf")))
+    return step, (params, ostate, batch)
+
+
+def _step_args(step, args, i):
+    out = args + (jnp.int32(i),)
+    if hasattr(step, "guard_config"):   # a guarded step takes weights
+        out = out + (step.default_comm_weights,)
+    return out
+
+
+def _stripped_hlo(step, args):
+    """The optimized HLO of the step with what a named scope may change
+    taken out: every ``metadata={...}`` and the tables of file and
+    function names that the stack frames refer to."""
+    import re
+
+    text = step.lower(*_step_args(step, args, 0)).compile().as_text()
+    out, skip = [], False
+    for line in text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            skip = True
+        elif skip:
+            skip = line != ""
+        else:
+            out.append(re.sub(r", metadata=\{[^}]*\}", "", line))
+    return text, "\n".join(out)
+
+
+@pytest.mark.parametrize("kind", ["bucketed", "none", "atc", "guarded"])
+def test_observe_toggle_leaves_compiled_programs_untouched(monkeypatch,
+                                                           kind):
     """Acceptance: identical jit cache sizes and bit-identical
-    train-step outputs with BLUEFOG_OBSERVE on vs off."""
+    train-step outputs with BLUEFOG_OBSERVE on vs off, for every
+    builder that carries the named scopes; and two builds of one step
+    give the same optimized HLO once metadata is stripped (a scope is
+    metadata and nothing else)."""
     mesh = Mesh(np.array(jax.devices()[:N]), ("bf",))
-    step, params0, ostate0, batch = _bucketed_step(mesh)
+    step, args = _scoped_step(mesh, kind)
 
     def run3():
-        p, o = params0, ostate0
+        p, o = args[0], args[1]
         for i in range(3):
-            p, o, loss = step(p, o, batch, jnp.int32(i))
+            out = step(*_step_args(step, (p, o) + args[2:], i))
+            p, o, loss = out[0], out[1], out[2]
         return p, loss
 
     monkeypatch.setenv("BLUEFOG_OBSERVE", "1")
@@ -434,6 +575,36 @@ def test_observe_toggle_leaves_compiled_programs_untouched(monkeypatch):
                                   np.asarray(loss_off))
     for a, b in zip(jax.tree.leaves(p_on), jax.tree.leaves(p_off)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    again, args2 = _scoped_step(mesh, kind)
+    assert _stripped_hlo(step, args)[1] == _stripped_hlo(again, args2)[1]
+
+
+@pytest.mark.parametrize("kind,scopes", [
+    ("none", ("bf.forward_backward", "bf.optimizer")),
+    ("atc", ("bf.forward_backward", "bf.optimizer", "bf.exchange")),
+    ("bucketed", ("bf.forward_backward", "bf.optimizer", "bf.exchange")),
+    ("guarded", ("bf.forward_backward", "bf.optimizer", "bf.exchange")),
+])
+def test_named_scopes_show_in_compiled_op_names(kind, scopes):
+    """The parts of the train step are named in the compiled program's
+    ``op_name`` metadata — inside the ``lax.switch`` branches of the
+    exchange too — and JAX's own ``transpose(jvp(...))`` below
+    ``bf.forward_backward`` splits forward from backward."""
+    import re
+
+    mesh = Mesh(np.array(jax.devices()[:N]), ("bf",))
+    step, args = _scoped_step(mesh, kind)
+    names = set(re.findall(r'op_name="([^"]*)"',
+                           _stripped_hlo(step, args)[0]))
+    for scope in scopes:
+        assert any(f"/{scope}/" in n for n in names), (scope, kind)
+    if "bf.exchange" not in scopes:
+        assert not any("bf.exchange" in n for n in names)
+    elif kind != "bucketed":    # two rounds: a switch over two branches
+        assert any(re.search(r"bf\.exchange/.*branch_1_fun/.*ppermute", n)
+                   for n in names)
+    assert any("bf.forward_backward/transpose(jvp(" in n for n in names)
+    assert any("bf.forward_backward/jvp(" in n for n in names)
 
 
 def test_train_step_publishes_and_opt_out(monkeypatch):

@@ -187,6 +187,18 @@ def test_temperature_sampling_deterministic_and_in_range():
     assert np.all((a[1] >= 0) & (a[1] < 256))
 
 
+@pytest.mark.parametrize("seed", [0, 5, 2**31 + 7])
+def test_the_kept_sampling_key_is_the_one_prngkey_makes(seed):
+    """``_rng_key`` keeps a seed's key on the host, made once a seed
+    and not once a slot a decode step: the same words ``PRNGKey`` gives,
+    and one array however often it is asked for."""
+    from bluefog_tpu.serving.engine import _rng_key
+
+    np.testing.assert_array_equal(
+        _rng_key(seed), np.asarray(jax.random.PRNGKey(seed)))
+    assert _rng_key(seed) is _rng_key(seed)
+
+
 def test_deadline_cancels_running_and_queued():
     cfg, variables = _setup()
     clock = VirtualClock()
@@ -401,6 +413,180 @@ def test_metrics_and_timeline_spans(tmp_path):
     tracks = {e.get("tid") for e in events}
     for r in reqs:
         assert f"request.{r.rid}" in tracks
+
+
+def _engine_spans(events):
+    """``[(name, begin_us, end_us, args)]`` of the ``engine`` track's
+    spans, from the tracer's B/E events, in the order they began."""
+    out, stack = [], []
+    for phase, name, track, ts, args in events:
+        if track != "engine":
+            continue
+        if phase == "B":
+            stack.append(len(out))
+            out.append([name, ts, None, args])
+        elif phase == "E":
+            out[stack.pop()][2] = ts
+    assert not stack
+    return out
+
+
+PHASE_ORDER = ["admit", "prefill_chunk", "decode_inputs",
+               "decode_dispatch", "token_fetch", "emit"]
+
+
+@pytest.mark.parametrize("budget", [1, 2])
+def test_every_engine_step_holds_its_phases_in_order(budget):
+    """One ``step`` span a call on the ``engine`` track, its phases
+    inside it in the order of the work, each at most once a step with
+    the default prefill budget (a larger budget may admit again after a
+    prefill that ended inside the step), durations summing to no more
+    than the step's; ``prefill_chunk`` names the request it worked
+    for."""
+    from bluefog_tpu import observe
+
+    cfg, variables = _setup()
+    eng = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
+                        prefill_chunk=4, prefill_budget=budget)
+    tracer = observe.get_tracer()
+    tracer.clear()
+    reqs = [Request(p, b) for p, b in
+            zip(_prompts((11, 2, 7, 1), seed=12), (4, 6, 2, 3))]
+    eng.submit(reqs[0])
+    eng.step()
+    for r in reqs[1:]:
+        eng.submit(r)
+    n_steps = 1
+    while eng.step():
+        n_steps += 1
+    n_steps += 1
+    spans = _engine_spans(tracer.events())
+    steps = [sp for sp in spans if sp[0] == "step"]
+    assert len(steps) == n_steps
+    chunk_rids, emitted, admitted = set(), 0, 0
+    for i, (_, s0, s1, _) in enumerate(steps):
+        nxt = steps[i + 1][1] if i + 1 < len(steps) else float("inf")
+        held = [sp for sp in spans
+                if sp[0] != "step" and s0 <= sp[1] < nxt]
+        assert all(s0 <= sp[1] <= sp[2] <= s1 for sp in held)
+        assert all(a[2] <= b[1] for a, b in zip(held, held[1:]))
+        names = [sp[0] for sp in held]
+        assert names[0] == "admit"
+        decode = [n for n in names if n not in ("admit", "prefill_chunk")]
+        assert decode in ([], PHASE_ORDER[2:])
+        before = names[:len(names) - len(decode)]
+        assert set(before) <= {"admit", "prefill_chunk"}
+        assert before.count("prefill_chunk") <= budget
+        if budget == 1:
+            assert before in (["admit"], ["admit", "prefill_chunk"])
+        assert sum(sp[2] - sp[1] for sp in held) <= s1 - s0
+        for name, _, _, args in held:
+            if name == "prefill_chunk":
+                chunk_rids.add(args["rid"])
+                assert 1 <= args["tokens"] <= 4 and args["slot"] in (0, 1)
+            elif name == "emit":
+                emitted += args["tokens"]
+            elif name == "admit":
+                admitted += args["admitted"]
+            elif name == "decode_inputs":
+                assert 1 <= args["slots"] <= 2
+    assert chunk_rids == {r.rid for r in reqs if r.prompt.size > 1}
+    assert emitted == sum(len(r.tokens) for r in reqs) == 15
+    assert admitted == len(reqs)
+
+
+def test_engine_counters_equal_what_the_requests_imply():
+    """The counters at the phase boundaries: decode slots summed over
+    decode steps are the tokens generated (horizon 1), valid prefill
+    positions are every prompt less its last token, chunks are their
+    ceilings, and every admitted request gave one queue-wait sample
+    (``admit_t - submit_t`` on the engine's clock)."""
+    from bluefog_tpu.observe import MetricsRegistry
+
+    cfg, variables = _setup()
+    clock = VirtualClock()
+    reg = MetricsRegistry()
+    eng = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
+                        prefill_chunk=4, clock=clock, registry=reg)
+    sizes, budgets = (11, 2, 7, 1, 6), (4, 6, 2, 3, 5)
+    reqs = [eng.submit(Request(p, b))
+            for p, b in zip(_prompts(sizes, seed=13), budgets)]
+    n_steps = 1
+    while eng.step():
+        clock.advance(0.5)
+        n_steps += 1
+    snap = reg.snapshot()
+
+    def value(name):
+        return snap[name][0]["value"]
+
+    assert all(r.state == "completed" for r in reqs)
+    assert value("bf_serving_decode_slots_total") == sum(budgets)
+    steps = value("bf_serving_decode_steps_total")
+    assert 0 < steps <= n_steps
+    # two slots, so between one and two tokens a decode step
+    assert sum(budgets) / 2 <= steps <= sum(budgets)
+    assert value("bf_serving_prefill_tokens_total") == \
+        sum(n - 1 for n in sizes)
+    assert value("bf_serving_prefill_chunks_total") == \
+        sum(-(-(n - 1) // 4) for n in sizes)
+    assert value("bf_serving_steps_total") == n_steps
+    waits = snap["bf_serving_queue_wait_seconds"][0]
+    assert waits["count"] == len(reqs)
+    # the first finds a free slot at once; the rest wait for a slot or
+    # for the one prefill that runs at a time
+    recs = eng.metrics._req.values()
+    assert sorted(reg.histogram(
+        "bf_serving_queue_wait_seconds").window_values) == sorted(
+            r.admit_t - r.submit_t for r in recs)
+    assert min(r.admit_t - r.submit_t for r in recs) == 0.0
+    assert waits["sum"] > 0
+    # the step's wall time is the step span's two stamps: one sample a
+    # step, each a real (positive) duration although the engine's own
+    # clock is virtual
+    wall = reg.histogram("bf_step_wall_seconds", loop="serving")
+    assert wall.count == n_steps
+    assert all(0 < v < 60 for v in wall.window_values)
+
+
+def test_compile_listener_counts_one_backend_compile_a_function():
+    """``observe.compiles``: a fresh function's first call is one
+    backend compile, with seconds in every stage's counter and one
+    ``compile.<fun_name>`` instant; a second call is none."""
+    from bluefog_tpu import observe
+
+    reg = observe.get_registry()
+
+    def count(name, **labels):
+        for got, _, _, got_labels, m in reg.collect():
+            if got == name and got_labels == labels:
+                return m.value
+        return 0.0
+
+    @jax.jit
+    def a_function_no_test_has_compiled(x):
+        return jnp.tanh(x) * 3.0 + jnp.sum(x)
+
+    x = jnp.arange(7.0)     # (made before counting: arange compiles too)
+    jax.block_until_ready(x)
+    tracer = observe.get_tracer()
+    tracer.clear()
+    compiles = count("bf_compiles_total")
+    stages = {s: count("bf_compile_seconds_total", stage=s)
+              for s in ("trace", "lower", "backend")}
+    jax.block_until_ready(a_function_no_test_has_compiled(x))
+    assert count("bf_compiles_total") == compiles + 1
+    after = {s: count("bf_compile_seconds_total", stage=s)
+             for s in stages}
+    assert all(after[s] > stages[s] for s in stages)
+    instants = [e for e in tracer.events()
+                if e[0] == "i" and e[2] == "compile"]
+    assert len(instants) == 1
+    assert "a_function_no_test_has_compiled" in instants[0][1]
+    jax.block_until_ready(a_function_no_test_has_compiled(x))
+    assert count("bf_compiles_total") == compiles + 1
+    assert {s: count("bf_compile_seconds_total", stage=s)
+            for s in stages} == after
 
 
 def test_no_recompiles_across_arrival_patterns():
