@@ -183,7 +183,8 @@ def chaos_run(steps: int, seed: int) -> dict:
         "n_rollbacks": res.n_rollbacks,
         "dead_ranks": [int(r) for r in np.nonzero(res.dead_mask)[0]],
         "skips_per_rank": [int(v) for v in res.total_skips],
-        "recompiles": step_g.jitted._cache_size() - 1,
+        # (one program a round of the schedule is the contract)
+        "recompiles": step_g.jitted._cache_size() - len(sched),
         "events": [(e.kind, e.step) for e in res.events
                    if e.kind != "skip"],
         "final_loss_live_mean_chaos": chaos_live_loss,
@@ -429,7 +430,8 @@ def rejoin_cycle(steps: int, sim_rounds: int, dim: int, seed: int) -> dict:
         "events": [(e.kind, e.step) for e in res.events
                    if e.kind != "skip"],
         "n_rollbacks": res.n_rollbacks,
-        "recompiles": step_g.jitted._cache_size() - 1,
+        # (one program a round of the schedule is the contract)
+        "recompiles": step_g.jitted._cache_size() - len(sched),
         "promote_step": promote_step,
         "promote_disagreement": (
             float(promos[0].detail["disagreement"]) if promos else None),

@@ -164,10 +164,10 @@ def heal_cycle(mesh, seed):
     pod = PodSpec(4, 2, dcn_cost=4.0)
     plan = dispatch_plan(compile_all_to_all(pod).schedule)
     opt = optax.sgd(1e-2)
+    sched = torus_one_peer_schedule((4, 2), "exp2")
     step = F.build_train_step(
         make_moe_loss(plan, "bf", 3), opt, mesh, comm_mode="cta",
-        schedule=torus_one_peer_schedule((4, 2), "exp2"),
-        moe=F.MoEConfig(n_experts=experts, capacity=3))
+        schedule=sched, moe=F.MoEConfig(n_experts=experts, capacity=3))
 
     sh = NamedSharding(mesh, P("bf"))
     put = lambda t: jax.tree.map(
@@ -196,11 +196,13 @@ def heal_cycle(mesh, seed):
     dead = np.zeros(n, bool)
     dead[5] = True                        # kill a replica of expert 1
     healed = heal_route_table(route, dead, experts)
+    # (the same round's program each time: a step over a schedule is
+    # one program a round, and the healed tables are its data)
     params, ostate, _ = step(params, ostate,
                              batch(healed, capacity_mask_of(dead), 1),
-                             jnp.int32(1))
+                             jnp.int32(len(sched)))
     params, ostate, loss2 = step(params, ostate, batch(route, cmask0, 2),
-                                 jnp.int32(2))
+                                 jnp.int32(2 * len(sched)))
     recompiles = step.jitted._cache_size() - baseline
     return {
         "n": n,

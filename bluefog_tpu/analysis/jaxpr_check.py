@@ -36,8 +36,9 @@ three framework contracts mechanically:
     refactor banned.
 
 **collective contract** (:func:`check_collective_contracts`)
-    The scheduled exchange programs (flat switch over the
-    ``compile_topology`` schedule; hierarchical per-machine-round) are
+    The scheduled exchange programs (the ``compile_topology``
+    schedule's rounds, one program a round as ``build_train_step``
+    runs them; hierarchical per-machine-round) are
     lowered and held to ``predicted_collectives`` through the supported
     :func:`bluefog_tpu.benchutil.verify_collective_contract` — permute
     count after in-degree-1 fusion, per-permute payload bytes,
@@ -224,13 +225,18 @@ def _weight_shape_profile(leaves) -> set:
 def check_traced(closed, *, name: str,
                  weight_leaves: Sequence = (),
                  taint_seed: Optional[List[bool]] = None,
-                 large_const_floor: Optional[int] = None) -> List[Finding]:
+                 large_const_floor: Optional[int] = None,
+                 live_weights: Optional[Sequence[int]] = None,
+                 ) -> List[Finding]:
     """Contract-check one traced program (a ClosedJaxpr).
 
     ``weight_leaves``: the declared comm-weight arrays; when non-empty
     the trailing ``len(weight_leaves)`` invars must carry their avals
     and be live, and no closed-over constant may match their shape
-    profile.  ``taint_seed``: per-invar per-rank flags enabling the
+    profile.  ``live_weights``: the indices among them that THIS
+    program must consume (one round's program of a scheduled step
+    consumes that round's tables and no other's); all of them where it
+    is None.  ``taint_seed``: per-invar per-rank flags enabling the
     divergent-cond walk.  ``large_const_floor``: additionally flag any
     float constant with at least that many elements (serving residents:
     model state must arrive as arguments, not baked weights).
@@ -263,7 +269,8 @@ def check_traced(closed, *, name: str,
                     break
             else:
                 live = _live_invars(jaxpr)
-                dead = [i for i in range(n_w)
+                dead = [i for i in (range(n_w) if live_weights is None
+                                    else live_weights)
                         if invars[len(invars) - n_w + i] not in live]
                 if dead:
                     findings.append(Finding(
@@ -310,9 +317,14 @@ def check_traced(closed, *, name: str,
     return findings
 
 
-def check_step(step, args: Tuple, *, name: str) -> List[Finding]:
+def check_step(step, args: Tuple, *, name: str,
+               round_index: Optional[int] = None) -> List[Finding]:
     """Contract-check one built train step against its public call
-    ``step(*args)``.
+    ``step(*args)``: the program that call would run.  A scheduled step
+    is one program a round, picked on the host from the ``step`` among
+    ``args``: give its ``round_index`` and the program is held to
+    consuming that round's weight tables (the others are operands it
+    has no use for).
 
     The step's ``.trace`` (shared with ``.lower`` — same program) maps
     the public signature onto the jitted program, whose flattened
@@ -333,8 +345,15 @@ def check_step(step, args: Tuple, *, name: str) -> List[Finding]:
     seed = [True] * n
     for i in range(max(0, n - n_w - 1), n):
         seed[i] = False
+    live_w = None
+    if round_index is not None and n_w:
+        # leaves of ``default_comm_weights[round_index]``
+        sizes = [len(jax.tree.leaves(w))
+                 for w in step.default_comm_weights]
+        lo = sum(sizes[:round_index])
+        live_w = range(lo, lo + sizes[round_index])
     return check_traced(closed, name=name, weight_leaves=weight_leaves,
-                        taint_seed=seed)
+                        taint_seed=seed, live_weights=live_w)
 
 
 # --------------------------------------------------------------------- #
@@ -412,8 +431,9 @@ def sweep_cases() -> List[dict]:
     epilogue parity matrix (tests/test_epilogue.py ``_matrix``) —
     guard x health x compress x comm_mode x overlap on the weighted
     static ring, int8 wire, push_sum (the in-graph gossip mix),
-    lax.switch schedules, hierarchical two-level — so the analyzer
-    covers exactly the program space the parity tests pin."""
+    dynamic schedules (``P`` programs, one a round, then none: every
+    round's program is checked), hierarchical two-level — so the
+    analyzer covers exactly the program space the parity tests pin."""
     ring = _weighted_ring()
     cases: List[dict] = []
     for comm_mode in ("cta", "atc"):
@@ -431,7 +451,7 @@ def sweep_cases() -> List[dict]:
     cases.append(dict(comm_mode="atc", overlap="none", guard=True,
                       health=True, compress="int8", topology=ring))
     # error-feedback compressed mixing: the "topk" epilogue threads
-    # MixState through the switch branches — lint it like any other
+    # MixState through the round's branch — lint it like any other
     cases.append(dict(comm_mode="cta", overlap="none", guard=False,
                       health=False, compress="topk", topology=ring))
     cases.append(dict(comm_mode="atc", overlap="bucketed", guard=True,
@@ -535,18 +555,27 @@ def _build_and_check(case: dict, mesh) -> List[Finding]:
                                  (N_RANKS, N_RANKS)).copy())
     else:
         batch = np.zeros((N_RANKS, 3, 4), np.float32)
-    args = (params, ostate, batch, jnp.int32(0))
-    if guarded:
-        args = args + (step.default_comm_weights,)
-    return check_step(step, args, name=f"step[{case_id(case)}]")
+    # a schedule of P rounds is P programs (the host picks the round's
+    # from ``step``): each is held to the contracts
+    findings: List[Finding] = []
+    scheduled = "schedule" in kwargs
+    for r in range(len(kwargs["schedule"]) if scheduled else 1):
+        args = (params, ostate, batch, np.int32(r))
+        if guarded:
+            args = args + (step.default_comm_weights,)
+        findings += check_step(
+            step, args, round_index=r if scheduled else None,
+            name=f"step[{case_id(case)}]"
+            + (f"[round {r}]" if scheduled else ""))
+    return findings
 
 
 def check_collective_contracts() -> List[Finding]:
     """Lower the topology compiler's scheduled programs and hold the
     HLO to ``predicted_collectives`` via the supported
-    ``verify_collective_contract`` — the flat (1, 8)-pod switch program
-    (every round in ONE executable, exactly how build_train_step
-    consumes a schedule) and the hierarchical (4, 2)-pod rounds."""
+    ``verify_collective_contract`` — the flat (1, 8)-pod schedule, one
+    program a round (exactly how build_train_step consumes a
+    schedule), and the hierarchical (4, 2)-pod rounds."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -564,18 +593,7 @@ def check_collective_contracts() -> List[Finding]:
     pred = compiled.predicted_collectives(payload)
     schedule = compiled.schedule
 
-    def combine(v, step):
-        branches = [
-            (lambda s: lambda y: C.neighbor_allreduce(y, s, "bf"))(s)
-            for s in schedule]
-        return jax.lax.switch(step % len(branches), branches, v)
-
-    sm = jax.shard_map(combine, mesh=mesh, in_specs=(P("bf"), P()),
-                       out_specs=P("bf"), check_vma=False)
-    hlo = jax.jit(sm).lower(x, jnp.asarray(0)).compile().as_text()
-    for msg in benchutil.verify_collective_contract(hlo, pred, payload):
-        findings.append(Finding("collective-contract",
-                                "schedule[pod_1x8]", 0, "period", msg))
+    # (each call also holds the period's totals to the rounds' sum)
     for i, rnd in enumerate(schedule):
         def one(v, r=rnd):
             return C.neighbor_allreduce(v, r, "bf")

@@ -139,9 +139,10 @@ def hlo_collective_bytes(hlo_text: str) -> dict:
     at the matching ``-done`` so pairs are not double-counted).  For
     all-gather the result is the gathered buffer, an upper bound within
     (n-1)/n of the wire bytes.  Collectives inside ``conditional``
-    branches (``lax.switch`` dynamic schedules) are all present in the
-    module text but only one branch executes per step — callers divide by
-    the branch count for per-step figures."""
+    branches (a caller's own ``lax.switch``; ``build_train_step``
+    compiles a dynamic schedule one program a round and emits none) are
+    all present in the module text but only one branch executes per
+    step — callers divide by the branch count for per-step figures."""
     out: dict = {}
     # tuple types are printed with /*index=N*/ comments whose '=' would
     # truncate the types capture — strip them first
@@ -473,7 +474,7 @@ def verify_collective_contract(compiled, predicted, payload_bytes,
     (a jit ``Compiled``); ``predicted`` is a
     ``CompiledTopology.predicted_collectives(payload_bytes)`` /
     ``CompiledHierarchicalTopology`` dict.  With ``round_index=None``
-    the module is the full (e.g. ``lax.switch``) program and is checked
+    the module is a program holding the full period and is checked
     against the per-period totals; with ``round_index=i`` it is round
     *i* lowered alone and is checked against ``per_round[i]``.
 

@@ -5,7 +5,8 @@ reference's host-driven hook model (reference bluefog/torch/optimizers.py) —
 good for parity, but each op is a separate dispatch.  This module is the
 TPU-first fast path: ONE compiled SPMD program per train step containing
 forward, backward, the base optax update, and the decentralized combine —
-XLA overlaps the ppermutes with compute, exactly what the reference gets
+each leaf's ppermute a top-level asynchronous operation that XLA's
+scheduler can fly under compute, which is what the reference gets
 from its background thread + tensor fusion (reference
 common/operations.cc:453-1020), but compiler-scheduled instead of
 hand-scheduled.
@@ -13,9 +14,25 @@ hand-scheduled.
 Key design points (SURVEY.md §7 "hard parts"):
 
 * **Dynamic topologies without retrace storms** — pass ``schedule`` (a list
-  of topology specs, e.g. the log2(n) one-peer exponential-2 rounds); the
-  step index selects the round's combine via ``lax.switch`` inside the one
-  compiled program.  No retracing, no host round-trip per iteration.
+  of ``P`` topology specs, e.g. the log2(n) one-peer exponential-2
+  rounds): the step is ``P`` compiled programs, one a round, and the host
+  picks the round's from the ``step`` it is called with
+  (``_round_selector``; the round is a static argument of the step's one
+  ``jax.jit``).  ``P`` compiles in a cold process (from the persistent
+  cache afterwards), none after the first cycle, and the model is traced
+  once for all of them: forward and backward are a jitted function of
+  their own that every round's program calls (``fwd_bwd_all`` in
+  ``_build_fused_train_step``, traced at the top level before the first
+  program is).  The round is NOT a
+  ``lax.switch`` inside one program: a ``conditional`` takes the whole
+  parameter tree as its operand, so no permute inside it could start
+  before the backward pass's last gradient existed, and none overlapped
+  anything (PERF.md §6, PR 25).  Within a round's program the buckets'
+  exchanges are chained largest first (``_exchange_buckets``), so that
+  each permute flies under the next bucket's weight-gradient fusion
+  instead of after them all.  ``step`` must therefore be concrete (a
+  Python or NumPy integer; a device scalar costs one read a dispatch): a
+  tracer raises ``TypeError``.  Weights, faults and ratios remain data.
 * **Rank-major state** — every rank owns its own parameters (decentralized
   DP: nothing is replicated).  Params/opt-state/batch leaves all carry a
   leading ``[n_ranks]`` axis sharded over ``axis_name``; use
@@ -785,23 +802,77 @@ def _combine_fn(spec: CommSpec, axis_name: str,
                                        compress=compress), tree)
 
 
-def _observed_step(step_fn: Callable, labels: dict,
-                   edge_traffic: Optional[tuple] = None) -> Callable:
-    """Host-side observability wrapper for a built train step: each
-    dispatch increments ``bf_train_steps_total{comm_mode,overlap,
-    guarded}`` and runs inside a ``train_step`` span on the ``train``
-    track (``bf.train.train_step`` in a profiler trace), the edge
-    accounting in a ``record_edges`` span inside it.  Everything happens
-    OUTSIDE the traced program — the wrapper
-    calls the same jitted executable, so jit cache sizes and step
+def _round_selector(comm_mode: str, specs: Sequence[CommSpec],
+                    k_comm: int) -> Optional[Callable]:
+    """``select(step)`` names the program one dispatch runs: the
+    schedule's round ``step % len(specs)``, or ``None`` on an off-cycle
+    step of ``num_steps_per_communication`` (the variant with no
+    exchange in it).  The round is a STATIC argument of the step's one
+    ``jax.jit``, so the host picks one of the step's executables and no
+    ``conditional`` inside one executable does: a round's
+    ``collective-permute``s are top-level asynchronous operations, each
+    waiting for its own leaf alone, which can fly under compute (a
+    ``conditional`` waits for its last operand, the backward pass's
+    last gradient).  ``None`` for a step that is one program (a
+    static topology exchanged every step, or no neighbor exchange at
+    all): nothing of ``step`` is read on the host."""
+    n_rounds = len(specs)
+    if (comm_mode not in ("cta", "atc", "push_sum")
+            or (n_rounds <= 1 and k_comm <= 1)):
+        return None
+
+    def select(step):
+        try:
+            i = int(step)
+        except TypeError as e:
+            raise TypeError(
+                f"this train step is {n_rounds} round(s) of a schedule "
+                f"with num_steps_per_communication={k_comm}: one compiled "
+                "program a round, picked on the host from `step`, which "
+                "must therefore be a concrete integer (a Python or NumPy "
+                "integer; a device scalar costs one read a dispatch), not "
+                f"{type(step).__name__}.  Under jit/scan around the step, "
+                "build a one-round step (topology=schedule[r]) or call "
+                "the step once a round from Python.") from e
+        return i % n_rounds if i % k_comm == 0 else None
+
+    return select
+
+
+def _public_step(jitted: Callable, labels: dict, *,
+                 select: Optional[Callable], has_aux: bool,
+                 tail: tuple = (),
+                 edge_traffic: Optional[tuple] = None,
+                 prepare: Optional[Callable] = None) -> Callable:
+    """The step a caller holds, over the jitted program ``jitted(params,
+    aux, opt_state, batch, step, *more, round)``: the public signature
+    leaves out ``aux`` (argument and output) unless ``has_aux``, never
+    has ``tail`` (operands the builder supplies itself: the default
+    combine weights of an unguarded fused step), and never the round,
+    which ``select`` reads from ``step`` ONCE a dispatch, on the host,
+    for the program and for the edge accounting alike.  ``.lower`` and
+    ``.trace`` take the public arguments too (the round from the
+    concrete ``step`` they are given), so AOT compilation (benchmarks)
+    and jaxpr inspection (bluefog_tpu.analysis) see the program a call
+    would run; ``.jitted`` is the one jitted object, with one cache
+    entry a round.  ``prepare`` is called once, with the program's
+    arguments, before the first program is made (the fused builder
+    traces the model there).
+
+    Host-side observability: each dispatch increments
+    ``bf_train_steps_total{comm_mode,overlap,guarded}`` and runs inside
+    a ``train_step`` span on the ``train`` track (``bf.train.train_step``
+    in a profiler trace, ``round=`` the program it picked, -1 off-cycle),
+    the edge accounting in a ``record_edges`` span inside it.  Everything
+    happens OUTSIDE the traced program, so jit cache sizes and step
     outputs are bit-identical with ``BLUEFOG_OBSERVE`` on or off
     (asserted in tests/test_observe.py).  The span measures host
     dispatch (jax is async); sync before reading it as a step time.
 
-    ``edge_traffic`` — ``(specs, step_argpos, k_comm, n_ranks,
-    filtered, local_size)`` for the neighbor modes: per on-cycle
-    dispatch, the round's edges each get the per-rank parameter payload
-    added to ``bf_edge_bytes_total{src,dst}`` through
+    ``edge_traffic`` — ``(specs, n_ranks, filtered, local_size)`` for
+    the neighbor modes: per on-cycle dispatch, the round's edges each
+    get the per-rank parameter payload added to
+    ``bf_edge_bytes_total{src,dst}`` through
     ``observe.fleet.record_edge_traffic`` (logical bytes — wire
     compression is not folded in), the fleet-telemetry traffic account
     derived from the topology's shift classes.  ``filtered`` selects
@@ -817,23 +888,16 @@ def _observed_step(step_fn: Callable, labels: dict,
     load."""
     payload_cache: list = []
     pairs_cache: dict = {}
+    todo = [prepare] if prepare is not None else []
 
-    def record_edges(args) -> None:
-        specs, step_argpos, k_comm, n_ranks, filtered, local_size = \
-            edge_traffic
-        try:
-            step_i = int(args[step_argpos])
-        except (TypeError, ValueError, IndexError):
-            return
-        if step_i % k_comm != 0:
-            return
+    def record_edges(params, si: int) -> None:
+        specs, n_ranks, filtered, local_size = edge_traffic
         if not payload_cache:
             payload_cache.append(sum(
                 int(getattr(leaf, "nbytes", 0))
-                for leaf in jax.tree.leaves(args[0])) // max(n_ranks, 1))
+                for leaf in jax.tree.leaves(params)) // max(n_ranks, 1))
         from bluefog_tpu.observe import fleet as _fleet
 
-        si = step_i % len(specs)
         if local_size:
             pairs = pairs_cache.get(si)
             if pairs is None:
@@ -862,24 +926,41 @@ def _observed_step(step_fn: Callable, labels: dict,
         _fleet.record_edge_traffic(specs[si], payload_cache[0],
                                    pairs=pairs)
 
-    def step(*args, **kwargs):
+    def adapt(*args):
+        if not has_aux:
+            args = args[:1] + ((),) + args[1:]
+        args = args + tail + (select(args[4]) if select else 0,)
+        if todo:
+            todo.pop()(*args)   # once, before the first program is made
+        return args
+
+    def step(*args):
         from bluefog_tpu import observe
 
+        args = adapt(*args)
         tr = observe.publish_tracer()
         if tr is None:
-            return step_fn(*args, **kwargs)
-        observe.get_registry().counter(
-            "bf_train_steps_total", "train-step dispatches",
-            **labels).inc()
-        with tr.span("train", "train_step"):
-            if edge_traffic is not None:
-                # a span of its own: with a device scalar for ``step``
-                # the accounting costs one device-to-host read a
-                # dispatch, which a trace can now show
-                with tr.span("train", "record_edges"):
-                    record_edges(args)
-            return step_fn(*args, **kwargs)
+            out = jitted(*args)
+        else:
+            observe.get_registry().counter(
+                "bf_train_steps_total", "train-step dispatches",
+                **labels).inc()
+            si = args[-1]
+            with tr.span("train", "train_step",
+                         round=-1 if si is None else si):
+                # (a step traced by someone's jit is no dispatch: the
+                # accounting waits for real ones)
+                if (edge_traffic is not None and si is not None
+                        and not isinstance(args[4], jax.core.Tracer)):
+                    with tr.span("train", "record_edges"):
+                        record_edges(args[0], si)
+                out = jitted(*args)
+        return out if has_aux else out[:1] + out[2:]
 
+    step.jitted = jitted
+    step.lower = lambda *args: jitted.lower(*adapt(*args))
+    step.trace = lambda *args: jitted.trace(*adapt(*args))
+    step.has_aux = has_aux
     return step
 
 
@@ -935,6 +1016,10 @@ def _build_fused_train_step(
     # inter-machine matrix as data); push_sum derives its
     # column-stochastic scales from the edge structure
     use_traced_w = neighbor
+    # the step's programs: one a round of a schedule (and the off-cycle
+    # one), picked on the host; None where the step is one program
+    select = _round_selector(comm_mode, specs, k_comm)
+    chained = select is not None
     wire = compress == "int8_sr"
     wire_compress = "int8" if wire else compress
     zero = lambda: jnp.zeros((), jnp.float32)
@@ -1013,72 +1098,58 @@ def _build_fused_train_step(
     def _fused_combine_branch(spec: CommSpec, r_index: int) -> Callable:
         """fn(tree, key, w, mix_state) -> (combined_tree, cons_sq,
         mix_state'): the per-bucket pipeline over an already-
-        materialized param tree (cta pre-update; guarded/plain atc
-        post-update)."""
+        materialized param tree (cta pre-update; atc post-update).
+
+        In a step of several programs the buckets' exchanges are
+        CHAINED, largest bucket first: a bucket's buffer reaches its
+        permute through a ``lax.optimization_barrier`` with the mixed
+        output of the bucket before it.  Left alone, XLA's scheduler
+        (which sinks the weight-gradient-with-update fusions below the
+        backward chain) keeps a handful of permutes in flight and opens
+        them largest first from the program's END: the smallest leaves
+        get the fusions to fly under, the large ones start when nothing
+        is left but the mixing.  Chained, each permute flies under the
+        next bucket's fusion — about as long as the transfer, the
+        buckets being sorted — and the round ends on its smallest
+        transfers (PERF.md §6, PR 25: 43 ms of exchange exposed
+        unchained, 6 chained, of 63).  The barrier is an identity:
+        every value, and the order of every sum, is the unchained
+        build's."""
 
         def fn(tree, key, w, mix_state):
             leaves, treedef = jax.tree_util.tree_flatten(tree)
             if not leaves:
                 return tree, zero(), mix_state
-            plan = _plan(leaves)
+            buckets = _plan(leaves).buckets
+            pres = [_pack_bucket(leaves, list(b.leaves)) for b in buckets]
+            inexact = [jnp.issubdtype(jnp.dtype(b.dtype), jnp.inexact)
+                       for b in buckets]
+            # a bucket's place among those that carry error-feedback state
+            cis = [sum(inexact[:i]) if mix_on else 0
+                   for i in range(len(buckets))]
+            order = list(range(len(buckets)))
+            if chained:
+                order.sort(
+                    key=lambda i: -pres[i].size * pres[i].dtype.itemsize)
+            mixed, upds = [None] * len(buckets), [None] * len(buckets)
+            for n, i in enumerate(order):
+                pre = pres[i]
+                if chained and n:
+                    pre, mixed[order[n - 1]] = lax.optimization_barrier(
+                        (pre, mixed[order[n - 1]]))
+                mixed[i], upds[i] = _bucket_exchange(
+                    pre, spec, key, buckets[i], w, mix_state, r_index,
+                    cis[i])
             outs = [None] * len(leaves)
             cons = zero()
             acc = ([list(mix_state.err), list(mix_state.ref),
                     list(mix_state.mirror)] if mix_on else None)
-            ci = 0
-            for b in plan.buckets:
-                pre = _pack_bucket(leaves, list(b.leaves))
-                out, upd = _bucket_exchange(pre, spec, key, b, w,
-                                            mix_state, r_index, ci)
-                if upd is not None:
-                    _advance_mix(mix_state, r_index, ci, upd, acc)
-                    ci += 1
-                if want_cons and jnp.issubdtype(jnp.dtype(b.dtype),
-                                                jnp.inexact):
-                    cons = cons + _bucket_cons_sq(pre, out)
-                _unpack_bucket(out, leaves, list(b.leaves), outs)
-            return (jax.tree_util.tree_unflatten(treedef, outs), cons,
-                    _mix_result(mix_state, acc))
-
-        return fn
-
-    def _fused_apply_combine_branch(spec: CommSpec,
-                                    r_index: int) -> Callable:
-        """fn((params, updates), key, w, mix_state) -> (params,
-        cons_sq, mix_state'): the unguarded ATC pipeline — bucket *i*'s
-        optax apply feeds its own exchange before bucket *i+1*'s apply,
-        and the consensus partial comes from the bucket's applied/mixed
-        buffers (the pre-fusion path re-applied the full update tree
-        just to measure it)."""
-
-        def fn(operand, key, w, mix_state):
-            params, updates = operand
-            leaves, treedef = jax.tree_util.tree_flatten(params)
-            upd_leaves = jax.tree_util.tree_flatten(updates)[0]
-            if not leaves:
-                return params, zero(), mix_state
-            plan = _plan(leaves)
-            outs = [None] * len(leaves)
-            cons = zero()
-            acc = ([list(mix_state.err), list(mix_state.ref),
-                    list(mix_state.mirror)] if mix_on else None)
-            ci = 0
-            for b in plan.buckets:
-                g = list(b.leaves)
-                fresh = list(leaves)
-                for i in g:
-                    fresh[i] = _apply_updates(leaves[i],
-                                                   upd_leaves[i])
-                pre = _pack_bucket(fresh, g)
-                out, upd = _bucket_exchange(pre, spec, key, b, w,
-                                            mix_state, r_index, ci)
-                if upd is not None:
-                    _advance_mix(mix_state, r_index, ci, upd, acc)
-                    ci += 1
-                if want_cons and jnp.issubdtype(jnp.dtype(b.dtype),
-                                                jnp.inexact):
-                    cons = cons + _bucket_cons_sq(pre, out)
-                _unpack_bucket(out, fresh, g, outs)
+            for i, b in enumerate(buckets):
+                if upds[i] is not None:
+                    _advance_mix(mix_state, r_index, cis[i], upds[i], acc)
+                if want_cons and inexact[i]:
+                    cons = cons + _bucket_cons_sq(pres[i], mixed[i])
+                _unpack_bucket(mixed[i], leaves, list(b.leaves), outs)
             return (jax.tree_util.tree_unflatten(treedef, outs), cons,
                     _mix_result(mix_state, acc))
 
@@ -1124,47 +1195,21 @@ def _build_fused_train_step(
     branches = [_fused_combine_branch(s, r)
                 for r, s in enumerate(specs)] \
         if neighbor else []
-    # the interleaved apply+exchange rides the BUCKETED unguarded atc
-    # path only: on the plain path the whole-tree apply stays outside
-    # the combine (and outside any lax.switch branch) so the healthy
-    # arithmetic is bit-identical to the pre-fusion builder — an apply
-    # moved inside a conditional invites a different mul+add
-    # contraction (1-ulp) on some backends
-    ac_branches = [_fused_apply_combine_branch(s, r)
-                   for r, s in enumerate(specs)] \
-        if (neighbor and comm_mode == "atc" and not guarded
-            and n_buckets is not None and moe is None) else []
     ps_branches = [_fused_push_sum_branch(s) for s in specs] \
         if comm_mode == "push_sum" else []
 
+    # ``r`` below is the program's STATIC round (``_round_selector``):
+    # the round's branch is called directly, and an off-cycle program
+    # (``r is None``) holds no collective and no epilogue stage riding
+    # one — the mix state rides through untouched (no wire, no delta)
     @jax.named_scope(SCOPE_EXCHANGE)
-    def fused_combine(params, step, comm_weights, mix_state):
-        if not branches:
+    def fused_combine(params, step, comm_weights, mix_state, r):
+        if not branches or r is None:
             return params, zero(), mix_state
-
-        def run(operand):
-            params, mix_state = operand
-            key = jax.random.fold_in(jax.random.PRNGKey(0x51EED), step)
-            if len(branches) == 1:
-                return branches[0](params, key,
-                                   comm_weights[0] if use_traced_w
-                                   else (), mix_state)
-            picked = [
-                (lambda fn, i: lambda p, k, ws, m: fn(
-                    p, k, ws[i] if use_traced_w else (), m))(fn, i)
-                for i, fn in enumerate(branches)
-            ]
-            return lax.switch(step % len(branches), picked, params, key,
-                              comm_weights, mix_state)
-
-        if k_comm > 1:
-            # lax.cond actually skips the collectives (and the epilogue
-            # stages riding them) on off-cycle steps — the mix state
-            # rides through untouched (no wire, no delta)
-            return lax.cond(step % k_comm == 0, run,
-                            lambda op: (op[0], zero(), op[1]),
-                            (params, mix_state))
-        return run((params, mix_state))
+        key = jax.random.fold_in(jax.random.PRNGKey(0x51EED), step)
+        return branches[r](params, key,
+                           comm_weights[r] if use_traced_w else (),
+                           mix_state)
 
     if moe is not None:
         # Expert-sharded MoE: only the SHARED leaves ride the mixing
@@ -1175,7 +1220,7 @@ def _build_fused_train_step(
         # untouched and never cost a byte of exchange.
         _dense_fused_combine = fused_combine
 
-        def fused_combine(params, step, comm_weights, mix_state):
+        def fused_combine(params, step, comm_weights, mix_state, r):
             leaves, treedef = jax.tree_util.tree_flatten(params)
             mask = _moe_shared_mask(params, moe)
             if not any(mask):
@@ -1186,60 +1231,20 @@ def _build_fused_train_step(
                     "never reach consensus")
             shared = [l for l, m in zip(leaves, mask) if m]
             mixed, cons, mix_state = _dense_fused_combine(
-                shared, step, comm_weights, mix_state)
+                shared, step, comm_weights, mix_state, r)
             it = iter(mixed)
             out = [next(it) if m else l for l, m in zip(leaves, mask)]
             return (jax.tree_util.tree_unflatten(treedef, out), cons,
                     mix_state)
 
     @jax.named_scope(SCOPE_EXCHANGE)
-    def fused_apply_then_combine(params, updates, step, comm_weights,
-                                 mix_state):
-        if not ac_branches:
-            return (_apply_updates(params, updates), zero(),
-                    mix_state)
+    def fused_push_sum(params, ps, r):
+        if r is None:
+            return params, ps, zero()
+        return ps_branches[r]((params, ps))
 
-        def run(operand):
-            params, updates, mix_state = operand
-            key = jax.random.fold_in(jax.random.PRNGKey(0x51EED), step)
-            if len(ac_branches) == 1:
-                return ac_branches[0]((params, updates), key,
-                                      comm_weights[0] if use_traced_w
-                                      else (), mix_state)
-            picked = [
-                (lambda fn, i: lambda op, k, ws, m: fn(
-                    op, k, ws[i] if use_traced_w else (), m))(fn, i)
-                for i, fn in enumerate(ac_branches)
-            ]
-            return lax.switch(step % len(ac_branches), picked,
-                              (params, updates), key, comm_weights,
-                              mix_state)
-
-        if k_comm > 1:
-            # off-cycle steps still apply the optax update — only the
-            # collectives (and their epilogue stages) are skipped
-            return lax.cond(
-                step % k_comm == 0, run,
-                lambda op: (_apply_updates(op[0], op[1]), zero(),
-                            op[2]),
-                (params, updates, mix_state))
-        return run((params, updates, mix_state))
-
-    @jax.named_scope(SCOPE_EXCHANGE)
-    def fused_push_sum(params, ps, step):
-        def run(operand):
-            if len(ps_branches) == 1:
-                return ps_branches[0](operand)
-            return lax.switch(step % len(ps_branches), ps_branches,
-                              operand)
-
-        if k_comm > 1:
-            return lax.cond(step % k_comm == 0, run,
-                            lambda op: (op[0], op[1], zero()),
-                            (params, ps))
-        return run((params, ps))
-
-    def per_rank_step(params, aux, opt_state, batch, step, comm_weights):
+    def per_rank_step(r, params, aux, opt_state, batch, step,
+                      comm_weights, fwd_bwd=None):
         mix_state = ()
         if mix_on:
             # the MixState rides opt_state as (base, MixState) — the
@@ -1248,7 +1253,7 @@ def _build_fused_train_step(
             # a local skip, so ref/mirror/err must advance to stay
             # bitwise-consistent with what the neighbors received)
             opt_state, mix_state = opt_state
-        loss, grads, new_aux = _loss_and_grads(
+        loss, grads, new_aux = fwd_bwd or _loss_and_grads(
             loss_fn, has_aux, sp_axis, pp_axis, param_specs,
             params, aux, batch)
         groups = _plan(jax.tree.leaves(params)).groups \
@@ -1264,16 +1269,16 @@ def _build_fused_train_step(
             grads = _allreduce_grads(grads, axis_name)
         if comm_mode == "push_sum":
             base_state, ps = opt_state
-            params, ps, cons = fused_push_sum(params, ps, step)
+            params, ps, cons = fused_push_sum(params, ps, r)
             updates, base_state = _opt_update(optimizer, grads, base_state,
-                                                   params)
+                                              params)
             params = _apply_updates(params, updates)
             hv = _fused_health(loss, grad_sq, updates, groups, cons,
                                None) if want_health else None
             return params, new_aux, (base_state, ps), loss, None, hv
         if comm_mode == "cta":
             params, cons, mix_state = fused_combine(
-                params, step, comm_weights, mix_state)
+                params, step, comm_weights, mix_state, r)
         updates, new_opt = _opt_update(optimizer, grads, opt_state, params)
         skipped = None
         if guarded:
@@ -1291,17 +1296,16 @@ def _build_fused_train_step(
             new_opt = jax.tree.map(pick, new_opt, opt_state)
             if comm_mode == "atc":
                 params, cons, mix_state = fused_combine(
-                    params, step, comm_weights, mix_state)
+                    params, step, comm_weights, mix_state, r)
             skipped = jnp.where(ok, jnp.int32(0), jnp.int32(1))
         else:
-            if comm_mode == "atc" and ac_branches:
-                params, cons, mix_state = fused_apply_then_combine(
-                    params, updates, step, comm_weights, mix_state)
-            else:
-                params = _apply_updates(params, updates)
-                if comm_mode == "atc":
-                    params, cons, mix_state = fused_combine(
-                        params, step, comm_weights, mix_state)
+            # (atc: every bucket's exchange depends on its own leaves'
+            # apply alone, so a bucket's permute can launch before the
+            # next bucket's update — the overlap engine's dataflow)
+            params = _apply_updates(params, updates)
+            if comm_mode == "atc":
+                params, cons, mix_state = fused_combine(
+                    params, step, comm_weights, mix_state, r)
         if mix_on:
             new_opt = (new_opt, mix_state)
         hv = _fused_health(loss, grad_sq, updates, groups, cons,
@@ -1323,10 +1327,11 @@ def _build_fused_train_step(
     squeeze = lambda t: jax.tree.map(lambda x: x[0], t)
     expand = lambda t: jax.tree.map(lambda x: x[None], t)
 
-    def wrapped(params, aux, opt_state, batch, step, comm_weights):
+    def per_shard(r, params, aux, opt_state, batch, step, comm_weights,
+                  *fwd_bwd):
         params, aux, opt_state, loss, skipped, hv = per_rank_step(
-            squeeze(params), squeeze(aux), squeeze(opt_state),
-            squeeze(batch), step, comm_weights)
+            r, squeeze(params), squeeze(aux), squeeze(opt_state),
+            squeeze(batch), step, comm_weights, squeeze(fwd_bwd))
         outs = (expand(params), expand(aux), expand(opt_state),
                 jnp.reshape(loss, (1,)))
         if guarded:
@@ -1365,15 +1370,48 @@ def _build_fused_train_step(
         out_specs = out_specs + (p_rank,)
     if want_health:
         out_specs = out_specs + (p_rank,)  # spec prefix over HealthVector
-    sm = jax.shard_map(
-        wrapped,
-        mesh=mesh,
-        in_specs=(p_params, p_rank, p_opt, batch_specs, P(), p_comm),
-        out_specs=out_specs,
-        check_vma=False,
-    )
+
+    # A step of several programs keeps the model out of them: forward
+    # and backward are a jitted function of their own, traced ONCE, at
+    # the top level (``prepare``, before the first program is), whose
+    # jaxpr every round's program then calls; a program's own trace is
+    # the optimizer and its round's exchange.  (Traced inside a
+    # program's trace instead, three traces deep, the model costs twice
+    # the seconds on the chip's host; and a model traced again for each
+    # program gives the lowering new kernel objects to lower, where the
+    # same ones are found lowered: PERF.md §6, PR 25.)  XLA inlines the
+    # call: the program is one computation as before.
+    fwd_bwd_all, prepare = None, None
+    if select is not None:
+        def _fwd_bwd_shard(params, aux, batch):
+            loss, grads, new_aux = _loss_and_grads(
+                loss_fn, has_aux, sp_axis, pp_axis, param_specs,
+                squeeze(params), squeeze(aux), squeeze(batch))
+            return jnp.reshape(loss, (1,)), expand(grads), expand(new_aux)
+
+        fwd_bwd_all = jax.jit(jax.shard_map(
+            _fwd_bwd_shard, mesh=mesh,
+            in_specs=(p_params, p_rank, batch_specs),
+            out_specs=(p_rank, p_params, p_rank), check_vma=False))
+
+        def prepare(params, aux, opt_state, batch, *rest):
+            fwd_bwd_all.trace(params, aux, batch)
+
+    def wrapped(params, aux, opt_state, batch, step, comm_weights, r):
+        fwd_bwd = () if fwd_bwd_all is None else \
+            fwd_bwd_all(params, aux, batch)
+        return jax.shard_map(
+            partial(per_shard, r),
+            mesh=mesh,
+            in_specs=(p_params, p_rank, p_opt, batch_specs, P(), p_comm)
+            + ((p_rank, p_params, p_rank) if fwd_bwd else ()),
+            out_specs=out_specs,
+            check_vma=False,
+        )(params, aux, opt_state, batch, step, comm_weights, *fwd_bwd)
+
     donate_argnums = (0, 1, 2) if donate else ()
-    jitted = jax.jit(sm, donate_argnums=donate_argnums)
+    jitted = jax.jit(wrapped, static_argnums=6,
+                     donate_argnums=donate_argnums)
     default_w = comm_weight_inputs(specs) if use_traced_w else ()
 
     obs_labels = dict(
@@ -1381,8 +1419,7 @@ def _build_fused_train_step(
         overlap="bucketed" if n_buckets is not None else "none",
         guarded="true" if guarded else "false")
     needs_topo = comm_mode in ("cta", "atc", "push_sum")
-    edge_traffic = (list(specs), 4 if has_aux else 3, k_comm,
-                    int(mesh.shape[axis_name]),
+    edge_traffic = (list(specs), int(mesh.shape[axis_name]),
                     comm_mode == "push_sum",
                     hierarchical_local_size if neighbor else None) \
         if (specs and needs_topo) else None
@@ -1505,90 +1542,28 @@ def _build_fused_train_step(
         return (base, ms._replace(
             ratio=jnp.full_like(ms.ratio, jnp.float32(float(ratio)))))
 
-    def _decorate(step_fn, adapt):
-        # ``adapt`` maps the step's PUBLIC signature to the jitted
-        # program's full argument tuple; .lower and .trace share it so
-        # AOT compilation (benchmarks) and jaxpr inspection
-        # (bluefog_tpu.analysis) see the identical program.
-        step_fn.jitted = jitted
-        step_fn.lower = lambda *args: jitted.lower(*adapt(*args))
-        step_fn.trace = lambda *args: jitted.trace(*adapt(*args))
-        step_fn.health_config = health
-        step_fn.epilogue_stages = stages
-        step_fn.has_aux = has_aux
-        step_fn.hierarchical_local_size = \
-            hierarchical_local_size if neighbor else None
-        step_fn.mix_config = mix
-        step_fn.moe_config = moe
-        if mix_on:
-            step_fn.init_mix_state = init_mix_state
-            step_fn.mix_wire_layout = mix_wire_layout
-            step_fn.set_mix_ratio = set_mix_ratio
-            # pytree-prefix PartitionSpecs of the MixState (AOT callers
-            # turn these into NamedShardings for abstract avals)
-            step_fn.mix_state_specs = p_mix
-        if guarded:
-            step_fn.guard_config = guard
-        if guarded or use_traced_w:
-            step_fn.default_comm_weights = default_w
-        return step_fn
-
+    step_fn = _public_step(
+        jitted, obs_labels, select=select, has_aux=has_aux,
+        tail=() if guarded else (default_w,), edge_traffic=edge_traffic,
+        prepare=prepare)
+    step_fn.health_config = health
+    step_fn.epilogue_stages = stages
+    step_fn.hierarchical_local_size = \
+        hierarchical_local_size if neighbor else None
+    step_fn.mix_config = mix
+    step_fn.moe_config = moe
+    if mix_on:
+        step_fn.init_mix_state = init_mix_state
+        step_fn.mix_wire_layout = mix_wire_layout
+        step_fn.set_mix_ratio = set_mix_ratio
+        # pytree-prefix PartitionSpecs of the MixState (AOT callers
+        # turn these into NamedShardings for abstract avals)
+        step_fn.mix_state_specs = p_mix
     if guarded:
-        if has_aux:
-            def aux_step(params, aux, opt_state, batch, step,
-                         comm_weights):
-                return jitted(params, aux, opt_state, batch, step,
-                              comm_weights)
-
-            return _decorate(
-                _observed_step(aux_step, obs_labels, edge_traffic),
-                lambda params, aux, opt_state, batch, step,
-                comm_weights: (params, aux, opt_state, batch, step,
-                               comm_weights))
-
-        if health is None:
-            def no_aux_step(params, opt_state, batch, step,
-                            comm_weights):
-                params, _, opt_state, loss, skipped = jitted(
-                    params, (), opt_state, batch, step, comm_weights)
-                return params, opt_state, loss, skipped
-        else:
-            def no_aux_step(params, opt_state, batch, step,
-                            comm_weights):
-                params, _, opt_state, loss, skipped, hv = jitted(
-                    params, (), opt_state, batch, step, comm_weights)
-                return params, opt_state, loss, skipped, hv
-
-        return _decorate(
-            _observed_step(no_aux_step, obs_labels, edge_traffic),
-            lambda params, opt_state, batch, step, comm_weights:
-            (params, (), opt_state, batch, step, comm_weights))
-
-    if has_aux:
-        def aux_step(params, aux, opt_state, batch, step):
-            return jitted(params, aux, opt_state, batch, step,
-                          default_w)
-
-        return _decorate(
-            _observed_step(aux_step, obs_labels, edge_traffic),
-            lambda params, aux, opt_state, batch, step:
-            (params, aux, opt_state, batch, step, default_w))
-
-    if health is None:
-        def no_aux_step(params, opt_state, batch, step):
-            params, _, opt_state, loss = jitted(
-                params, (), opt_state, batch, step, default_w)
-            return params, opt_state, loss
-    else:
-        def no_aux_step(params, opt_state, batch, step):
-            params, _, opt_state, loss, hv = jitted(
-                params, (), opt_state, batch, step, default_w)
-            return params, opt_state, loss, hv
-
-    return _decorate(
-        _observed_step(no_aux_step, obs_labels, edge_traffic),
-        lambda params, opt_state, batch, step:
-        (params, (), opt_state, batch, step, default_w))
+        step_fn.guard_config = guard
+    if guarded or use_traced_w:
+        step_fn.default_comm_weights = default_w
+    return step_fn
 
 
 def build_train_step(
@@ -1643,8 +1618,21 @@ def build_train_step(
         supported in this mode.
       * ``"none"`` — no communication (pure local SGD)
 
-    Exactly one of ``topology`` (static) or ``schedule`` (dynamic, indexed
-    by ``step % len(schedule)`` via ``lax.switch``) for the neighbor modes.
+    Exactly one of ``topology`` (static) or ``schedule`` (dynamic) for the
+    neighbor modes.  A schedule of ``P`` rounds builds ``P`` programs
+    behind the one returned step (one ``jax.jit``, the round
+    ``step % P`` its static argument, picked on the host at each call):
+    ``P`` compiles, once, then none; a round's permutes are top-level
+    asynchronous operations and no ``conditional`` holds them.
+    ``num_steps_per_communication=k > 1`` adds the off-cycle program (no
+    exchange in it) by the same selector.  So with a schedule or ``k > 1``
+    ``step`` must be a concrete integer at every call (pass a Python or
+    NumPy integer; a device scalar costs a device-to-host read): under
+    someone's ``jit`` or ``scan`` it is a tracer and the step raises
+    ``TypeError`` — build a one-round step (``topology=schedule[r]``) or
+    call the step once a round.  A static topology exchanged every step,
+    ``"none"`` and ``"gradient_allreduce"`` are one program and read
+    nothing of ``step`` on the host.
 
     ``compress="int8"`` quantizes the cta/atc combine's wire payload
     (per-tensor absmax int8; see ``collectives.neighbor_allreduce``) —
@@ -1955,80 +1943,49 @@ def build_train_step(
     ] if comm_mode == "push_sum" else []
     k_comm = int(num_steps_per_communication)
 
+    # ``r`` is the program's static round (``_round_selector``); an
+    # off-cycle program (``r is None``) holds no collective at all
     @jax.named_scope(SCOPE_EXCHANGE)
-    def combine(params, step):
-        if not branches:
+    def combine(params, step, r):
+        if not branches or r is None:
             return params
-
-        def run(params):
-            # per-step key for the stochastic wire rounder (int8_sr);
-            # unused operands are dead-code-eliminated otherwise
-            key = jax.random.fold_in(
-                jax.random.PRNGKey(0x51EED), step)
-            if len(branches) == 1:
-                return branches[0](params, key)
-            return lax.switch(step % len(branches), branches, params, key)
-
-        if k_comm > 1:
-            # lax.cond actually skips the collectives on off-cycle steps
-            # (a select/where would still execute them every step).
-            return lax.cond(step % k_comm == 0, run, lambda p: p, params)
-        return run(params)
+        # per-step key for the stochastic wire rounder (int8_sr);
+        # unused operands are dead-code-eliminated otherwise
+        key = jax.random.fold_in(jax.random.PRNGKey(0x51EED), step)
+        return branches[r](params, key)
 
     @jax.named_scope(SCOPE_EXCHANGE)
-    def combine_push_sum(params, ps, step):
-        def run(operand):
-            params, ps = operand
-            # Push-sum state is the BIASED pair (x, w) with readout
-            # z = x / w; we carry (z, w) so the user-visible params stay
-            # de-biased, and re-bias before every mix (x = z * w) — mixing
-            # z directly is only correct on doubly-stochastic graphs and
-            # diverges on general digraphs.  The whole re-bias -> mix ->
-            # de-bias round stays in f32 (push_sum_mix returns the
-            # accumulation dtype); one cast back at the end.
-            dtypes = jax.tree.map(lambda z: z.dtype, params)
-            biased = jax.tree.map(
-                lambda z: z.astype(jnp.float32) * ps, params)
-            if len(ps_branches) == 1:
-                mixed, mixed_ps = ps_branches[0]((biased, ps))
-            else:
-                mixed, mixed_ps = lax.switch(
-                    step % len(ps_branches), ps_branches, (biased, ps))
-            # de-bias: z = x / w (reference optimizers.py:1151-1155)
-            debiased = jax.tree.map(
-                lambda x, dt: (x / mixed_ps).astype(dt), mixed, dtypes)
-            return debiased, mixed_ps
-
-        if k_comm > 1:
-            return lax.cond(step % k_comm == 0, run, lambda op: op,
-                            (params, ps))
-        return run((params, ps))
+    def combine_push_sum(params, ps, r):
+        if r is None:
+            return params, ps
+        # Push-sum state is the BIASED pair (x, w) with readout
+        # z = x / w; we carry (z, w) so the user-visible params stay
+        # de-biased, and re-bias before every mix (x = z * w) — mixing
+        # z directly is only correct on doubly-stochastic graphs and
+        # diverges on general digraphs.  The whole re-bias -> mix ->
+        # de-bias round stays in f32 (push_sum_mix returns the
+        # accumulation dtype); one cast back at the end.
+        dtypes = jax.tree.map(lambda z: z.dtype, params)
+        biased = jax.tree.map(
+            lambda z: z.astype(jnp.float32) * ps, params)
+        mixed, mixed_ps = ps_branches[r]((biased, ps))
+        # de-bias: z = x / w (reference optimizers.py:1151-1155)
+        debiased = jax.tree.map(
+            lambda x, dt: (x / mixed_ps).astype(dt), mixed, dtypes)
+        return debiased, mixed_ps
 
     @jax.named_scope(SCOPE_EXCHANGE)
-    def apply_then_combine(params, updates, step):
+    def apply_then_combine(params, updates, step, r):
         """ATC overlap engine: the interleaved per-bucket apply+combine
         (see _bucketed_apply_combine_fn).  Off-cycle steps under
-        num_steps_per_communication still apply the optax update —
-        only the collectives are skipped (lax.cond, like combine())."""
-        if not ac_branches:
+        num_steps_per_communication still apply the optax update."""
+        if not ac_branches or r is None:
             return _apply_updates(params, updates)
+        key = jax.random.fold_in(jax.random.PRNGKey(0x51EED), step)
+        return ac_branches[r]((params, updates), key)
 
-        def run(operand):
-            params, updates = operand
-            key = jax.random.fold_in(
-                jax.random.PRNGKey(0x51EED), step)
-            if len(ac_branches) == 1:
-                return ac_branches[0]((params, updates), key)
-            return lax.switch(step % len(ac_branches), ac_branches,
-                              (params, updates), key)
-
-        if k_comm > 1:
-            return lax.cond(step % k_comm == 0, run,
-                            lambda op: _apply_updates(op[0], op[1]),
-                            (params, updates))
-        return run((params, updates))
-
-    def per_rank_step(params, aux, opt_state, batch, step):
+    select = _round_selector(comm_mode, specs, k_comm)
+    def per_rank_step(r, params, aux, opt_state, batch, step):
         loss, grads, new_aux = _loss_and_grads(
             loss_fn, has_aux, sp_axis, pp_axis, param_specs,
             params, aux, batch)
@@ -2041,7 +1998,7 @@ def build_train_step(
         if comm_mode == "push_sum":
             base_state, ps = opt_state
             pre = params
-            params, ps = combine_push_sum(params, ps, step)
+            params, ps = combine_push_sum(params, ps, r)
             if health is not None and health.consensus:
                 consensus = _tree_distance(pre, params)
             updates, base_state = _opt_update(
@@ -2052,12 +2009,12 @@ def build_train_step(
             return params, new_aux, (base_state, ps), loss, hv
         if comm_mode == "cta":
             pre = params
-            params = combine(params, step)
+            params = combine(params, step, r)
             if health is not None and health.consensus:
                 consensus = _tree_distance(pre, params)
         updates, opt_state = _opt_update(optimizer, grads, opt_state, params)
         if atc_bucketed:
-            new_params = apply_then_combine(params, updates, step)
+            new_params = apply_then_combine(params, updates, step, r)
             if health is not None and health.consensus:
                 # the per-bucket applies inside apply_then_combine are
                 # the same pure arithmetic — XLA CSEs the duplicate
@@ -2068,7 +2025,7 @@ def build_train_step(
             params = _apply_updates(params, updates)
             if comm_mode == "atc":
                 pre = params
-                params = combine(params, step)
+                params = combine(params, step, r)
                 if health is not None and health.consensus:
                     consensus = _tree_distance(pre, params)
         hv = (_make_health_vector(loss, grad_sq, updates, consensus)
@@ -2081,10 +2038,10 @@ def build_train_step(
     obs_labels = dict(comm_mode=comm_mode, overlap=overlap,
                       guarded="false")
 
-    def wrapped(params, aux, opt_state, batch, step):
+    def per_shard(r, params, aux, opt_state, batch, step):
         # strip the leading per-shard rank axis of size 1
         params, aux, opt_state, loss, hv = per_rank_step(
-            squeeze(params), squeeze(aux), squeeze(opt_state),
+            r, squeeze(params), squeeze(aux), squeeze(opt_state),
             squeeze(batch), step)
         outs = (expand(params), expand(aux), expand(opt_state),
                 jnp.reshape(loss, (1,)))
@@ -2104,54 +2061,29 @@ def build_train_step(
     out_specs = (p_params, p_rank, p_opt, p_rank)
     if health is not None:
         out_specs = out_specs + (p_rank,)  # spec prefix over HealthVector
-    sm = jax.shard_map(
-        wrapped,
-        mesh=mesh,
-        in_specs=(p_params, p_rank, p_opt, batch_specs, P()),
-        out_specs=out_specs,
-        check_vma=False,
-    )
+
+    def wrapped(params, aux, opt_state, batch, step, r):
+        return jax.shard_map(
+            partial(per_shard, r),
+            mesh=mesh,
+            in_specs=(p_params, p_rank, p_opt, batch_specs, P()),
+            out_specs=out_specs,
+            check_vma=False,
+        )(params, aux, opt_state, batch, step)
+
     donate_argnums = (0, 1, 2) if donate else ()
-    jitted = jax.jit(sm, donate_argnums=donate_argnums)
+    jitted = jax.jit(wrapped, static_argnums=5,
+                     donate_argnums=donate_argnums)
     # traffic accounting only for modes that actually run a neighbor
     # exchange — a topology passed alongside comm_mode='none' /
     # 'gradient_allreduce' must not count phantom edge bytes
-    edge_traffic = (list(specs), 4 if has_aux else 3, k_comm,
-                    int(mesh.shape[axis_name]),
+    edge_traffic = (list(specs), int(mesh.shape[axis_name]),
                     comm_mode == "push_sum",
                     hierarchical_local_size
                     if comm_mode in ("cta", "atc") else None) \
         if (specs and needs_topo) else None
-    if has_aux:
-        aux_step = _observed_step(jitted, obs_labels, edge_traffic)
-        aux_step.jitted = jitted
-        aux_step.lower = jitted.lower
-        aux_step.trace = jitted.trace
-        aux_step.health_config = health
-        aux_step.hierarchical_local_size = \
-            hierarchical_local_size if comm_mode in ("cta", "atc") else None
-        return aux_step
-
-    if health is None:
-        def no_aux_step(params, opt_state, batch, step):
-            params, _, opt_state, loss = jitted(
-                params, (), opt_state, batch, step)
-            return params, opt_state, loss
-    else:
-        def no_aux_step(params, opt_state, batch, step):
-            params, _, opt_state, loss, hv = jitted(
-                params, (), opt_state, batch, step)
-            return params, opt_state, loss, hv
-
-    step_fn = _observed_step(no_aux_step, obs_labels, edge_traffic)
-    # AOT access for benchmarks: lower/compile the real program (e.g. for
-    # XLA cost analysis / MFU accounting) without re-jitting the wrapper;
-    # .trace is the jaxpr-inspection analog bluefog_tpu.analysis uses.
-    step_fn.jitted = jitted
-    step_fn.lower = lambda params, opt_state, batch, step: jitted.lower(
-        params, (), opt_state, batch, step)
-    step_fn.trace = lambda params, opt_state, batch, step: jitted.trace(
-        params, (), opt_state, batch, step)
+    step_fn = _public_step(jitted, obs_labels, select=select,
+                           has_aux=has_aux, edge_traffic=edge_traffic)
     step_fn.health_config = health
     step_fn.hierarchical_local_size = \
         hierarchical_local_size if comm_mode in ("cta", "atc") else None
@@ -2195,26 +2127,16 @@ def _build_guarded_train_step(
     ] if neighbor else []
 
     @jax.named_scope(SCOPE_EXCHANGE)
-    def combine(params, step, comm_weights):
-        if not wbranches:
+    def combine(params, step, comm_weights, r):
+        # ``r``: the program's static round, None off-cycle
+        if not wbranches or r is None:
             return params
+        key = jax.random.fold_in(jax.random.PRNGKey(0x51EED), step)
+        return wbranches[r](params, key, comm_weights[r])
 
-        def run(params):
-            key = jax.random.fold_in(jax.random.PRNGKey(0x51EED), step)
-            if len(wbranches) == 1:
-                return wbranches[0](params, key, comm_weights[0])
-            picked = [
-                (lambda fn, i: lambda p, k, ws: fn(p, k, ws[i]))(fn, i)
-                for i, fn in enumerate(wbranches)
-            ]
-            return lax.switch(step % len(wbranches), picked, params, key,
-                              comm_weights)
-
-        if k_comm > 1:
-            return lax.cond(step % k_comm == 0, run, lambda p: p, params)
-        return run(params)
-
-    def per_rank_step(params, aux, opt_state, batch, step, comm_weights):
+    select = _round_selector(comm_mode, specs, k_comm)
+    def per_rank_step(r, params, aux, opt_state, batch, step,
+                      comm_weights):
         loss, grads, new_aux = _loss_and_grads(
             loss_fn, has_aux, sp_axis, pp_axis, param_specs,
             params, aux, batch)
@@ -2228,7 +2150,7 @@ def _build_guarded_train_step(
             grads = _allreduce_grads(grads, axis_name)
         if comm_mode == "cta":
             pre = params
-            params = combine(params, step, comm_weights)
+            params = combine(params, step, comm_weights, r)
             if health is not None and health.consensus:
                 consensus = _tree_distance(pre, params)
         updates, new_opt_state = _opt_update(
@@ -2256,7 +2178,7 @@ def _build_guarded_train_step(
         out_opt = jax.tree.map(pick, new_opt_state, opt_state)
         if comm_mode == "atc":
             pre = params
-            params = combine(params, step, comm_weights)
+            params = combine(params, step, comm_weights, r)
             if health is not None and health.consensus:
                 consensus = _tree_distance(pre, params)
         skipped = jnp.where(ok, jnp.int32(0), jnp.int32(1))
@@ -2268,9 +2190,9 @@ def _build_guarded_train_step(
     squeeze = lambda t: jax.tree.map(lambda x: x[0], t)
     expand = lambda t: jax.tree.map(lambda x: x[None], t)
 
-    def wrapped(params, aux, opt_state, batch, step, comm_weights):
+    def per_shard(r, params, aux, opt_state, batch, step, comm_weights):
         params, aux, opt_state, loss, skipped, hv = per_rank_step(
-            squeeze(params), squeeze(aux), squeeze(opt_state),
+            r, squeeze(params), squeeze(aux), squeeze(opt_state),
             squeeze(batch), step, comm_weights)
         outs = (expand(params), expand(aux), expand(opt_state),
                 jnp.reshape(loss, (1,)), jnp.reshape(skipped, (1,)))
@@ -2289,66 +2211,32 @@ def _build_guarded_train_step(
     out_specs = (p_params, p_rank, p_opt, p_rank, p_rank)
     if health is not None:
         out_specs = out_specs + (p_rank,)  # spec prefix over HealthVector
-    sm = jax.shard_map(
-        wrapped,
-        mesh=mesh,
-        in_specs=(p_params, p_rank, p_opt, batch_specs, P(), p_comm),
-        out_specs=out_specs,
-        check_vma=False,
-    )
-    donate_argnums = (0, 1, 2) if donate else ()
-    jitted = jax.jit(sm, donate_argnums=donate_argnums)
-    default_w = comm_weight_inputs(specs) if wbranches else ()
 
+    def wrapped(params, aux, opt_state, batch, step, comm_weights, r):
+        return jax.shard_map(
+            partial(per_shard, r),
+            mesh=mesh,
+            in_specs=(p_params, p_rank, p_opt, batch_specs, P(), p_comm),
+            out_specs=out_specs,
+            check_vma=False,
+        )(params, aux, opt_state, batch, step, comm_weights)
+
+    donate_argnums = (0, 1, 2) if donate else ()
+    jitted = jax.jit(wrapped, static_argnums=6,
+                     donate_argnums=donate_argnums)
     obs_labels = dict(
         comm_mode=comm_mode,
         overlap="bucketed" if n_buckets is not None else "none",
         guarded="true")
-
     # guarded steps are cta/atc only — neighbor_allreduce moves bytes
     # on every declared edge, so the unfiltered edge set is correct
-    edge_traffic = (list(specs), 4 if has_aux else 3, k_comm,
-                    int(mesh.shape[axis_name]), False,
+    edge_traffic = (list(specs), int(mesh.shape[axis_name]), False,
                     hierarchical_local_size) \
         if wbranches else None
-    if has_aux:
-        def aux_step(params, aux, opt_state, batch, step, comm_weights):
-            return jitted(params, aux, opt_state, batch, step,
-                          comm_weights)
-
-        step_fn = _observed_step(aux_step, obs_labels, edge_traffic)
-        step_fn.jitted = jitted
-        step_fn.lower = jitted.lower
-        step_fn.trace = jitted.trace
-        step_fn.default_comm_weights = default_w
-        step_fn.has_aux = True  # run_resilient rejects aux signatures
-        step_fn.guard_config = guard
-        step_fn.health_config = health
-        step_fn.hierarchical_local_size = \
-            hierarchical_local_size if neighbor else None
-        return step_fn
-
-    if health is None:
-        def no_aux_step(params, opt_state, batch, step, comm_weights):
-            params, _, opt_state, loss, skipped = jitted(
-                params, (), opt_state, batch, step, comm_weights)
-            return params, opt_state, loss, skipped
-    else:
-        def no_aux_step(params, opt_state, batch, step, comm_weights):
-            params, _, opt_state, loss, skipped, hv = jitted(
-                params, (), opt_state, batch, step, comm_weights)
-            return params, opt_state, loss, skipped, hv
-
-    step_fn = _observed_step(no_aux_step, obs_labels, edge_traffic)
-    step_fn.jitted = jitted
-    step_fn.lower = (
-        lambda params, opt_state, batch, step, comm_weights:
-        jitted.lower(params, (), opt_state, batch, step, comm_weights))
-    step_fn.trace = (
-        lambda params, opt_state, batch, step, comm_weights:
-        jitted.trace(params, (), opt_state, batch, step, comm_weights))
-    step_fn.default_comm_weights = default_w
-    step_fn.has_aux = False
+    step_fn = _public_step(jitted, obs_labels, select=select,
+                           has_aux=has_aux, edge_traffic=edge_traffic)
+    step_fn.default_comm_weights = \
+        comm_weight_inputs(specs) if wbranches else ()
     step_fn.guard_config = guard
     step_fn.health_config = health
     step_fn.hierarchical_local_size = \

@@ -15,8 +15,8 @@ jitted-program *inputs*, never shapes, so nothing ever recompiles:
   the heartbeat beacons;
 * :mod:`~bluefog_tpu.resilience.healing` — dead-rank excision as a
   weight re-planning problem: row-stochasticity-preserving healed
-  weights delivered as traced DATA through the train step's existing
-  ``lax.switch`` schedule machinery;
+  weights delivered as traced DATA to the train step's programs (one
+  a round of the schedule, the tables an operand of each);
 * :mod:`~bluefog_tpu.resilience.runner` — ``run_resilient``, the
   skip -> detect -> heal -> rollback-with-backoff control loop over the
   ``Checkpointer``.

@@ -24,8 +24,8 @@ Delivery: :func:`healed_comm_weights` emits the same
 ``(class_weights, self_weights)`` pytree as
 ``optim.functional.comm_weight_inputs`` — same shapes over the same
 shift classes — so a guarded train step swaps topologies as pure input
-data through its existing ``lax.switch`` schedule machinery.  Zero
-recompiles is the whole point: the zero-weight edges still transfer
+data: each round's compiled program takes that round's tables as an
+operand.  Zero recompiles is the whole point: the zero-weight edges still transfer
 (the reference also ships scaled-by-zero payloads rather than skipping
 sends, mpi_controller.cc:594-600), which is sound because the skip
 guard keeps every rank's params finite — 0 * finite == 0.

@@ -390,8 +390,11 @@ def run_resilient(
                 # BLUEFOG_OP_TIMEOUT layer owns this failure class
             batch = fault_plan.corrupt_batch(batch, step)
         t_step = time.monotonic()
+        # (a NumPy integer: a scheduled step picks its round's program
+        # from ``step`` on the host, and a device scalar would cost a
+        # read a dispatch)
         out = train_step(
-            params, opt_state, batch, jnp.int32(step), comm_weights)
+            params, opt_state, batch, np.int32(step), comm_weights)
         # a health-built step appends the HealthVector; the loop keys
         # on the guard outputs either way
         params, opt_state, loss, skipped = out[:4]

@@ -60,8 +60,9 @@ def one_peer_dynamic_schedule(topo, rounds: int = None) -> list:
     one-peer rounds as ``DynamicTopology`` specs with the reference's
     uniform combine weights 1/(in_degree+1) (reference
     torch/mpi_ops.py:504-510).  Feed the result to
-    ``optim.functional.build_train_step(schedule=...)`` — the step index
-    picks the round via ``lax.switch``.
+    ``optim.functional.build_train_step(schedule=...)`` — the host picks
+    the round's compiled program from the step index (one program a
+    round: ``len(schedule)`` compiles, then none).
 
     ``topo``: a DiGraph, or an int n for ExponentialTwoGraph(n) — BlueFog's
     O(1)-communication-per-step graph (reference README.rst:51-60).

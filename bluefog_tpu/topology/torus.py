@@ -348,7 +348,8 @@ def default_pod_schedule(
     compressor (benchmarks/scaling_projection_r05.json).
 
     Returns ``(schedule, report)``: the winning round list (feed it to
-    ``optim.functional.build_train_step(schedule=...)``, or iterate it
+    ``optim.functional.build_train_step(schedule=...)``, which compiles
+    one program a round, or iterate it
     as the per-step weight schedule for the eager
     ``api.neighbor_allreduce`` dynamic mode) and the per-candidate score
     table the choice was made from.

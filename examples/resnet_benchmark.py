@@ -4,8 +4,9 @@ optimizer and per-step dynamic topology.
 TPU twin of reference examples/pytorch_benchmark.py (+ the dynamic-topology
 update pattern of examples/pytorch_resnet.py:333-372).  Uses the fully-
 jitted train step (bluefog_tpu.optim.functional): the dynamic one-peer
-exponential-2 schedule is compiled once and selected by step index — the
-per-iteration "dynamic_topology_update" becomes a lax.switch, not a retrace.
+exponential-2 schedule is compiled once (one program a round) and the
+round's program is picked by step index — the per-iteration
+"dynamic_topology_update" becomes a choice among executables, not a retrace.
 
   --dist-optimizer neighbor_allreduce : ATC over the static exp2 graph
   --dist-optimizer dynamic            : one-peer exp2 schedule (BlueFog's

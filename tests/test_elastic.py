@@ -604,14 +604,17 @@ def test_preempt_rejoin_cycle_zero_recompiles(tmp_path):
     """Acceptance (d): preempt a rank past the death window — the
     fleet declares it dead, heals, rolls back; the window ends, the
     rank is admitted (rank_joining), bootstraps under quarantine, and
-    is PROMOTED back to a fully-live fleet — all through the ONE
-    compiled program (join/leave/rejoin are pure weight data)."""
+    is PROMOTED back to a fully-live fleet — all through the programs
+    one cycle of the schedule compiled, one a round (join/leave/rejoin
+    are pure weight data)."""
     step_g, sched, mesh = _guarded_step()
-    params, opt_state = _state(mesh)
-    step_g(params, opt_state, _batch_fn(0), jnp.int32(0),
-           step_g.default_comm_weights)
+    for s in range(len(sched)):
+        params, opt_state = _state(mesh)  # a call donates the buffers
+        step_g(params, opt_state, _batch_fn(0), np.int32(s),
+               step_g.default_comm_weights)
     baseline = step_g.jitted._cache_size()
-    params, opt_state = _state(mesh)  # the warm-up donated the buffers
+    assert baseline == len(sched)
+    params, opt_state = _state(mesh)
     plan = R.FaultPlan.preempt(N, rank=2, step=6, duration=6)
     ck = Checkpointer(str(tmp_path / "ck"))
     res = R.run_resilient(

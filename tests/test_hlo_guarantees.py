@@ -8,7 +8,8 @@ schedule (reference README.rst:51-60) and log2(n) permutes for the static
 exponential-2 graph.  These tests compile the real programs and count
 ``collective-permute`` ops in the optimized HLO, turning the docstring claim
 into a regression-guarded fact — and verify the dynamic schedule compiles
-ONE program (no retrace across rounds).
+one program a ROUND, each holding that round's permutes at its top level
+(no ``conditional``), with the model traced once for all of them.
 """
 
 import re
@@ -89,10 +90,11 @@ def test_ring_combine_is_one_permute_per_direction(mesh):
     assert _count_permutes(_compiled_hlo(_sharded_combine(mesh, bi), x)) == 2
 
 
-def test_dynamic_schedule_compiles_one_program(mesh):
-    """The full dynamic train step traces ONCE: the round is selected by
-    ``lax.switch`` on the step operand, so stepping through the schedule
-    never retraces or recompiles (SURVEY.md §7 hard part #2)."""
+def test_dynamic_schedule_compiles_one_program_a_round(mesh):
+    """The full dynamic train step traces the MODEL once and compiles
+    one program a round: the host picks the round's executable from the
+    step it is called with, so stepping through the schedule a second
+    time neither retraces nor recompiles (SURVEY.md §7 hard part #2)."""
     schedule = one_peer_dynamic_schedule(N)
     trace_count = 0
 
@@ -114,42 +116,46 @@ def test_dynamic_schedule_compiles_one_program(mesh):
     for step in range(2 * len(schedule)):
         params, opt_state, _ = step_fn(params, opt_state, batch,
                                        jnp.asarray(step))
+        assert step_fn.jitted._cache_size() == min(step + 1, len(schedule))
     assert trace_count == 1, (
         f"dynamic schedule retraced: loss_fn traced {trace_count} times "
         f"over {2 * len(schedule)} steps")
 
 
 def test_dynamic_step_program_permute_total_is_schedule_size(mesh):
-    """The compiled dynamic step contains one permute per switch branch
-    (= log2(n) total across the whole program); at runtime exactly one
-    branch executes, so the per-step wire cost is a single permute."""
+    """Each round of the dynamic step is its own program holding ONE
+    permute (= log2(n) across the schedule's programs), at the
+    program's top level and under no conditional: the per-step wire
+    cost is a single permute that waits for its own operand alone."""
     schedule = one_peer_dynamic_schedule(N)
-
-    def combine(x, step):
-        branches = [
-            (lambda s: lambda v: C.neighbor_allreduce(v, s, "bf"))(s)
-            for s in schedule
-        ]
-        return jax.lax.switch(step % len(branches), branches, x)
-
-    sm = jax.shard_map(combine, mesh=mesh, in_specs=(P("bf"), P()),
-                       out_specs=P("bf"), check_vma=False)
-    x = jnp.zeros((N, 64), jnp.float32)
-    hlo = _compiled_hlo(sm, x, jnp.asarray(0))
-    assert _count_permutes(hlo) == len(schedule)
-    # and the branches live under a conditional, not flattened inline
-    assert "conditional" in hlo
+    step_fn = F.build_train_step(
+        lambda params, batch: jnp.mean((batch @ params["w"]) ** 2),
+        optax.sgd(0.1), mesh, comm_mode="cta", schedule=schedule,
+        donate=False)
+    sharding = NamedSharding(mesh, P("bf"))
+    params = {"w": jax.device_put(jnp.ones((N, 4, 2)), sharding)}
+    opt_state = F.rank_major(optax.sgd(0.1).init({"w": jnp.ones((4, 2))}),
+                             mesh)
+    batch = jax.device_put(jnp.ones((N, 3, 4)), sharding)
+    total = 0
+    for r in range(len(schedule)):
+        hlo = step_fn.lower(params, opt_state, batch,
+                            r).compile().as_text()
+        assert _count_permutes(hlo) == 1, r
+        assert "conditional" not in hlo, r
+        total += _count_permutes(hlo)
+    assert total == len(schedule)
 
 
 @pytest.mark.topology
 def test_compiled_schedule_lowers_to_predicted_permutes_and_bytes(mesh):
     """ISSUE 7 acceptance: the topology compiler's cost model and the
     real lowering must agree.  Compile the (1, 8)-pod schedule (its
-    winner carries bidirectional multi-shift rounds), lower it as one
-    lax.switch dynamic program (exactly how build_train_step consumes
-    it), and hold the compiled HLO to the prediction: the predicted
-    permute count per round — shift classes after the lowering's
-    in-degree-1 fusion rule — all branches present in the one program,
+    winner carries bidirectional multi-shift rounds), lower it one
+    program a round (exactly how build_train_step consumes it), and
+    hold the compiled HLO to the prediction: the predicted permute
+    count per round — shift classes after the lowering's in-degree-1
+    fusion rule — the rounds' programs together holding the period's,
     each permute carrying exactly the per-rank payload bytes, measured
     through benchutil.scheduled_collective_windows."""
     from bluefog_tpu import benchutil as BU
@@ -161,26 +167,19 @@ def test_compiled_schedule_lowers_to_predicted_permutes_and_bytes(mesh):
     pred = compiled.predicted_collectives(payload)
     assert pred["permutes_per_period"] > len(schedule)  # multi-shift
 
-    def combine(x, step):
-        branches = [
-            (lambda s: lambda v: C.neighbor_allreduce(v, s, "bf"))(s)
-            for s in schedule
-        ]
-        return jax.lax.switch(step % len(branches), branches, x)
-
-    sm = jax.shard_map(combine, mesh=mesh, in_specs=(P("bf"), P()),
-                       out_specs=P("bf"), check_vma=False)
     x = jnp.zeros((N, 64), jnp.float32)
-    hlo = _compiled_hlo(sm, x, jnp.asarray(0))
     # thin wrapper over the supported contract check (count, per-permute
-    # payload, total bytes — the assertions this test used to hand-roll)
-    assert BU.verify_collective_contract(hlo, pred, payload) == []
-    # and per round: lowering each branch alone reproduces the
-    # per-round permute counts the cost model charged
-    for i, rnd in enumerate(schedule):
-        hlo_r = _compiled_hlo(_sharded_combine(mesh, rnd), x)
+    # payload, total bytes — the assertions this test used to hand-roll):
+    # each round's program reproduces the per-round permute counts the
+    # cost model charged, and together they are the period
+    rounds = [_compiled_hlo(_sharded_combine(mesh, rnd), x)
+              for rnd in schedule]
+    for i, hlo_r in enumerate(rounds):
         assert BU.verify_collective_contract(
             hlo_r, pred, payload, round_index=i) == []
+        assert "conditional" not in hlo_r
+    assert sum(_count_permutes(h) for h in rounds) == \
+        pred["permutes_per_period"]
 
 
 @pytest.mark.topology
@@ -367,17 +366,17 @@ def _overlap_problem():
     return base, loss_fn
 
 
-def _lower_step(mesh, base, loss_fn, **kw):
+def _lower_step(mesh, base, loss_fn, step=0, **kw):
     import optax as ox
 
     opt = ox.sgd(0.05)
-    step = F.build_train_step(loss_fn, opt, mesh, donate=False, **kw)
+    step_fn = F.build_train_step(loss_fn, opt, mesh, donate=False, **kw)
     params = F.rank_major(base, mesh)
     ostate = F.rank_major(opt.init(base), mesh)
     batch = jax.device_put(
         np.zeros((N, 8, 16)), NamedSharding(mesh, P("bf")))
-    return step.lower(params, ostate, batch,
-                      jnp.int32(0)).compile().as_text()
+    return step_fn.lower(params, ostate, batch,
+                         np.int32(step)).compile().as_text()
 
 
 def _permute_gap_flops(hlo):
@@ -459,17 +458,18 @@ def test_unbucketed_step_is_per_leaf_tail_exchange(mesh):
 
 
 def test_bucketed_dynamic_schedule_total_permutes(mesh):
-    """The bucketed combine plumbs through the lax.switch dynamic
-    schedule: the one compiled program holds >= K permutes per branch
-    (one branch executes per step), under a conditional."""
+    """The bucketed combine plumbs through the dynamic schedule: each
+    round's program holds >= K permutes (one program runs per step),
+    under no conditional."""
     K = 3
     base, loss_fn = _overlap_problem()
     schedule = one_peer_dynamic_schedule(N)
-    hlo = _lower_step(mesh, base, loss_fn, comm_mode="atc",
-                      schedule=schedule, overlap="bucketed",
-                      overlap_buckets=K)
-    assert _count_permutes(hlo) >= K * len(schedule)
-    assert "conditional" in hlo
+    for r in range(len(schedule)):
+        hlo = _lower_step(mesh, base, loss_fn, step=r, comm_mode="atc",
+                          schedule=schedule, overlap="bucketed",
+                          overlap_buckets=K)
+        assert _count_permutes(hlo) >= K, r
+        assert "conditional" not in hlo, r
 
 
 _ASYNC_FIXTURE = """\

@@ -276,12 +276,15 @@ def _moe_batch(put, route, cmask, seed=0):
 
 def test_expert_kill_heal_cycle_zero_recompiles(mesh, plan):
     """ISSUE 19 acceptance: an expert-machine kill→heal cycle through
-    the fused step is pure traced data — the jit cache never grows,
-    expert weights stay rank-local, the router keeps mixing."""
+    the fused step is pure traced data — the round's program is the
+    one its first visit compiled (steps 0, P, 2P of a P-round schedule
+    run round 0), expert weights stay rank-local, the router keeps
+    mixing."""
     loss_fn = make_moe_loss(plan, "bf", 3)
+    sched = torus_one_peer_schedule((4, 2), "exp2")
+    P_ = len(sched)
     step = F.build_train_step(loss_fn, _OPT, mesh, comm_mode="cta",
-                              schedule=torus_one_peer_schedule(
-                                  (4, 2), "exp2"),
+                              schedule=sched,
                               moe=F.MoEConfig(n_experts=4, capacity=3))
     assert step.moe_config.n_experts == 4
     p, o, put = _moe_state(mesh)
@@ -296,11 +299,11 @@ def test_expert_kill_heal_cycle_zero_recompiles(mesh, plan):
     dead[5] = True
     healed = heal_route_table(route, dead, 4)
     p, o, _ = step(p, o, _moe_batch(put, healed, capacity_mask_of(dead),
-                                    seed=1), jnp.int32(1))
+                                    seed=1), jnp.int32(P_))
     # heal back: the machine returns
     p, o, _ = step(p, o, _moe_batch(put, route, cmask0, seed=2),
-                   jnp.int32(2))
-    assert step.jitted._cache_size() == baseline
+                   jnp.int32(2 * P_))
+    assert step.jitted._cache_size() == baseline == 1
     wi = np.asarray(p["expert"]["wi"])
     assert not np.allclose(wi[0], wi[1])     # experts stayed local
     rw = np.asarray(p["router"]["w"])
@@ -310,9 +313,9 @@ def test_expert_kill_heal_cycle_zero_recompiles(mesh, plan):
 
 def test_moe_composes_with_guard_and_health(mesh, plan):
     loss_fn = make_moe_loss(plan, "bf", 3)
+    sched = torus_one_peer_schedule((4, 2), "exp2")
     step = F.build_train_step(loss_fn, _OPT, mesh, comm_mode="atc",
-                              schedule=torus_one_peer_schedule(
-                                  (4, 2), "exp2"),
+                              schedule=sched,
                               guard=F.GuardConfig(),
                               health=F.HealthConfig(),
                               moe=F.MoEConfig(n_experts=4, capacity=3))
@@ -327,8 +330,8 @@ def test_moe_composes_with_guard_and_health(mesh, plan):
     out = step(out[0], out[1],
                _moe_batch(put, heal_route_table(route, dead, 4),
                           capacity_mask_of(dead), seed=1),
-               jnp.int32(1), w)
-    assert step.jitted._cache_size() == baseline
+               jnp.int32(len(sched)), w)    # round 0 again, healed data
+    assert step.jitted._cache_size() == baseline == 1
     assert isinstance(out[-1], F.HealthVector)
 
 
@@ -337,9 +340,9 @@ def test_moe_topk_mix_covers_only_shared_leaves(mesh, plan):
     layout cover the router ONLY — expert leaves never touch the
     consensus wire, compressed or not."""
     loss_fn = make_moe_loss(plan, "bf", 3)
+    sched = torus_one_peer_schedule((4, 2), "exp2")
     step = F.build_train_step(
-        loss_fn, _OPT, mesh, comm_mode="cta",
-        schedule=torus_one_peer_schedule((4, 2), "exp2"),
+        loss_fn, _OPT, mesh, comm_mode="cta", schedule=sched,
         compress=F.MixCompressConfig(ratio=0.5),
         moe=F.MoEConfig(n_experts=4, capacity=3))
     p, o, put = _moe_state(mesh)
@@ -358,8 +361,8 @@ def test_moe_topk_mix_covers_only_shared_leaves(mesh, plan):
     p, state, _ = step(p, state,
                        _moe_batch(put, heal_route_table(route, dead, 4),
                                   capacity_mask_of(dead), seed=1),
-                       jnp.int32(1))
-    assert step.jitted._cache_size() == baseline
+                       jnp.int32(len(sched)))   # round 0, healed data
+    assert step.jitted._cache_size() == baseline == 1
     wi = np.asarray(p["expert"]["wi"])
     assert not np.allclose(wi[0], wi[1])
 

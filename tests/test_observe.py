@@ -528,13 +528,14 @@ def _step_args(step, args, i):
     return out
 
 
-def _stripped_hlo(step, args):
-    """The optimized HLO of the step with what a named scope may change
-    taken out: every ``metadata={...}`` and the tables of file and
-    function names that the stack frames refer to."""
+def _stripped_hlo(step, args, i=0):
+    """The optimized HLO of the step's program for step ``i`` with what
+    a named scope may change taken out: every ``metadata={...}`` and
+    the tables of file and function names that the stack frames refer
+    to."""
     import re
 
-    text = step.lower(*_step_args(step, args, 0)).compile().as_text()
+    text = step.lower(*_step_args(step, args, i)).compile().as_text()
     out, skip = [], False
     for line in text.splitlines():
         if line in ("FileNames", "FunctionNames", "FileLocations",
@@ -587,24 +588,33 @@ def test_observe_toggle_leaves_compiled_programs_untouched(monkeypatch,
 ])
 def test_named_scopes_show_in_compiled_op_names(kind, scopes):
     """The parts of the train step are named in the compiled program's
-    ``op_name`` metadata — inside the ``lax.switch`` branches of the
-    exchange too — and JAX's own ``transpose(jvp(...))`` below
+    ``op_name`` metadata — in every round's program of a scheduled
+    step, the exchange's permutes under ``bf.exchange`` at the
+    program's top level — and JAX's own ``transpose(jvp(...))`` below
     ``bf.forward_backward`` splits forward from backward."""
     import re
 
+    from bluefog_tpu.topology.dynamic import one_peer_dynamic_schedule
+
     mesh = Mesh(np.array(jax.devices()[:N]), ("bf",))
     step, args = _scoped_step(mesh, kind)
-    names = set(re.findall(r'op_name="([^"]*)"',
-                           _stripped_hlo(step, args)[0]))
-    for scope in scopes:
-        assert any(f"/{scope}/" in n for n in names), (scope, kind)
-    if "bf.exchange" not in scopes:
-        assert not any("bf.exchange" in n for n in names)
-    elif kind != "bucketed":    # two rounds: a switch over two branches
-        assert any(re.search(r"bf\.exchange/.*branch_1_fun/.*ppermute", n)
+    rounds = 1 if kind in ("none", "bucketed") else \
+        len(one_peer_dynamic_schedule(N))
+    for r in range(rounds):
+        text = _stripped_hlo(step, args, r)[0]
+        names = set(re.findall(r'op_name="([^"]*)"', text))
+        for scope in scopes:
+            assert any(f"/{scope}/" in n for n in names), (scope, kind, r)
+        if "bf.exchange" not in scopes:
+            assert not any("bf.exchange" in n for n in names)
+        else:
+            assert any(re.search(r"bf\.exchange/.*ppermute", n)
+                       for n in names), (kind, r)
+            assert "conditional" not in text and \
+                not any("branch_" in n for n in names), (kind, r)
+        assert any("bf.forward_backward/transpose(jvp(" in n
                    for n in names)
-    assert any("bf.forward_backward/transpose(jvp(" in n for n in names)
-    assert any("bf.forward_backward/jvp(" in n for n in names)
+        assert any("bf.forward_backward/jvp(" in n for n in names)
 
 
 def test_train_step_publishes_and_opt_out(monkeypatch):

@@ -426,17 +426,20 @@ def test_rollback_restores_exact_checkpoint(tmp_path):
 
 
 def test_zero_recompiles_across_fault_patterns(tmp_path):
-    """Acceptance (d), compile half: the SAME compiled program serves a
-    healthy run, a transient NaN burst, and a rank death with healed
-    weights — fault patterns are pure input data (asserted the way
+    """Acceptance (d), compile half: the SAME compiled programs (one a
+    round of the schedule, all there after one cycle) serve a healthy
+    run, a transient NaN burst, and a rank death with healed weights —
+    fault patterns are pure input data (asserted the way
     test_serving.py asserts compile counts)."""
     step_g, sched, mesh = _guarded_step()
-    # the shared step may have been compiled by an earlier test; pin
-    # whatever the count is now and require it never grows
-    params, opt_state = _state(mesh)
-    step_g(params, opt_state, _batch_fn(0), jnp.int32(0),
-           step_g.default_comm_weights)
+    for s in range(len(sched)):
+        params, opt_state = _state(mesh)  # a call donates the buffers
+        step_g(params, opt_state, _batch_fn(0), np.int32(s),
+               step_g.default_comm_weights)
+    # (the shared step may hold entries of earlier tests too: pin the
+    # count after the cycle and require that it never grows)
     baseline = step_g.jitted._cache_size()
+    assert baseline >= len(sched)
     plans = [
         R.FaultPlan.healthy(N),
         R.FaultPlan.nan_burst(N, rank=1, step=2, duration=2),

@@ -440,15 +440,17 @@ def test_control_requires_matching_carrier():
 
 def test_shrink_swap_cycle_zero_recompiles_e2e(tmp_path):
     """Two ranks die -> membership trigger -> inline synthesis ->
-    swap -> probation -> commit, all through the ONE compiled step.
-    The delivered weights at every boundary stay carrier-shaped, so
-    the jit cache never grows."""
+    swap -> probation -> commit, all through the programs one cycle of
+    the carrier compiled (one a round).  The delivered weights at every
+    boundary stay carrier-shaped, so the jit cache never grows."""
     step_g, sched, mesh = _e2e_setup()
-    params, opt_state = _e2e_state(mesh)
-    step_g(params, opt_state, _e2e_batch(0), jnp.int32(0),
-           step_g.default_comm_weights)
+    for s in range(len(sched)):
+        params, opt_state = _e2e_state(mesh)  # a call donates the buffers
+        step_g(params, opt_state, _e2e_batch(0), np.int32(s),
+               step_g.default_comm_weights)
     baseline = step_g.jitted._cache_size()
-    params, opt_state = _e2e_state(mesh)  # warm-up donated the buffers
+    assert baseline == len(sched)
+    params, opt_state = _e2e_state(mesh)
     plane = TopologyControlPlane(
         _pod(), sched, window=0, margin=0.05, cooldown=4, probation=3,
         rollback_tolerance=4.0, synchronous=True)
