@@ -26,7 +26,7 @@ from jax import lax
 from bluefog_tpu.models.llama import Llama, LlamaConfig
 
 __all__ = ["init_cache", "llama_generate", "decode_config",
-           "prefill_cache", "decode_token_step", "verify_window"]
+           "prefill_cache", "decode_token_step"]
 
 
 def _decode_cfg(cfg: LlamaConfig, max_len: int, keep_tp: bool = False,
@@ -121,21 +121,6 @@ def decode_token_step(model: Llama, params, cache, tok: jax.Array):
     logits, mut = model.apply({"params": params, "cache": cache}, tok,
                               mutable=["cache"])
     return logits[:, -1], mut["cache"]
-
-
-def verify_window(model: Llama, params, cache, tokens: jax.Array):
-    """Multi-token cached forward that keeps EVERY position's logits:
-    append ``tokens [B, T]``'s K/V (exactly like :func:`prefill_cache`)
-    and return ``(logits [B, T, V], cache')``.  This is speculative
-    decoding's verify step — one target forward scores a whole draft
-    window, so position *i*'s logits give the target distribution after
-    ``tokens[:, :i+1]`` and acceptance/rejection is decided without T
-    separate decode steps.  Cache writes are identical to
-    ``prefill_cache``'s, so a verify window and a chunked prefill leave
-    the same K/V behind."""
-    logits, mut = model.apply({"params": params, "cache": cache}, tokens,
-                              all_logits=True, mutable=["cache"])
-    return logits, mut["cache"]
 
 
 def init_cache(cfg: LlamaConfig, batch_size: int, max_len: int,

@@ -380,6 +380,41 @@ class LlamaConfig:
         h = int(8 * self.dim / 3)
         return ((h + 255) // 256) * 256
 
+    # -- the serving protocol (serving/protocol.py): the engine, the
+    # slot pool and the prefix cache reach the model through these ---- #
+    def serving_layout(self, max_len: int, *, chunk: int = 1,
+                       kv_quant: str = "none", weight_quant: str = "none",
+                       decode_attn: str = "xla") -> "LlamaConfig":
+        """The decode layout (``generate.decode_config``); every layer
+        caches ``max_len`` positions, so ``chunk`` changes nothing."""
+        from bluefog_tpu.models.generate import decode_config
+
+        return decode_config(self, max_len, kv_quant=kv_quant,
+                             weight_quant=weight_quant,
+                             decode_attn=decode_attn)
+
+    def init_cache(self, batch_size: int, max_len: int):
+        from bluefog_tpu.models.generate import init_cache
+
+        return init_cache(self, batch_size, max_len,
+                          kv_quant=self.kv_quant)
+
+    def apply_cached(self, params, cache, tokens, all_logits=False,
+                     live=None):
+        """Append ``tokens [B, T]``'s K/V to ``cache`` (the call
+        ``generate.prefill_cache`` makes): ``(logits, cache')``, the
+        final position's logits alone unless ``all_logits`` (a
+        speculative step's verify window keeps every position's).
+        ``live`` (which tokens are no padding) changes nothing here:
+        every token costs a dense layer the same."""
+        logits, mut = Llama(self).apply(
+            {"params": params, "cache": cache}, tokens,
+            all_logits=all_logits, mutable=["cache"])
+        return logits, mut["cache"]
+
+    def cache_kinds(self) -> dict:
+        return {"full": (self.n_layers, None)}
+
     @staticmethod
     def llama3_8b(**overrides) -> "LlamaConfig":
         return LlamaConfig(

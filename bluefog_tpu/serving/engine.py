@@ -26,8 +26,9 @@ residents a prefill-chunk and a decode-step program (plus the slot
 housekeeping scatter); a :class:`SpeculativeConfig` swaps the decode
 step for a draft/verify pair — the draft model proposes ``lookahead``
 tokens through the same single-token step, ONE multi-token target
-forward scores the whole window (``verify_window``), and acceptance is
-rejection sampling (token-exact greedy at temperature 0).  Either way
+forward scores the whole window (``apply_cached(..., all_logits=True)``),
+and acceptance is rejection sampling (token-exact greedy at temperature
+0).  Either way
 the count is fixed before the first request arrives, and
 :meth:`ServingEngine.profile` enumerates whatever is resident.
 
@@ -38,8 +39,9 @@ instead of re-running prefill (chain-hashed whole chunks — bit-exact vs
 cold prefill), and ``registry=`` isolates the engine's metrics for
 multi-replica fleets (:mod:`bluefog_tpu.serving.fleet`).
 
-Numerics are the one-shot path's numerics: both are built from the same
-:func:`prefill_cache` / :func:`decode_token_step` pieces, so a GREEDY
+Numerics are the one-shot path's numerics: both are the same cached
+``model.apply`` (``cfg.apply_cached`` here, :func:`prefill_cache` /
+:func:`decode_token_step` there), so a GREEDY
 request served through the engine reproduces its one-shot
 ``llama_generate(prompt[None], n, max_len=pool_max_len)`` output token
 for token (tests/test_serving.py).  Temperature sampling is
@@ -65,9 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from bluefog_tpu.models.generate import (decode_config, decode_token_step,
-                                         prefill_cache, verify_window)
-from bluefog_tpu.models.llama import Llama, LlamaConfig
+from bluefog_tpu.serving import protocol
 from bluefog_tpu.serving.kv_pool import SlotPool
 from bluefog_tpu.serving.metrics import ServingMetrics
 from bluefog_tpu.serving.scheduler import FifoScheduler, RequestRejected
@@ -178,8 +178,7 @@ def _corrected_index(new_cache, old_cache, valid_len):
     stays in the cache but above the index, where the causal mask hides
     it until real tokens overwrite it — exactness needs only the index."""
     def fix(path, new, old):
-        name = getattr(path[-1], "key", None)
-        if name == "cache_index":
+        if protocol.leaf_kind(path) == protocol.INDEX:
             return old + jnp.asarray(valid_len, old.dtype)
         return new
 
@@ -187,20 +186,21 @@ def _corrected_index(new_cache, old_cache, valid_len):
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
-def _prefill_chunk_prog(params, pool, slot, chunk, valid_len,
-                        cfg: LlamaConfig):
+def _prefill_chunk_prog(params, pool, slot, chunk, valid_len, cfg):
     """Write one fixed-shape prompt chunk into ``slot``'s cache.  Only
     the K/V side effect matters: the engine prefills ``prompt[:-1]``
     through chunks (their logits are never sampled — in decode layout
     the model only materializes the FINAL position's logits, which for a
     padded chunk is a pad row) and routes the last prompt token through
     the regular decode step, whose output IS the first generated token.
-    Shapes depend on ``(cfg, chunk_len)`` alone."""
-    model = Llama(cfg)
+    Shapes depend on ``(cfg, chunk_len)`` alone.  ``cfg`` is the
+    model's serving layout (``serving/protocol.py``)."""
     cache = jax.tree.map(
         lambda leaf: lax.dynamic_index_in_dim(leaf, slot, 0,
                                               keepdims=False), pool)
-    _, new_cache = prefill_cache(model, params, cache, chunk)
+    # the chunk's padded tail is no token: no expert is read for it
+    live = jnp.arange(chunk.shape[-1])[None] < valid_len
+    _, new_cache = cfg.apply_cached(params, cache, chunk, live=live)
     new_cache = _corrected_index(new_cache, cache, valid_len)
     return jax.tree.map(
         lambda p, c: lax.dynamic_update_index_in_dim(p, c, slot, 0),
@@ -209,7 +209,7 @@ def _prefill_chunk_prog(params, pool, slot, chunk, valid_len,
 
 @partial(jax.jit, static_argnames=("cfg", "horizon"), donate_argnums=(1,))
 def _decode_step_prog(params, pool, toks, active, keys, counts, temps,
-                      cfg: LlamaConfig, horizon: int):
+                      cfg, horizon: int):
     """Advance EVERY slot ``horizon`` decode tokens (vmapped
     single-token steps inside one ``lax.scan`` — each slot carries its
     own cache index, so rotary/mask positions are per-request) and
@@ -224,8 +224,6 @@ def _decode_step_prog(params, pool, toks, active, keys, counts, temps,
     surplus tail, and the slot's zero-on-free makes its overrun cache
     writes unobservable.  Returns ``(pool, tokens [horizon, n_slots])``.
     """
-    model = Llama(cfg)
-
     def keep_index(path, new, old):
         # Freezing an inactive slot needs only its cache_index: the
         # step's K/V write lands AT the frozen index, where the causal
@@ -233,7 +231,7 @@ def _decode_step_prog(params, pool, toks, active, keys, counts, temps,
         # prefill chunk (mid-admission slots), the next real decode
         # write, or the zero-on-free (free slots).  Masking just the
         # index leaves skips two whole-pool copies per token.
-        if getattr(path[-1], "key", None) != "cache_index":
+        if protocol.leaf_kind(path) != protocol.INDEX:
             return new
         m = active.reshape(active.shape + (1,) * (new.ndim - 1))
         return jnp.where(m, new, old)
@@ -241,14 +239,16 @@ def _decode_step_prog(params, pool, toks, active, keys, counts, temps,
     def hstep(carry, j):
         pool, toks = carry
 
-        def one(cache, tok, key, count, temp):
-            last, cache = decode_token_step(model, params, cache,
-                                            tok[None, None])
-            nxt = _sample(last[0], jax.random.fold_in(key, count + j),
-                          temp)
+        def one(cache, tok, act, key, count, temp):
+            logits, cache = cfg.apply_cached(params, cache,
+                                             tok[None, None],
+                                             live=act[None, None])
+            nxt = _sample(logits[0, -1],
+                          jax.random.fold_in(key, count + j), temp)
             return cache, nxt
 
-        new_pool, nxt = jax.vmap(one)(pool, toks, keys, counts, temps)
+        new_pool, nxt = jax.vmap(one)(pool, toks, active, keys, counts,
+                                      temps)
         nxt = jnp.where(active, nxt, toks)
         return (jax.tree_util.tree_map_with_path(keep_index, new_pool,
                                                  pool), nxt), nxt
@@ -266,7 +266,7 @@ class SpeculativeConfig:
     target; typically much smaller).  Each engine step the draft
     proposes ``lookahead`` tokens through the resident single-token
     step, the target scores the whole window in ONE multi-token forward
-    (:func:`~bluefog_tpu.models.generate.verify_window`), and standard
+    (``cfg.apply_cached(..., all_logits=True)``), and standard
     rejection sampling accepts a prefix of the proposals plus one
     correction/bonus token — so every step emits between 1 and
     ``lookahead + 1`` tokens with the TARGET model's distribution
@@ -275,7 +275,7 @@ class SpeculativeConfig:
     positions of headroom per slot (checked at submit)."""
 
     variables: dict
-    cfg: LlamaConfig
+    cfg: object   # the draft model's config (serving/protocol.py)
     lookahead: int = 4
     weight_quant: str = "none"
 
@@ -283,8 +283,7 @@ class SpeculativeConfig:
 @partial(jax.jit, static_argnames=("cfg_t", "cfg_d", "k"),
          donate_argnums=(2, 3))
 def _spec_step_prog(params_t, params_d, pool_t, pool_d, toks, active,
-                    keys, counts, temps, cfg_t: LlamaConfig,
-                    cfg_d: LlamaConfig, k: int):
+                    keys, counts, temps, cfg_t, cfg_d, k: int):
     """One speculative decode step for EVERY slot: draft ``k`` proposals
     (a ``k+1``-step single-token scan — the extra step writes the last
     proposal's K/V so the draft cache index stays position-aligned
@@ -310,18 +309,16 @@ def _spec_step_prog(params_t, params_d, pool_t, pool_d, toks, active,
     mask hides them until real tokens overwrite — the same invariant
     padded prefill chunks use.  Returns
     ``(pool_t, pool_d, emitted [cap, k+1], n_emit [cap])``."""
-    target = Llama(cfg_t)
-    draft = Llama(cfg_d)
-
     def one(cache_t, cache_d, tok, act, key, count, temp):
         old_t, old_d = cache_t, cache_d
         tmp = jnp.maximum(temp, 1e-6)
 
         def dstep(carry, i):
             cache_d, cur = carry
-            last, cache_d = decode_token_step(draft, params_d, cache_d,
-                                              cur[None, None])
-            lg = last[0]
+            last, cache_d = cfg_d.apply_cached(params_d, cache_d,
+                                               cur[None, None],
+                                               live=act[None, None])
+            lg = last[0, -1]
             nxt = _sample(lg, jax.random.fold_in(
                 jax.random.fold_in(key, 1), count + i), temp)
             return (cache_d, nxt), (cur, nxt, lg)
@@ -332,8 +329,9 @@ def _spec_step_prog(params_t, params_d, pool_t, pool_d, toks, active,
         # cache); props = [d_1..d_{k+1}] (the k+1-th proposal is only
         # drafted so d_k's K/V gets written — it is never considered);
         # dlg[i] is the draft distribution that proposed props[i]
-        vlogits, cache_t = verify_window(target, params_t, cache_t,
-                                         window[None])
+        vlogits, cache_t = cfg_t.apply_cached(
+            params_t, cache_t, window[None], all_logits=True,
+            live=jnp.broadcast_to(act, window[None].shape))
         vlogits = vlogits[0]                          # [k+1, V]
         tgt = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)
 
@@ -383,8 +381,9 @@ class ServingEngine:
       variables: ``{"params": ...}`` (full-precision, or the
         ``quantize_llama_params`` tree with ``weight_quant`` set — same
         contract as ``llama_generate``).
-      cfg: model config (training layout fine; normalized through
-        :func:`decode_config`).
+      cfg: model config, a :class:`~bluefog_tpu.serving.protocol.
+        ServedModel` (training layout fine; normalized through its
+        ``serving_layout``).
       capacity: resident request slots (= decode batch).
       max_len: per-slot cache length; every request needs
         ``len(prompt) + max_new_tokens <= max_len`` (checked at submit).
@@ -432,7 +431,7 @@ class ServingEngine:
         positions of headroom per request (checked at submit).
     """
 
-    def __init__(self, variables, cfg: LlamaConfig, *, capacity: int,
+    def __init__(self, variables, cfg, *, capacity: int,
                  max_len: int, prefill_chunk: int = 32,
                  decode_horizon: int = 1, prefill_budget: int = 1,
                  kv_quant: str = "none", weight_quant: str = "none",
@@ -487,9 +486,14 @@ class ServingEngine:
                 raise ValueError(
                     "SpeculativeConfig.weight_quant does not match the "
                     "draft param tree (quantize_llama_params contract)")
-        self.cfg = decode_config(cfg, max_len, kv_quant=kv_quant,
-                                 weight_quant=weight_quant,
-                                 decode_attn=decode_attn)
+        # the most tokens one cached call writes: what a window
+        # layer's ring needs beyond its window
+        call = prefill_chunk if speculative is None else max(
+            prefill_chunk, speculative.lookahead + 1)
+        self.cfg = cfg.serving_layout(max_len, chunk=call,
+                                      kv_quant=kv_quant,
+                                      weight_quant=weight_quant,
+                                      decode_attn=decode_attn)
         from bluefog_tpu.serving.prefix_cache import PrefixCache
 
         prefix = None
@@ -503,19 +507,21 @@ class ServingEngine:
                     f" ({prefill_chunk}) — hashes must match the chunk "
                     "grid prefill writes")
         self.pool = SlotPool(cfg, capacity, max_len, kv_quant=kv_quant,
-                             zero_on_free=zero_on_free, prefix=prefix)
+                             zero_on_free=zero_on_free, prefix=prefix,
+                             chunk=call)
+        self._kinds = self.cfg.cache_kinds()
         self._spec = speculative
         self._draft_pool: Optional[SlotPool] = None
         self._draft_params = None
-        self.draft_cfg: Optional[LlamaConfig] = None
+        self.draft_cfg = None
         if speculative is not None:
             from bluefog_tpu.serving.prefix_cache import PrefixCache
 
             dprefix = (PrefixCache(prefill_chunk,
                                    prefix_cache_bytes)
                        if prefix is not None else None)
-            self.draft_cfg = decode_config(
-                speculative.cfg, max_len, kv_quant=kv_quant,
+            self.draft_cfg = speculative.cfg.serving_layout(
+                max_len, chunk=call, kv_quant=kv_quant,
                 weight_quant=speculative.weight_quant,
                 decode_attn=decode_attn)
             # the draft pool mirrors the target pool's alloc/free order,
@@ -523,10 +529,11 @@ class ServingEngine:
             self._draft_pool = SlotPool(speculative.cfg, capacity,
                                         max_len, kv_quant=kv_quant,
                                         zero_on_free=zero_on_free,
-                                        prefix=dprefix)
+                                        prefix=dprefix, chunk=call)
             self._draft_params = speculative.variables["params"]
         self.scheduler = FifoScheduler(max_queue=max_queue)
         self.metrics = ServingMetrics(registry=registry)
+        self.metrics.on_pool(self.pool.cache_bytes())
         self.prefill_chunk = prefill_chunk
         self.decode_horizon = decode_horizon
         self.prefill_budget = prefill_budget
@@ -990,11 +997,27 @@ class ServingEngine:
             self.pool.cache, hist = _decode_step_prog(
                 self._params, self.pool.cache, *operands, cfg=self.cfg,
                 horizon=self.decode_horizon)
+        observed = self.metrics.publishing
+        stats, attended = None, ()
         with span("token_fetch"):
-            hist = np.asarray(hist)  # [horizon, cap] — the per-step host
-            # sync: tokens stream
+            # [horizon, cap] — the per-step host sync: tokens stream; a
+            # model's stat_* leaves (the step's own outputs) come with
+            # them where somebody counts them
+            if observed and self.pool.has_stats:
+                hist, stats = jax.device_get((hist, self.pool.stats()))
+            else:
+                hist = np.asarray(hist)
+        if observed:
+            # the query of a slot sits on its last token and sees every
+            # position up to itself
+            attended = protocol.attended_positions(
+                self._kinds, [r.prompt.size + len(r.tokens)
+                              for r in decoding.values()])
+            if stats is not None:
+                self.metrics.on_expert_choices(
+                    stats.values(), sorted(decoding), self.cfg.held)
         self._emit(decoding, lambda slot: hist[:, slot])
-        self.metrics.on_decode_step(len(decoding))
+        self.metrics.on_decode_step(len(decoding), attended)
 
     def _spec_decode_step(self, decoding: Dict[int, Request]) -> None:
         """The speculative twin of :meth:`_decode_step`: one resident

@@ -31,8 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bluefog_tpu.models.generate import decode_config, init_cache
-from bluefog_tpu.models.llama import LlamaConfig
+from bluefog_tpu.serving import protocol
 
 __all__ = ["SlotPool"]
 
@@ -50,7 +49,7 @@ def _reset_index_slot(pool, slot):
     fresh admission observes: K/V above it is causally masked and gets
     overwritten position by position as the new request prefills."""
     def fix(path, leaf):
-        if getattr(path[-1], "key", None) == "cache_index":
+        if protocol.leaf_kind(path) == protocol.INDEX:
             return leaf.at[slot].set(jnp.zeros((), leaf.dtype))
         return leaf
 
@@ -61,8 +60,9 @@ class SlotPool:
     """Fixed-capacity pool of per-request K/V caches.
 
     Args:
-      cfg: the model's config (training layout fine — normalized through
-        :func:`decode_config` internally, same as ``llama_generate``).
+      cfg: the model's config (:class:`~bluefog_tpu.serving.protocol.
+        ServedModel`; training layout fine — normalized through its
+        ``serving_layout``, same as ``llama_generate``).
       capacity: number of resident request slots.  Decode advances ALL
         slots every step (inactive ones are masked), so capacity is the
         decode batch size the hardware is sized for.
@@ -77,21 +77,26 @@ class SlotPool:
         :class:`~bluefog_tpu.serving.prefix_cache.PrefixCache` whose
         ``chunk`` is the engine's prefill chunk; enables
         :meth:`restore_prefix` / :meth:`stash_chunk`.
+      chunk: the most tokens one cached call writes (the engine's
+        prefill chunk): the slack a window layer's ring leaf needs.
+
+    The pool stacks whatever leaves the model declares: a window
+    layer's ring is about a window long whatever ``max_len`` is
+    (:meth:`cache_bytes` reports both kinds).
     """
 
-    def __init__(self, cfg: LlamaConfig, capacity: int, max_len: int,
+    def __init__(self, cfg, capacity: int, max_len: int,
                  kv_quant: str = "none",
                  zero_on_free: Optional[bool] = None,
-                 prefix=None):
+                 prefix=None, chunk: int = 1):
         if capacity < 1:
             raise ValueError(f"capacity ({capacity}) must be >= 1")
         if zero_on_free is None:
             from bluefog_tpu import config as bfconfig
 
             zero_on_free = bfconfig.kv_zero_on_free()
-        dcfg = decode_config(cfg, max_len, kv_quant=kv_quant)
-        slot_shapes = jax.eval_shape(
-            lambda: init_cache(dcfg, 1, max_len, kv_quant=kv_quant))
+        dcfg = cfg.serving_layout(max_len, chunk=chunk, kv_quant=kv_quant)
+        slot_shapes = jax.eval_shape(lambda: dcfg.init_cache(1, max_len))
         self.cache = jax.tree.map(
             lambda s: jnp.zeros((capacity,) + s.shape, s.dtype),
             slot_shapes)
@@ -112,6 +117,29 @@ class SlotPool:
             self._seq_axes = seq_axes(cfg, max_len, kv_quant)
         self._free: List[int] = list(range(capacity - 1, -1, -1))
         self._in_use: set = set()
+        # (name, position among the leaves) of the model's stat_* leaves
+        self._stat_leaves = [
+            (jax.tree_util.keystr(path), i) for i, (path, _) in enumerate(
+                jax.tree_util.tree_flatten_with_path(self.cache)[0])
+            if protocol.leaf_kind(path) == protocol.STAT]
+        self.has_stats = bool(self._stat_leaves)
+
+    def cache_bytes(self) -> dict:
+        """``{"full" | "window": bytes}`` the pool reserves in leaves of
+        that kind, all slots together (index and stat leaves left
+        out)."""
+        out = {}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                self.cache)[0]:
+            kind = protocol.leaf_kind(path)
+            if kind in (protocol.FULL, protocol.WINDOW):
+                out[kind] = out.get(kind, 0) + leaf.size * leaf.dtype.itemsize
+        return out
+
+    def stats(self) -> dict:
+        """The ``stat_*`` leaves, ``{path: leaf [capacity, ...]}``."""
+        leaves = jax.tree.leaves(self.cache)
+        return {name: leaves[i] for name, i in self._stat_leaves}
 
     @property
     def n_free(self) -> int:

@@ -103,6 +103,12 @@ class ServingMetrics:
             return None
         return obs_registry.get_registry()
 
+    @property
+    def publishing(self) -> bool:
+        """Whether a registry takes what the engine counts: where none
+        does, the engine skips the work that only feeds counters."""
+        return self._reg() is not None
+
     def _tracer(self):
         return obs_tracer.effective_tracer(timeline_mod.get_timeline())
 
@@ -218,10 +224,13 @@ class ServingMetrics:
                         "valid positions in cold prefill chunks"
                         ).inc(n_tokens)
 
-    def on_decode_step(self, n_slots: int):
+    def on_decode_step(self, n_slots: int, attended=()):
         """One decode program call (plain or speculative) advanced
         ``n_slots`` active slots: slots / steps is the batch size a
-        decode step."""
+        decode step.  ``attended``: ``((kind, positions), ...)``, the
+        cache positions the step's queries attended by kind of layer
+        (``protocol.attended_positions``), from the lengths the host
+        holds."""
         reg = self._reg()
         if reg is not None:
             reg.counter("bf_serving_decode_steps_total",
@@ -229,6 +238,60 @@ class ServingMetrics:
             reg.counter("bf_serving_decode_slots_total",
                         "active slots summed over decode program calls"
                         ).inc(n_slots)
+            for kind, positions in attended:
+                reg.counter(
+                    "bf_serving_attended_positions_total",
+                    "cache positions decode steps attended, summed over "
+                    "slots and the layers of the kind", kind=kind
+                ).inc(positions)
+
+    def on_pool(self, cache_bytes: dict):
+        """The slot pool was built: ``{"full" | "window": bytes}`` it
+        reserves (``SlotPool.cache_bytes``)."""
+        reg = self._reg()
+        if reg is not None:
+            for kind, nbytes in cache_bytes.items():
+                reg.gauge(
+                    "bf_serving_cache_bytes",
+                    "bytes the slot pool reserves, by kind of cache leaf "
+                    "(full: max_len positions; window: a ring)",
+                    kind=kind).set(nbytes)
+
+    def on_expert_choices(self, chosen, slots, held):
+        """A decode program call of a model with expert layers:
+        ``chosen``, one ``[capacity, top_k]`` array of expert ids a
+        layer (its ``stat_experts`` leaf: what each slot's LAST token
+        chose, so with ``decode_horizon`` h the counters sample one
+        token in h: their ratios hold, their totals are 1/h), the
+        ``slots`` that decoded, and ``held = (first, count)``, the
+        experts this share holds.  Counts the assignments that fell on
+        held experts and on absent ones, and the held experts hit (the
+        ones the step's expert loop read), a layer a step."""
+        reg = self._reg()
+        if reg is None or not len(slots):
+            return
+        first, count = held
+        slots = np.asarray(slots)
+        n_held = n_absent = n_hit = n_layers = 0
+        for ids in chosen:
+            ids = np.asarray(ids)[slots].reshape(-1)
+            mine = ids[(ids >= first) & (ids < first + count)]
+            n_held += mine.size
+            n_absent += ids.size - mine.size
+            n_hit += np.unique(mine).size
+            n_layers += 1
+        for label, n in (("true", n_held), ("false", n_absent)):
+            reg.counter(
+                "bf_moe_assignments_total",
+                "token-to-expert assignments of decode steps, by whether "
+                "this share holds the expert", held=label).inc(n)
+        reg.counter(
+            "bf_moe_experts_hit_total",
+            "held experts chosen by at least one token, summed over "
+            "expert layers and decode steps").inc(n_hit)
+        reg.counter(
+            "bf_moe_layer_steps_total",
+            "expert layers times decode steps").inc(n_layers)
 
     def on_prefix_restore(self, rid, n_chunks: int, n_tokens: int):
         """``n_chunks`` cached K/V chunks (``n_tokens`` prompt tokens)

@@ -53,23 +53,31 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from bluefog_tpu.models.generate import init_cache
-from bluefog_tpu.models.llama import LlamaConfig
+from bluefog_tpu.serving import protocol
 
 __all__ = ["PrefixCache", "seq_axes"]
 
 
-def seq_axes(cfg: LlamaConfig, max_len: int,
+def seq_axes(cfg, max_len: int,
              kv_quant: str = "none") -> Tuple[Optional[int], ...]:
     """Per-leaf sequence axis of the SINGLE-REQUEST cache tree, in
     ``jax.tree.leaves`` order (None for index leaves).  Detected by
     comparing the cache's shapes at two cache lengths — the axis that
     scales with ``max_len`` is the sequence axis — so new layouts never
     need a registry entry here."""
-    a = jax.eval_shape(lambda: init_cache(cfg, 1, max_len,
-                                          kv_quant=kv_quant))
-    b = jax.eval_shape(lambda: init_cache(cfg, 1, 2 * max_len,
-                                          kv_quant=kv_quant))
+    def shapes(n):
+        dcfg = cfg.serving_layout(n, kv_quant=kv_quant)
+        return jax.eval_shape(lambda: dcfg.init_cache(1, n))
+
+    a, b = shapes(max_len), shapes(2 * max_len)
+    for path, _ in jax.tree_util.tree_flatten_with_path(a)[0]:
+        if protocol.leaf_kind(path) == protocol.WINDOW:
+            # a ring's rows are not a prefix's positions: once it has
+            # wrapped, a chunk cut out of it is another chunk's keys
+            raise ValueError(
+                f"cache leaf {jax.tree_util.keystr(path)} is a ring of "
+                "about a window of positions; a prefix cache cannot "
+                "restore chunks into a model with window layers")
     axes: List[Optional[int]] = []
     for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         diff = [i for i, (sa, sb) in enumerate(zip(la.shape, lb.shape))

@@ -1,0 +1,84 @@
+"""What the serving layer needs of a model: nothing under ``serving/``
+imports a model class or asks a model's name.
+
+A model is served through its CONFIG: a frozen, hashable dataclass (it
+is the static argument of the engine's resident programs) with the
+methods of :class:`ServedModel`.  ``models.llama.LlamaConfig`` and
+``models.afmoe.AfmoeConfig`` implement it.
+
+The cache of one sequence is a tree of leaves per layer, and the pool
+stacks whatever it is given (``SlotPool``: ``[capacity, *leaf]``).  The
+serving layer reads a leaf's KIND off its name (:func:`leaf_kind`):
+
+* ``cache_index``: how many positions the layer has written; the
+  engine corrects it after a padded chunk, freezes it for inactive
+  slots and resets it when a slot is freed.  It is the only state a
+  new admission observes.
+* ``window_*``: a ring of about a window of positions.  It does not
+  grow with ``max_len``, and its rows are not the positions of a
+  prefix, so a prefix cache refuses a model that declares one.
+* ``stat_*``: small per-step observations.  The one kind there is,
+  ``stat_experts [top_k]``, holds the experts a sequence's last token
+  chose; where a registry counts, they leave the device with the
+  step's tokens and ``ServingMetrics.on_expert_choices`` counts them
+  against the model's ``held = (first, count)``.
+* anything else: ``max_len`` positions along one axis ("full").
+"""
+
+from __future__ import annotations
+
+from typing import Protocol, Tuple
+
+INDEX, WINDOW, FULL, STAT = "index", "window", "full", "stat"
+
+
+class ServedModel(Protocol):
+    """A model's config, as the engine, the pool and the prefix cache
+    use it."""
+
+    vocab_size: int
+
+    def serving_layout(self, max_len: int, *, chunk: int = 1,
+                       kv_quant: str = "none", weight_quant: str = "none",
+                       decode_attn: str = "xla") -> "ServedModel":
+        """The config the resident programs run under: caches of
+        ``max_len`` positions, calls of at most ``chunk`` tokens."""
+
+    def init_cache(self, batch_size: int, max_len: int):
+        """The zeroed cache tree of ``batch_size`` sequences."""
+
+    def apply_cached(self, params, cache, tokens, all_logits=False,
+                     live=None):
+        """Append ``tokens [B, T]`` at the cache index: ``(logits [B, 1
+        or T, vocab], cache')``; the last position's logits alone unless
+        ``all_logits``.  ``live [B, T]`` is False where a token is
+        padding (a slot that does not decode, a chunk's tail): a model
+        may skip what only such a token would need; what it returns for
+        one is never read."""
+
+    def cache_kinds(self) -> dict:
+        """``{"full" | "window": (layers, most positions a query
+        attends, or None for all behind it)}``."""
+
+
+def leaf_kind(path) -> str:
+    """The kind of the cache leaf at ``path`` (a key path of
+    ``jax.tree_util``), by the leaf's name."""
+    name = getattr(path[-1], "key", None) or ""
+    if name == "cache_index":
+        return INDEX
+    if name.startswith("window_"):
+        return WINDOW
+    if name.startswith("stat_"):
+        return STAT
+    return FULL
+
+
+def attended_positions(kinds: dict, lengths) -> Tuple[Tuple[str, int], ...]:
+    """``((kind, positions), ...)``: cache positions a decode step over
+    sequences of ``lengths`` attends, summed over the kind's layers."""
+    out = []
+    for kind, (layers, most) in kinds.items():
+        seen = sum(n if most is None else min(n, most) for n in lengths)
+        out.append((kind, layers * seen))
+    return tuple(out)

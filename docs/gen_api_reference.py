@@ -65,6 +65,12 @@ MODULES = [
     ("bluefog_tpu.models.llama", "Llama config/stack, TP/EP/vocab-parallel"),
     ("bluefog_tpu.models.generate", "K/V-cached autoregressive decode"),
     ("bluefog_tpu.models.quant", "int8 weight quantization for decode"),
+    ("bluefog_tpu.models.afmoe",
+     "decoder of mixed window/full attention, gated heads and an expert "
+     "layer told which experts it holds"),
+    ("bluefog_tpu.serving.protocol",
+     "what the serving layer needs of a model (config methods, cache "
+     "leaf kinds)"),
     ("bluefog_tpu.serving.engine",
      "continuous-batching serving engine (slot-pooled K/V decode)"),
     ("bluefog_tpu.serving.kv_pool", "fixed-capacity K/V cache slot pool"),

@@ -1,0 +1,374 @@
+"""The afmoe model (window and full attention mixed, gated heads,
+sandwich norms, an expert layer told which experts it holds) against
+the benchmark's plain reference, at a small size on the CPU: the full
+forward pass, the served path through ``ServingEngine`` (chunked
+prefill across the window's edge and the ring's wrap, slots reused,
+long beside short), the shares of an expert layer, the router, and the
+pool's two kinds of cache."""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bluefog_tpu.models import afmoe
+from bluefog_tpu.serving import Request, ServingEngine, SlotPool
+from bluefog_tpu.serving.prefix_cache import PrefixCache
+from perfbench.harness import loader
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REF = loader.load_module(REPO, "references", "afmoe_decoder")
+FAMILY = loader.load_module(REPO, "families", "afmoe_decoder")
+
+pytestmark = pytest.mark.serving
+
+WINDOW = 8
+# float32 program against float32-highest reference, both on the CPU:
+# what is left is the order of the sums (the program's loop over the
+# experts hit, its softmax over a ring).  A routing flip would show as
+# ~1e-1, a missing norm or gate as ~1.
+TOL = 2e-4
+
+SZ = {
+    "hidden_size": 64, "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "num_hidden_layers": 5, "num_dense_layers": 1,
+    "layer_types": ["sliding_attention"] * 3 + ["full_attention"]
+    + ["sliding_attention"],
+    "sliding_window": WINDOW, "vocab_size": 128, "rms_norm_eps": 1e-5,
+    "rope_theta": 10000.0, "num_experts": 16, "router_outputs": 16,
+    "experts_held_from": 0, "num_experts_per_tok": 4,
+    "num_shared_experts": 1, "route_norm": True, "route_scale": 2.448,
+    "mup_enabled": True, "initializer_range": 0.2, "router_bias_std": 0.1,
+    "compute_dtype": "float32", "param_dtype": "float32",
+}
+
+
+def _params(sz=SZ, seed=0):
+    return jax.jit(lambda k: FAMILY.make_params(sz, k, jnp.float32)[0])(
+        jax.random.PRNGKey(seed))
+
+
+def _reference(params, tokens, sz=SZ):
+    return np.asarray(REF.logits(params, jnp.asarray(tokens), sz))
+
+
+# ------------------------------------------------------------------ #
+# (a) the full forward pass
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("held", [(0, 16), (4, 8)])
+def test_full_forward_matches_the_reference(held):
+    sz = dict(SZ, experts_held_from=held[0], num_experts=held[1])
+    params = _params(sz)
+    tokens = np.random.default_rng(1).integers(0, sz["vocab_size"], 40)
+    cfg = FAMILY.model_config(sz)
+    got = afmoe.Afmoe(cfg).apply({"params": params}, tokens[None])[0]
+    want = _reference(params, tokens, sz)
+    assert got.shape == want.shape == (40, sz["vocab_size"])
+    assert np.abs(np.asarray(got) - want).max() < TOL * want.std()
+
+
+# ------------------------------------------------------------------ #
+# (b) through the engine
+# ------------------------------------------------------------------ #
+def _serve(params, prompts, budgets, **engine):
+    engine = dict(dict(capacity=2, max_len=64, prefill_chunk=4), **engine)
+    eng = ServingEngine({"params": params}, FAMILY.model_config(SZ),
+                        **engine)
+    reqs = [eng.submit(Request(p, n)) for p, n in zip(prompts, budgets)]
+    eng.run()
+    assert all(r.state == "completed" for r in reqs)
+    return eng, reqs
+
+
+def _assert_served_is_the_references_greedy(params, req):
+    """Teacher-forced: at every served position the reference's best
+    token is the served one (or ties with it inside the tolerance)."""
+    seq = req.output()
+    want = _reference(params, seq[:-1])
+    p, g = req.prompt.size, len(req.tokens)
+    rows = want[p - 1:p - 1 + g]
+    gap = rows.max(-1) - rows[np.arange(g), np.asarray(req.tokens)]
+    assert gap.max() < TOL * want.std(), (p, g, gap.max())
+
+
+@pytest.mark.parametrize("chunk, lengths, budgets", [
+    # a chunk that straddles the window's edge; prompts several windows
+    # long, so the ring (8 + 4 rows) wraps more than once
+    (4, (30, 6), (12, 12)),
+    # the chunk as wide as the window, a long prompt beside a short one
+    (8, (45, 3), (10, 16)),
+    # four requests through two slots: slots reused after a wrapped ring
+    (4, (27, 9, 33, 5), (6, 9, 4, 12)),
+    # a one-token prompt (no prefill at all) beside a long one
+    (2, (1, 41), (20, 5)),
+])
+def test_served_tokens_match_the_reference(chunk, lengths, budgets):
+    params = _params()
+    rng = np.random.default_rng(sum(lengths))
+    prompts = [rng.integers(0, SZ["vocab_size"], n) for n in lengths]
+    _, reqs = _serve(params, prompts, budgets, prefill_chunk=chunk)
+    for r in reqs:
+        _assert_served_is_the_references_greedy(params, r)
+
+
+def test_no_recompile_inside_the_window_or_across_the_wrap():
+    from bluefog_tpu.serving.engine import (_decode_step_prog,
+                                            _prefill_chunk_prog)
+
+    params = _params()
+    rng = np.random.default_rng(3)
+    eng, _ = _serve(params, [rng.integers(0, 128, 5)], [3])
+    sizes = (_prefill_chunk_prog._cache_size(),
+             _decode_step_prog._cache_size())
+    reqs = [eng.submit(Request(rng.integers(0, 128, n), 6))
+            for n in (40, 2, 17)]
+    eng.run()
+    assert all(r.state == "completed" for r in reqs)
+    assert sizes == (_prefill_chunk_prog._cache_size(),
+                     _decode_step_prog._cache_size())
+
+
+# ------------------------------------------------------------------ #
+# (c) the shares add up to the uncut layer
+# ------------------------------------------------------------------ #
+def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_reference():
+    sz = dict(SZ)
+    params = _params()
+    moe = params["layer_1"]["moe"]
+    m = jax.random.normal(jax.random.PRNGKey(5), (24, sz["hidden_size"]))
+    whole = REF.swiglu(m, moe["shared"], REF.mm_highest) \
+        + REF.routed_part(m, moe, sz, REF.mm_highest)
+    cfg = FAMILY.model_config(sz)
+    held_of = lambda part: dict(moe, **{k: moe[k][part] for k in
+                                        ("w1", "w2", "w3")})
+    shared = afmoe.SwiGLU(cfg, sz["moe_intermediate_size"]).apply(
+        {"params": moe["shared"]}, m)
+    total = shared      # what every share computes alike, counted once
+    for share in range(4):
+        part = slice(4 * share, 4 * share + 4)
+        layer = afmoe.ExpertLayer(dataclasses.replace(
+            cfg, experts_held=(4 * share, 4)))
+        routed = layer.apply({"params": held_of(part)}, m[None])[0] - shared
+        total = total + routed
+        # and the program's share is the reference's share
+        ref_part = REF.routed_part(
+            m, held_of(part), dict(sz, experts_held_from=4 * share,
+                                   num_experts=4), REF.mm_highest)
+        assert np.abs(np.asarray(routed - ref_part)).max() < 1e-5
+    assert np.abs(np.asarray(total - whole)).max() < 1e-5 * float(
+        np.abs(whole).max() + 1)
+
+
+def test_an_expert_no_token_chose_is_never_read_even_under_vmap():
+    """The decode step maps the model over slots; the expert loop takes
+    the slots' tokens together and runs over the experts they hit.  An
+    expert nobody chose holds NaNs here: one multiplication by its zero
+    weight would show."""
+    rng = np.random.default_rng(4)
+    held, d, f, slots = 6, 16, 8, 5
+    w1, w3 = (jnp.asarray(rng.normal(size=(held, d, f)), jnp.float32)
+              for _ in range(2))
+    w2 = jnp.asarray(rng.normal(size=(held, f, d)), jnp.float32)
+    combine = np.zeros((slots, 1, held), np.float32)
+    for s, e in enumerate([0, 2, 2, 5, 0]):
+        combine[s, 0, e] = 0.5 + 0.1 * s
+    m = jnp.asarray(rng.normal(size=(slots, 1, d)), jnp.float32)
+    dead = np.array([1, 3, 4])
+    w1, w3, w2 = (w.at[dead].set(jnp.nan) for w in (w1, w3, w2))
+    got = jax.jit(jax.vmap(
+        lambda x, c: afmoe.held_experts(x, c, w1, w3, w2)))(
+            m, jnp.asarray(combine))
+    assert got.shape == (slots, 1, d) and bool(jnp.isfinite(got).all())
+    for s, e in enumerate([0, 2, 2, 5, 0]):
+        x = np.asarray(m[s, 0], np.float64)
+        gate, up = x @ np.asarray(w1[e]), x @ np.asarray(w3[e])
+        want = (gate / (1 + np.exp(-gate)) * up * combine[s, 0, e]) \
+            @ np.asarray(w2[e])
+        np.testing.assert_allclose(np.asarray(got[s, 0]), want, rtol=2e-4,
+                                   atol=2e-4)
+
+
+@pytest.mark.parametrize("call", ["decode", "prefill"])
+def test_padding_chooses_no_expert_and_none_is_read_for_it(call):
+    """A slot that does not decode, and a chunk's padded tail, still go
+    through the model; ``live`` keeps their tokens out of the expert
+    loop.  Every held expert that no LIVE token chose holds NaNs here:
+    reading one for a padding token would show in the live rows too
+    (the loop applies an expert to all of the call's tokens)."""
+    cfg = FAMILY.model_config(SZ).serving_layout(64, chunk=4)
+    params = _params()
+    one = cfg.init_cache(1, 64)
+
+    def last_choice(tokens):
+        """The experts the last of ``tokens`` chose, a layer."""
+        _, cache = cfg.apply_cached(params, one, jnp.asarray(tokens)[None])
+        return {name: set(np.asarray(
+            layer["moe"]["stat_experts"]).ravel().tolist())
+            for name, layer in cache.items() if "moe" in layer}
+
+    if call == "decode":    # three slots, as the engine maps them
+        toks, live = jnp.asarray([5, 9, 77]), [True, False, False]
+        pool = jax.tree.map(lambda leaf: jnp.stack([leaf] * 3), one)
+
+        def run(p, mask):
+            return jax.vmap(lambda c, t, a: cfg.apply_cached(
+                p, c, t[None, None],
+                live=a[None, None] if mask else None)[0])(
+                    pool, toks, jnp.asarray(live))[0]
+
+        read = [last_choice([5])]
+    else:                   # a chunk of four, two of them the prompt's
+        chunk = jnp.asarray([[5, 9, 77, 3]])
+        live = jnp.asarray([[True, True, False, False]])
+
+        def run(p, mask):
+            return cfg.apply_cached(p, one, chunk, all_logits=True,
+                                    live=live if mask else None)[0][0, :2]
+
+        read = [last_choice([5]), last_choice([5, 9])]
+    poisoned = dict(params)
+    for name in read[0]:
+        keep = sorted(set().union(*(r[name] for r in read)))
+        dead = np.setdiff1d(np.arange(SZ["num_experts"]), keep)
+        assert dead.size
+        poisoned[name] = dict(params[name], moe=dict(
+            params[name]["moe"], **{k: params[name]["moe"][k].at[dead].set(
+                jnp.nan) for k in ("w1", "w2", "w3")}))
+    clean, dirty = run(params, True), run(poisoned, True)
+    assert bool(jnp.isfinite(dirty).all())
+    np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+    # and the test can tell: unmasked, the padding's experts are read
+    assert not bool(jnp.isfinite(run(poisoned, False)).all())
+
+
+# ------------------------------------------------------------------ #
+# (d) the router
+# ------------------------------------------------------------------ #
+def test_the_bias_selects_and_does_not_weigh():
+    scores = jnp.asarray([[0.9, 0.8, 0.7, 0.1, 0.2, 0.3]], jnp.float32)
+    bias = jnp.asarray([0.0, 0.0, -1.0, 1.0, 0.0, 0.0], jnp.float32)
+    chosen, weights = afmoe.route(scores, bias, 3, 2.0)
+    # expert 3 gets in on its bias, expert 2 is pushed out by its own
+    assert sorted(np.asarray(chosen)[0].tolist()) == [0, 1, 3]
+    picked = {int(e): float(w) for e, w in zip(np.asarray(chosen)[0],
+                                               np.asarray(weights)[0])}
+    total = 0.9 + 0.8 + 0.1
+    for e, s in ((0, 0.9), (1, 0.8), (3, 0.1)):
+        assert picked[e] == pytest.approx(2.0 * s / total, rel=1e-6)
+    assert weights.dtype == jnp.float32
+
+
+def test_the_router_stays_float32_in_a_bfloat16_model():
+    cfg = dataclasses.replace(FAMILY.model_config(SZ), dtype=jnp.bfloat16)
+    params = _params()
+    jaxpr = jax.make_jaxpr(lambda p, x: afmoe.ExpertLayer(cfg).apply(
+        {"params": p}, x))(params["layer_1"]["moe"],
+                           jnp.zeros((1, 3, 64), jnp.bfloat16))
+    sigmoids = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "logistic"]
+    assert sigmoids and all(e.outvars[0].aval.dtype == jnp.float32
+                            and e.outvars[0].aval.shape[-1] == 16
+                            for e in sigmoids[:1])
+
+
+def test_the_seeded_bias_changes_some_choices():
+    params = _params()
+    moe = params["layer_2"]["moe"]
+    m = jax.random.normal(jax.random.PRNGKey(9), (256, 64))
+    with_bias, _ = REF.route(m, moe, SZ, REF.mm_highest)
+    without, _ = REF.route(m, dict(moe, router_bias=jnp.zeros(16)), SZ,
+                           REF.mm_highest)
+    changed = np.mean(np.sort(np.asarray(with_bias), -1)
+                      != np.sort(np.asarray(without), -1))
+    assert 0.01 < changed < 0.9
+
+
+# ------------------------------------------------------------------ #
+# (e) the pool's two kinds of cache
+# ------------------------------------------------------------------ #
+def test_the_pool_reports_window_and_full_bytes_of_its_leaves():
+    cfg = FAMILY.model_config(SZ)
+    pool = SlotPool(cfg, capacity=3, max_len=64, chunk=4)
+    per_position = 2 * 2 * 16 * 4          # k and v, 2 heads of 16, f32
+    assert pool.cache_bytes() == {
+        "window": 3 * 4 * (WINDOW + 4) * per_position,
+        "full": 3 * 1 * 64 * per_position}
+    by_hand = {"window": 0, "full": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(pool.cache)[0]:
+        name = path[-1].key
+        if name.startswith("window_"):
+            assert leaf.shape == (3, 1, 2, WINDOW + 4, 16)
+            by_hand["window"] += leaf.nbytes
+        elif name.startswith("cached_"):
+            assert leaf.shape == (3, 1, 2, 64, 16)
+            by_hand["full"] += leaf.nbytes
+    assert by_hand == pool.cache_bytes()
+    assert pool.has_stats and len(pool.stats()) == 4
+    assert cfg.cache_kinds() == {"window": (4, WINDOW), "full": (1, None)}
+
+
+def test_the_counters_of_a_served_run():
+    from bluefog_tpu.observe.registry import MetricsRegistry
+
+    reg = MetricsRegistry()
+    params = _params()
+    rng = np.random.default_rng(2)
+    sz = dict(SZ, experts_held_from=4, num_experts=8)
+    part = lambda t: {k: (v[4:12] if k in ("w1", "w2", "w3") else v)
+                      for k, v in t.items()}
+    params = {k: (dict(v, moe=part(v["moe"])) if "moe" in v else v)
+              for k, v in params.items()}
+    eng = ServingEngine({"params": params}, FAMILY.model_config(sz),
+                        capacity=2, max_len=64, prefill_chunk=4,
+                        registry=reg)
+    reqs = [eng.submit(Request(rng.integers(0, 128, n), 5))
+            for n in (20, 3)]
+    eng.run()
+    value = lambda name, **labels: reg.counter(name, "", **labels).value
+    steps = value("bf_serving_decode_steps_total")
+    slots = value("bf_serving_decode_slots_total")
+    held = value("bf_moe_assignments_total", held="true")
+    absent = value("bf_moe_assignments_total", held="false")
+    assert held + absent == slots * 4 * 4      # 4 expert layers, top 4
+    assert 0 < held < held + absent
+    assert value("bf_moe_layer_steps_total") == steps * 4
+    assert 0 < value("bf_moe_experts_hit_total") <= held
+    # 5 decode steps a request: the query at position n - 1 + i sees
+    # n + i positions in the full layer, at most WINDOW in each of 4
+    full = sum(n + i for n in (20, 3) for i in range(5))
+    window = 4 * sum(min(n + i, WINDOW) for n in (20, 3) for i in range(5))
+    assert value("bf_serving_attended_positions_total", kind="full") == full
+    assert value("bf_serving_attended_positions_total",
+                 kind="window") == window
+    assert reg.gauge("bf_serving_cache_bytes", "", kind="window").value \
+        == eng.pool.cache_bytes()["window"]
+    assert all(r.state == "completed" for r in reqs)
+
+
+def test_with_no_registry_the_step_fetches_no_stat_leaf(monkeypatch):
+    """Observe off: the decode step reads its tokens and nothing else
+    off the device, and counts no lengths."""
+    monkeypatch.setenv("BLUEFOG_OBSERVE", "0")
+    eng = ServingEngine({"params": _params()}, FAMILY.model_config(SZ),
+                        capacity=2, max_len=64, prefill_chunk=4)
+    assert eng.pool.has_stats and not eng.metrics.publishing
+
+    def boom(*_):
+        raise AssertionError("counted with nobody to count for")
+
+    monkeypatch.setattr(eng.pool, "stats", boom)
+    monkeypatch.setattr("bluefog_tpu.serving.protocol.attended_positions",
+                        boom)
+    req = eng.submit(Request(np.arange(9), 4))
+    eng.run()
+    assert req.state == "completed" and len(req.tokens) == 4
+
+
+def test_a_prefix_cache_refuses_a_ring_leaf_at_construction():
+    with pytest.raises(ValueError, match="ring"):
+        ServingEngine({"params": _params()}, FAMILY.model_config(SZ),
+                      capacity=2, max_len=64, prefill_chunk=4,
+                      prefix_cache=PrefixCache(4, 1 << 20))
