@@ -168,6 +168,12 @@ class AfmoeConfig:
             kinds["full"] = (self.n_layers - n_window, None)
         return kinds
 
+    def streamed_positions(self, positions) -> tuple:
+        """The XLA lowering reads every row of every leaf."""
+        rows = {"window": self.ring_len, "full": self.max_seq_len}
+        return tuple((kind, layers * len(positions) * rows[kind])
+                     for kind, (layers, _) in self.cache_kinds().items())
+
 
 def _dense(cfg: AfmoeConfig, feats: int, name: str):
     return nn.Dense(feats, use_bias=False, dtype=cfg.dtype,

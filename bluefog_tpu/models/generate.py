@@ -61,19 +61,21 @@ def _decode_cfg(cfg: LlamaConfig, max_len: int, keep_tp: bool = False,
         moe = dict(capacity_factor=max(cfg.capacity_factor,
                                        float(cfg.n_experts)))
     if decode_attn == "auto":
-        # measured dispatch (decode_*_r05.json): the fused Pallas step
-        # wins only on full-precision caches at short context; int8
-        # caches and 2k+ positions belong to the XLA lowering.  The
-        # kernel also needs a viable S tiling (>=8-row block divisor) —
-        # awkward cache lengths fall back to XLA instead of erroring —
-        # and a REAL TPU: off-TPU the kernel would run in Pallas
-        # interpret mode, orders of magnitude slower than the einsums.
-        from bluefog_tpu.parallel.pallas_decode import _fit_block
+        # Decided from what is known when the program is traced; how
+        # much of the cache is live is the kernel's business at run
+        # time (it fetches the blocks at or before each row's position,
+        # parallel/pallas_decode.py).  The fused step serves a
+        # full-precision cache at any length (PERF.md section 6, PR 27,
+        # has the readings); an int8 cache stays on the XLA lowering,
+        # which no cell of the benchmark runs and PR 27 did not
+        # measure.  The kernel needs a viable S tiling (awkward cache
+        # lengths fall back instead of erroring) and a REAL TPU: off
+        # one it would run in Pallas interpret mode, orders of
+        # magnitude slower than the einsums.
+        from bluefog_tpu.parallel.pallas_decode import tileable
 
-        viable = max_len < 8 or _fit_block(max_len, 512) >= 8
-        decode_attn = ("pallas" if kv_quant == "none" and max_len <= 1024
-                       and viable and jax.default_backend() == "tpu"
-                       else "xla")
+        decode_attn = ("pallas" if kv_quant == "none" and tileable(max_len)
+                       and jax.default_backend() == "tpu" else "xla")
     tp = {} if keep_tp else {"tp_axis": None, "tp_size": 1}
     # vocab_parallel is a training-time memory layout (it shards the
     # optimizer-state-bearing vocab matrices); decode clears it like the
@@ -179,11 +181,10 @@ def llama_generate(variables, cfg: LlamaConfig, prompt: jax.Array,
         fused Pallas attention kernel (one launch per layer, in-kernel
         int8 cache dequant, float probabilities —
         parallel/pallas_decode.py); "xla" keeps the einsum lowering;
-        "auto" (default) picks by the measured boundary — pallas for
-        full-precision caches up to 1024 positions (+13%/+6%/+3% at
-        200M B8/B32/1B), xla for int8 caches and long context
-        (decode_*_r05.json).  Measure: examples/decode_benchmark.py
-        ``--decode-attn``.
+        "auto" (default) takes the kernel on a TPU for a
+        full-precision cache whose length it can tile, and the einsums
+        otherwise (``_decode_cfg``).  Measure:
+        examples/decode_benchmark.py ``--decode-attn``.
       eos_id: early-stop token id.  Once a row emits ``eos_id`` its
         remaining positions are frozen to ``eos_id`` (the done mask rides
         the ``lax.scan`` carry, so finished rows stop emitting sampled

@@ -164,13 +164,13 @@ class LlamaConfig:
     param_quant: str = "none"  # none | int8
     # decode_attn="pallas": single-token decode steps run the fused
     # Pallas attention kernel (parallel/pallas_decode.py: one launch per
-    # layer, in-kernel int8 cache dequant, probabilities kept float).
-    # "xla" keeps the einsum lowering.  Measured (r05, decode_*_r05
-    # artifacts): pallas wins on FULL-PRECISION caches at short context
-    # (+13% at 200M B8, +6% B32, +3% at 1B), loses ~5% on int8 caches
-    # (its in-kernel int8->f32 convert vs XLA's fused dequant) and ~2x
-    # at 2k+ cache positions.  ``llama_generate(decode_attn="auto")``
-    # dispatches on exactly that boundary.  Prefill (t > 1) always XLA.
+    # layer, in-kernel int8 cache dequant, probabilities kept float),
+    # which fetches only the cache blocks at or before each row's
+    # position.  "xla" keeps the einsum lowering, which reads every
+    # reserved position behind its mask.
+    # ``llama_generate(decode_attn="auto")`` chooses by platform, cache
+    # dtype and tiling (PERF.md section 6, PR 27: the readings on a
+    # v5e at 32 slots x 2048 positions).  Prefill (t > 1) always XLA.
     decode_attn: str = "xla"  # xla | pallas
     # Megatron-style vocab parallelism: the token embedding shards its
     # VOCAB rows and the logits head its VOCAB columns over ``tp_axis``,
@@ -405,15 +405,24 @@ class LlamaConfig:
         ``generate.prefill_cache`` makes): ``(logits, cache')``, the
         final position's logits alone unless ``all_logits`` (a
         speculative step's verify window keeps every position's).
-        ``live`` (which tokens are no padding) changes nothing here:
-        every token costs a dense layer the same."""
+        ``live`` (which tokens are no padding) reaches the fused
+        single-token attention, which fetches no cache block for a row
+        that does not decode; every token costs a dense layer the
+        same."""
         logits, mut = Llama(self).apply(
             {"params": params, "cache": cache}, tokens,
-            all_logits=all_logits, mutable=["cache"])
+            all_logits=all_logits, live=live, mutable=["cache"])
         return logits, mut["cache"]
 
     def cache_kinds(self) -> dict:
         return {"full": (self.n_layers, None)}
+
+    def streamed_positions(self, positions) -> tuple:
+        from bluefog_tpu.parallel.pallas_decode import streamed_positions
+
+        return (("full", self.n_layers * streamed_positions(
+            positions, self.max_seq_len,
+            fused=self.decode_attn == "pallas")),)
 
     @staticmethod
     def llama3_8b(**overrides) -> "LlamaConfig":
@@ -775,7 +784,7 @@ class Attention(nn.Module):
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, pos_offset):
+    def __call__(self, x, pos_offset, live=None):
         cfg = self.cfg
         hd = cfg.head_dim
         dense = lambda feats, name: _dense(cfg, feats, name)
@@ -794,7 +803,7 @@ class Attention(nn.Module):
         v = dense(n_kv * hd, "wv")(x).reshape(b, t, n_kv, hd)
         if cfg.decode:
             # rotary happens inside, at the cache-index positions
-            out = self._decode_attend(q, k, v)
+            out = self._decode_attend(q, k, v, live)
         else:
             positions = pos_offset + jnp.arange(t)
             q = rotary_embed(q, positions, cfg.rope_theta,
@@ -839,7 +848,7 @@ class Attention(nn.Module):
             proj = _leave_tp_region(proj, cfg)
         return proj
 
-    def _decode_attend(self, q, k, v):
+    def _decode_attend(self, q, k, v, live=None):
         """Incremental attention against the layer's K/V cache.
 
         Appends this call's K/V at the cache index (rotary applied at the
@@ -847,10 +856,13 @@ class Attention(nn.Module):
         cache with the causal mask in global coordinates
         (``_block_scores`` with ``q_offset=index``).  Works for both the
         multi-token prefill call and the one-token decode steps.
+        ``live [B, T]``: False where a token is padding (its output is
+        never read); only the fused single-token step looks at it.
         """
         cfg = self.cfg
         b, t, n_kv, hd = k.shape
         max_len = cfg.max_seq_len
+        row_live = None if live is None else live[:, 0]
         ci = self.variable("cache", "cache_index",
                            lambda: jnp.zeros((), jnp.int32))
         idx = ci.value
@@ -899,7 +911,7 @@ class Attention(nn.Module):
                 from bluefog_tpu.parallel.pallas_decode import (
                     decode_attention_int8)
                 return decode_attention_int8(q, kq_all, ks_all, vq_all,
-                                             vs_all, idx)
+                                             vs_all, idx, live=row_live)
             if cfg.param_quant == "w8a8" and max_len <= 1024:
                 # fully-integer attention: both contractions run s8xs8
                 # on the MXU against the raw int8 cache — the cache
@@ -929,7 +941,7 @@ class Attention(nn.Module):
             ck.value, cv.value, ci.value = k_all, v_all, idx + t
         if cfg.decode_attn == "pallas" and t == 1:
             from bluefog_tpu.parallel.pallas_decode import decode_attention
-            return decode_attention(q, k_all, v_all, idx)
+            return decode_attention(q, k_all, v_all, idx, live=row_live)
         # queries live at global positions [idx, idx+t); the causal mask
         # there also excludes the cache's unwritten (zero) tail
         return _cached_attention(q, k_all, v_all, idx)
@@ -1222,12 +1234,12 @@ class Block(nn.Module):
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, pos_offset):
+    def __call__(self, x, pos_offset, live=None):
         cfg = self.cfg
         naxis = cfg.tp_axis if cfg.tp_seq_shard else None
         x = x + Attention(cfg, name="attention")(
             RMSNorm(cfg.norm_eps, grad_psum_axis=naxis,
-                    name="attention_norm")(x), pos_offset)
+                    name="attention_norm")(x), pos_offset, live)
         ffn_cls = MoEFeedForward if cfg.n_experts else FeedForward
         name = "moe_ffn" if cfg.n_experts else "feed_forward"
         x = x + ffn_cls(cfg, name=name)(
@@ -1242,8 +1254,8 @@ class _ScanBlock(nn.Module):
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, pos_offset):
-        return Block(self.cfg, name="block")(x, pos_offset), None
+    def __call__(self, x, pos_offset, live=None):
+        return Block(self.cfg, name="block")(x, pos_offset, live), None
 
 
 class Llama(nn.Module):
@@ -1251,7 +1263,7 @@ class Llama(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, pos_offset=0, return_hidden=False,
-                 all_logits=False):
+                 all_logits=False, live=None):
         """tokens: [B, T_local] int32 -> logits [B, T_local, vocab] f32
         (with ``cfg.vocab_parallel``: [B, T_local, vocab/tp] — this
         shard's columns; train against ``vocab_parallel_xent``).
@@ -1269,7 +1281,10 @@ class Llama(nn.Module):
         samples nothing else).  Speculative decoding's verify step needs
         it: ONE multi-token cached forward scores a whole draft window,
         so acceptance reads the target distribution at each drafted
-        position.  No-op outside decode layout."""
+        position.  No-op outside decode layout.
+
+        ``live [B, T]`` (decode layout): False where a token is padding
+        whose output nobody reads (``serving/protocol.py``)."""
         cfg = self.cfg
         assert tokens.shape[1] <= cfg.max_seq_len, (
             f"sequence shard {tokens.shape[1]} exceeds max_seq_len "
@@ -1307,14 +1322,14 @@ class Llama(nn.Module):
                 length=cfg.n_layers,
                 metadata_params={nn.meta.PARTITION_NAME: None},
             )
-            x, _ = scan_cls(cfg, name="layers")(x, pos_offset)
+            x, _ = scan_cls(cfg, name="layers")(x, pos_offset, live)
         else:
             block_cls = Block
             if cfg.remat:
                 block_cls = nn.checkpoint(Block, static_argnums=(),
                                           policy=policy)
             for i in range(cfg.n_layers):
-                x = block_cls(cfg, name=f"layer_{i}")(x, pos_offset)
+                x = block_cls(cfg, name=f"layer_{i}")(x, pos_offset, live)
         x = RMSNorm(cfg.norm_eps,
                     grad_psum_axis=cfg.tp_axis if cfg.tp_seq_shard
                     else None, name="norm")(x)

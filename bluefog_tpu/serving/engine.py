@@ -408,9 +408,13 @@ class ServingEngine:
         :class:`RequestRejected` with the queue depth attached.
       clock: injectable monotonic clock (tests drive virtual time; the
         Poisson bench uses the default ``time.monotonic``).
-      decode_attn: attention lowering for the resident programs ("xla"
-        default — the vmapped per-slot step; the fused Pallas kernel is
-        a single-request-batch kernel, measure before switching).
+      decode_attn: attention lowering of the resident single-token
+        step: "xla" (default) reads every reserved position of every
+        slot behind a mask; "pallas" fetches the cache blocks at or
+        before each slot's position (``parallel/pallas_decode.py``; the
+        map over slots folds into the kernel's batch axis); "auto"
+        takes the kernel where ``generate.decode_config`` finds a TPU,
+        a full-precision cache and a length it can tile.
       registry: explicit metrics registry for this engine's
         :class:`ServingMetrics` (default: the global observe registry).
         A multi-replica fleet gives each replica its own so the router
@@ -998,7 +1002,7 @@ class ServingEngine:
                 self._params, self.pool.cache, *operands, cfg=self.cfg,
                 horizon=self.decode_horizon)
         observed = self.metrics.publishing
-        stats, attended = None, ()
+        stats, attended, streamed = None, (), ()
         with span("token_fetch"):
             # [horizon, cap] — the per-step host sync: tokens stream; a
             # model's stat_* leaves (the step's own outputs) come with
@@ -1010,14 +1014,17 @@ class ServingEngine:
         if observed:
             # the query of a slot sits on its last token and sees every
             # position up to itself
+            positions = [-1] * self.pool.capacity
+            for slot, r in decoding.items():
+                positions[slot] = r.prompt.size + len(r.tokens) - 1
             attended = protocol.attended_positions(
-                self._kinds, [r.prompt.size + len(r.tokens)
-                              for r in decoding.values()])
+                self._kinds, [p + 1 for p in positions if p >= 0])
+            streamed = self.cfg.streamed_positions(positions)
             if stats is not None:
                 self.metrics.on_expert_choices(
                     stats.values(), sorted(decoding), self.cfg.held)
         self._emit(decoding, lambda slot: hist[:, slot])
-        self.metrics.on_decode_step(len(decoding), attended)
+        self.metrics.on_decode_step(len(decoding), attended, streamed)
 
     def _spec_decode_step(self, decoding: Dict[int, Request]) -> None:
         """The speculative twin of :meth:`_decode_step`: one resident

@@ -224,13 +224,16 @@ class ServingMetrics:
                         "valid positions in cold prefill chunks"
                         ).inc(n_tokens)
 
-    def on_decode_step(self, n_slots: int, attended=()):
+    def on_decode_step(self, n_slots: int, attended=(), streamed=()):
         """One decode program call (plain or speculative) advanced
         ``n_slots`` active slots: slots / steps is the batch size a
         decode step.  ``attended``: ``((kind, positions), ...)``, the
         cache positions the step's queries attended by kind of layer
         (``protocol.attended_positions``), from the lengths the host
-        holds."""
+        holds; ``streamed``: the positions the program fetched to
+        attend them (``ServedModel.streamed_positions``, every slot of
+        the pool), from the same lengths.  With ``decode_horizon`` > 1
+        both are the call's first token step."""
         reg = self._reg()
         if reg is not None:
             reg.counter("bf_serving_decode_steps_total",
@@ -244,6 +247,12 @@ class ServingMetrics:
                     "cache positions decode steps attended, summed over "
                     "slots and the layers of the kind", kind=kind
                 ).inc(positions)
+            for kind, positions in streamed:
+                reg.counter(
+                    "bf_serving_streamed_positions_total",
+                    "cache positions decode steps fetched, summed over "
+                    "every slot of the pool and the layers of the kind",
+                    kind=kind).inc(positions)
 
     def on_pool(self, cache_bytes: dict):
         """The slot pool was built: ``{"full" | "window": bytes}`` it

@@ -60,6 +60,15 @@ class ServedModel(Protocol):
         """``{"full" | "window": (layers, most positions a query
         attends, or None for all behind it)}``."""
 
+    def streamed_positions(self, positions) -> Tuple[Tuple[str, int], ...]:
+        """``((kind, positions), ...)``: cache positions one
+        single-token step fetches from the pool, summed over the kind's
+        layers, when slot ``i``'s query sits at ``positions[i]`` (one
+        entry a slot of the pool; ``-1`` for a slot that does not
+        decode, which computes too).  A lowering that reads every
+        reserved row behind a mask streams them all, whatever is
+        live."""
+
 
 def leaf_kind(path) -> str:
     """The kind of the cache leaf at ``path`` (a key path of

@@ -53,8 +53,8 @@ parser.add_argument("--decode-attn", default="auto",
                     choices=["auto", "xla", "pallas"],
                     help="decode-step attention lowering: XLA einsums, "
                     "the fused Pallas kernel (parallel/pallas_decode.py), "
-                    "or the measured auto dispatch (pallas for full-"
-                    "precision caches <= 1024 positions, xla otherwise)")
+                    "or auto (the kernel on a TPU for a full-precision "
+                    "cache, xla otherwise)")
 parser.add_argument("--repeats", type=int, default=3)
 args = parser.parse_args()
 

@@ -14,8 +14,11 @@ import pytest
 from bluefog_tpu import models
 from bluefog_tpu.models.llama import (_amax_quantize, _cached_attention)
 from bluefog_tpu.models import llama_generate
+from bluefog_tpu.parallel import pallas_decode
 from bluefog_tpu.parallel.pallas_decode import (decode_attention,
-                                                decode_attention_int8)
+                                                decode_attention_int8,
+                                                streamed_positions)
+from bluefog_tpu.serving import Request, ServingEngine
 
 
 def _rand_cache(b, n_kv, s, d, seed=0):
@@ -143,3 +146,220 @@ def test_decode_attn_validation():
         models.LlamaConfig.tiny(decode_attn="pallas")  # decode-only knob
     with pytest.raises(ValueError):
         models.LlamaConfig.tiny(decode=True, decode_attn="mosaic")
+
+
+# ------------------------------------------------------------------ #
+# the stream is bounded by each row's position (PR 27)
+# ------------------------------------------------------------------ #
+S, BLOCK = 64, 16
+EDGES = [0, BLOCK - 1, BLOCK, S - 1]
+
+
+def _operands(quantized, k, v, past):
+    """``(kernel, clean cache operands, the same with NaN wherever
+    ``past [B, S]`` says)``: NaN goes into the float scales of a
+    quantized cache (int8 holds none).  A block that is fetched and only
+    masked turns the output NaN through ``0 * NaN``."""
+    spoil = lambda x, m: jnp.where(m, jnp.nan, x)
+    if quantized:
+        (kq, ks), (vq, vs) = _amax_quantize(k), _amax_quantize(v)
+        ks, vs = ks[..., 0], vs[..., 0]
+        return (decode_attention_int8, (kq, ks, vq, vs),
+                (kq, spoil(ks, past[:, None]), vq, spoil(vs, past[:, None])))
+    return (decode_attention, (k, v), (spoil(k, past[:, None, :, None]),
+                                       spoil(v, past[:, None, :, None])))
+
+
+def _past(positions, live=None):
+    """[B, S]: the positions in blocks wholly past each row's own (all
+    of a row that does not decode)."""
+    past = (np.arange(S)[None] // BLOCK
+            > np.asarray(positions)[:, None] // BLOCK)
+    return past if live is None else past | ~np.asarray(live)[:, None]
+
+
+def _kernel_and_reference(quantized, q, k, v, positions, poison=False):
+    """The kernel's output over rows at ``positions`` (its cache
+    poisoned past each row's position if asked) and the XLA lowering's
+    over the clean cache."""
+    positions = jnp.asarray(positions, jnp.int32)
+    kernel, clean, dirty = _operands(quantized, k, v, _past(positions))
+    if quantized:
+        kq, ks, vq, vs = clean
+        k = kq.astype(jnp.float32) * ks[..., None]
+        v = vq.astype(jnp.float32) * vs[..., None]
+    ref = jax.vmap(lambda q, k, v, i: _cached_attention(
+        q[None], k[None], v[None], i)[0])(q, k, v, positions)
+    out = kernel(q, *(dirty if poison else clean), positions, block_s=BLOCK)
+    return np.asarray(out), np.asarray(ref)
+
+
+def _rows(b, n_kv=2, rep=2, d=16, seed=7):
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(b, 1, n_kv * rep, d), jnp.float32)
+    return (q,) + _rand_cache(b, n_kv, S, d, seed=seed + 1)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("idx", EDGES)
+def test_parity_at_the_block_edges(idx, quantized):
+    out, ref = _kernel_and_reference(quantized, *_rows(2), [idx, idx])
+    np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("idx", EDGES[:-1])
+def test_blocks_past_the_position_are_not_read(idx, quantized):
+    """Not merely masked: a NaN in any of them would reach the output
+    through ``0 * NaN`` in the value product (the kernel before PR 27
+    fetched every block and fails this)."""
+    out, ref = _kernel_and_reference(quantized, *_rows(2), [idx, idx],
+                                     poison=True)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_rows_at_different_positions_stream_their_own_blocks(quantized):
+    """The engine's map over slots: one row at 0, one at a block edge,
+    one that does not decode, one at the end, folded into one launch,
+    each bounded by its own position."""
+    positions = jnp.asarray([0, BLOCK, 40, S - 1], jnp.int32)
+    live = jnp.asarray([True, True, False, True])
+    q, k, v = _rows(4)
+    kernel, clean, dirty = _operands(quantized, k, v,
+                                     _past(positions, live))
+
+    def one(q, idx, live, *ops):     # a slot of the pool: batch 1
+        return kernel(q[None], *[op[None] for op in ops], idx,
+                      live=live[None], block_s=BLOCK)[0]
+
+    mapped = jax.jit(jax.vmap(one))(q, positions, live, *dirty)
+    assert np.isfinite(np.asarray(mapped)).all()
+    for i in range(4):
+        alone = one(q[i], positions[i], live[i], *[op[i] for op in clean])
+        np.testing.assert_allclose(np.asarray(mapped[i]), np.asarray(alone),
+                                   atol=1e-6, rtol=1e-6)
+    assert not np.asarray(mapped[2]).any()   # the dead row: zeros
+
+
+@pytest.mark.parametrize("positions", [
+    [0], [BLOCK - 1, BLOCK], [S - 1], [0, 5, 17, 40, 63], [S + 9],
+    [-1, 20, -1, -1, 63, -1], [40, -1, -1, 3], [-1, -1], [-1]])
+def test_streamed_positions_counts_the_blocks_the_index_map_names(
+        positions):
+    """``-1``: a row that does not decode.  The pipeline fetches a block
+    when a grid step names another than the step before it did."""
+    live = jnp.asarray([p >= 0 for p in positions])
+    idx = jnp.clip(jnp.asarray(positions, jnp.int32), 0, S - 1)
+    plan = np.asarray(pallas_decode._stream_plan(idx, live, BLOCK))
+    named = [tuple(int(x) for x in pallas_decode._named_block(b, sj, plan))
+             for b in range(len(positions)) for sj in range(S // BLOCK)]
+    fetched = 1 + sum(a != b for a, b in zip(named, named[1:]))
+    assert len(set(named)) == fetched       # no block is fetched twice
+    assert streamed_positions(positions, S, block_s=BLOCK) \
+        == fetched * BLOCK
+    # the XLA lowering reads every row whole
+    assert streamed_positions(positions, S, fused=False) \
+        == len(positions) * S
+
+
+def test_the_block_follows_the_cache_length():
+    """No keyword chooses it: the largest divisor of the length up to
+    the measured block; a length with no block of 8 rows is refused."""
+    assert streamed_positions([0], 2048) == pallas_decode._BLOCK_S == 512
+    assert streamed_positions([0], 48) == 48
+    assert pallas_decode.tileable(2048) and pallas_decode.tileable(5)
+    assert not pallas_decode.tileable(1031)        # a prime
+    q, k, v = _rows(1)
+    with pytest.raises(ValueError, match="no block divisor"):
+        decode_attention(jnp.zeros((1, 1, 4, 16)),
+                         jnp.zeros((1, 2, 1031, 16)),
+                         jnp.zeros((1, 2, 1031, 16)), jnp.int32(3))
+
+
+# ------------------------------------------------------------------ #
+# through the serving engine
+# ------------------------------------------------------------------ #
+ENGINE = dict(capacity=5, max_len=1024, prefill_chunk=32)
+
+
+def _serve(decode_attn, monkeypatch):
+    """Four greedy requests through a five-slot engine (two blocks of
+    512 positions a slot): two cross the first block's edge while they
+    decode, one stays inside it, one arrives late and prefills (seven
+    chunks) while the others decode, and the fifth slot stays free.
+    Returns the tokens, the registry and every ``(positions, count)``
+    the kernel's own function was asked."""
+    from bluefog_tpu.observe import MetricsRegistry
+
+    asked = []
+
+    def recorded(positions, s_len, **kw):
+        asked.append((list(positions),
+                      streamed_positions(positions, s_len, **kw)))
+        return asked[-1][1]
+
+    monkeypatch.setattr(pallas_decode, "streamed_positions", recorded)
+    cfg = models.LlamaConfig.tiny(dtype=jnp.float32, max_seq_len=1024)
+    variables = models.Llama(cfg).init(jax.random.PRNGKey(1),
+                                       jnp.zeros((2, 4), jnp.int32))
+    reg = MetricsRegistry()
+    eng = ServingEngine(variables, cfg, decode_attn=decode_attn,
+                        registry=reg, **ENGINE)
+    assert eng.cfg.decode_attn == decode_attn
+    rs = np.random.RandomState(11)
+    reqs = [Request(rs.randint(0, 256, (n,)).astype(np.int32), new)
+            for n, new in ((506, 12), (5, 40), (511, 10), (200, 4))]
+    for r in reqs[:3]:
+        eng.submit(r)
+    for _ in range(36):      # 16 + 1 + 16 chunk steps, then decoding
+        eng.step()
+    eng.submit(reqs[3])
+    eng.run()
+    assert all(r.state == "completed" for r in reqs)
+    return [list(r.tokens) for r in reqs], reg, asked
+
+
+def test_the_engine_serves_the_same_tokens_and_counts_what_it_streams(
+        monkeypatch):
+    value = lambda reg, name, **labels: reg.counter(name, "",
+                                                    **labels).value
+    cap, max_len = ENGINE["capacity"], ENGINE["max_len"]
+    ref, reg_x, asked_x = _serve("xla", monkeypatch)
+    out, reg_p, asked_p = _serve("pallas", monkeypatch)
+    assert out == ref
+    steps = value(reg_x, "bf_serving_decode_steps_total")
+    assert steps == value(reg_p, "bf_serving_decode_steps_total") \
+        == len(asked_p)
+    layers = 2
+    assert value(reg_x, "bf_serving_streamed_positions_total",
+                 kind="full") == steps * cap * max_len * layers
+    assert value(reg_p, "bf_serving_streamed_positions_total",
+                 kind="full") == layers * sum(n for _, n in asked_p)
+    # the same lengths on both sides; every slot of the pool is a row
+    assert [p for p, _ in asked_p] == [p for p, _ in asked_x]
+    assert all(len(p) == cap for p, _ in asked_p)
+    block = pallas_decode._fit_block(max_len, pallas_decode._BLOCK_S)
+    # a slot that does not decode (free, or still prefilling) is no
+    # row of the stream: the most ever asked is the four requests'
+    assert all(1 <= sum(x >= 0 for x in p) <= 4 and -1 in p
+               for p, _ in asked_p)
+    assert max(n for _, n in asked_p) <= 5 * block
+    assert sum(n for _, n in asked_p) < steps * cap * max_len / 3
+
+
+@pytest.mark.parametrize("kv_quant,max_len,resolved", [
+    ("none", 2048, "pallas"), ("none", 1024, "pallas"),
+    ("int8", 2048, "xla"), ("none", 1031, "xla")])
+def test_auto_is_decided_from_platform_cache_dtype_and_tiling(
+        monkeypatch, kv_quant, max_len, resolved):
+    from bluefog_tpu.models import generate
+
+    cfg = models.LlamaConfig.tiny()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert generate.decode_config(cfg, max_len, kv_quant=kv_quant,
+                                  decode_attn="auto").decode_attn == resolved
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert generate.decode_config(cfg, max_len, kv_quant=kv_quant,
+                                  decode_attn="auto").decode_attn == "xla"

@@ -93,13 +93,18 @@ def test_splash_backward_compiles_for_v5e(chip):
                                                *_qkv(), chip=chip)
 
 
-@pytest.mark.parametrize("kv_quant,positions", [("none", 1024),
-                                                ("int8", 2048)])
-def test_decode_kernel_compiles_for_v5e(chip, kv_quant, positions):
-    q = jax.ShapeDtypeStruct((SLOTS, 1, CFG.n_heads, CFG.head_dim),
-                             jnp.bfloat16)
-    idx = jax.ShapeDtypeStruct((), jnp.int32)
-    shape = (SLOTS, CFG.n_kv_heads, positions, CFG.head_dim)
+# the last row is mistral7b-serve-steady's pool (BENCHMARK.json): 32
+# slots x 2048 positions, 32 query / 8 KV heads of 128
+@pytest.mark.parametrize("kv_quant,slots,positions,heads,head_dim", [
+    ("none", SLOTS, 1024, CFG.n_heads, CFG.head_dim),
+    ("int8", SLOTS, 2048, CFG.n_heads, CFG.head_dim),
+    ("none", 32, 2048, 32, 128),
+    ("int8", 32, 2048, 32, 128)])
+def test_decode_kernel_compiles_for_v5e(chip, kv_quant, slots, positions,
+                                        heads, head_dim):
+    q = jax.ShapeDtypeStruct((slots, 1, heads, head_dim), jnp.bfloat16)
+    idx = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    shape = (slots, CFG.n_kv_heads, positions, head_dim)
     if kv_quant == "int8":
         kv = jax.ShapeDtypeStruct(shape, jnp.int8)
         scale = jax.ShapeDtypeStruct(shape[:-1], jnp.float32)
