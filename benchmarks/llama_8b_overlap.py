@@ -86,8 +86,8 @@ def lower_bucketed_step(buckets: int, comm_mode: str = "atc",
 def _pod_step_setup(dp: int = DP, tp: int = TP, topo_kwargs=None):
     """The ONE 8B pod layout both audits measure: returns
     ``(build(**train_step_kwargs) -> step, (a_params, a_opt, a_batch))``
-    so the overlap and epilogue records in the same JSON are guaranteed
-    to describe the same model/mesh/spec configuration.  ``dp``/``tp``
+    so the records in the same JSON are guaranteed to describe the same
+    model/mesh/spec configuration.  ``dp``/``tp``
     reshape the same 16 virtual devices (the hierarchical audit needs a
     dp ring long enough to decompose into machines); ``topo_kwargs``
     overrides the default dp ring topology (e.g. a MACHINE-level
@@ -141,87 +141,8 @@ def _pod_step_setup(dp: int = DP, tp: int = TP, topo_kwargs=None):
     return build, (a_params, a_opt, a_batch)
 
 
-def lower_feature_step(buckets: int, fused: bool,
-                       comm_mode: str = "atc"):
-    """AOT-lower the guard+health+int8 bucketed 8B step with the fused
-    epilogue pipeline on or off (BLUEFOG_FUSE_EPILOGUES) and return its
-    StepProfile — the ISSUE-6 before/after accounting at the real pod
-    layout (same ``_pod_step_setup`` as the overlap audit)."""
-    from bluefog_tpu.observe import stepprof
-    from bluefog_tpu.optim.functional import GuardConfig, HealthConfig
-
-    build, a_args = _pod_step_setup()
-    # force the requested pipeline explicitly (and restore the caller's
-    # setting after): honoring an ambient BLUEFOG_FUSE_EPILOGUES=0 on
-    # the fused leg would silently compare unfused-vs-unfused
-    prior = os.environ.get("BLUEFOG_FUSE_EPILOGUES")
-    os.environ["BLUEFOG_FUSE_EPILOGUES"] = "1" if fused else "0"
-    try:
-        step = build(comm_mode=comm_mode, compress="int8",
-                     overlap="bucketed", overlap_buckets=buckets,
-                     guard=GuardConfig(), health=HealthConfig())
-    finally:
-        if prior is None:
-            os.environ.pop("BLUEFOG_FUSE_EPILOGUES", None)
-        else:
-            os.environ["BLUEFOG_FUSE_EPILOGUES"] = prior
-    return stepprof.profile_step(
-        step, *a_args, jnp.int32(0), step.default_comm_weights,
-        name="fused" if fused else "unfused", publish=False)
-
-
-def epilogue_audit(buckets: int, comm_mode: str = "atc") -> dict:
-    """Fused-vs-unfused non-collective accounting of the guarded+
-    health+int8 bucketed 8B step: the machine-checked half of the
-    ISSUE-6 MFU claim (fewer non-collective HLO ops at an unchanged
-    collective schedule)."""
-    t0 = time.perf_counter()
-    pf = lower_feature_step(buckets, fused=True, comm_mode=comm_mode)
-    pu = lower_feature_step(buckets, fused=False, comm_mode=comm_mode)
-
-    def summarize(p):
-        return {
-            "non_collective_ops": p.non_collective_ops(),
-            "non_collective_flops": p.non_collective_flops(),
-            "cost_bytes_accessed": p.cost_bytes_accessed,
-            "collective_bytes": p.collective_bytes,
-        }
-
-    sf, su = summarize(pf), summarize(pu)
-    return {
-        "method": "AOT StepProfile of the guard+health+int8 bucketed "
-                  f"(K={buckets}, {comm_mode}) tp8_seqshard 8B step, "
-                  "fused epilogue pipeline vs BLUEFOG_FUSE_EPILOGUES=0 "
-                  "(the pre-fusion tree-walk builders); "
-                  "tests/test_hlo_guarantees.py pins the same claim in "
-                  "tier-1 on the small CPU config",
-        "config": {"buckets": buckets, "comm_mode": comm_mode,
-                   "guard": True, "health": True, "compress": "int8"},
-        "compile_s": round(time.perf_counter() - t0, 1),
-        "fused": sf,
-        "unfused": su,
-        "claims": {
-            "noncollective_ops_delta":
-                sf["non_collective_ops"] - su["non_collective_ops"],
-            "noncollective_ops_ratio": round(
-                sf["non_collective_ops"]
-                / max(su["non_collective_ops"], 1), 4),
-            "fused_ops_leq_unfused":
-                sf["non_collective_ops"] <= su["non_collective_ops"],
-            "collective_schedule_unchanged":
-                sf["collective_bytes"] == su["collective_bytes"],
-            # the r11-layout fused record must hold the line after the
-            # hierarchical plumbing landed in the builders (the r11
-            # epilogue record measured 174.03 GB at this exact config)
-            "cost_bytes_not_above_r11":
-                sf["cost_bytes_accessed"] <= R11_FUSED_COST_BYTES,
-        },
-    }
-
-
 HIER_DP, HIER_TP = 4, 4   # same 16 devices, dp ring long enough to split
 HIER_M, HIER_L = 2, 2     # ... into 2 machines x 2 chips across DCN
-R11_FUSED_COST_BYTES = 174033747968.0  # epilogue record, r11 fused leg
 
 
 def hierarchical_audit(buckets: int, comm_mode: str = "atc") -> dict:
@@ -238,7 +159,7 @@ def hierarchical_audit(buckets: int, comm_mode: str = "atc") -> dict:
     inter-machine wire in either build (tp all-gather/reduce-scatter
     and the hierarchical ICI reduce stay inside the machine) — via
     ``stepprof.profile_step``, which also defends the tp overlap
-    fraction and the cost-model bytes/step against the r11 record."""
+    fraction and holds the cost-model bytes/step to the flat leg's."""
     from bluefog_tpu.observe import stepprof
     from bluefog_tpu.optim.functional import GuardConfig, HealthConfig
     from bluefog_tpu.topology.dynamic import one_peer_dynamic_schedule
@@ -515,9 +436,6 @@ def main():
                     choices=["atc", "cta"])
     ap.add_argument("--out",
                     default="benchmarks/llama_8b_measured.json")
-    ap.add_argument("--skip-epilogue", action="store_true",
-                    help="skip the fused-vs-unfused epilogue "
-                         "accounting (2 extra AOT compiles)")
     ap.add_argument("--skip-hierarchical", action="store_true",
                     help="skip the flat-vs-two-level DCN byte "
                          "accounting (2 extra AOT compiles)")
@@ -531,9 +449,6 @@ def main():
         with open(args.out) as fh:
             result = json.load(fh)
     result["overlap"] = audit(args.buckets, args.comm_mode)
-    if not args.skip_epilogue:
-        result["epilogue"] = epilogue_audit(args.buckets,
-                                            args.comm_mode)
     if not args.skip_hierarchical:
         result["hierarchical"] = hierarchical_audit(args.buckets,
                                                     args.comm_mode)
@@ -546,8 +461,6 @@ def main():
     with open(args.out, "w") as fh:
         json.dump(result, fh, indent=1)
     print(json.dumps(result["overlap"], indent=1))
-    if "epilogue" in result:
-        print(json.dumps(result["epilogue"]["claims"], indent=1))
     if "hierarchical" in result:
         print(json.dumps(result["hierarchical"]["claims"], indent=1))
     if "compressed" in result:

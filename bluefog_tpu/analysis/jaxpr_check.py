@@ -318,13 +318,15 @@ def check_traced(closed, *, name: str,
 
 
 def check_step(step, args: Tuple, *, name: str,
-               round_index: Optional[int] = None) -> List[Finding]:
+               round_index: Optional[int] = None,
+               off_cycle: bool = False) -> List[Finding]:
     """Contract-check one built train step against its public call
     ``step(*args)``: the program that call would run.  A scheduled step
     is one program a round, picked on the host from the ``step`` among
     ``args``: give its ``round_index`` and the program is held to
     consuming that round's weight tables (the others are operands it
-    has no use for).
+    has no use for).  ``off_cycle``: the program of a step between two
+    exchanges (``num_steps_per_communication``), which consumes none.
 
     The step's ``.trace`` (shared with ``.lower`` — same program) maps
     the public signature onto the jitted program, whose flattened
@@ -345,7 +347,7 @@ def check_step(step, args: Tuple, *, name: str,
     seed = [True] * n
     for i in range(max(0, n - n_w - 1), n):
         seed[i] = False
-    live_w = None
+    live_w = () if off_cycle else None
     if round_index is not None and n_w:
         # leaves of ``default_comm_weights[round_index]``
         sizes = [len(jax.tree.leaves(w))
@@ -427,13 +429,16 @@ def _weighted_schedule():
 
 
 def sweep_cases() -> List[dict]:
-    """The build_train_step configurations the sweep traces: the
-    epilogue parity matrix (tests/test_epilogue.py ``_matrix``) —
-    guard x health x compress x comm_mode x overlap on the weighted
-    static ring, int8 wire, push_sum (the in-graph gossip mix),
-    dynamic schedules (``P`` programs, one a round, then none: every
-    round's program is checked), hierarchical two-level — so the
-    analyzer covers exactly the program space the parity tests pin."""
+    """The build_train_step configurations the sweep traces, and the
+    ONE list of them: tests/test_epilogue.py draws its parity matrix
+    from here (every case a plain reference step can follow: all but
+    ``topk`` and ``moe``), so the analyzer covers the program space the
+    parity tests pin.  Guard x health x compress x comm_mode x overlap
+    on the weighted static ring, the quantized wires, push_sum (the
+    in-graph gossip mix), dynamic schedules (``P`` programs, one a
+    round, then none: every round's program is checked), hierarchical
+    two-level, the two modes with no neighbor exchange, and
+    ``num_steps_per_communication=2`` (the off-cycle program too)."""
     ring = _weighted_ring()
     cases: List[dict] = []
     for comm_mode in ("cta", "atc"):
@@ -450,6 +455,11 @@ def sweep_cases() -> List[dict]:
                               topology=ring))
     cases.append(dict(comm_mode="atc", overlap="none", guard=True,
                       health=True, compress="int8", topology=ring))
+    # the other two wire formats: stochastic rounding, bfloat16
+    cases.append(dict(comm_mode="atc", overlap="none", guard=False,
+                      health=True, compress="int8_sr", topology=ring))
+    cases.append(dict(comm_mode="cta", overlap="none", guard=False,
+                      health=True, compress="bf16", topology=ring))
     # error-feedback compressed mixing: the "topk" epilogue threads
     # MixState through the round's branch — lint it like any other
     cases.append(dict(comm_mode="cta", overlap="none", guard=False,
@@ -486,6 +496,17 @@ def sweep_cases() -> List[dict]:
         cases.append(dict(comm_mode=comm_mode, overlap=overlap,
                           guard=guard, health=health, compress=compress,
                           topology=mring, hierarchical=2))
+    # no neighbor exchange: the all-reduce baseline and local SGD (what
+    # the one-chip cells train under), plain and with guard and health
+    for comm_mode in ("gradient_allreduce", "none"):
+        for on in (False, True):
+            cases.append(dict(comm_mode=comm_mode, overlap="none",
+                              guard=on, health=on, compress=None))
+    # an exchange every second step: an on-cycle and an off-cycle program
+    for comm_mode in ("cta", "atc"):
+        cases.append(dict(comm_mode=comm_mode, overlap="none", guard=False,
+                          health=False, compress=None, topology=ring,
+                          num_steps_per_communication=2))
     return cases
 
 
@@ -496,8 +517,28 @@ def case_id(c: dict) -> str:
         "health" if c["health"] else "nohealth",
         c["compress"] or "fp",
         "hier" if "hierarchical" in c
-        else ("sched" if "schedule" in c else "static")]
-        + (["moe"] if c.get("moe") else []))
+        else "sched" if "schedule" in c
+        else "static" if "topology" in c else "nograph"]
+        + (["moe"] if c.get("moe") else [])
+        + ([f"k{c['num_steps_per_communication']}"]
+           if "num_steps_per_communication" in c else []))
+
+
+def build_kwargs(case: dict) -> dict:
+    """``build_train_step``'s keywords for one sweep case (``moe`` is
+    the caller's to resolve: it brings its own loss)."""
+    from bluefog_tpu.optim import functional as F
+
+    kwargs = dict(case)
+    if kwargs.pop("overlap") != "none":
+        kwargs.update(overlap="bucketed", overlap_buckets=3)
+    if kwargs.get("compress") is None:
+        kwargs.pop("compress")
+    if kwargs.get("schedule") == "one_peer":
+        kwargs["schedule"] = _weighted_schedule()
+    kwargs["guard"] = F.GuardConfig() if kwargs["guard"] else None
+    kwargs["health"] = F.HealthConfig() if kwargs["health"] else None
+    return kwargs
 
 
 def _build_and_check(case: dict, mesh) -> List[Finding]:
@@ -509,8 +550,7 @@ def _build_and_check(case: dict, mesh) -> List[Finding]:
     opt = optax.sgd(0.05, momentum=0.9)
     base, loss_fn = _problem()
     c = dict(case)
-    guarded = c.pop("guard")
-    health = c.pop("health")
+    guarded = c["guard"]
     push_sum = c["comm_mode"] == "push_sum"
     moe = c.pop("moe", False)
     if moe:
@@ -524,19 +564,7 @@ def _build_and_check(case: dict, mesh) -> List[Finding]:
         base = init_moe_params(jax.random.PRNGKey(0), 4, 4, 4)
         loss_fn = make_moe_loss(plan, "bf", 2)
         c["moe"] = F.MoEConfig(n_experts=4, capacity=2)
-    kwargs = dict(c)
-    if kwargs.pop("overlap") != "none":
-        kwargs.update(overlap="bucketed", overlap_buckets=3)
-    if kwargs.get("compress") is None:
-        kwargs.pop("compress")
-    if kwargs.get("schedule") == "one_peer":
-        kwargs["schedule"] = _weighted_schedule()
-    if "hierarchical" in kwargs:
-        pass  # hierarchical=2 passes through verbatim
-    if guarded:
-        kwargs["guard"] = F.GuardConfig()
-    if health:
-        kwargs["health"] = F.HealthConfig()
+    kwargs = build_kwargs(c)
 
     step = F.build_train_step(loss_fn, opt, mesh, donate=False, **kwargs)
     params = F.rank_major(base, mesh)
@@ -556,17 +584,21 @@ def _build_and_check(case: dict, mesh) -> List[Finding]:
     else:
         batch = np.zeros((N_RANKS, 3, 4), np.float32)
     # a schedule of P rounds is P programs (the host picks the round's
-    # from ``step``): each is held to the contracts
+    # from ``step``), an exchange every k-th step two (on and off
+    # cycle): each is held to the contracts
     findings: List[Finding] = []
     scheduled = "schedule" in kwargs
-    for r in range(len(kwargs["schedule"]) if scheduled else 1):
+    programs = (len(kwargs["schedule"]) if scheduled
+                else kwargs.get("num_steps_per_communication", 1))
+    for r in range(programs):
         args = (params, ostate, batch, np.int32(r))
         if guarded:
             args = args + (step.default_comm_weights,)
         findings += check_step(
             step, args, round_index=r if scheduled else None,
+            off_cycle=not scheduled and r > 0,
             name=f"step[{case_id(case)}]"
-            + (f"[round {r}]" if scheduled else ""))
+            + (f"[step {r}]" if programs > 1 else ""))
     return findings
 
 

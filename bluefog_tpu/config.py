@@ -28,7 +28,6 @@ __all__ = [
     "ops_on_cpu",
     "stall_warning_time",
     "op_timeout",
-    "fuse_epilogues",
     "fusion_threshold",
     "hier_local_size",
     "mix_compress",
@@ -175,21 +174,6 @@ def straggler_z_threshold() -> float:
         return float(_env("BLUEFOG_STRAGGLER_Z", "4.0"))
     except ValueError:
         return 4.0
-
-
-def fuse_epilogues() -> bool:
-    """BLUEFOG_FUSE_EPILOGUES (default on): whether
-    :func:`bluefog_tpu.optim.functional.build_train_step` builds the
-    FUSED per-bucket epilogue pipeline (quantize -> exchange ->
-    dequantize -> guard-select -> health-norm composed into one pass
-    per fusion-plan bucket).  ``0`` falls back to the pre-fusion
-    builders where the guard's isfinite reduce, the health vector's
-    norms, and the consensus distance each re-traverse the full param
-    tree around the exchange — the escape hatch for debugging, and the
-    golden reference path the epilogue parity matrix compares against
-    (tests/test_epilogue.py)."""
-    return _env("BLUEFOG_FUSE_EPILOGUES", "1") not in ("0", "false",
-                                                       "False")
 
 
 def hier_local_size():

@@ -22,7 +22,7 @@ Key design points (SURVEY.md §7 "hard parts"):
   cache afterwards), none after the first cycle, and the model is traced
   once for all of them: forward and backward are a jitted function of
   their own that every round's program calls (``fwd_bwd_all`` in
-  ``_build_fused_train_step``, traced at the top level before the first
+  ``_build_step``, traced at the top level before the first
   program is).  The round is NOT a
   ``lax.switch`` inside one program: a ``conditional`` takes the whole
   parameter tree as its operand, so no permute inside it could start
@@ -44,12 +44,9 @@ Key design points (SURVEY.md §7 "hard parts"):
 Combine math is f32-accumulated via the shard-level kernels in
 ``bluefog_tpu.parallel.collectives``.
 
-**Fused per-bucket epilogue pipeline** (default since ISSUE 6): the
-skip guard's isfinite reduce, the HealthVector's norms, wire
-quantization, and the consensus distance used to each re-traverse the
-full param tree around the same neighbor exchange — pure non-collective
-overhead stacked on the hot path (the flat 2723→2746 img/s/chip BENCH
-trajectory across r01–r05).  The builder now plans the param tree into
+**One builder, a per-bucket epilogue pipeline** — ``build_train_step``
+validates its arguments and hands them to ``_build_step``, which
+builds every comm mode and every feature.  It plans the param tree into
 fusion buckets (``optim.fusion.EpiloguePlan`` — one bucket per leaf on
 the plain path, size-balanced buckets under ``overlap="bucketed"``) and
 emits ONE composed closure per bucket running quantize → exchange →
@@ -57,12 +54,14 @@ dequantize → guard-select → health-norm over that bucket's leaves; the
 guard/health reductions are accumulated as per-bucket partials combined
 at the end, and the consensus distance is computed from the exchange's
 already-materialized pre/post buffers (no re-mix, no second tree walk).
-``BLUEFOG_FUSE_EPILOGUES=0`` restores the pre-fusion builders — the
-debugging escape hatch and the golden reference of the epilogue parity
-matrix (tests/test_epilogue.py).  The fused combine weights ride as
-TRACED OPERANDS in both the guarded and unguarded builds, so the two
-share one association order: the uniform-weight static-CTA constant-
-fold 1-ulp caveat of the pre-fusion path (CHANGES.md PR 3) is gone.
+The cta/atc combine weights ride as TRACED OPERANDS in both the guarded
+and unguarded builds, so the two share one association order (guarded ==
+unguarded bit for bit on finite data, uniform-weight static CTA
+included) and healing swaps weight data without recompiling either.
+The step is held to a plain reference written from the definitions
+(tests/reference_step.py, tests/test_epilogue.py): per rank
+``value_and_grad`` and the optax update, mixing as one dense product
+with the round's matrix.
 """
 
 from __future__ import annotations
@@ -301,28 +300,6 @@ def _moe_shared_mask(tree, moe: "MoEConfig"):
             for path, _ in flat]
 
 
-def _tree_sq_sum(tree) -> jax.Array:
-    """f32 sum of squares over every inexact leaf (0.0 for none)."""
-    acc = jnp.zeros((), jnp.float32)
-    for leaf in jax.tree.leaves(tree):
-        leaf = jnp.asarray(leaf)
-        if jnp.issubdtype(leaf.dtype, jnp.inexact):
-            acc = acc + jnp.sum(jnp.square(leaf.astype(jnp.float32)))
-    return acc
-
-
-def _tree_distance(a, b) -> jax.Array:
-    """f32 L2 distance between two structurally-identical trees
-    (inexact leaves only) — the in-graph consensus-distance kernel."""
-    acc = jnp.zeros((), jnp.float32)
-    for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-        la = jnp.asarray(la)
-        if jnp.issubdtype(la.dtype, jnp.inexact):
-            d = la.astype(jnp.float32) - jnp.asarray(lb).astype(jnp.float32)
-            acc = acc + jnp.sum(jnp.square(d))
-    return jnp.sqrt(acc)
-
-
 def comm_weight_inputs(specs: Sequence[CommSpec]) -> tuple:
     """The combine weights of a topology/schedule as TRACED-OPERAND data:
     one ``(class_weights [n_classes, n], self_weights [n])`` pair per
@@ -335,23 +312,11 @@ def comm_weight_inputs(specs: Sequence[CommSpec]) -> tuple:
         (C.class_recv_weights(s), C.self_weight_vector(s)) for s in specs)
 
 
-def _all_finite(loss: jax.Array, updates: Any) -> jax.Array:
-    """Scalar health bit: loss and every inexact update leaf finite —
-    the in-graph ``jnp.isfinite`` reduce the failure detector and the
-    skip guard share."""
-    ok = jnp.all(jnp.isfinite(loss))
-    for leaf in jax.tree.leaves(updates):
-        if jnp.issubdtype(jnp.asarray(leaf).dtype, jnp.inexact):
-            ok = ok & jnp.all(jnp.isfinite(leaf))
-    return ok
-
-
 def _grouped_sq_sum(leaves, groups) -> jax.Array:
     """f32 sum of squares over inexact leaves, accumulated as
-    per-bucket partials in plan order — the epilogue pipeline's
-    incremental form of :func:`_tree_sq_sum`.  Groups partition the
-    leaves in tree order, so the accumulation association is identical
-    to the flat walk (bitwise-equal totals)."""
+    per-bucket partials in plan order.  Groups partition the leaves in
+    tree order, so the accumulation association is that of a flat walk
+    over the tree."""
     acc = jnp.zeros((), jnp.float32)
     for g in groups:
         for i in g:
@@ -363,9 +328,9 @@ def _grouped_sq_sum(leaves, groups) -> jax.Array:
 
 def _grouped_all_finite(loss: jax.Array, upd_leaves, groups) -> jax.Array:
     """The guard's isfinite reduce as per-bucket partials combined at
-    the end (boolean AND is associative — same flag as
-    :func:`_all_finite`), so the reduce fuses into the same per-bucket
-    pass as the norms instead of a separate full-tree walk."""
+    the end (boolean AND is associative: the flag of a flat walk over the
+    tree), so the reduce fuses into the same per-bucket pass as the
+    norms instead of a separate full-tree walk."""
     ok = jnp.all(jnp.isfinite(loss))
     for g in groups:
         part = jnp.bool_(True)
@@ -386,31 +351,13 @@ def _bucket_cons_sq(pre_buf: jax.Array, out_buf: jax.Array) -> jax.Array:
     return jnp.sum(jnp.square(d))
 
 
-def _make_health_vector(loss, grad_sq, updates, consensus,
-                        skipped=None) -> "HealthVector":
-    """The per-rank HealthVector (traced scalars), shared by the
-    guarded and unguarded builders so the field definitions cannot
-    drift — ``skipped`` defaults to the same in-graph isfinite reduce
-    the guard uses, reported as a would-skip bit."""
-    if skipped is None:
-        ok = _all_finite(loss, updates)
-        skipped = jnp.where(ok, jnp.float32(0), jnp.float32(1))
-    return HealthVector(
-        loss=jnp.asarray(loss, jnp.float32),
-        grad_norm=jnp.sqrt(grad_sq),
-        update_norm=jnp.sqrt(_tree_sq_sum(updates)),
-        skipped=jnp.asarray(skipped, jnp.float32),
-        consensus=jnp.asarray(consensus, jnp.float32))
-
-
 # Named scopes of the compiled step: metadata only (the optimized HLO
 # with ``metadata={...}`` stripped is what it was), so that a device
 # trace can bill each operation to a part of the step by its ``tf_op``
 # path instead of by an HLO number.  JAX itself writes ``jvp(...)`` and
 # ``transpose(jvp(...))`` below ``bf.forward_backward``: the
-# forward/backward split.  Where scopes nest (the bucketed ATC engine
-# applies each bucket's update inside the exchange), the innermost
-# names the work.
+# forward/backward split.  Where scopes nest, the innermost names the
+# work.
 SCOPE_FORWARD_BACKWARD = "bf.forward_backward"
 SCOPE_OPTIMIZER = "bf.optimizer"
 SCOPE_EXCHANGE = "bf.exchange"
@@ -464,58 +411,6 @@ def _loss_and_grads(loss_fn, has_aux, sp_axis, pp_axis, param_specs,
 
         grads = jax.tree.map(_pp_reduce, grads, param_specs)
     return loss, grads, new_aux
-
-
-def _weighted_combine_fn(spec: CommSpec, axis_name: str,
-                         compress: Optional[str],
-                         n_buckets: Optional[int],
-                         hierarchical_local_size: Optional[int] = None,
-                         ) -> Callable:
-    """Combine branch ``fn(tree, key, (class_w, self_w))`` with the
-    weights as traced operands — ``spec`` contributes only the edge
-    structure (same design as windows.py's put/update kernels).  With
-    ``n_buckets`` the bucketed overlap packing is applied around the
-    weighted combine.  Under ``hierarchical_local_size`` the spec and
-    the weight tables are MACHINE-level and the exchange is the
-    two-level combine (compression on the DCN leg only)."""
-    wire = compress == "int8_sr"
-    wire_compress = "int8" if wire else compress
-    hls = hierarchical_local_size
-
-    def one(p, key, cw, sw):
-        if hls is not None:
-            return C.hierarchical_neighbor_allreduce(
-                p, spec, hls, axis_name, compress=wire_compress,
-                wire_key=key, class_weights=cw, self_weights=sw)
-        return C.neighbor_allreduce(
-            p, spec, axis_name, compress=wire_compress, wire_key=key,
-            class_weights=cw, self_weights=sw)
-
-    def fn(tree, key, w):
-        cw, sw = w
-        leaves, treedef = jax.tree_util.tree_flatten(tree)
-        if not leaves:
-            return tree
-        if n_buckets is None:
-            outs = [
-                one(p, (jax.random.fold_in(key, i) if wire else None),
-                    cw, sw)
-                for i, p in enumerate(leaves)
-            ]
-            return jax.tree_util.tree_unflatten(treedef, outs)
-        groups = _bucket_groups(leaves, n_buckets)
-        buffers = [_pack_bucket(leaves, g) for g in groups]
-        combined = C.neighbor_allreduce_buckets(
-            buffers, spec, axis_name, compress=wire_compress,
-            wire_key=key if wire else None,
-            hierarchical_local_size=hls,
-            class_weights=cw, self_weights=sw)
-        outs = [None] * len(leaves)
-        for g, buf in zip(groups, combined):
-            _unpack_bucket(buf, leaves, g, outs)
-        return jax.tree_util.tree_unflatten(treedef, outs)
-
-    return fn
 
 
 def rank_major(tree, mesh: Mesh, axis_name: str = "bf", specs=None):
@@ -652,20 +547,6 @@ def push_sum_weights(mesh: Mesh, axis_name: str = "bf") -> jax.Array:
                           NamedSharding(mesh, P(axis_name)))
 
 
-def _bucket_groups(leaves, n_buckets: int):
-    """Trace-time size-balanced bucket assignment over per-shard leaves —
-    the SAME grouping walk as the eager wrappers' fusion planner
-    (optim.fusion.plan_groups), thresholded at ceil(total/K) so the
-    buckets are size-balanced.  Dtype boundaries only ever increase the
-    count; leaf granularity bounds it from above (a single dominant
-    leaf — one stacked scan_layers kernel, the embed table — is never
-    split, so such trees get the best bucket count achievable at leaf
-    granularity, possibly < K; see fusion.size_balanced_threshold)."""
-    rows = _fusion.bucket_signature(leaves)
-    threshold = _fusion.size_balanced_threshold(rows, n_buckets)
-    return _fusion.plan_groups(rows, threshold)
-
-
 def _pack_bucket(leaves, group):
     """Concatenate a bucket's leaves into one flat per-shard buffer (a
     single-leaf bucket keeps its shape: no reshape traffic, and compress
@@ -687,119 +568,6 @@ def _unpack_bucket(buf, leaves, group, outs):
         k = leaves[i].size
         outs[i] = jnp.reshape(buf[off:off + k], leaves[i].shape)
         off += k
-
-
-def _bucketed_combine_fn(spec: CommSpec, axis_name: str,
-                         hierarchical_local_size: Optional[int],
-                         compress: Optional[str],
-                         n_buckets: int) -> Callable:
-    """Bucketed combine branch ``fn(tree, key)`` (CTA): the param tree is
-    packed into K size-balanced buckets and each bucket issues its own
-    neighbor combine, in tree order.  Under CTA the forward consumes the
-    combined params bucket-by-bucket (tree order IS layer order for the
-    standard model trees), so forward compute that only needs early
-    buckets is dataflow-independent of late buckets' transfers — exactly
-    the freedom the latency-hiding scheduler needs to overlap them."""
-    wire = compress == "int8_sr"
-    wire_compress = "int8" if wire else compress
-
-    def fn(tree, key):
-        leaves, treedef = jax.tree_util.tree_flatten(tree)
-        if not leaves:
-            return tree
-        groups = _bucket_groups(leaves, n_buckets)
-        buffers = [_pack_bucket(leaves, g) for g in groups]
-        combined = C.neighbor_allreduce_buckets(
-            buffers, spec, axis_name, compress=wire_compress,
-            wire_key=key if wire else None,
-            hierarchical_local_size=hierarchical_local_size)
-        outs = [None] * len(leaves)
-        for g, buf in zip(groups, combined):
-            _unpack_bucket(buf, leaves, g, outs)
-        return jax.tree_util.tree_unflatten(treedef, outs)
-
-    return fn
-
-
-def _bucketed_apply_combine_fn(spec: CommSpec, axis_name: str,
-                               hierarchical_local_size: Optional[int],
-                               compress: Optional[str],
-                               n_buckets: int) -> Callable:
-    """Bucketed ATC branch ``fn((params, updates), key) -> params``:
-    bucket *i*'s optax update is applied and its neighbor combine issued
-    BEFORE bucket *i+1*'s update is applied — the jitted counterpart of
-    the reference's per-parameter hooks that enqueue communication while
-    the framework keeps computing (reference optimizers.py:485-841).
-    Bucket *i+1*'s apply arithmetic is dataflow-independent of bucket
-    *i*'s in-flight collective-permute, so the latency-hiding scheduler
-    can place it inside the start->done window."""
-    wire = compress == "int8_sr"
-    wire_compress = "int8" if wire else compress
-
-    def fn(operand, key):
-        params, updates = operand
-        leaves, treedef = jax.tree_util.tree_flatten(params)
-        upd_leaves = jax.tree_util.tree_flatten(updates)[0]
-        if not leaves:
-            return params
-        groups = _bucket_groups(leaves, n_buckets)
-        outs = [None] * len(leaves)
-        for bi, g in enumerate(groups):
-            fresh = list(leaves)
-            for i in g:
-                fresh[i] = _apply_updates(leaves[i], upd_leaves[i])
-            buf = _pack_bucket(fresh, g)
-            wk = jax.random.fold_in(key, bi) if wire else None
-            if hierarchical_local_size is not None:
-                out = C.hierarchical_neighbor_allreduce(
-                    buf, spec, hierarchical_local_size, axis_name,
-                    compress=wire_compress, wire_key=wk)
-            else:
-                out = C.neighbor_allreduce(
-                    buf, spec, axis_name, compress=wire_compress,
-                    wire_key=wk)
-            _unpack_bucket(out, fresh, g, outs)
-        return jax.tree_util.tree_unflatten(treedef, outs)
-
-    return fn
-
-
-def _combine_fn(spec: CommSpec, axis_name: str,
-                hierarchical_local_size: Optional[int],
-                compress: Optional[str] = None) -> Callable:
-    """Combine branch ``fn(tree, key)``; ``key`` feeds the stochastic
-    wire rounder under ``compress='int8_sr'`` and is ignored (then DCE'd
-    by XLA) everywhere else."""
-    if hierarchical_local_size is not None:
-        wire = compress == "int8_sr"
-        wire_compress = "int8" if wire else compress
-
-        def hier_fn(tree, key):
-            leaves, treedef = jax.tree_util.tree_flatten(tree)
-            outs = [
-                C.hierarchical_neighbor_allreduce(
-                    p, spec, hierarchical_local_size, axis_name,
-                    compress=wire_compress,
-                    wire_key=(jax.random.fold_in(key, i) if wire
-                              else None))
-                for i, p in enumerate(leaves)
-            ]
-            return jax.tree_util.tree_unflatten(treedef, outs)
-        return hier_fn
-    if compress == "int8_sr":
-        def fn(tree, key):
-            leaves, treedef = jax.tree_util.tree_flatten(tree)
-            outs = [
-                C.neighbor_allreduce(
-                    p, spec, axis_name, compress="int8",
-                    wire_key=jax.random.fold_in(key, i))
-                for i, p in enumerate(leaves)
-            ]
-            return jax.tree_util.tree_unflatten(treedef, outs)
-        return fn
-    return lambda tree, key: jax.tree.map(
-        lambda p: C.neighbor_allreduce(p, spec, axis_name,
-                                       compress=compress), tree)
 
 
 def _round_selector(comm_mode: str, specs: Sequence[CommSpec],
@@ -848,7 +616,7 @@ def _public_step(jitted: Callable, labels: dict, *,
     aux, opt_state, batch, step, *more, round)``: the public signature
     leaves out ``aux`` (argument and output) unless ``has_aux``, never
     has ``tail`` (operands the builder supplies itself: the default
-    combine weights of an unguarded fused step), and never the round,
+    combine weights of an unguarded step), and never the round,
     which ``select`` reads from ``step`` ONCE a dispatch, on the host,
     for the program and for the edge accounting alike.  ``.lower`` and
     ``.trace`` take the public arguments too (the round from the
@@ -856,8 +624,8 @@ def _public_step(jitted: Callable, labels: dict, *,
     and jaxpr inspection (bluefog_tpu.analysis) see the program a call
     would run; ``.jitted`` is the one jitted object, with one cache
     entry a round.  ``prepare`` is called once, with the program's
-    arguments, before the first program is made (the fused builder
-    traces the model there).
+    arguments, before the first program is made (a step of
+    several programs traces the model there).
 
     Host-side observability: each dispatch increments
     ``bf_train_steps_total{comm_mode,overlap,guarded}`` and runs inside
@@ -964,7 +732,7 @@ def _public_step(jitted: Callable, labels: dict, *,
     return step
 
 
-def _build_fused_train_step(
+def _build_step(
     loss_fn: Callable,
     optimizer: optax.GradientTransformation,
     mesh: Mesh,
@@ -988,9 +756,9 @@ def _build_fused_train_step(
     mix: Optional[MixCompressConfig] = None,
     moe: Optional[MoEConfig] = None,
 ) -> Callable:
-    """The fused per-bucket epilogue pipeline — the default
-    :func:`build_train_step` data plane (see its docstring for the
-    user contract, and the module docstring for the design).
+    """The builder behind :func:`build_train_step` (see its docstring
+    for the user contract, and the module docstring for the design);
+    the arguments arrive validated.
 
     One builder serves every feature combination: the param tree is
     planned into fusion buckets (``EpiloguePlan`` — one bucket per leaf
@@ -999,13 +767,11 @@ def _build_fused_train_step(
     dequantize → guard-select → health-norm → consensus) as one
     composed pass, for every comm mode including push_sum.  The
     guard's isfinite reduce and the health norms accumulate as
-    per-bucket partials in plan order (bitwise-equal to the flat walk);
-    the consensus distance reuses the exchange's own pre/post bucket
-    buffers.  The cta/atc combine weights are TRACED OPERANDS in the
-    guarded AND unguarded builds, so both share one association order
-    (the pre-fusion uniform-weight static-CTA constant-fold caveat is
-    gone) and topology healing swaps weight data without recompiling
-    either variant."""
+    per-bucket partials in plan order; the consensus distance reuses
+    the exchange's own pre/post bucket buffers.  The cta/atc combine
+    weights are TRACED OPERANDS in the guarded AND unguarded builds, so
+    both share one association order and topology healing swaps weight
+    data without recompiling either variant."""
     guarded = guard is not None
     want_health = health is not None
     want_cons = want_health and health.consensus
@@ -1170,8 +936,13 @@ def _build_fused_train_step(
             plan = _plan(leaves)
             bufs = [_pack_bucket(leaves, list(b.leaves))
                     for b in plan.buckets]
-            # re-bias -> mix -> de-bias stays in f32 (see the unfused
-            # combine_push_sum for the digraph-correctness rationale);
+            # Push-sum state is the BIASED pair (x, w) with readout
+            # z = x / w; the step carries (z, w) so the user-visible
+            # params stay de-biased, and re-biases before every mix
+            # (x = z * w): mixing z directly is only correct on doubly-
+            # stochastic graphs and diverges on general digraphs
+            # (reference optimizers.py:1151-1155).  The whole re-bias ->
+            # mix -> de-bias round stays in f32, one cast back at the end.
             # push_sum_mix takes any pytree, so the bucket-buffer list
             # mixes as one extended payload [buckets ‖ ps] — column-
             # stochastic mixing distributes over concatenation, each
@@ -1285,8 +1056,18 @@ def _build_fused_train_step(
             ok = _grouped_all_finite(
                 loss, jax.tree_util.tree_flatten(updates)[0], groups)
 
-            # elementwise select, NOT lax.cond — see the unfused
-            # guarded builder for why (bit-identity + mul+add fusion)
+            # The skip guard is per-rank arithmetic only: the collective
+            # combine stays OUTSIDE it (a per-rank-divergent branch must
+            # never contain a collective), and a skipping rank keeps
+            # params/aux/opt_state, so the combine feeds its last-good
+            # params to its neighbors.  An elementwise select over the
+            # unconditionally applied update, NOT a lax.cond: a
+            # traced-pred cond becomes a select anyway, but its branch
+            # boundary would block XLA's mul+add contraction inside
+            # apply_updates and cost the healthy path its bit-identity
+            # with the unguarded step.  A discarded non-finite branch is
+            # safe under select: it is elementwise, and nothing
+            # differentiates through it here.
             def pick(new, old):
                 return jnp.where(ok, new, old)
 
@@ -1669,11 +1450,9 @@ def build_train_step(
     hierarchical exchange the sparse wire rides the DCN leg only (the
     ICI machine reduce stays exact, ref/mirror state at machine-mean
     granularity).  Env defaults: ``BLUEFOG_MIX_COMPRESS`` /
-    ``BLUEFOG_MIX_COMPRESS_RATIO`` (explicit arguments win).  Needs
-    the fused epilogue pipeline (not available under
-    ``BLUEFOG_FUSE_EPILOGUES=0``) and does not compose with the
-    string wire modes (the int8 stage already rides the kept
-    values).
+    ``BLUEFOG_MIX_COMPRESS_RATIO`` (explicit arguments win).  Does
+    not compose with the string wire modes (the int8 stage already
+    rides the kept values).
 
     ``overlap="bucketed"`` (cta/atc only) is the overlap engine: the
     param tree is split into ``overlap_buckets`` size-balanced buckets
@@ -1688,8 +1467,9 @@ def build_train_step(
     compute (the reference gets the same overlap from its background
     MPI thread + fusion buffers, operations.cc:943-1020); the HLO-level
     guarantee (>= K collective-permutes — leaf granularity permitting,
-    see ``_bucket_groups`` — with compute scheduled between them) is
-    regression-checked in tests/test_hlo_guarantees.py.
+    see ``fusion.size_balanced_threshold`` — each with compute that
+    does not depend on it) is regression-checked in
+    tests/test_hlo_guarantees.py.
     Numerics match ``overlap="none"`` exactly except under
     ``compress="int8*"``, where the absmax scale becomes per-bucket.
     ``compress=`` and dynamic ``schedule=`` plumb through unchanged.
@@ -1745,20 +1525,17 @@ def build_train_step(
     the step bit-identical to a pre-feature build.  Composes with
     ``guard=`` (``skipped`` then carries the guard's actual flags).
 
-    **Fused epilogue pipeline** (default): every feature above is
-    emitted as a per-bucket stage of ONE composed pass per fusion-plan
-    bucket — quantize → exchange → dequantize → guard-select →
-    health-norm — instead of separate full-tree walks around the
-    exchange (see the module docstring).  All comm modes ride it,
-    including ``push_sum`` (whose exchange now also accepts
-    ``overlap="bucketed"``); the cta/atc combine weights are traced
-    operands in BOTH the guarded and unguarded builds, so the two share
-    one association order (guarded == unguarded bitwise on every
-    topology, including uniform-weight static CTA) and healing swaps
-    weight data without recompiling either.  Set
-    ``BLUEFOG_FUSE_EPILOGUES=0`` to fall back to the pre-fusion
-    builders (debugging escape hatch; also the golden reference of
-    tests/test_epilogue.py's parity matrix).
+    **Per-bucket epilogue pipeline**: every feature above is emitted
+    as a per-bucket stage of ONE composed pass per fusion-plan bucket —
+    quantize → exchange → dequantize → guard-select → health-norm —
+    instead of separate full-tree walks around the exchange (see the
+    module docstring).  All comm modes ride it, including ``push_sum``
+    (whose exchange also accepts ``overlap="bucketed"``); the cta/atc
+    combine weights are traced operands in BOTH the guarded and
+    unguarded builds, so the two share one association order (guarded
+    == unguarded bitwise on every topology, including uniform-weight
+    static CTA) and healing swaps weight data without recompiling
+    either.
 
     Returns ``train_step(params, opt_state, batch, step) ->
     (params, opt_state, loss)`` — all rank-major, jit-compiled with
@@ -1874,371 +1651,16 @@ def build_train_step(
             raise ValueError(
                 f"overlap_buckets must be >= 1, got {overlap_buckets}")
     bucketed = overlap == "bucketed"
-    atc_bucketed = bucketed and comm_mode == "atc"
 
     specs = list(schedule) if schedule is not None else (
         [topology] if topology is not None else [])
-    if _config.fuse_epilogues():
-        return _build_fused_train_step(
-            loss_fn, optimizer, mesh, axis_name=axis_name,
-            comm_mode=comm_mode, specs=specs,
-            k_comm=int(num_steps_per_communication),
-            hierarchical_local_size=hierarchical_local_size,
-            sp_axis=sp_axis, pp_axis=pp_axis, batch_specs=batch_specs,
-            param_specs=param_specs, opt_state_specs=opt_state_specs,
-            donate=donate, has_aux=has_aux, compress=compress,
-            n_buckets=overlap_buckets if bucketed else None,
-            guard=guard, health=health, mix=mix, moe=moe)
-    # ------- BLUEFOG_FUSE_EPILOGUES=0: the pre-fusion builders -------
-    if mix is not None:
-        raise ValueError(
-            "compress='topk' (error-feedback compressed mixing) needs "
-            "the fused epilogue pipeline — unset "
-            "BLUEFOG_FUSE_EPILOGUES=0 (the pre-fusion builders have no "
-            "ef_encode/ef_decode stages)")
-    if moe is not None:
-        raise ValueError(
-            "moe= (expert-sharded MoE) needs the fused epilogue "
-            "pipeline — unset BLUEFOG_FUSE_EPILOGUES=0 (the pre-fusion "
-            "builders mix the whole param tree and would drag expert "
-            "leaves onto the wire)")
-    if comm_mode == "push_sum" and bucketed:
-        raise ValueError(
-            "overlap='bucketed' with comm_mode='push_sum' needs the "
-            "fused epilogue pipeline (unset BLUEFOG_FUSE_EPILOGUES=0): "
-            "the unfused builder mixes the extended payload whole")
-    if guard is not None:
-        return _build_guarded_train_step(
-            loss_fn, optimizer, mesh, guard=guard, axis_name=axis_name,
-            comm_mode=comm_mode, specs=specs,
-            num_steps_per_communication=num_steps_per_communication,
-            hierarchical_local_size=hierarchical_local_size,
-            sp_axis=sp_axis, pp_axis=pp_axis, batch_specs=batch_specs,
-            param_specs=param_specs, opt_state_specs=opt_state_specs,
-            donate=donate, has_aux=has_aux, compress=compress,
-            n_buckets=overlap_buckets if bucketed else None,
-            health=health)
-    if bucketed and comm_mode == "cta":
-        branches = [
-            _bucketed_combine_fn(s, axis_name, hierarchical_local_size,
-                                 compress, overlap_buckets)
-            for s in specs
-        ]
-    elif atc_bucketed:
-        branches = []  # ATC bucketed routes through ac_branches only
-    else:
-        branches = [
-            _combine_fn(s, axis_name, hierarchical_local_size, compress)
-            for s in specs
-        ]
-    ac_branches = [
-        _bucketed_apply_combine_fn(s, axis_name, hierarchical_local_size,
-                                   compress, overlap_buckets)
-        for s in specs
-    ] if atc_bucketed else []
-    ps_branches = [
-        (lambda spec: lambda op: C.push_sum_mix(op[0], op[1], spec,
-                                                axis_name))(s)
-        for s in specs
-    ] if comm_mode == "push_sum" else []
-    k_comm = int(num_steps_per_communication)
-
-    # ``r`` is the program's static round (``_round_selector``); an
-    # off-cycle program (``r is None``) holds no collective at all
-    @jax.named_scope(SCOPE_EXCHANGE)
-    def combine(params, step, r):
-        if not branches or r is None:
-            return params
-        # per-step key for the stochastic wire rounder (int8_sr);
-        # unused operands are dead-code-eliminated otherwise
-        key = jax.random.fold_in(jax.random.PRNGKey(0x51EED), step)
-        return branches[r](params, key)
-
-    @jax.named_scope(SCOPE_EXCHANGE)
-    def combine_push_sum(params, ps, r):
-        if r is None:
-            return params, ps
-        # Push-sum state is the BIASED pair (x, w) with readout
-        # z = x / w; we carry (z, w) so the user-visible params stay
-        # de-biased, and re-bias before every mix (x = z * w) — mixing
-        # z directly is only correct on doubly-stochastic graphs and
-        # diverges on general digraphs.  The whole re-bias -> mix ->
-        # de-bias round stays in f32 (push_sum_mix returns the
-        # accumulation dtype); one cast back at the end.
-        dtypes = jax.tree.map(lambda z: z.dtype, params)
-        biased = jax.tree.map(
-            lambda z: z.astype(jnp.float32) * ps, params)
-        mixed, mixed_ps = ps_branches[r]((biased, ps))
-        # de-bias: z = x / w (reference optimizers.py:1151-1155)
-        debiased = jax.tree.map(
-            lambda x, dt: (x / mixed_ps).astype(dt), mixed, dtypes)
-        return debiased, mixed_ps
-
-    @jax.named_scope(SCOPE_EXCHANGE)
-    def apply_then_combine(params, updates, step, r):
-        """ATC overlap engine: the interleaved per-bucket apply+combine
-        (see _bucketed_apply_combine_fn).  Off-cycle steps under
-        num_steps_per_communication still apply the optax update."""
-        if not ac_branches or r is None:
-            return _apply_updates(params, updates)
-        key = jax.random.fold_in(jax.random.PRNGKey(0x51EED), step)
-        return ac_branches[r]((params, updates), key)
-
-    select = _round_selector(comm_mode, specs, k_comm)
-    def per_rank_step(r, params, aux, opt_state, batch, step):
-        loss, grads, new_aux = _loss_and_grads(
-            loss_fn, has_aux, sp_axis, pp_axis, param_specs,
-            params, aux, batch)
-        # local (pre-allreduce) gradient norm: the per-rank attribution
-        # signal the fleet layer gossips
-        grad_sq = _tree_sq_sum(grads) if health is not None else None
-        consensus = jnp.zeros((), jnp.float32)
-        if comm_mode == "gradient_allreduce":
-            grads = _allreduce_grads(grads, axis_name)
-        if comm_mode == "push_sum":
-            base_state, ps = opt_state
-            pre = params
-            params, ps = combine_push_sum(params, ps, r)
-            if health is not None and health.consensus:
-                consensus = _tree_distance(pre, params)
-            updates, base_state = _opt_update(
-                optimizer, grads, base_state, params)
-            params = _apply_updates(params, updates)
-            hv = (_make_health_vector(loss, grad_sq, updates, consensus)
-                  if health is not None else None)
-            return params, new_aux, (base_state, ps), loss, hv
-        if comm_mode == "cta":
-            pre = params
-            params = combine(params, step, r)
-            if health is not None and health.consensus:
-                consensus = _tree_distance(pre, params)
-        updates, opt_state = _opt_update(optimizer, grads, opt_state, params)
-        if atc_bucketed:
-            new_params = apply_then_combine(params, updates, step, r)
-            if health is not None and health.consensus:
-                # the per-bucket applies inside apply_then_combine are
-                # the same pure arithmetic — XLA CSEs the duplicate
-                applied = _apply_updates(params, updates)
-                consensus = _tree_distance(applied, new_params)
-            params = new_params
-        else:
-            params = _apply_updates(params, updates)
-            if comm_mode == "atc":
-                pre = params
-                params = combine(params, step, r)
-                if health is not None and health.consensus:
-                    consensus = _tree_distance(pre, params)
-        hv = (_make_health_vector(loss, grad_sq, updates, consensus)
-              if health is not None else None)
-        return params, new_aux, opt_state, loss, hv
-
-    squeeze = lambda t: jax.tree.map(lambda x: x[0], t)
-    expand = lambda t: jax.tree.map(lambda x: x[None], t)
-
-    obs_labels = dict(comm_mode=comm_mode, overlap=overlap,
-                      guarded="false")
-
-    def per_shard(r, params, aux, opt_state, batch, step):
-        # strip the leading per-shard rank axis of size 1
-        params, aux, opt_state, loss, hv = per_rank_step(
-            r, squeeze(params), squeeze(aux), squeeze(opt_state),
-            squeeze(batch), step)
-        outs = (expand(params), expand(aux), expand(opt_state),
-                jnp.reshape(loss, (1,)))
-        if health is not None:
-            outs = outs + (HealthVector(
-                *[jnp.reshape(x, (1,)) for x in hv]),)
-        return outs
-
-    p_rank = P(axis_name)
-    if batch_specs is None:
-        batch_specs = p_rank
-    # Model-parallel (e.g. tensor-parallel) param layouts: per-leaf specs
-    # carry the extra mesh axes (see models.llama.llama_param_specs);
-    # grads/updates follow params automatically under shard_map.
-    p_params = param_specs if param_specs is not None else p_rank
-    p_opt = opt_state_specs if opt_state_specs is not None else p_rank
-    out_specs = (p_params, p_rank, p_opt, p_rank)
-    if health is not None:
-        out_specs = out_specs + (p_rank,)  # spec prefix over HealthVector
-
-    def wrapped(params, aux, opt_state, batch, step, r):
-        return jax.shard_map(
-            partial(per_shard, r),
-            mesh=mesh,
-            in_specs=(p_params, p_rank, p_opt, batch_specs, P()),
-            out_specs=out_specs,
-            check_vma=False,
-        )(params, aux, opt_state, batch, step)
-
-    donate_argnums = (0, 1, 2) if donate else ()
-    jitted = jax.jit(wrapped, static_argnums=5,
-                     donate_argnums=donate_argnums)
-    # traffic accounting only for modes that actually run a neighbor
-    # exchange — a topology passed alongside comm_mode='none' /
-    # 'gradient_allreduce' must not count phantom edge bytes
-    edge_traffic = (list(specs), int(mesh.shape[axis_name]),
-                    comm_mode == "push_sum",
-                    hierarchical_local_size
-                    if comm_mode in ("cta", "atc") else None) \
-        if (specs and needs_topo) else None
-    step_fn = _public_step(jitted, obs_labels, select=select,
-                           has_aux=has_aux, edge_traffic=edge_traffic)
-    step_fn.health_config = health
-    step_fn.hierarchical_local_size = \
-        hierarchical_local_size if comm_mode in ("cta", "atc") else None
-    return step_fn
-
-
-def _build_guarded_train_step(
-    loss_fn: Callable,
-    optimizer: optax.GradientTransformation,
-    mesh: Mesh,
-    *,
-    guard: GuardConfig,
-    axis_name: str,
-    comm_mode: str,
-    specs: Sequence[CommSpec],
-    num_steps_per_communication: int,
-    hierarchical_local_size: Optional[int],
-    sp_axis: Optional[str],
-    pp_axis: Optional[str],
-    batch_specs: Any,
-    param_specs: Any,
-    opt_state_specs: Any,
-    donate: bool,
-    has_aux: bool,
-    compress: Optional[str],
-    n_buckets: Optional[int],
-    health: Optional[HealthConfig] = None,
-) -> Callable:
-    """The ``guard=`` variant of :func:`build_train_step` (see its
-    docstring for the contract).  Kept separate so the unguarded fast
-    path stays byte-for-byte what it was; numerics are identical when
-    every rank is healthy — the skip guard's taken branch IS the
-    unguarded arithmetic, and the traced combine weights carry the same
-    values the unguarded branches bake in."""
-    k_comm = int(num_steps_per_communication)
-    neighbor = comm_mode in ("cta", "atc")
-    wbranches = [
-        _weighted_combine_fn(s, axis_name, compress, n_buckets,
-                             hierarchical_local_size)
-        for s in specs
-    ] if neighbor else []
-
-    @jax.named_scope(SCOPE_EXCHANGE)
-    def combine(params, step, comm_weights, r):
-        # ``r``: the program's static round, None off-cycle
-        if not wbranches or r is None:
-            return params
-        key = jax.random.fold_in(jax.random.PRNGKey(0x51EED), step)
-        return wbranches[r](params, key, comm_weights[r])
-
-    select = _round_selector(comm_mode, specs, k_comm)
-    def per_rank_step(r, params, aux, opt_state, batch, step,
-                      comm_weights):
-        loss, grads, new_aux = _loss_and_grads(
-            loss_fn, has_aux, sp_axis, pp_axis, param_specs,
-            params, aux, batch)
-        grad_sq = _tree_sq_sum(grads) if health is not None else None
-        consensus = jnp.zeros((), jnp.float32)
-        if comm_mode == "gradient_allreduce":
-            # NOTE: the allreduce mixes GRADIENTS, so one rank's NaN
-            # reaches every rank's update — the guard then skips
-            # globally (all ranks keep their state).  The neighbor
-            # modes contain the blast radius to the faulty rank.
-            grads = _allreduce_grads(grads, axis_name)
-        if comm_mode == "cta":
-            pre = params
-            params = combine(params, step, comm_weights, r)
-            if health is not None and health.consensus:
-                consensus = _tree_distance(pre, params)
-        updates, new_opt_state = _opt_update(
-            optimizer, grads, opt_state, params)
-        ok = _all_finite(loss, updates)
-
-        # The skip guard: a per-rank conditional over pure arithmetic
-        # only — the collective combine stays OUTSIDE (a per-rank-
-        # divergent branch must never contain a collective).  The
-        # skipping rank keeps params/aux/opt_state, so the combine
-        # below feeds its last-good params to its neighbors.  Lowered
-        # as an elementwise select over the unconditionally-applied
-        # update rather than a lax.cond: a traced-pred cond becomes a
-        # select anyway, but the cond's branch boundary would also
-        # block XLA's mul+add contraction inside apply_updates and cost
-        # the healthy path its bit-identity with the unguarded step.
-        # A discarded non-finite branch is safe under select: it is
-        # elementwise, and nothing differentiates through it here.
-        def pick(new, old):
-            return jnp.where(ok, new, old)
-
-        params = jax.tree.map(pick, _apply_updates(params, updates),
-                              params)
-        out_aux = jax.tree.map(pick, new_aux, aux)
-        out_opt = jax.tree.map(pick, new_opt_state, opt_state)
-        if comm_mode == "atc":
-            pre = params
-            params = combine(params, step, comm_weights, r)
-            if health is not None and health.consensus:
-                consensus = _tree_distance(pre, params)
-        skipped = jnp.where(ok, jnp.int32(0), jnp.int32(1))
-        hv = (_make_health_vector(loss, grad_sq, updates, consensus,
-                                  skipped=skipped)
-              if health is not None else None)
-        return params, out_aux, out_opt, loss, skipped, hv
-
-    squeeze = lambda t: jax.tree.map(lambda x: x[0], t)
-    expand = lambda t: jax.tree.map(lambda x: x[None], t)
-
-    def per_shard(r, params, aux, opt_state, batch, step, comm_weights):
-        params, aux, opt_state, loss, skipped, hv = per_rank_step(
-            r, squeeze(params), squeeze(aux), squeeze(opt_state),
-            squeeze(batch), step, comm_weights)
-        outs = (expand(params), expand(aux), expand(opt_state),
-                jnp.reshape(loss, (1,)), jnp.reshape(skipped, (1,)))
-        if health is not None:
-            outs = outs + (HealthVector(
-                *[jnp.reshape(x, (1,)) for x in hv]),)
-        return outs
-
-    p_rank = P(axis_name)
-    if batch_specs is None:
-        batch_specs = p_rank
-    p_params = param_specs if param_specs is not None else p_rank
-    p_opt = opt_state_specs if opt_state_specs is not None else p_rank
-    # comm weights ride replicated (every rank reads the full tables)
-    p_comm = tuple((P(), P()) for _ in wbranches)
-    out_specs = (p_params, p_rank, p_opt, p_rank, p_rank)
-    if health is not None:
-        out_specs = out_specs + (p_rank,)  # spec prefix over HealthVector
-
-    def wrapped(params, aux, opt_state, batch, step, comm_weights, r):
-        return jax.shard_map(
-            partial(per_shard, r),
-            mesh=mesh,
-            in_specs=(p_params, p_rank, p_opt, batch_specs, P(), p_comm),
-            out_specs=out_specs,
-            check_vma=False,
-        )(params, aux, opt_state, batch, step, comm_weights)
-
-    donate_argnums = (0, 1, 2) if donate else ()
-    jitted = jax.jit(wrapped, static_argnums=6,
-                     donate_argnums=donate_argnums)
-    obs_labels = dict(
-        comm_mode=comm_mode,
-        overlap="bucketed" if n_buckets is not None else "none",
-        guarded="true")
-    # guarded steps are cta/atc only — neighbor_allreduce moves bytes
-    # on every declared edge, so the unfiltered edge set is correct
-    edge_traffic = (list(specs), int(mesh.shape[axis_name]), False,
-                    hierarchical_local_size) \
-        if wbranches else None
-    step_fn = _public_step(jitted, obs_labels, select=select,
-                           has_aux=has_aux, edge_traffic=edge_traffic)
-    step_fn.default_comm_weights = \
-        comm_weight_inputs(specs) if wbranches else ()
-    step_fn.guard_config = guard
-    step_fn.health_config = health
-    step_fn.hierarchical_local_size = \
-        hierarchical_local_size if neighbor else None
-    return step_fn
+    return _build_step(
+        loss_fn, optimizer, mesh, axis_name=axis_name,
+        comm_mode=comm_mode, specs=specs,
+        k_comm=int(num_steps_per_communication),
+        hierarchical_local_size=hierarchical_local_size,
+        sp_axis=sp_axis, pp_axis=pp_axis, batch_specs=batch_specs,
+        param_specs=param_specs, opt_state_specs=opt_state_specs,
+        donate=donate, has_aux=has_aux, compress=compress,
+        n_buckets=overlap_buckets if bucketed else None,
+        guard=guard, health=health, mix=mix, moe=moe)

@@ -5,7 +5,7 @@ Two independent failure signals, fused here:
 * **Numeric health** — the guarded train step's in-graph
   ``jnp.isfinite`` reduce over (loss, updates) surfaces as a rank-major
   ``skipped`` vector every step (see
-  ``optim.functional._all_finite``); :class:`FailureDetector` folds the
+  ``optim.functional._grouped_all_finite``); :class:`FailureDetector` folds the
   per-step flags into per-rank *consecutive* and *total* skip counts.
   A rank that skips ``k`` steps in a row is a death suspect — a
   transient NaN burst recovers its streak to zero, a dead rank never

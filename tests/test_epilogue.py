@@ -1,31 +1,25 @@
-"""Fused per-bucket epilogue pipeline (ISSUE 6): parity matrix +
-association-order guarantees.
+"""The train step against a plain reference, and the guarantees of its
+per-bucket epilogue pipeline.
 
-Two contracts:
+* **Parity matrix** — every configuration of
+  ``bluefog_tpu.analysis.jaxpr_check.sweep_cases()`` that a plain
+  reference can follow (all but ``topk`` and ``moe``: guard x health x
+  wire x comm_mode x overlap on a weighted static ring, push_sum, the
+  dynamic one-peer schedule, the two-level exchange, ``gradient_allreduce``
+  and ``none``, an exchange every second step) is held two ways:
 
-* **Golden parity matrix** — for every feature combination
-  (guard x health x compress x comm_mode x overlap) the fused
-  pipeline's training trajectory matches the pre-fusion reference
-  builders (``BLUEFOG_FUSE_EPILOGUES=0``, the escape hatch that IS the
-  pre-refactor code): params/opt_state/loss/skip flags bit-identical,
-  HealthVector fields equal to f32 tolerance (the per-bucket consensus
-  and norm partials may associate reductions differently under
-  ``overlap="bucketed"``; on the plain path they accumulate in leaf
-  order and match bitwise too).
+  - to ``tests/reference_step.py`` (per rank ``value_and_grad`` and the
+    optax update, mixing as one dense float64 product with the round's
+    matrix), step by step from the program's OWN state before each
+    step, by a tolerance stated beside its derivation (``_hold``);
+  - bit for bit to one anchor build of the same (comm_mode, graph,
+    wire), wherever a feature is documented as a no-op on finite data:
+    guard on against off, health on against off, and for a
+    full-precision wire ``overlap="bucketed"`` against ``"none"``.
 
-  The matrix runs on a NON-uniform weighted static ring and on the
-  dynamic one-peer schedule: with uniform static weights the unfused
-  unguarded builder bakes the weight vector as a constant that XLA may
-  legally refactor (the documented PR-3 1-ulp fold), which is exactly
-  the behavior the fused path retires — covered by the dedicated test
-  below instead.
-
-* **Uniform-weight static CTA bit-identity** (the converted PR-3
-  caveat): the fused combine carries its weights as traced operands in
-  BOTH the guarded and unguarded builds, so the two share one
-  association order and are bit-identical on every topology —
-  including the uniform-weight static CTA case the pre-fusion test had
-  to exclude by design.
+* **Uniform-weight static CTA bit-identity**: the combine carries its
+  weights as traced operands in BOTH the guarded and unguarded builds,
+  so the two share one association order on every topology.
 """
 
 import numpy as np
@@ -36,87 +30,30 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+import reference_step as R
+from bluefog_tpu.analysis import jaxpr_check as J
 from bluefog_tpu.optim import functional as F
 from bluefog_tpu.optim import fusion
+from bluefog_tpu.resilience import healing
 from bluefog_tpu.topology import (ExponentialTwoGraph,
-                                  one_peer_dynamic_schedule,
                                   uniform_topology_spec)
 from bluefog_tpu.topology.spec import Topology
 
-N = 8
+N = J.N_RANKS
 _OPT = optax.sgd(0.05, momentum=0.9)
+_U32, _U64 = 2.0 ** -24, 2.0 ** -53    # unit roundoffs
+_weighted_ring, _machine_ring = J._weighted_ring, J._machine_ring
+_problem = J._problem
 
 
 def _mesh():
     return Mesh(np.array(jax.devices()[:N]), ("bf",))
 
 
-def _weighted_ring():
-    """Non-uniform row-stochastic ring: no weight value repeats within
-    a row, so XLA cannot factor the unfused builder's constant-weight
-    combine — fused and unfused associate identically and the matrix
-    can assert bitwise equality."""
-    W = np.zeros((N, N))
-    for r in range(N):
-        W[(r - 1) % N, r] = 0.3
-        W[(r + 1) % N, r] = 0.1
-        W[r, r] = 0.6
-    return Topology.from_weight_matrix(W)
-
-
-def _weighted_schedule():
-    """The one-peer dynamic rounds with NON-uniform weights (self 0.7,
-    neighbor 0.3): the stock schedule's uniform 0.5/0.5 lets XLA fold
-    the unfused builder's constant-weight combine into (x+r)*0.5 —
-    the same association rewrite the static-CTA caveat documents —
-    so the bitwise matrix uses weights that cannot factor."""
-    from bluefog_tpu.topology.spec import DynamicTopology
-
-    out = []
-    for s in one_peer_dynamic_schedule(N):
-        out.append(DynamicTopology.from_edges(
-            s.size, {e: 0.3 for e in s.edges}, [0.7] * s.size))
-    return out
-
-
-def _machine_ring():
-    """Non-uniform MACHINE-level ring (8 ranks as 4 machines of 2): the
-    hierarchical matrix's inter-machine schedule, weighted so XLA
-    cannot factor the combine (same reasoning as ``_weighted_ring``)."""
-    m = N // 2
-    W = np.zeros((m, m))
-    for r in range(m):
-        W[(r - 1) % m, r] = 0.3
-        W[(r + 1) % m, r] = 0.1
-        W[r, r] = 0.6
-    return Topology.from_weight_matrix(W)
-
-
-def _problem():
-    base = {"w1": jnp.asarray(np.random.RandomState(7).randn(4, 4) * 0.3),
-            "b1": jnp.zeros((4,)),
-            "w2": jnp.asarray(np.random.RandomState(8).randn(4, 2) * 0.3),
-            "b2": jnp.zeros((2,))}
-
-    def loss_fn(params, batch):
-        h = jnp.tanh(batch @ params["w1"] + params["b1"])
-        return jnp.mean((h @ params["w2"] + params["b2"]) ** 2)
-
-    return base, loss_fn
-
-
-def _build(monkeypatch, fused, **kwargs):
-    base, loss_fn = _problem()
-    if fused:
-        monkeypatch.delenv("BLUEFOG_FUSE_EPILOGUES", raising=False)
-    else:
-        monkeypatch.setenv("BLUEFOG_FUSE_EPILOGUES", "0")
-    try:
-        step = F.build_train_step(loss_fn, _OPT, _mesh(), donate=False,
-                                  **kwargs)
-    finally:
-        monkeypatch.delenv("BLUEFOG_FUSE_EPILOGUES", raising=False)
-    return step
+def _build(**kwargs):
+    _, loss_fn = _problem()
+    return F.build_train_step(loss_fn, _OPT, _mesh(), donate=False,
+                              **kwargs)
 
 
 def _state(mesh, push_sum=False):
@@ -133,11 +70,15 @@ def _batch(mesh, s):
     return jax.device_put(raw, NamedSharding(mesh, P("bf")))
 
 
-def _run(step, mesh, *, guarded, push_sum=False, steps=2):
+def _run(step, mesh, *, guarded, push_sum=False, steps=2, each=None):
+    """Drive ``step`` from the common start; ``each(s, before, after)``
+    sees the state around every step (``before`` = ``(params, ostate,
+    batch)``, ``after`` = ``(params, ostate, loss, skips, hv)``)."""
     params, ostate = _state(mesh, push_sum=push_sum)
     skips, hv = None, None
     for s in range(steps):
-        args = (params, ostate, _batch(mesh, s), jnp.int32(s))
+        before = (params, ostate, _batch(mesh, s))
+        args = before + (np.int32(s),)
         if guarded:
             args = args + (step.default_comm_weights,)
         out = step(*args)
@@ -147,148 +88,302 @@ def _run(step, mesh, *, guarded, push_sum=False, steps=2):
             skips, rest = rest[0], rest[1:]
         if rest:
             hv = rest[0]
+        if each is not None:
+            each(s, before, (params, ostate, loss, skips, hv))
     return params, ostate, loss, skips, hv
 
 
-def _matrix():
-    """The guard x health x compress x comm_mode x overlap parity
-    matrix, budgeted for tier-1 wall time (each case is two jit builds
-    on the 8-device mesh):
-
-    * the FULL fp product over (comm_mode, overlap, guard, health) on
-      the static weighted ring — every builder branch combination;
-    * int8 wire with health on (health's consensus term is the one
-      consumer of the dequantized buffers): both modes x both guard
-      values on the bucketed path (per-BUCKET scales + guarded
-      weighted path + key folding — the interactions the refactor
-      touches) plus one plain case (per-TENSOR scales);
-    * push_sum (guard/compress rejected by validation) over
-      (overlap, health);
-    * two lax.switch schedule pins: the plain-atc config that caught
-      apply-inside-switch contraction drift, plus the fully-loaded
-      bucketed case (switch x per-bucket closures).
-    """
-    ring = _weighted_ring()
-    cases = []
-    for comm_mode in ("cta", "atc"):
-        for overlap in ("none", "bucketed"):
-            for guard in (False, True):
-                for health in (False, True):
-                    cases.append(dict(
-                        comm_mode=comm_mode, overlap=overlap,
-                        guard=guard, health=health, compress=None,
-                        topology=ring))
-        for guard in (False, True):
-            cases.append(dict(
-                comm_mode=comm_mode, overlap="bucketed", guard=guard,
-                health=True, compress="int8", topology=ring))
-    cases.append(dict(comm_mode="atc", overlap="none", guard=True,
-                      health=True, compress="int8", topology=ring))
-    for overlap in ("none", "bucketed"):
-        for health in (False, True):
-            cases.append(dict(
-                comm_mode="push_sum", overlap=overlap, guard=False,
-                health=health, compress=None, topology=ring))
-    cases.append(dict(comm_mode="atc", overlap="none", guard=False,
-                      health=False, compress=None,
-                      schedule=_weighted_schedule()))
-    cases.append(dict(comm_mode="atc", overlap="bucketed", guard=True,
-                      health=True, compress=None,
-                      schedule=_weighted_schedule()))
-    # hierarchical x {guard, health, int8, bucketed overlap}: the
-    # two-level exchange (4 machines of 2) through every epilogue
-    # feature, fused-vs-unfused parity like the flat matrix
-    mring = _machine_ring()
-    for comm_mode, overlap, guard, health, compress in (
-            ("cta", "none", False, False, None),
-            ("cta", "bucketed", True, True, None),
-            ("atc", "none", True, False, None),
-            ("atc", "bucketed", False, True, None),
-            ("cta", "bucketed", True, True, "int8"),
-            ("atc", "none", True, True, "int8")):
-        cases.append(dict(comm_mode=comm_mode, overlap=overlap,
-                          guard=guard, health=health, compress=compress,
-                          topology=mring, hierarchical=2))
-    return cases
+# ------------------------------------------------------------------ #
+# the parity matrix
+# ------------------------------------------------------------------ #
+def _cases():
+    """The product's own list, less what a plain reference cannot
+    follow: error-feedback state (``topk``) and an expert layer
+    (``moe``) have their own tests."""
+    return [c for c in J.sweep_cases()
+            if c["compress"] != "topk" and not c.get("moe")]
 
 
-def _case_id(c):
-    return "-".join([
-        c["comm_mode"], c["overlap"],
-        "guard" if c["guard"] else "noguard",
-        "health" if c["health"] else "nohealth",
-        c["compress"] or "fp",
-        "hier" if "hierarchical" in c
-        else ("sched" if "schedule" in c else "static")])
+def _specs(kwargs):
+    return list(kwargs.get("schedule")
+                or ([kwargs["topology"]] if "topology" in kwargs else []))
+
+
+def _groups(kwargs, n_leaves):
+    """The leaves that share one wire scale: the bucket plan's under
+    ``overlap="bucketed"``, each leaf alone on the plain path."""
+    if "overlap" not in kwargs:
+        return [[i] for i in range(n_leaves)]
+    base, _ = _problem()
+    return fusion.EpiloguePlan.for_leaves(
+        jax.tree.leaves(base), kwargs["overlap_buckets"]).groups
+
+
+def _hold(kwargs, ref, s, before, after):
+    """One step of the program against the reference's step from the
+    same state.  The bounds, elementwise, ``amax`` the largest magnitude
+    of the leaf over all ranks:
+
+    * full-precision wire (and the modes with no wire): both sides
+      compute in float64 and differ in the order of their sums alone.
+      The longest chain behind an element is the gradient (3 rows x 4
+      features x 2 layers, forward and backward: under 100 operations),
+      the momentum update (3) and the mix (3 products, 2 sums; 8 terms
+      for the all-reduce): under 200 roundings of ``2**-53``, ``3e-14``
+      of ``amax``; the bound is ``1e-12 * amax`` (two steps of this
+      problem stay 30 times inside it);
+    * ``push_sum``: the step re-biases, mixes and de-biases in float32
+      by design (``optim/functional.py``): a cast, the product with
+      ``w``, the scale, at most two sums and the division, and as many
+      on the weight's side: under 16 roundings of ``2**-24`` on values
+      no larger than ``amax`` (the mixed weight divides out);
+    * a quantized wire: ``reference_step.wire_error_bound`` of what the
+      step put on the wire.  The update is momentum SGD's, which reads
+      the gradient alone, so the wire's error reaches the parameters
+      once, unamplified, in ``cta`` and ``atc`` alike; the optimizer's
+      state holds to the float64 bound.
+
+    ``HealthVector`` is float32: a norm over the tree's 30 elements
+    carries at most 32 roundings (``2e-6`` relative); the consensus
+    distance subtracts two float32 casts, ``2 * 2**-24 * amax`` an
+    element, so ``2**-23 * sqrt(30) * amax`` on the norm, and under a
+    quantized wire the norm of the wire's bounds besides."""
+    mode = kwargs["comm_mode"]
+    push_sum = mode == "push_sum"
+    params0, ostate0, batch = before
+    params1, ostate1, loss1, skips, hv = after
+    ps0 = np.asarray(ostate0[1], np.float64) if push_sum else None
+    base0 = ostate0[0] if push_sum else ostate0
+    rp, ro, rl, rps, rh, premix = ref(
+        R.unstack(params0, N), R.unstack(base0, N), np.asarray(batch),
+        s, ps0)
+    rp, ro = R.stack(rp), R.stack(ro)
+
+    def close(got, want, atol, what):
+        for (path, g), w, a in zip(
+                jax.tree_util.tree_flatten_with_path(got)[0],
+                jax.tree.leaves(want), atol):
+            np.testing.assert_allclose(
+                np.asarray(g), w, rtol=0, atol=a,
+                err_msg=f"step {s} {what}{jax.tree_util.keystr(path)}")
+
+    def f64_bound(tree):
+        return [1e-12 * max(float(np.max(np.abs(l))), 1e-3)
+                for l in jax.tree.leaves(tree)]
+
+    amax = [float(np.max(np.abs(l))) for l in jax.tree.leaves(rp)]
+    f64 = f64_bound(rp)
+    wire = kwargs.get("compress")
+    cons_tol = 0.0
+    if push_sum:
+        p_tol = [16 * _U32 * a for a in amax]
+        np.testing.assert_allclose(np.asarray(ostate1[1]), rps,
+                                   rtol=8 * _U32)
+    elif wire and premix is not None:
+        L = kwargs.get("hierarchical")
+        bounds = R.wire_error_bound(
+            ref.matrix(s), premix, _groups(kwargs, len(amax)), wire, L)
+        # the same bound on every rank's row of a leaf: take the widest
+        p_tol = [max(b[i] for b in bounds) + f64[i]
+                 for i in range(len(amax))]
+        sizes = [np.size(l[0]) for l in jax.tree.leaves(rp)]
+        cons_tol = float(np.sqrt(sum(
+            n * t * t for n, t in zip(sizes, p_tol))))
+    else:
+        p_tol = f64
+    close(params1, rp, p_tol, "params")
+    close(ostate1[0] if push_sum else ostate1, ro, f64_bound(ro),
+          "opt_state")
+    np.testing.assert_allclose(np.asarray(loss1), rl, rtol=1e-12)
+    if skips is not None:
+        np.testing.assert_array_equal(np.asarray(skips),
+                                      np.zeros(N, np.int32))
+    if kwargs["health"] is not None:
+        assert isinstance(hv, F.HealthVector)
+        np.testing.assert_allclose(np.asarray(hv.loss), rh.loss,
+                                   rtol=2 * _U32)
+        np.testing.assert_allclose(np.asarray(hv.grad_norm),
+                                   rh.grad_norm, rtol=2e-6)
+        np.testing.assert_allclose(np.asarray(hv.update_norm),
+                                   rh.update_norm, rtol=2e-6)
+        np.testing.assert_array_equal(np.asarray(hv.skipped), rh.skipped)
+        np.testing.assert_allclose(
+            np.asarray(hv.consensus), rh.consensus, rtol=2e-6,
+            atol=2 * _U32 * np.sqrt(30) * max(amax) + cons_tol)
+
+
+def _follow(kwargs):
+    """Run the program for the case's steps, every step held to the
+    reference; returns the state after each step, as numpy."""
+    _, loss_fn = _problem()
+    mode = kwargs["comm_mode"]
+    k = kwargs.get("num_steps_per_communication", 1)
+    specs = _specs(kwargs)
+    ref = R.ReferenceStep(loss_fn, _OPT, N, mode, specs=specs,
+                          local_size=kwargs.get("hierarchical"), every=k)
+    trace = []
+
+    def each(s, before, after):
+        _hold(kwargs, ref, s, before, after)
+        trace.append(jax.tree.map(np.asarray, after[:3]))
+
+    _run(_build(**kwargs), _mesh(), guarded=kwargs["guard"] is not None,
+         push_sum=mode == "push_sum", steps=k * max(2, len(specs)),
+         each=each)
+    return trace
+
+
+def _anchor_kwargs(kwargs):
+    """The build a case must equal bit for bit: the same comm_mode,
+    graph and wire with guard and health off and, for a full-precision
+    wire, the plain path (a wire's scale is per bucket, so a quantized
+    case keeps its overlap)."""
+    anchor = dict(kwargs, guard=None, health=None)
+    if "compress" not in anchor:
+        anchor.pop("overlap", None)
+        anchor.pop("overlap_buckets", None)
+    return anchor
+
+
+@pytest.fixture(scope="module")
+def anchor_traces():
+    """Each anchor is built and followed once for the cases that share
+    it (itself held to the reference by ``_follow``)."""
+    return {}
 
 
 @pytest.mark.perf
-@pytest.mark.parametrize("case", _matrix(), ids=_case_id)
-def test_fused_matches_unfused_reference(case, monkeypatch):
-    """The fused pipeline reproduces the pre-fusion reference path:
-    bit-identical params/opt_state/loss/skip flags at every matrix
-    point, HealthVector within f32 tolerance (bitwise too on the
-    plain path)."""
-    mesh = _mesh()
-    case = dict(case)
-    guarded = case.pop("guard")
-    health = case.pop("health")
-    push_sum = case["comm_mode"] == "push_sum"
-    kwargs = dict(case)
-    if kwargs["overlap"] == "none":
-        kwargs.pop("overlap")
-    else:
-        kwargs["overlap_buckets"] = 3
-    if kwargs.get("compress") is None:
-        kwargs.pop("compress")
-    if guarded:
-        kwargs["guard"] = F.GuardConfig()
-    if health:
-        kwargs["health"] = F.HealthConfig()
-
-    fused = _build(monkeypatch, True, **kwargs)
-    if push_sum and case["overlap"] == "bucketed":
-        # no unfused reference exists (the pre-fusion builder rejects
-        # it) — pin against the fused PLAIN path instead, which the
-        # rest of the matrix anchors to the reference: bucketing is an
-        # exact rewrite of the push-sum mix (elementwise-linear)
-        ref_kwargs = dict(kwargs)
-        ref_kwargs.pop("overlap")
-        ref_kwargs.pop("overlap_buckets")
-        ref = _build(monkeypatch, True, **ref_kwargs)
-    else:
-        ref = _build(monkeypatch, False, **kwargs)
-
-    pf, of, lf, sf, hf = _run(fused, mesh, guarded=guarded,
-                              push_sum=push_sum)
-    pr, orr, lr, sr, hr = _run(ref, mesh, guarded=guarded,
-                               push_sum=push_sum)
-    np.testing.assert_array_equal(np.asarray(lf), np.asarray(lr))
-    for a, b in zip(jax.tree.leaves((pf, of)), jax.tree.leaves((pr, orr))):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    if guarded:
-        np.testing.assert_array_equal(np.asarray(sf), np.asarray(sr))
-    if health:
-        assert isinstance(hf, F.HealthVector)
-        for name, a, b in zip(hf._fields, hf, hr):
+@pytest.mark.parametrize("case", _cases(), ids=J.case_id)
+def test_step_matches_plain_reference(case, anchor_traces):
+    """Every step of the built program within the stated bound of the
+    plain reference's step from the same state, and bit-identical
+    (loss, parameters, optimizer state) to the anchor build wherever
+    guard, health and bucketing are no-ops (``_hold`` has seen the skip
+    flags all zero)."""
+    kwargs = J.build_kwargs(case)
+    trace = _follow(kwargs)
+    anchor = _anchor_kwargs(kwargs)
+    if anchor == kwargs:
+        return
+    anchor_id = J.case_id(dict(
+        case, guard=False, health=False,
+        overlap="bucketed" if "overlap" in anchor else "none"))
+    if anchor_id not in anchor_traces:
+        anchor_traces[anchor_id] = _follow(anchor)
+    # One pair is not bitwise on this jaxlib's CPU: the guard over a
+    # quantized wire.  With the guard's select between the update and the
+    # wire, XLA re-derives ``p + u`` inside the quantizer's fusion and
+    # inside the combine's instead of reading one buffer, and LLVM
+    # contracts that multiply-add into an FMA in one and not the other:
+    # the self term moves by one rounding (step 0: 6 to 10 elements a
+    # leaf, 1.3e-16 relative).  Held to 16 roundings of ``amax`` over the
+    # steps followed; every other pair is exact.
+    exact = not ("compress" in kwargs and kwargs["guard"] is not None)
+    for s, (got, want) in enumerate(zip(trace, anchor_traces[anchor_id])):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-7,
-                err_msg=f"HealthVector.{name}")
+                a, b, rtol=0, err_msg=f"step {s}",
+                atol=0 if exact else 16 * _U64 * float(np.max(np.abs(b))))
 
 
-def test_uniform_static_cta_guarded_bit_identical(monkeypatch):
-    """The converted PR-3 caveat: uniform-weight static CTA was the one
-    config where guarded != unguarded bitwise (the unfused builder's
-    constant weights let XLA fold the combine into (sum)*w, which
-    traced weight operands cannot legally reproduce — this very test
-    FAILS under BLUEFOG_FUSE_EPILOGUES=0, reproducing the caveat).
-    The fused pipeline feeds BOTH builds the same traced-weight
-    combine, so the association orders agree and the caveat is gone."""
+# ------------------------------------------------------------------ #
+# the reference itself, against closed forms
+# ------------------------------------------------------------------ #
+def _digraph():
+    """A directed ring plus one edge (out-degrees 2, 1, ...): strongly
+    connected and not doubly stochastic."""
+    w = np.eye(N)
+    for r in range(N):
+        w[r, (r + 1) % N] = 1.0
+    w[0, 4] = 1.0
+    return Topology.from_weight_matrix(w)
+
+
+def test_reference_matrices():
+    """The reference reads its matrices off the declared edges; the
+    product's ``healing.mixing_matrix`` walks the shift classes.  They
+    agree, rows of a mixing round sum to 1 and columns of a push round
+    do."""
+    for spec in [_weighted_ring(), _machine_ring(), _digraph(),
+                 *J._weighted_schedule()]:
+        np.testing.assert_array_equal(R.mixing_matrix(spec),
+                                      healing.mixing_matrix(spec))
+    H = R.hierarchical_matrix(_machine_ring(), 2)
+    np.testing.assert_allclose(H.sum(axis=1), np.ones(N), rtol=1e-15)
+    # ranks 0 and 1 are one machine: the same row, and a machine's
+    # weight spread evenly over its members
+    np.testing.assert_array_equal(H[0], H[1])
+    np.testing.assert_allclose(H[0, :2], [0.3, 0.3], rtol=1e-15)
+    A = R.push_sum_matrix(_digraph())
+    np.testing.assert_allclose(A.sum(axis=0), np.ones(N), rtol=1e-15)
+    assert A[0, 0] == pytest.approx(1 / 3) and A[4, 0] == A[1, 0] == A[0, 0]
+
+
+@pytest.mark.parametrize("mode",
+                         ["cta", "atc", "gradient_allreduce", "none"])
+def test_reference_quadratic_fixed_point(mode):
+    """On ``f_i(x) = |x - c_i|^2 / 2`` with plain SGD of rate ``a`` the
+    iteration is linear and its fixed point known: ``cta``
+    ``x = W x - a (x - c)``, so ``x* = a ((1 + a) I - W)^-1 c``; ``atc``
+    ``x = W (x - a (x - c))``, so ``x* = a (I - (1 - a) W)^-1 W c``;
+    the all-reduce reaches the mean of the ``c_i`` and ``none`` each
+    rank's own.  The four differ, so a swapped order or a wrong matrix
+    shows."""
+    a = 0.2
+    W = R.mixing_matrix(_weighted_ring())
+    c = np.random.RandomState(3).randn(N, 5)
+    want = {
+        "cta": a * np.linalg.solve((1 + a) * np.eye(N) - W, c),
+        "atc": a * np.linalg.solve(np.eye(N) - (1 - a) * W, W @ c),
+        "gradient_allreduce": np.tile(c.mean(0), (N, 1)),
+        "none": c,
+    }[mode]
+    ref = R.ReferenceStep(
+        lambda p, b: 0.5 * jnp.sum((p["x"] - b) ** 2), optax.sgd(a), N,
+        mode, specs=[_weighted_ring()])
+    params, ostate, _ = ref.init({"x": np.zeros(5)})
+    for s in range(300):
+        params, ostate, *_ = ref(params, ostate, c, s)
+    np.testing.assert_allclose(R.stack(params)["x"], want, atol=1e-12)
+
+
+def test_reference_push_sum_averages_on_a_digraph():
+    """Push-sum's defining property: on a strongly connected digraph
+    that is NOT doubly stochastic, with no gradient, every rank's
+    de-biased value reaches the mean of the initial values and the
+    weights keep their sum; mixing the de-biased values directly with
+    the same matrix does not reach the mean."""
+    ref = R.ReferenceStep(lambda p, b: 0.0 * jnp.sum(p["x"]),
+                          optax.sgd(0.0), N, "push_sum",
+                          specs=[_digraph()])
+    x0 = np.random.RandomState(5).randn(N, 3)
+    params = [{"x": x0[r]} for r in range(N)]
+    ostate = [optax.sgd(0.0).init(params[0])] * N
+    ps = np.ones(N)
+    for s in range(200):
+        params, ostate, _, ps, health, _ = ref(params, ostate,
+                                               np.zeros((N, 1)), s, ps)
+    np.testing.assert_allclose(R.stack(params)["x"],
+                               np.tile(x0.mean(0), (N, 1)), atol=1e-12)
+    assert ps.sum() == pytest.approx(N, rel=1e-13)
+    assert health.consensus.max() < 1e-12
+    A = R.push_sum_matrix(_digraph())
+    naive = np.linalg.matrix_power(A, 200) @ x0
+    assert np.abs(naive - x0.mean(0)).max() > 1e-2
+
+
+def test_uniform_static_cta_guarded_bit_identical():
+    """Uniform-weight static CTA: with the weights baked in as
+    constants XLA may fold the combine into ``(sum) * w``, which traced
+    weight operands cannot legally reproduce, so a builder that baked
+    them only without a guard gave guarded != unguarded by an ulp.  The
+    step feeds BOTH builds the same traced-weight combine, so the
+    association orders agree."""
     mesh = _mesh()
     spec = uniform_topology_spec(ExponentialTwoGraph(N))
     kwargs = dict(comm_mode="cta", topology=spec)
-    step_u = _build(monkeypatch, True, **kwargs)
-    step_g = _build(monkeypatch, True, guard=F.GuardConfig(), **kwargs)
+    step_u = _build(**kwargs)
+    step_g = _build(guard=F.GuardConfig(), **kwargs)
     params, ostate = _state(mesh)
     p2, o2 = params, ostate
     for s in range(5):
@@ -304,7 +399,7 @@ def test_uniform_static_cta_guarded_bit_identical(monkeypatch):
 
 
 @pytest.mark.hier
-def test_hierarchical_single_rank_machines_bitwise_flat(monkeypatch):
+def test_hierarchical_single_rank_machines_bitwise_flat():
     """The L == 1 degeneracy contract: with every machine holding ONE
     rank the two-level decomposition IS the flat exchange — singleton
     psum is the identity, counterpart expansion reproduces the rank
@@ -316,8 +411,8 @@ def test_hierarchical_single_rank_machines_bitwise_flat(monkeypatch):
         kw = dict(comm_mode="cta", topology=ring)
         if compress:
             kw["compress"] = compress
-        flat = _build(monkeypatch, True, **kw)
-        hier = _build(monkeypatch, True, hierarchical=1, **kw)
+        flat = _build(**kw)
+        hier = _build(hierarchical=1, **kw)
         pf, of, lf, _, _ = _run(flat, mesh, guarded=False, steps=4)
         ph, oh, lh, _, _ = _run(hier, mesh, guarded=False, steps=4)
         np.testing.assert_array_equal(np.asarray(lf), np.asarray(lh))
@@ -327,7 +422,7 @@ def test_hierarchical_single_rank_machines_bitwise_flat(monkeypatch):
 
 
 @pytest.mark.hier
-def test_hierarchical_guarded_matches_unguarded_bitwise(monkeypatch):
+def test_hierarchical_guarded_matches_unguarded_bitwise():
     """Guard + hierarchical composes (the rejection this PR lifts):
     the guarded build carries the MACHINE-level weight tables as traced
     operands exactly like the unguarded fused build, so on a clean run
@@ -335,8 +430,8 @@ def test_hierarchical_guarded_matches_unguarded_bitwise(monkeypatch):
     mesh = _mesh()
     kwargs = dict(comm_mode="cta", topology=_machine_ring(),
                   hierarchical=2)
-    step_u = _build(monkeypatch, True, **kwargs)
-    step_g = _build(monkeypatch, True, guard=F.GuardConfig(), **kwargs)
+    step_u = _build(**kwargs)
+    step_g = _build(guard=F.GuardConfig(), **kwargs)
     assert step_g.hierarchical_local_size == 2
     params, ostate = _state(mesh)
     p2, o2 = params, ostate
@@ -427,7 +522,7 @@ def _mix_problem_state(mesh, step):
     return params, (ostate, step.init_mix_state(params))
 
 
-def test_mix_ratio_one_short_circuits_to_dense(monkeypatch):
+def test_mix_ratio_one_short_circuits_to_dense():
     """``MixCompressConfig(ratio>=1.0)`` drops the whole mixing
     apparatus at BUILD time (``step.mix_config is None``, plain
     signature, no MixState) and the trajectory is bit-identical to an
@@ -435,9 +530,8 @@ def test_mix_ratio_one_short_circuits_to_dense(monkeypatch):
     mesh = _mesh()
     kwargs = dict(comm_mode="cta", topology=_weighted_ring(),
                   overlap="bucketed", overlap_buckets=2)
-    dense = _build(monkeypatch, True, **kwargs)
-    one = _build(monkeypatch, True,
-                 compress=F.MixCompressConfig(ratio=1.0), **kwargs)
+    dense = _build(**kwargs)
+    one = _build(compress=F.MixCompressConfig(ratio=1.0), **kwargs)
     assert one.mix_config is None
     assert not hasattr(one, "init_mix_state")
     pA, _, lA, _, _ = _run(dense, mesh, guarded=False, steps=3)
@@ -447,7 +541,7 @@ def test_mix_ratio_one_short_circuits_to_dense(monkeypatch):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_mix_state_checkpoint_roundtrip(monkeypatch, tmp_path):
+def test_mix_state_checkpoint_roundtrip(tmp_path):
     """The EF state survives a checkpoint: save mid-run, restore with
     ``like=`` (preserving the MixState/optax NamedTuple containers),
     and the restored trajectory continues bit-identically to the live
@@ -455,7 +549,7 @@ def test_mix_state_checkpoint_roundtrip(monkeypatch, tmp_path):
     from bluefog_tpu.checkpoint import Checkpointer
 
     mesh = _mesh()
-    step = _build(monkeypatch, True, comm_mode="cta",
+    step = _build(comm_mode="cta",
                   topology=_weighted_ring(),
                   compress=F.MixCompressConfig(ratio=0.5, values="int8"),
                   overlap="bucketed", overlap_buckets=2)
@@ -487,7 +581,7 @@ def test_mix_state_checkpoint_roundtrip(monkeypatch, tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_mix_heal_grow_ratio_swap_zero_recompile(monkeypatch):
+def test_mix_heal_grow_ratio_swap_zero_recompile():
     """The full elastic cycle on a guarded compressed step — heal a
     dead rank (weight DATA swap), grow it back, then drop the live
     compression ratio — all through ONE compiled program: the jit
@@ -497,7 +591,7 @@ def test_mix_heal_grow_ratio_swap_zero_recompile(monkeypatch):
 
     mesh = _mesh()
     ring = _weighted_ring()
-    step = _build(monkeypatch, True, comm_mode="atc", topology=ring,
+    step = _build(comm_mode="atc", topology=ring,
                   compress=F.MixCompressConfig(ratio=0.25),
                   overlap="bucketed", overlap_buckets=2,
                   guard=F.GuardConfig(), health=F.HealthConfig())

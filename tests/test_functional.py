@@ -349,18 +349,7 @@ def test_bucketed_overlap_mode_validation():
         F.build_train_step(loss_fn, optax.sgd(0.1), mesh,
                            comm_mode="gradient_allreduce",
                            overlap="bucketed")
-    # push_sum + bucketed is supported by the fused epilogue pipeline
-    # (ISSUE 6); only the unfused escape-hatch builder rejects it
-    import os
-
-    os.environ["BLUEFOG_FUSE_EPILOGUES"] = "0"
-    try:
-        with pytest.raises(ValueError, match="bucketed"):
-            F.build_train_step(loss_fn, optax.sgd(0.1), mesh,
-                               comm_mode="push_sum", topology=spec,
-                               overlap="bucketed")
-    finally:
-        os.environ.pop("BLUEFOG_FUSE_EPILOGUES", None)
+    # push_sum + bucketed is supported: the pair's buckets mix as a unit
     step = F.build_train_step(loss_fn, optax.sgd(0.1), mesh,
                               comm_mode="push_sum", topology=spec,
                               overlap="bucketed")

@@ -155,18 +155,19 @@ def test_shared_planner_identity_with_eager_plan():
 
 
 def test_shared_planner_matches_jitted_bucket_groups():
-    """The bucketed train step's trace-time bucket assignment is the
-    shared walk at the size-balanced threshold (functional._bucket_groups
-    delegates to fusion.plan_groups)."""
+    """The bucketed train step's trace-time bucket assignment
+    (``EpiloguePlan.for_leaves(leaves, K)``, the plan the builder asks
+    for) is the shared walk at the size-balanced threshold."""
     from bluefog_tpu.optim import fusion
-    from bluefog_tpu.optim.functional import _bucket_groups
 
     leaves = [jnp.zeros((32, 16), jnp.float32) for _ in range(10)]
     rows = fusion.bucket_signature(leaves)
     k = 4
     expect = fusion.plan_groups(
         rows, fusion.size_balanced_threshold(rows, k))
-    assert _bucket_groups(leaves, k) == expect
+    plan = fusion.EpiloguePlan.for_leaves(leaves, k)
+    assert plan.groups == expect
+    assert [list(b.leaves) for b in plan.buckets] == expect
     assert len(expect) >= k  # size-balanced floor
     # every leaf appears exactly once, in order
     flat = [i for g in expect for i in g]

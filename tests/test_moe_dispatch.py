@@ -386,16 +386,6 @@ def test_moe_config_validation(mesh, plan):
         F.MoEConfig(n_experts=4, capacity=3, expert_path_tokens=())
 
 
-def test_moe_requires_fused_epilogue(mesh, plan, monkeypatch):
-    monkeypatch.setenv("BLUEFOG_FUSE_EPILOGUES", "0")
-    with pytest.raises(ValueError, match="fused epilogue"):
-        F.build_train_step(make_moe_loss(plan, "bf", 3), _OPT, mesh,
-                           comm_mode="cta",
-                           schedule=torus_one_peer_schedule(
-                               (4, 2), "exp2"),
-                           moe=F.MoEConfig(n_experts=4, capacity=3))
-
-
 def test_moe_rejects_all_expert_params(mesh, plan):
     """A parameter tree with NO shared leaf is a config error the
     build surfaces at trace time, not a silent no-mix step."""
