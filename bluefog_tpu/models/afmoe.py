@@ -26,22 +26,28 @@ sqrt(dim)``)::
 
 The expert layer routes every token over all ``n_experts`` and computes
 the shared expert and the part of the sum that the experts it holds
-give (``experts_held = (first, count)``), dropless: a loop over the held
-experts that at least one token chose, each applied to the call's tokens
-and weighted by a column of a ``[tokens, held]`` matrix that is zero
-wherever a token did not choose it (``held_experts``).  What the absent
-experts would add is left out; nothing stands in for their chips or
-their traffic.  On one chip it runs without an exchange; the sum of
-every share's routed part, plus the shared expert once, is the whole
-layer (``tests/test_afmoe.py``).
+give (``experts_held = (first, count)``), dropless: a loop over the
+(token, expert) assignments the share holds, taken expert by expert in
+tiles of ``EXPERT_TILE`` rows, one turn a tile (``held_experts``).  A
+held expert costs what the rows that chose it cost: one that more rows
+chose than a tile holds takes as many tiles as it needs, one that no
+row chose takes none and is never read, padding makes no assignment;
+where the whole call fits one tile (a decode step's slots) a hit
+expert's tile is the call.  The path follows from the call's shape
+alone.  What the absent experts would add is left out; nothing stands
+in for their chips or their traffic.  On one chip it runs without an
+exchange; the sum of every share's routed part, plus the shared expert
+once, is the whole layer (``tests/test_afmoe.py``).
 
 The cache of a served sequence is a tree of leaves per layer: a full
 layer keeps ``cached_key``/``cached_value`` of ``max_len`` positions, a
 window layer a RING ``window_key``/``window_value`` of ``window +
 ring_slack`` positions (position ``p`` at row ``p % ring``), each with
 its ``cache_index``; an expert layer keeps ``stat_experts``, the
-experts its last token chose, for the ``bf_moe_*`` counters of
-``serving/metrics.py``.  A call of up to
+experts its last token chose, and ``stat_expert_rows``, the rows its
+expert loop has computed and the held assignments they were computed
+for, summed over the sequence's calls (``experts_cost``), for the
+``bf_moe_*`` counters of ``serving/metrics.py``.  A call of up to
 ``ring_slack`` tokens writes its keys first and attends afterwards, so
 a chunk's first query still finds the ``window - 1`` keys behind it
 and a wrapped ring is read through its positions, not its rows.
@@ -71,6 +77,8 @@ SCOPE_MOE_EXPERTS = "bf.moe.experts"
 # query rows x key positions of one score block: a prefill chunk against
 # a long full-attention cache is computed in row blocks under this size
 SCORE_BLOCK = 1 << 21
+# rows of one turn of the expert loop (``_experts_hit``)
+EXPERT_TILE = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -342,28 +350,74 @@ def route(scores, bias, top_k: int, route_scale: float):
     return chosen, picked * route_scale
 
 
-def _experts_hit(m, combine, w1, w3, w2):
-    """``sum_e combine[:, e] * expert_e(m)`` over the held experts that
-    at least one token chose, one after another: a loop of as many
-    turns as experts were hit, so an expert no token chose is never
-    read.  m ``[N, d]``, combine ``[N, held]`` float32, w1 and w3
-    ``[held, d, f]``, w2 ``[held, f, d]``; float32 ``[N, d]``."""
-    hit = jnp.any(combine != 0, axis=0)
-    order = jnp.argsort(~hit, stable=True)     # the hit ones first
+def _tiles(combine):
+    """How the expert loop cuts a call's held assignments (the non-zero
+    entries of ``combine [N, held]``): ``(rows, chose, count, tiles)``,
+    the rows of one turn (``EXPERT_TILE``, or the whole call where it
+    fits one tile), the assignments, how many rows chose each held
+    expert, and how many turns each takes: ``ceil(count / rows)``, none
+    for an expert no row chose."""
+    rows = min(combine.shape[0], EXPERT_TILE)
+    chose = combine != 0
+    count = chose.sum(0, dtype=jnp.int32)
+    return rows, chose, count, -(-count // rows)
 
-    def one(i, acc):
-        e = order[i]
+
+def _experts_hit(m, combine, w1, w3, w2):
+    """``sum_e combine[:, e] * expert_e(m)`` over the assignments this
+    share holds, taken expert by expert in tiles of rows (``_tiles``),
+    one loop turn a tile: an expert costs what the rows that chose it
+    cost, one that more rows chose than a tile holds takes as many tiles
+    as it needs, and one no row chose is never read.  A row's place
+    among its expert's rows is a running count down the expert's column;
+    a turn picks the rows whose place falls in its tile with a one-hot
+    matrix (a matmul gathers them, exactly), and the transposed matrix
+    adds the tile's result back onto their rows.  Where the whole call
+    fits one tile a hit expert's tile IS the call, and the turn takes
+    ``m`` as it stands.  m ``[N, d]``, combine ``[N, held]`` float32, w1
+    and w3 ``[held, d, f]``, w2 ``[held, f, d]``; float32 ``[N, d]``."""
+    rows, chose, _, tiles = _tiles(combine)
+    tiled = m.shape[0] > rows
+    ends = jnp.cumsum(tiles, dtype=jnp.int32)
+    if tiled:
+        place = jnp.cumsum(chose, axis=0, dtype=jnp.int32) - 1  # [N, held]
+
+    def turn(i, acc):
+        e = jnp.sum(ends <= i)                   # the expert of turn i
+        column = lambda x: lax.dynamic_index_in_dim(x, e, 1, keepdims=False)
         pick = lambda w: lax.dynamic_index_in_dim(
             w, e, 0, keepdims=False).astype(m.dtype)
-        gate = jnp.dot(m, pick(w1), preferred_element_type=jnp.float32)
-        up = jnp.dot(m, pick(w3), preferred_element_type=jnp.float32)
-        share = lax.dynamic_index_in_dim(combine, e, 1, keepdims=True)
-        act = (nn.silu(gate) * up * share).astype(m.dtype)
-        return acc + jnp.dot(act, pick(w2),
-                             preferred_element_type=jnp.float32)
+        x, share = m, column(combine)
+        if tiled:
+            # this turn's tile of the expert's rows: places first ..
+            # first + rows - 1
+            first = (i - ends[e] + tiles[e]) * rows
+            take = (column(place) - first == jnp.arange(rows)[:, None]) \
+                & column(chose)                                 # [rows, N]
+            x = jnp.dot(take.astype(m.dtype), m,
+                        precision=lax.Precision.HIGHEST)
+            share = jnp.where(take, share, 0.0).sum(1)
+        gate = jnp.dot(x, pick(w1), preferred_element_type=jnp.float32)
+        up = jnp.dot(x, pick(w3), preferred_element_type=jnp.float32)
+        act = (nn.silu(gate) * up * share[:, None]).astype(m.dtype)
+        out = jnp.dot(act, pick(w2), preferred_element_type=jnp.float32)
+        if tiled:
+            out = jnp.einsum("rn,rd->nd", take.astype(jnp.float32), out,
+                             precision=lax.Precision.HIGHEST)
+        return acc + out
 
-    return lax.fori_loop(0, hit.sum(), one,
+    return lax.fori_loop(0, ends[-1], turn,
                          jnp.zeros((m.shape[0], w2.shape[-1]), jnp.float32))
+
+
+def _joint(fn, axis_size, in_batched, *args):
+    """``fn`` over the rows of every mapped sequence taken together:
+    unmapped arguments are spread, the leading two axes folded."""
+    spread = lambda x, batched: x if batched else jnp.broadcast_to(
+        x, (axis_size,) + x.shape)
+    args = [spread(x, b) for x, b in zip(args, in_batched)]
+    return args[0].shape, fn(*(x.reshape((-1,) + x.shape[2:])
+                               for x in args))
 
 
 @jax.custom_batching.custom_vmap
@@ -381,12 +435,33 @@ def held_experts(m, combine, w1, w3, w2):
 def _held_experts_vmap(axis_size, in_batched, m, combine, w1, w3, w2):
     if any(in_batched[2:]):
         raise NotImplementedError("held_experts: vmap over the weights")
-    spread = lambda x, batched: x if batched else jnp.broadcast_to(
-        x, (axis_size,) + x.shape)
-    m, combine = spread(m, in_batched[0]), spread(combine, in_batched[1])
-    out = _experts_hit(m.reshape(-1, m.shape[-1]),
-                       combine.reshape(-1, combine.shape[-1]), w1, w3, w2)
-    return out.reshape(m.shape[:-1] + out.shape[-1:]), True
+    shape, out = _joint(lambda x, c: _experts_hit(x, c, w1, w3, w2),
+                        axis_size, in_batched[:2], m, combine)
+    return out.reshape(shape[:-1] + out.shape[-1:]), True
+
+
+def _experts_cost(combine):
+    rows, _, count, tiles = _tiles(combine)
+    return jnp.stack([tiles.sum(dtype=jnp.int32) * rows,
+                      count.sum(dtype=jnp.int32)])
+
+
+@jax.custom_batching.custom_vmap
+def experts_cost(combine):
+    """What ``held_experts`` does for a call of these assignments, as
+    ``[2]`` int32: the rows its expert matmuls compute (turns x rows a
+    turn) and the held assignments they are computed for.  Apart from
+    ``held_experts``, so that a call whose output nobody reads (the last
+    layer of a prefill chunk) is still dropped whole.  Under ``vmap`` the
+    joint call's cost is the first sequence's and the others' nothing:
+    the sequences' costs add up to the call's."""
+    return _experts_cost(combine)
+
+
+@experts_cost.def_vmap
+def _experts_cost_vmap(axis_size, in_batched, combine):
+    _, cost = _joint(_experts_cost, axis_size, in_batched, combine)
+    return jnp.zeros((axis_size, 2), cost.dtype).at[0].set(cost), True
 
 
 class ExpertLayer(nn.Module):
@@ -431,6 +506,10 @@ class ExpertLayer(nn.Module):
                                  (b, cfg.top_k), jnp.int32)
             stat.value = chosen.reshape(b, t, cfg.top_k)[:, -1].astype(
                 jnp.int32)
+            # what the sequence's calls have cost so far: it only grows
+            rows = self.variable("cache", "stat_expert_rows", jnp.zeros,
+                                 (2,), jnp.int32)
+            rows.value = rows.value + experts_cost(combine)
         out = shared.astype(jnp.float32) + routed
         return out.astype(cfg.dtype).reshape(b, t, d)
 

@@ -1022,7 +1022,9 @@ class ServingEngine:
             streamed = self.cfg.streamed_positions(positions)
             if stats is not None:
                 self.metrics.on_expert_choices(
-                    stats.values(), sorted(decoding), self.cfg.held)
+                    stats.get("stat_experts", ()), sorted(decoding),
+                    self.cfg.held)
+                self.metrics.on_expert_rows(stats.get("stat_expert_rows", ()))
         self._emit(decoding, lambda slot: hist[:, slot])
         self.metrics.on_decode_step(len(decoding), attended, streamed)
 

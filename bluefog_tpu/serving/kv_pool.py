@@ -119,7 +119,7 @@ class SlotPool:
         self._in_use: set = set()
         # (name, position among the leaves) of the model's stat_* leaves
         self._stat_leaves = [
-            (jax.tree_util.keystr(path), i) for i, (path, _) in enumerate(
+            (path[-1].key, i) for i, (path, _) in enumerate(
                 jax.tree_util.tree_flatten_with_path(self.cache)[0])
             if protocol.leaf_kind(path) == protocol.STAT]
         self.has_stats = bool(self._stat_leaves)
@@ -137,9 +137,13 @@ class SlotPool:
         return out
 
     def stats(self) -> dict:
-        """The ``stat_*`` leaves, ``{path: leaf [capacity, ...]}``."""
+        """The ``stat_*`` leaves by name, ``{name: [leaf [capacity, ...]
+        a layer that declares it]}``."""
         leaves = jax.tree.leaves(self.cache)
-        return {name: leaves[i] for name, i in self._stat_leaves}
+        out = {}
+        for name, i in self._stat_leaves:
+            out.setdefault(name, []).append(leaves[i])
+        return out
 
     @property
     def n_free(self) -> int:

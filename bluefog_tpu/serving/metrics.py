@@ -94,6 +94,8 @@ class ServingMetrics:
         self.n_spec_steps = 0
         self.n_spec_active = 0
         self.n_spec_emitted = 0
+        # the stat_expert_rows leaves as last read (``on_expert_rows``)
+        self._expert_rows_seen = None
 
     # -- observe plumbing --------------------------------------------- #
     def _reg(self):
@@ -301,6 +303,35 @@ class ServingMetrics:
         reg.counter(
             "bf_moe_layer_steps_total",
             "expert layers times decode steps").inc(n_layers)
+
+    def on_expert_rows(self, totals):
+        """``totals``: a model's ``stat_expert_rows`` leaves as a decode
+        step brought them, one ``[capacity, 2]`` array a layer: the rows
+        the layer's expert matmuls have computed and the held
+        assignments they were computed for, summed over every call that
+        wrote the slot (prefill chunks and decode steps alike; a decode
+        step's joint count lands on one slot).  Counts what they grew
+        by since the last reading; a total that fell was zeroed with
+        its slot (or wrapped) and counts from nothing."""
+        reg = self._reg()
+        if reg is None or not len(totals):
+            return
+        now = np.stack([np.asarray(t).astype(np.uint32).astype(np.int64)
+                        for t in totals])
+        seen = self._expert_rows_seen
+        self._expert_rows_seen = now
+        if seen is not None:
+            now = np.where(now >= seen, now - seen, now)
+        rows, assigned = now.reshape(-1, 2).sum(0)
+        reg.counter(
+            "bf_moe_expert_rows_total",
+            "rows the held experts' matmuls computed (loop turns x rows "
+            "a turn), over expert layers and calls").inc(int(rows))
+        reg.counter(
+            "bf_moe_expert_assignments_total",
+            "held token-to-expert assignments of the calls that "
+            "bf_moe_expert_rows_total counts (prefill and decode)"
+            ).inc(int(assigned))
 
     def on_prefix_restore(self, rid, n_chunks: int, n_tokens: int):
         """``n_chunks`` cached K/V chunks (``n_tokens`` prompt tokens)

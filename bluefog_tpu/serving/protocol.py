@@ -17,11 +17,14 @@ serving layer reads a leaf's KIND off its name (:func:`leaf_kind`):
 * ``window_*``: a ring of about a window of positions.  It does not
   grow with ``max_len``, and its rows are not the positions of a
   prefix, so a prefix cache refuses a model that declares one.
-* ``stat_*``: small per-step observations.  The one kind there is,
-  ``stat_experts [top_k]``, holds the experts a sequence's last token
-  chose; where a registry counts, they leave the device with the
-  step's tokens and ``ServingMetrics.on_expert_choices`` counts them
-  against the model's ``held = (first, count)``.
+* ``stat_*``: small observations of the model's own calls; where a
+  registry counts, they leave the device with a decode step's tokens.
+  ``stat_experts [top_k]`` holds the experts a sequence's last token
+  chose (``ServingMetrics.on_expert_choices`` counts them against the
+  model's ``held = (first, count)``); ``stat_expert_rows [2]`` adds up,
+  over every call that wrote the slot, the rows the expert loop
+  computed and the held assignments they were computed for
+  (``ServingMetrics.on_expert_rows`` counts what it grew by).
 * anything else: ``max_len`` positions along one axis ("full").
 """
 

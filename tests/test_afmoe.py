@@ -246,6 +246,100 @@ def test_padding_chooses_no_expert_and_none_is_read_for_it(call):
 
 
 # ------------------------------------------------------------------ #
+# (c2) the expert loop runs over tiles of the rows that chose an expert
+# ------------------------------------------------------------------ #
+TILE = afmoe.EXPERT_TILE
+
+
+def _routing(case, rng, held):
+    """``(combine [..., N, held], slots)``: the held weights of a call's
+    rows, as the expert layer makes them; ``slots`` where the engine
+    maps the call over them."""
+    def rows(n, top_k=2, of=3 * held):
+        # each row chooses top_k of ``of`` experts; the first ``held``
+        # are held
+        c = np.zeros((n, held), np.float32)
+        for r in range(n):
+            for e in rng.choice(of, top_k, replace=False):
+                if e < held and e != 1:     # nobody's choice is 1
+                    c[r, e] = rng.uniform(0.2, 1.5)
+        return c
+
+    if case == "one_row":
+        c = rows(1)
+        c[0, 3] = 0.7       # at least one held assignment
+        return c, None
+    if case == "slots_24_vmap":
+        return rows(24)[:, None], 24
+    if case == "chunk_512_padded_tail":
+        c = rows(512, top_k=4, of=2 * held)
+        c[389:] = 0.0       # the chunk's tail is padding
+        return c, None
+    assert case == "skewed_512"
+    # every row chose expert 2 (four tiles of it: nothing is dropped),
+    # a third of them expert 5 as well (more than one tile, the last
+    # one part full), nobody any other
+    c = np.zeros((512, held), np.float32)
+    c[:, 2] = rng.uniform(0.2, 1.5, 512)
+    c[::3, 5] = rng.uniform(0.2, 1.5, 171)
+    return c, None
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["one_row", "slots_24_vmap",
+                                  "chunk_512_padded_tail", "skewed_512"])
+def test_the_tiled_sum_is_the_dense_sum_and_takes_a_turn_a_tile(case, dtype):
+    """``held_experts`` against ``sum_e combine[:, e] * expert_e(m)``
+    in float64, over the same rounded operands; an expert nobody chose
+    holds NaNs; the loop's turns, read from its count of the rows it
+    computed (``experts_cost``), are ``sum_e ceil(count_e / TILE)``."""
+    rng = np.random.default_rng(len(case))
+    held, d, f = 8, 32, 16
+    dtype = jnp.dtype(dtype)
+    combine, slots = _routing(case, rng, held)
+    flat = combine.reshape(-1, held)
+    n = flat.shape[0]
+    m = jnp.asarray(rng.normal(size=combine.shape[:-1] + (d,)), dtype)
+    w1, w3 = (jnp.asarray(rng.normal(size=(held, d, f)) / np.sqrt(d),
+                          jnp.float32) for _ in range(2))
+    w2 = jnp.asarray(rng.normal(size=(held, f, d)) / np.sqrt(f), jnp.float32)
+    count = (flat != 0).sum(0)
+    dead = np.flatnonzero(count == 0)
+    assert dead.size
+    poisoned = [w.at[dead].set(jnp.nan) for w in (w1, w3, w2)]
+    call = lambda x, c: (afmoe.held_experts(x, c, *poisoned),
+                         afmoe.experts_cost(c))
+    got, stat = jax.jit(jax.vmap(call) if slots else call)(
+        m, jnp.asarray(combine))
+    assert got.dtype == jnp.float32 and got.shape == m.shape
+
+    x = np.asarray(m.astype(jnp.float32), np.float64).reshape(n, d)
+    rounded = lambda w: np.asarray(w.astype(dtype).astype(jnp.float32),
+                                   np.float64)
+    want = np.zeros((n, d))
+    for e in np.flatnonzero(count):
+        gate, up = x @ rounded(w1[e]), x @ rounded(w3[e])
+        want += (gate / (1 + np.exp(-gate)) * up * flat[:, e:e + 1]) \
+            @ rounded(w2[e])
+    # float32: the order of the sums; bfloat16: the activation is
+    # rounded once before the last product
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    assert np.abs(np.asarray(got, np.float64).reshape(n, d) - want).max() \
+        < tol * np.abs(want).max()
+    assert not np.asarray(got).reshape(n, d)[(flat == 0).all(1)].any()
+
+    # the slots' counts add up to the joint call's
+    rows, assigned = np.asarray(stat).reshape(-1, 2).sum(0)
+    assert assigned == (flat != 0).sum()
+    if n <= TILE:       # a hit expert's tile is the call
+        assert rows == n * (count > 0).sum()
+    else:
+        assert rows == TILE * np.ceil(count / TILE).sum()
+        if case == "skewed_512":
+            assert rows == TILE * (4 + 2)
+
+
+# ------------------------------------------------------------------ #
 # (d) the router
 # ------------------------------------------------------------------ #
 def test_the_bias_selects_and_does_not_weigh():
@@ -306,7 +400,9 @@ def test_the_pool_reports_window_and_full_bytes_of_its_leaves():
             assert leaf.shape == (3, 1, 2, 64, 16)
             by_hand["full"] += leaf.nbytes
     assert by_hand == pool.cache_bytes()
-    assert pool.has_stats and len(pool.stats()) == 4
+    # an expert layer's last choices and what its expert loop has cost
+    assert pool.has_stats and {k: len(v) for k, v in pool.stats().items()} \
+        == {"stat_experts": 4, "stat_expert_rows": 4}
     assert cfg.cache_kinds() == {"window": (4, WINDOW), "full": (1, None)}
 
 
@@ -346,6 +442,67 @@ def test_the_counters_of_a_served_run():
     assert reg.gauge("bf_serving_cache_bytes", "", kind="window").value \
         == eng.pool.cache_bytes()["window"]
     assert all(r.state == "completed" for r in reqs)
+
+
+def test_the_expert_rows_counters_of_a_served_run():
+    """A chunk longer than a tile goes through the tiled loop, a decode
+    step (two slots) through the one-tile case; with every expert held
+    each live token makes ``top_k`` assignments a layer, so the
+    assignments are known exactly, and the rows are whole tiles (or
+    whole calls) that hold them."""
+    from bluefog_tpu.observe.registry import MetricsRegistry
+
+    reg = MetricsRegistry()
+    rng = np.random.default_rng(6)
+    chunk = TILE + 64
+    eng = ServingEngine({"params": _params()}, FAMILY.model_config(SZ),
+                        capacity=2, max_len=2 * chunk, prefill_chunk=chunk,
+                        registry=reg)
+    lengths, new = (chunk + 41, 3), 4
+    reqs = [eng.submit(Request(rng.integers(0, 128, n), new))
+            for n in lengths]
+    eng.run()
+    assert all(r.state == "completed" for r in reqs)
+    value = lambda name, **labels: reg.counter(name, "", **labels).value
+    rows = value("bf_moe_expert_rows_total")
+    assigned = value("bf_moe_expert_assignments_total")
+    # the last prompt token and the answer's tokens but the last decode
+    slot_steps = value("bf_serving_decode_slots_total")
+    assert slot_steps == len(lengths) * new
+    live = sum(n - 1 for n in lengths) + slot_steps
+    assert assigned == 4 * SZ["num_experts_per_tok"] * live
+    assert assigned == 4 * 4 * sum(n - 1 for n in lengths) \
+        + value("bf_moe_assignments_total", held="true")
+    # three chunk calls, each of at most 4 x chunk / TILE full tiles
+    # and one part-full tile an expert, a layer; a decode step applies
+    # at most 16 experts to its 2 rows
+    steps = value("bf_serving_decode_steps_total")
+    assert assigned < rows <= 4 * (
+        3 * TILE * (4 * chunk // TILE + 16) + steps * 16 * 2)
+
+
+def test_expert_rows_are_counted_by_what_the_totals_grew():
+    from bluefog_tpu.observe.registry import MetricsRegistry
+    from bluefog_tpu.serving.metrics import ServingMetrics
+
+    reg = MetricsRegistry()
+    metrics = ServingMetrics(registry=reg)
+    value = lambda name: reg.counter(name, "").value
+    read = lambda: (value("bf_moe_expert_rows_total"),
+                    value("bf_moe_expert_assignments_total"))
+    layer = lambda *slots: np.asarray(slots, np.int32)
+    metrics.on_expert_rows([layer((256, 20), (0, 0)),
+                            layer((128, 9), (3, 1))])
+    assert read() == (387, 30)
+    metrics.on_expert_rows([layer((256, 20), (128, 7)),
+                            layer((384, 30), (3, 1))])
+    assert read() == (387 + 128 + 256, 30 + 7 + 21)
+    # a slot freed and zeroed, then written again: it counts from zero
+    metrics.on_expert_rows([layer((128, 5), (128, 7)),
+                            layer((384, 30), (3, 1))])
+    assert read() == (771 + 128, 58 + 5)
+    metrics.on_expert_rows([])
+    assert read() == (899, 63)
 
 
 def test_with_no_registry_the_step_fetches_no_stat_leaf(monkeypatch):
