@@ -1,0 +1,406 @@
+"""A decoder with LATENT attention and softmax-routed experts beside a
+shared one (``model_type: mistral4``; the layer's keys are
+DeepSeek-V3's and are read that way).
+
+Imported lazily (nothing on ``import bluefog_tpu``'s path names it); it
+reuses ``RMSNorm`` of ``models/llama.py`` and the expert layer of
+``models/experts.py`` (``score_func`` "softmax": no bias), and is served
+by the same ``ServingEngine`` through the protocol of
+``serving/protocol.py``, which :class:`MlaMoeConfig` implements.
+
+One layer on the residual stream ``h`` (``h0 = E[tok]``), ``H`` heads,
+position ``p``::
+
+    a = norm1(h)
+    c_q = rms(a W_dq);  q = c_q W_uq        per head [q_n (dn) ; q_r (dr)]
+    [c ; k_r] = a W_dkv;  c = rms(c)        the latent (dc) and ONE key
+                                            of dr columns for all heads
+    q_r, k_r = rope(q_r, p), rope(k_r, p)   YaRN frequencies, interleaved
+    [k_n ; v] = c W_ukv                     per head (dn + dv)
+    q = q * (1 + beta ln(1 + floor(p / original_max)))
+    o = softmax(s [q_n ; q_r] [k_n ; k_r]^T, j <= i) v
+    h = h + [o_1 .. o_H] W_o
+    m = norm2(h);  h = h + shared(m) + sum_{e in top_k, e held} w_e expert_e(m)
+
+with ``s = (dn + dr)^-1/2 * yarn_mscale(factor, mscale_all_dim)^2``.
+
+What a position leaves in the cache is ``[c ; k_r]``: ``dc + dr``
+values, 640 bytes in bfloat16 at the published widths where the
+expanded keys and values of 32 heads are 16 KiB.  No leaf holds an
+expanded key or value.  Attention reads the latent in one of two forms,
+equal in exact arithmetic, and the call's length says which:
+
+* ABSORBED, a single-token step (the engine's decode program, one token
+  a slot under ``vmap``): ``qa_h = q_n,h W_uk,h^T`` scores the latent
+  directly, ``score_j = s ([qa_h ; q_r,h] . [c_j ; k_r,j])``, the
+  weighted latent ``u_h = sum_j p_j c_j`` goes through ``W_uv,h``.
+  Nothing is expanded: every head reads the one ``dc + dr`` row a
+  position holds, over every reserved row behind a mask.
+* EXPANDED, a call of several tokens (a prefill chunk, the training
+  layout): ``[k_n ; v] = c W_ukv`` is rebuilt for the cached rows and
+  attention is the plain one.  It walks the cache in blocks of
+  ``key_block`` positions with a running softmax, only as far as the
+  call's last position: its cost follows the context that is live, not
+  the rows reserved.  (A chunk that absorbed instead was timed slower on
+  the chip and does more work at every context: PERF.md section 6, PR 30.)
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from bluefog_tpu.models.experts import ExpertLayer, _dense
+from bluefog_tpu.models.llama import RMSNorm
+
+__all__ = ["MlaMoeConfig", "MlaMoe", "yarn_frequencies", "yarn_mscale",
+           "query_scale"]
+
+SCOPE_ATTN_LATENT = "bf.attn.latent"
+SCOPE_ATTN_ABSORB = "bf.attn.latent_absorb"
+SCOPE_ATTN_EXPAND = "bf.attn.latent_expand"
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaMoeConfig:
+    vocab_size: int = 256
+    dim: int = 64
+    n_layers: int = 2
+    n_heads: int = 4
+    q_lora_rank: int = 32
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 8
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    expert_hidden_dim: int = 32
+    n_experts: int = 16              # the router's outputs
+    top_k: int = 4
+    route_scale: float = 1.0
+    score_func = "softmax"           # the expert layer's (models/experts.py)
+    # (first, count) of the experts this layer holds; None: all of them
+    experts_held: Optional[Tuple[int, int]] = None
+    # rope_parameters (rope_type yarn)
+    rope_theta: float = 10000.0
+    rope_factor: float = 1.0
+    rope_original_max: int = 8192
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    # the query at p is scaled by 1 + beta ln(1 + floor(p / original_max))
+    query_scale_beta: float = 0.0
+    norm_eps: float = 1e-6
+    initializer_range: float = 0.02
+    dtype: Any = jnp.bfloat16
+    # the cached positions a block of a several-token call's attention holds
+    key_block: int = 1024
+    # the serving layout (``serving_layout``)
+    decode: bool = False
+    max_seq_len: int = 2048
+
+    def __post_init__(self):
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even")
+        first, count = self.held
+        if first < 0 or count < 1 or first + count > self.n_experts:
+            raise ValueError(f"experts_held {self.experts_held} lies "
+                             f"outside the {self.n_experts} experts")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
+
+    @property
+    def latent_width(self) -> int:
+        """Values one position leaves in one layer's cache."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return m * m / math.sqrt(self.qk_nope_head_dim
+                                 + self.qk_rope_head_dim)
+
+    # -- the serving protocol (serving/protocol.py) -------------------- #
+    def serving_layout(self, max_len: int, *, chunk: int = 1,
+                       kv_quant: str = "none", weight_quant: str = "none",
+                       decode_attn: str = "xla") -> "MlaMoeConfig":
+        del chunk  # a call of any length writes max_len leaves alike
+        if kv_quant != "none" or weight_quant != "none":
+            raise NotImplementedError(
+                "the latent-attention model serves full-precision weights "
+                f"and caches only (kv_quant={kv_quant!r}, weight_quant="
+                f"{weight_quant!r})")
+        # no fused decode kernel reads a latent: "auto" is the XLA lowering
+        if decode_attn not in ("xla", "auto"):
+            raise NotImplementedError(f"decode_attn={decode_attn!r}")
+        return dataclasses.replace(self, decode=True, max_seq_len=max_len)
+
+    def init_cache(self, batch_size: int, max_len: int):
+        """Zero caches of ``batch_size`` sequences, from shapes alone."""
+        cfg = self if self.decode and self.max_seq_len == max_len \
+            else self.serving_layout(max_len)
+        shapes = jax.eval_shape(
+            lambda: MlaMoe(cfg).init(jax.random.PRNGKey(0),
+                                     jnp.zeros((batch_size, 1), jnp.int32)))
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                            shapes["cache"])
+
+    def apply_cached(self, params, cache, tokens, all_logits=False,
+                     live=None):
+        """Append ``tokens [B, T]`` to ``cache`` and return ``(logits
+        [B, 1 or T, vocab], cache')``.  ``live [B, T]``: False marks a
+        token that is padding; it chooses no expert."""
+        logits, mut = MlaMoe(self).apply(
+            {"params": params, "cache": cache}, tokens,
+            all_logits=all_logits, live=live, mutable=["cache"])
+        return logits, mut["cache"]
+
+    def cache_kinds(self) -> dict:
+        """A latent position is a position: every layer is "full"."""
+        return {"full": (self.n_layers, None)}
+
+    def streamed_positions(self, positions) -> tuple:
+        """The single-token step reads every reserved row of every
+        layer's leaf behind its mask, whatever is live."""
+        return (("full", self.n_layers * len(positions)
+                 * self.max_seq_len),)
+
+    def rebuilt_positions(self, start: int, tokens: int) -> int:
+        """Cached positions whose keys and values a call of ``tokens``
+        tokens at cache index ``start`` rebuilds from the latent, summed
+        over layers: the key blocks up to the call's last position; none
+        for a single-token step, which absorbs."""
+        if tokens == 1:
+            return 0
+        kb = _divisor(self.max_seq_len, self.key_block)
+        return self.n_layers * ((start + tokens - 1) // kb + 1) * kb
+
+
+# ------------------------------------------------------------------ #
+# positions: YaRN's frequencies and the query's scale
+# ------------------------------------------------------------------ #
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float,
+                     original_max: int, beta_fast: float,
+                     beta_slow: float) -> np.ndarray:
+    """The ``dim / 2`` rotation frequencies, float32: pair ``i`` turns at
+    ``f_i = theta^(-2i/dim)`` where it makes more than ``beta_fast``
+    turns over the original context (``i <= low``), at ``f_i / factor``
+    where it makes fewer than ``beta_slow`` (``i >= high``), and on the
+    straight ramp between the two in between."""
+    i = np.arange(dim // 2, dtype=np.float32)
+    plain = np.float32(theta) ** (-2.0 * i / np.float32(dim))
+
+    def turns_at(rotations):
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    ramp = np.clip((i - low) / max(high - low, 0.001), 0.0, 1.0)
+    return (plain * (1 - ramp) + plain / np.float32(factor) * ramp
+            ).astype(np.float32)
+
+
+def rotate_pairs(x, positions, freqs, scale: float = 1.0):
+    """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of ``x [..., T,
+    H, D]`` by ``positions [T] * freqs [D/2]`` (``rotary_embed``'s
+    pairing), cos and sin times ``scale``."""
+    ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]
+    cos = (jnp.cos(ang) * scale)[:, None, :]
+    sin = (jnp.sin(ang) * scale)[:, None, :]
+    x1 = x[..., ::2].astype(jnp.float32)
+    x2 = x[..., 1::2].astype(jnp.float32)
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def query_scale(positions, beta: float, original_max: int):
+    """``1 + beta ln(1 + floor(p / original_max))``, float32 ``[T]``."""
+    return 1.0 + beta * jnp.log1p(jnp.floor(
+        positions.astype(jnp.float32) / original_max))
+
+
+# ------------------------------------------------------------------ #
+# attention over the latent
+# ------------------------------------------------------------------ #
+def _divisor(s: int, most: int) -> int:
+    """The largest divisor of ``s`` that is at most ``most``."""
+    block = min(s, most)
+    while s % block:
+        block -= 1
+    return block
+
+
+def absorbed_step(q_n, q_r, latent, pos, w_ukv, dc: int, dn: int):
+    """One query a sequence over every row of its cache, absorbed.  q_n
+    ``[B, 1, H, dn]`` and q_r ``[B, 1, H, dr]`` (scaled); latent ``[B,
+    S, dc + dr]``; pos ``[1]``; w_ukv ``[dc, H, dn + dv]``.  Rows above
+    ``pos`` hold positions no query has reached.  ``[B, 1, H, dv]``."""
+    dtype = latent.dtype
+    # [q_n W_uk^T ; q_r]: a head's query against the latent's own columns
+    qa = jnp.einsum("bthn,chn->bthc", q_n, w_ukv[..., :dn],
+                    preferred_element_type=jnp.float32)
+    qcat = jnp.concatenate([qa.astype(dtype), q_r], axis=-1)
+    scores = jnp.einsum("bthc,bsc->bhts", qcat, latent,
+                        preferred_element_type=jnp.float32)
+    seen = jnp.arange(latent.shape[1])[None, :] <= pos[:, None]
+    p = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30), axis=-1)
+    u = jnp.einsum("bhts,bsc->bthc", p.astype(dtype), latent,
+                   preferred_element_type=jnp.float32)[..., :dc]
+    return jnp.einsum("bthc,chv->bthv", u.astype(dtype), w_ukv[..., dn:],
+                      preferred_element_type=jnp.float32)
+
+
+def blocked_attention(q_n, q_r, latent, pos, w_ukv, dc: int, dn: int,
+                      kb: int, blocks=None):
+    """Queries at ``pos [T]`` (ascending from 0 or from the cache index)
+    of ONE call over its cache, block of ``kb`` keys by block, with a
+    running softmax, over the first ``blocks`` blocks (``None``: up to
+    the block that holds the last query's position, a bound only the
+    device knows).  Shapes as ``absorbed_step`` with ``T`` queries.  A
+    block's keys and values are expanded from its ``[K, dc + dr]`` rows."""
+    b, t, h, _ = q_n.shape
+    dtype = latent.dtype
+    query = jnp.concatenate([q_n, q_r], axis=-1)
+
+    def turn(i, carry):
+        top, total, acc = carry
+        rows = lax.dynamic_slice_in_dim(latent, i * kb, kb, axis=1)
+        with jax.named_scope(SCOPE_ATTN_EXPAND):
+            kv = jnp.einsum("bsc,chf->bshf", rows[..., :dc], w_ukv,
+                            preferred_element_type=jnp.float32
+                            ).astype(dtype)
+            k_r = jnp.broadcast_to(rows[:, :, None, dc:],
+                                   (b, kb, h, rows.shape[-1] - dc))
+            keys = jnp.concatenate([kv[..., :dn], k_r], axis=-1)
+            scores = jnp.einsum("bthd,bshd->bhts", query, keys,
+                                preferred_element_type=jnp.float32)
+            values = kv[..., dn:]
+        key_pos = i * kb + jnp.arange(kb)
+        seen = key_pos[None, :] <= pos[:, None]
+        scores = jnp.where(seen[None, None], scores, -1e30)
+        new_top = jnp.maximum(top, scores.max(-1))
+        # every query sees position 0, in the first block: its top is a
+        # real score from then on and a masked key's weight is exactly 0
+        p = jnp.exp(scores - new_top[..., None])
+        fade = jnp.exp(top - new_top)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "bhts,bshv->bhtv", p.astype(dtype), values,
+            preferred_element_type=jnp.float32)
+        return new_top, total * fade + p.sum(-1), acc
+
+    init = (jnp.full((b, h, t), -1e30, jnp.float32),
+            jnp.zeros((b, h, t), jnp.float32),
+            jnp.zeros((b, h, t, w_ukv.shape[-1] - dn), jnp.float32))
+    if blocks is None:
+        blocks = pos[-1] // kb + 1
+    _, total, acc = lax.fori_loop(0, blocks, turn, init)
+    return jnp.swapaxes(acc / total[..., None], 1, 2)        # [B, T, H, dv]
+
+
+class LatentAttention(nn.Module):
+    cfg: MlaMoeConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        b, t, _ = x.shape
+        h, dc = cfg.n_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        with jax.named_scope(SCOPE_ATTN_LATENT):
+            c_q = RMSNorm(cfg.norm_eps, name="q_norm")(
+                _dense(cfg, cfg.q_lora_rank, "wq_a")(x))
+            q = _dense(cfg, h * (dn + dr), "wq_b")(c_q).reshape(
+                b, t, h, dn + dr)
+            ckr = _dense(cfg, dc + dr, "wkv_a")(x)
+            c = RMSNorm(cfg.norm_eps, name="kv_norm")(ckr[..., :dc])
+            w_ukv = self.param(
+                "wkv_b", nn.initializers.normal(cfg.initializer_range),
+                (dc, h, dn + dv), jnp.float32).astype(cfg.dtype)
+            idx = jnp.zeros((), jnp.int32)
+            if cfg.decode:
+                ci = self.variable("cache", "cache_index",
+                                   lambda: jnp.zeros((), jnp.int32))
+                idx = ci.value
+            pos = idx + jnp.arange(t)
+            freqs = jnp.asarray(yarn_frequencies(
+                dr, cfg.rope_theta, cfg.rope_factor, cfg.rope_original_max,
+                cfg.rope_beta_fast, cfg.rope_beta_slow))
+            turn = yarn_mscale(cfg.rope_factor, cfg.rope_mscale) \
+                / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+            k_r = rotate_pairs(ckr[:, :, None, dc:], pos, freqs, turn)[:, :, 0]
+            # the softmax's scale and the position's ride on the query
+            scale = cfg.softmax_scale * query_scale(
+                pos, cfg.query_scale_beta, cfg.rope_original_max)
+            scale = scale[None, :, None, None]
+            q_n = (q[..., :dn].astype(jnp.float32) * scale).astype(cfg.dtype)
+            q_r = (rotate_pairs(q[..., dn:], pos, freqs, turn).astype(
+                jnp.float32) * scale).astype(cfg.dtype)
+            latent = jnp.concatenate([c, k_r], axis=-1).astype(cfg.dtype)
+            if cfg.decode:
+                cl = self.variable("cache", "cached_latent", jnp.zeros,
+                                   (b, cfg.max_seq_len, dc + dr), cfg.dtype)
+                zero = jnp.zeros((), idx.dtype)
+                latent = lax.dynamic_update_slice(cl.value, latent,
+                                                  (zero, idx, zero))
+                cl.value, ci.value = latent, idx + t
+            if cfg.decode and t == 1:
+                with jax.named_scope(SCOPE_ATTN_ABSORB):
+                    out = absorbed_step(q_n, q_r, latent, pos, w_ukv, dc, dn)
+            else:
+                # the training layout's cache is the call: every block
+                kb = _divisor(latent.shape[1], cfg.key_block)
+                out = blocked_attention(
+                    q_n, q_r, latent, pos, w_ukv, dc, dn, kb,
+                    blocks=None if cfg.decode else t // kb)
+            out = out.astype(cfg.dtype).reshape(b, t, h * dv)
+            return _dense(cfg, cfg.dim, "wo")(out)
+
+
+class Block(nn.Module):
+    cfg: MlaMoeConfig
+
+    @nn.compact
+    def __call__(self, x, live=None):
+        cfg = self.cfg
+        norm = lambda name: RMSNorm(cfg.norm_eps, name=name)
+        x = x + LatentAttention(cfg, name="attention")(
+            norm("attention_norm")(x))
+        return x + ExpertLayer(cfg, name="moe")(norm("ffn_norm")(x), live)
+
+
+class MlaMoe(nn.Module):
+    cfg: MlaMoeConfig
+
+    @nn.compact
+    def __call__(self, tokens, all_logits=False, live=None):
+        """tokens ``[B, T]`` int32 -> float32 logits ``[B, T, vocab]``;
+        in the serving layout the final position's alone unless
+        ``all_logits``.  ``live [B, T]``: see ``apply_cached``."""
+        cfg = self.cfg
+        x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
+                     param_dtype=jnp.float32, name="tok_embeddings",
+                     embedding_init=nn.initializers.normal(
+                         cfg.initializer_range))(tokens)
+        for i in range(cfg.n_layers):
+            x = Block(cfg, name=f"layer_{i}")(x, live)
+        x = RMSNorm(cfg.norm_eps, name="norm")(x)
+        if cfg.decode and not all_logits:
+            x = x[:, -1:]
+        w_out = self.param("output", nn.initializers.normal(
+            cfg.initializer_range), (cfg.dim, cfg.vocab_size), jnp.float32)
+        return jnp.einsum("btd,dv->btv", x, w_out.astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)

@@ -514,6 +514,8 @@ class ServingEngine:
                              zero_on_free=zero_on_free, prefix=prefix,
                              chunk=call)
         self._kinds = self.cfg.cache_kinds()
+        # optional in the protocol: what a chunk rebuilds before it attends
+        self._rebuilt = getattr(self.cfg, "rebuilt_positions", None)
         self._spec = speculative
         self._draft_pool: Optional[SlotPool] = None
         self._draft_params = None
@@ -932,7 +934,8 @@ class ServingEngine:
                     self._draft_params, self._draft_pool.cache,
                     jnp.int32(req.slot), chunk, jnp.int32(valid),
                     cfg=self.draft_cfg)
-            self.metrics.on_prefill_chunk(int(valid))
+            self.metrics.on_prefill_chunk(
+                int(valid), self._rebuilt(pos, c) if self._rebuilt else 0)
             if (valid == c and req._prefix_keys
                     and pos // c < len(req._prefix_keys)):
                 # a FULL cold chunk just landed on the chunk grid —

@@ -212,11 +212,16 @@ class ServingMetrics:
             reg.counter("bf_serving_failovers_total",
                         "requests handed off to another replica").inc()
 
-    def on_prefill_chunk(self, n_tokens: int):
+    def on_prefill_chunk(self, n_tokens: int, rebuilt: int = 0):
         """One cold prefill chunk ran (a model forward over one chunk)
         with ``n_tokens`` valid positions; the rest of the chunk's
         width was padding.  Together with :meth:`on_prefix_restore`
-        this splits prompt coverage into compute vs copy."""
+        this splits prompt coverage into compute vs copy.  ``rebuilt``:
+        the cached positions whose keys and values the chunk rebuilt
+        from a latent before it attended, summed over layers (the
+        model's ``rebuilt_positions``, from the lengths the host holds;
+        0 and no counter for a model that reads its cache as it
+        stands)."""
         self.n_prefill_chunks += 1
         reg = self._reg()
         if reg is not None:
@@ -225,6 +230,12 @@ class ServingMetrics:
             reg.counter("bf_serving_prefill_tokens_total",
                         "valid positions in cold prefill chunks"
                         ).inc(n_tokens)
+            if rebuilt:
+                reg.counter(
+                    "bf_serving_latent_expanded_positions_total",
+                    "cached positions whose keys and values prefill "
+                    "chunks rebuilt from a latent, summed over layers"
+                ).inc(rebuilt)
 
     def on_decode_step(self, n_slots: int, attended=(), streamed=()):
         """One decode program call (plain or speculative) advanced

@@ -3,8 +3,9 @@ imports a model class or asks a model's name.
 
 A model is served through its CONFIG: a frozen, hashable dataclass (it
 is the static argument of the engine's resident programs) with the
-methods of :class:`ServedModel`.  ``models.llama.LlamaConfig`` and
-``models.afmoe.AfmoeConfig`` implement it.
+methods of :class:`ServedModel`.  ``models.llama.LlamaConfig``,
+``models.afmoe.AfmoeConfig`` and ``models.mla_moe.MlaMoeConfig``
+implement it.
 
 The cache of one sequence is a tree of leaves per layer, and the pool
 stacks whatever it is given (``SlotPool``: ``[capacity, *leaf]``).  The
@@ -25,7 +26,16 @@ serving layer reads a leaf's KIND off its name (:func:`leaf_kind`):
   over every call that wrote the slot, the rows the expert loop
   computed and the held assignments they were computed for
   (``ServingMetrics.on_expert_rows`` counts what it grew by).
-* anything else: ``max_len`` positions along one axis ("full").
+* anything else: ``max_len`` positions along one axis ("full"),
+  whatever a position holds: a key or a value of every head, or one
+  latent that all heads share.
+
+A model whose leaves hold something compressed MAY also declare
+``rebuilt_positions(start, tokens) -> int``: the cached positions whose
+keys and values a call of ``tokens`` tokens at cache index ``start``
+rebuilds from its leaves before it can attend, summed over layers (0
+where the call reads the leaves as they stand); the engine counts it a
+prefill chunk (``bf_serving_latent_expanded_positions_total``).
 """
 
 from __future__ import annotations

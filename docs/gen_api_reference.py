@@ -68,6 +68,12 @@ MODULES = [
     ("bluefog_tpu.models.afmoe",
      "decoder of mixed window/full attention, gated heads and an expert "
      "layer told which experts it holds"),
+    ("bluefog_tpu.models.experts",
+     "the expert layer of both expert models: float32 routing (sigmoid "
+     "with a bias, or softmax), a shared expert, the held experts in tiles"),
+    ("bluefog_tpu.models.mla_moe",
+     "decoder of latent attention (a 640-byte-a-token cache, absorbed "
+     "decode), YaRN rotation and softmax-routed experts"),
     ("bluefog_tpu.serving.protocol",
      "what the serving layer needs of a model (config methods, cache "
      "leaf kinds)"),
