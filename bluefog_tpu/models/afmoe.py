@@ -44,7 +44,12 @@ for, summed over the sequence's calls (``experts_cost``), for the
 ``bf_moe_*`` counters of ``serving/metrics.py``.  A call of up to
 ``ring_slack`` tokens writes its keys first and attends afterwards, so
 a chunk's first query still finds the ``window - 1`` keys behind it
-and a wrapped ring is read through its positions, not its rows.
+and a wrapped ring is read through its positions, not its rows.  A
+single-token step scores every row of a leaf at once (``attend``); a
+call of several tokens, the prefill chunk, walks the leaf in blocks of
+``KEY_BLOCK`` rows with a running softmax, as far as a row is written
+and no further (``blocked_attend``): the bound is worked out from the
+cache index inside the program, so one program serves every position.
 """
 
 from __future__ import annotations
@@ -68,9 +73,9 @@ __all__ = ["AfmoeConfig", "Afmoe", "SLIDING", "FULL"]
 SLIDING, FULL = "sliding_attention", "full_attention"
 SCOPE_ATTN_WINDOW = "bf.attn.window"
 SCOPE_ATTN_FULL = "bf.attn.full"
-# query rows x key positions of one score block: a prefill chunk against
-# a long full-attention cache is computed in row blocks under this size
-SCORE_BLOCK = 1 << 21
+# key rows of one block of ``blocked_attend``: a cached call of several
+# tokens reads a leaf block by block, as far as a row is written
+KEY_BLOCK = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,20 +174,58 @@ class AfmoeConfig:
             kinds["full"] = (self.n_layers - n_window, None)
         return kinds
 
-    def streamed_positions(self, positions) -> tuple:
-        """The XLA lowering reads every row of every leaf."""
+    def _leaves(self):
+        """``(kind, layers, rows of a leaf)`` of each kind of cache."""
         rows = {"window": self.ring_len, "full": self.max_seq_len}
-        return tuple((kind, layers * len(positions) * rows[kind])
-                     for kind, (layers, _) in self.cache_kinds().items())
+        return [(kind, layers, rows[kind])
+                for kind, (layers, _) in self.cache_kinds().items()]
+
+    def streamed_positions(self, positions) -> tuple:
+        """The single-token step reads every row of every leaf."""
+        return tuple((kind, layers * len(positions) * rows)
+                     for kind, layers, rows in self._leaves())
+
+    def chunk_streamed_positions(self, start: int, tokens: int) -> tuple:
+        """``((kind, rows), ...)`` that a call of ``tokens`` tokens at
+        cache index ``start`` reads, summed over the kind's layers: the
+        key blocks up to the last row written (``live_blocks``); every
+        row for a single-token step."""
+        if tokens == 1:
+            return self.streamed_positions((start,))
+        return tuple(
+            (kind, layers * key_block(rows) * live_blocks(start + tokens,
+                                                          rows))
+            for kind, layers, rows in self._leaves())
 
 
-def _row_blocks(t: int, s: int) -> int:
-    """How many blocks of query rows keep a score block under
-    ``SCORE_BLOCK``: the smallest divisor of ``t`` that does."""
-    for n in range(1, t + 1):
-        if t % n == 0 and (t // n) * s <= SCORE_BLOCK:
-            return n
-    return t
+def key_block(size: int) -> int:
+    """The largest divisor of a leaf's ``size`` rows that is at most
+    ``KEY_BLOCK``."""
+    block = min(size, KEY_BLOCK)
+    while size % block:
+        block -= 1
+    return block
+
+
+def live_blocks(written, size: int):
+    """Key blocks of a leaf of ``size`` rows that hold a written row
+    once ``written`` positions are in it (an integer, or a traced
+    scalar): the first ``ceil(written / block)``.  A full leaf never
+    holds more than ``size``; a ring that has wrapped is live in every
+    row, and before it wraps row ``r`` holds position ``r``."""
+    kb = key_block(size)
+    least = jnp.minimum if isinstance(written, jax.Array) else min
+    return least((written + kb - 1) // kb, size // kb)
+
+
+def _seen(q_pos, key_pos, window: Optional[int]):
+    """``[T, S]``: the keys at ``key_pos`` that queries at ``q_pos``
+    see (a negative key position marks an empty row)."""
+    gap = q_pos[:, None] - key_pos[None, :]
+    seen = (gap >= 0) & (key_pos[None, :] >= 0)
+    if window is not None:
+        seen &= gap < window
+    return seen
 
 
 def attend(q, k_all, v_all, q_pos, key_pos, window: Optional[int]):
@@ -190,33 +233,64 @@ def attend(q, k_all, v_all, q_pos, key_pos, window: Optional[int]):
     ``key_pos [S]`` (a negative position marks an empty row): a key is
     visible where ``0 <= q_pos - key_pos`` and, under ``window``, ``<
     window``.  q ``[B, T, Hq, D]``; k_all, v_all KV-HEAD-MAJOR ``[B,
-    Hkv, S, D]``.  Scores and probabilities are float32; long calls go
-    in blocks of query rows."""
+    Hkv, S, D]``.  Scores and probabilities are float32, over every row
+    at once: the single-token step's form, and the plain one that
+    ``blocked_attend`` is held to."""
     b, t, n_q, d = q.shape
-    n_kv, s = k_all.shape[1], k_all.shape[2]
-    rep = n_q // n_kv
+    n_kv = k_all.shape[1]
     k32, v32 = k_all.astype(jnp.float32), v_all.astype(jnp.float32)
+    q5 = q.reshape(b, t, n_kv, n_q // n_kv, d).astype(jnp.float32)
+    scores = jnp.einsum("btkrd,bksd->bkrts", q5, k32) / math.sqrt(d)
+    seen = _seen(q_pos, key_pos, window)
+    scores = jnp.where(seen[None, None, None], scores, -1e30)
+    # every query sees at least its own key
+    p = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bkrts,bksd->btkrd", p, v32).reshape(
+        b, t, n_q, d).astype(q.dtype)
 
-    def rows(args):
-        qb, pos = args
-        q5 = qb.reshape(b, -1, n_kv, rep, d).astype(jnp.float32)
+
+def blocked_attend(q, k_all, v_all, q_pos, key_pos, window: Optional[int],
+                   blocks):
+    """``attend`` over the first ``blocks`` key blocks (``key_block`` of
+    the leaf's rows each; a traced scalar makes the bound data, so one
+    program serves every cache index), block by block with a running
+    softmax.  Only the block that is read is cast; scores, the running
+    top, the sum and the probabilities are float32, as in ``attend``:
+    what differs is the order in which the softmax is summed, and that
+    rows past the bound are never read."""
+    b, t, n_q, d = q.shape
+    n_kv = k_all.shape[1]
+    rep, kb = n_q // n_kv, key_block(k_all.shape[2])
+    q5 = q.reshape(b, t, n_kv, rep, d).astype(jnp.float32)
+
+    def turn(i, carry):
+        top, total, acc = carry
+        k32 = lax.dynamic_slice_in_dim(k_all, i * kb, kb, 2).astype(
+            jnp.float32)
+        v32 = lax.dynamic_slice_in_dim(v_all, i * kb, kb, 2).astype(
+            jnp.float32)
+        seen = _seen(q_pos, lax.dynamic_slice_in_dim(key_pos, i * kb, kb),
+                     window)[None, None, None]
         scores = jnp.einsum("btkrd,bksd->bkrts", q5, k32) / math.sqrt(d)
-        gap = pos[:, None] - key_pos[None, :]
-        seen = (gap >= 0) & (key_pos[None, :] >= 0)
-        if window is not None:
-            seen &= gap < window
-        scores = jnp.where(seen[None, None, None], scores, -1e30)
-        # every query sees at least its own key
-        p = jax.nn.softmax(scores, axis=-1)
-        return jnp.einsum("bkrts,bksd->btkrd", p, v32).reshape(
-            b, -1, n_q, d).astype(q.dtype)
+        scores = jnp.where(seen, scores, -1e30)
+        new_top = jnp.maximum(top, scores.max(-1))
+        # a query may see no key of the blocks before its first visible
+        # one (the rows a wrapped ring is about to lose): its top is then
+        # still the mask's value and exp(masked - top) is 1.  The first
+        # real score fades such a sum to exactly 0; the weights are
+        # masked too, so that no sum ever holds them
+        p = jnp.where(seen, jnp.exp(scores - new_top[..., None]), 0.0)
+        fade = jnp.exp(top - new_top)
+        acc = acc * fade[..., None] + jnp.einsum("bkrts,bksd->bkrtd", p, v32)
+        return new_top, total * fade + p.sum(-1), acc
 
-    n = _row_blocks(t, s)
-    if n == 1:
-        return rows((q, q_pos))
-    out = lax.map(rows, (jnp.moveaxis(q.reshape(b, n, t // n, n_q, d), 1, 0),
-                         q_pos.reshape(n, t // n)))
-    return jnp.moveaxis(out, 0, 1).reshape(b, t, n_q, d)
+    init = (jnp.full((b, n_kv, rep, t), -1e30, jnp.float32),
+            jnp.zeros((b, n_kv, rep, t), jnp.float32),
+            jnp.zeros((b, n_kv, rep, t, d), jnp.float32))
+    _, total, acc = lax.fori_loop(0, blocks, turn, init)
+    # every query sees at least its own key, inside the bound
+    out = jnp.moveaxis(acc / total[..., None], 3, 1)      # [B, T, KV, R, D]
+    return out.reshape(b, t, n_q, d).astype(q.dtype)
 
 
 class Attention(nn.Module):
@@ -244,9 +318,10 @@ class Attention(nn.Module):
                 if sliding:
                     q = rotary_embed(q, pos, cfg.rope_theta)
                     k = rotary_embed(k, pos, cfg.rope_theta)
-                out = attend(q, jnp.swapaxes(k, 1, 2),
-                             jnp.swapaxes(v, 1, 2), pos, pos,
-                             cfg.window if sliding else None)
+                out = blocked_attend(q, jnp.swapaxes(k, 1, 2),
+                                     jnp.swapaxes(v, 1, 2), pos, pos,
+                                     cfg.window if sliding else None,
+                                     t // key_block(t))
         out = out.reshape(b, t, n_q * hd).astype(jnp.float32) \
             * jax.nn.sigmoid(gate.astype(jnp.float32))
         return _dense(cfg, cfg.dim, "wo")(out.astype(cfg.dtype))
@@ -254,7 +329,10 @@ class Attention(nn.Module):
     def _cached(self, q, k, v, sliding: bool):
         """Write this call's keys and values at the cache index, then
         attend over the cache: a full layer's ``max_len`` rows, or a
-        window layer's ring read through the position each row holds."""
+        window layer's ring read through the position each row holds.  A
+        single-token step reads every row (``attend``); a call of several
+        tokens the key blocks that hold a written row
+        (``blocked_attend``)."""
         cfg = self.cfg
         b, t, n_kv, hd = k.shape
         ci = self.variable("cache", "cache_index",
@@ -267,6 +345,13 @@ class Attention(nn.Module):
         k = jnp.swapaxes(k, 1, 2).astype(cfg.dtype)   # [B, KV, T, D]
         v = jnp.swapaxes(v, 1, 2).astype(cfg.dtype)
         zero = jnp.zeros((), idx.dtype)
+
+        def over(k_all, v_all, key_pos, window):
+            if t == 1:
+                return attend(q, k_all, v_all, pos, key_pos, window)
+            return blocked_attend(q, k_all, v_all, pos, key_pos, window,
+                                  live_blocks(idx + t, k_all.shape[2]))
+
         if not sliding:
             size = cfg.max_seq_len
             ck = self.variable("cache", "cached_key", jnp.zeros,
@@ -279,7 +364,7 @@ class Attention(nn.Module):
                                              (zero, zero, idx, zero))
             ck.value, cv.value, ci.value = k_all, v_all, idx + t
             # rows above the index hold positions no query has reached
-            return attend(q, k_all, v_all, pos, jnp.arange(size), None)
+            return over(k_all, v_all, jnp.arange(size), None)
         size = cfg.ring_len
         if t > cfg.ring_slack:
             raise ValueError(
@@ -311,7 +396,7 @@ class Attention(nn.Module):
         # the ring; negative: never written
         rows = jnp.arange(size)
         key_pos = rows + size * ((idx + t - 1 - rows) // size)
-        return attend(q, k_all, v_all, pos, key_pos, cfg.window)
+        return over(k_all, v_all, key_pos, cfg.window)
 
 
 class Block(nn.Module):

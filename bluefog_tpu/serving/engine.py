@@ -516,6 +516,9 @@ class ServingEngine:
         self._kinds = self.cfg.cache_kinds()
         # optional in the protocol: what a chunk rebuilds before it attends
         self._rebuilt = getattr(self.cfg, "rebuilt_positions", None)
+        # and what it reads to attend, by kind of leaf
+        self._chunk_streamed = getattr(self.cfg, "chunk_streamed_positions",
+                                       None)
         self._spec = speculative
         self._draft_pool: Optional[SlotPool] = None
         self._draft_params = None
@@ -935,7 +938,8 @@ class ServingEngine:
                     jnp.int32(req.slot), chunk, jnp.int32(valid),
                     cfg=self.draft_cfg)
             self.metrics.on_prefill_chunk(
-                int(valid), self._rebuilt(pos, c) if self._rebuilt else 0)
+                int(valid), self._rebuilt(pos, c) if self._rebuilt else 0,
+                self._chunk_streamed(pos, c) if self._chunk_streamed else ())
             if (valid == c and req._prefix_keys
                     and pos // c < len(req._prefix_keys)):
                 # a FULL cold chunk just landed on the chunk grid —

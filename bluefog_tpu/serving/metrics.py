@@ -212,7 +212,8 @@ class ServingMetrics:
             reg.counter("bf_serving_failovers_total",
                         "requests handed off to another replica").inc()
 
-    def on_prefill_chunk(self, n_tokens: int, rebuilt: int = 0):
+    def on_prefill_chunk(self, n_tokens: int, rebuilt: int = 0,
+                         streamed=()):
         """One cold prefill chunk ran (a model forward over one chunk)
         with ``n_tokens`` valid positions; the rest of the chunk's
         width was padding.  Together with :meth:`on_prefix_restore`
@@ -221,7 +222,10 @@ class ServingMetrics:
         from a latent before it attended, summed over layers (the
         model's ``rebuilt_positions``, from the lengths the host holds;
         0 and no counter for a model that reads its cache as it
-        stands)."""
+        stands).  ``streamed``: ``((kind, rows), ...)``, the cache rows
+        the chunk read to attend, summed over the kind's layers (the
+        model's ``chunk_streamed_positions``, from the same lengths;
+        empty for a model that declares none)."""
         self.n_prefill_chunks += 1
         reg = self._reg()
         if reg is not None:
@@ -236,6 +240,11 @@ class ServingMetrics:
                     "cached positions whose keys and values prefill "
                     "chunks rebuilt from a latent, summed over layers"
                 ).inc(rebuilt)
+            for kind, rows in streamed:
+                reg.counter(
+                    "bf_serving_chunk_streamed_positions_total",
+                    "cache rows prefill chunks read to attend, summed "
+                    "over the layers of the kind", kind=kind).inc(rows)
 
     def on_decode_step(self, n_slots: int, attended=(), streamed=()):
         """One decode program call (plain or speculative) advanced

@@ -36,6 +36,13 @@ keys and values a call of ``tokens`` tokens at cache index ``start``
 rebuilds from its leaves before it can attend, summed over layers (0
 where the call reads the leaves as they stand); the engine counts it a
 prefill chunk (``bf_serving_latent_expanded_positions_total``).
+
+A model whose call of several tokens reads less than every reserved row
+MAY declare ``chunk_streamed_positions(start, tokens) -> ((kind, rows),
+...)``: the cache rows such a call reads to attend, summed over the
+kind's layers, from the same two lengths; the engine counts them a
+prefill chunk (``bf_serving_chunk_streamed_positions_total{kind}``).  A
+model that declares neither counts neither.
 """
 
 from __future__ import annotations

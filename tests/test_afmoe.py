@@ -133,6 +133,165 @@ def test_no_recompile_inside_the_window_or_across_the_wrap():
 
 
 # ------------------------------------------------------------------ #
+# (b') a chunk's attention, block of key rows by block
+# ------------------------------------------------------------------ #
+CHUNK, MAX_LEN = 4, 64            # the ring: WINDOW + CHUNK = 12 rows
+
+
+def _chunk_layer(kind, dtype):
+    """One attention layer in the serving layout, its parameters, and a
+    cache whose every row holds something (a slot that was used before):
+    rows past the index are garbage no query may see."""
+    cfg = FAMILY.model_config(dict(SZ, compute_dtype=dtype)) \
+        .serving_layout(MAX_LEN, chunk=CHUNK)
+    layer = afmoe.Attention(cfg, kind)
+    x = jnp.zeros((1, CHUNK, cfg.dim), cfg.dtype)
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    return layer, variables["params"], variables["cache"]
+
+
+def _used(cache, batch, idx, seed):
+    rng = np.random.default_rng(seed)
+    return {name: jnp.asarray(idx, jnp.int32) if name == "cache_index"
+            else jnp.asarray(rng.standard_normal((batch,) + leaf.shape[1:]),
+                             leaf.dtype)
+            for name, leaf in cache.items()}
+
+
+def _attend_a_chunk(layer, params, cache, x, monkeypatch, plain):
+    """The layer's output for one cached call of several tokens: by
+    ``blocked_attend`` in blocks of 4 key rows, or (``plain``) by
+    ``attend`` over every row, the parent's lowering."""
+    with monkeypatch.context() as m:
+        m.setattr(afmoe, "KEY_BLOCK", 4)
+        if plain:
+            m.setattr(afmoe, "blocked_attend",
+                      lambda *args: afmoe.attend(*args[:-1]))
+        out, mut = layer.apply({"params": params, "cache": cache}, x,
+                               mutable=["cache"])
+    return np.asarray(out, np.float32), mut["cache"]
+
+
+def _tolerance(dtype, want):
+    """Both forms hold scores, weights and sums in float32 and differ in
+    the order of a softmax's sum over at most 64 keys; the output is
+    rounded to ``dtype`` once, so two roundings of nearly equal numbers
+    may lie an ulp apart."""
+    eps = max(2 * float(jnp.finfo(dtype).eps),
+              64 * float(jnp.finfo(jnp.float32).eps))
+    return eps * max(1.0, np.abs(want).max())
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind, idx, batch", [
+    (afmoe.FULL, 0, 1),           # a first chunk
+    (afmoe.SLIDING, 0, 1),
+    # the chunk before it was padded: the index is on no block's edge
+    # and the rows above it hold the padded tail's keys
+    (afmoe.FULL, 6, 1),
+    (afmoe.SLIDING, 6, 1),        # a ring before the wrap
+    (afmoe.FULL, MAX_LEN - CHUNK, 1),   # ends on the leaf's last row
+    (afmoe.SLIDING, 10, 1),       # across the wrap
+    # (idx + t) % ring == 0: rows 0-3 hold the positions the call's last
+    # query no longer sees, so its first block is wholly masked
+    (afmoe.SLIDING, 8, 1),
+    (afmoe.SLIDING, 20, 1),
+    (afmoe.FULL, 17, 3),          # more than one sequence
+    (afmoe.SLIDING, 29, 3),
+])
+def test_a_chunk_attends_block_by_block_as_over_every_row(
+        kind, idx, batch, dtype, monkeypatch):
+    layer, params, cache = _chunk_layer(kind, dtype)
+    cache = _used(cache, batch, idx, seed=idx)
+    x = jnp.asarray(np.random.default_rng(idx + 1).standard_normal(
+        (batch, CHUNK, layer.cfg.dim)), layer.cfg.dtype)
+    want, cache_want = _attend_a_chunk(layer, params, cache, x,
+                                       monkeypatch, plain=True)
+    got, cache_got = _attend_a_chunk(layer, params, cache, x, monkeypatch,
+                                     plain=False)
+    assert np.isfinite(want).all() and np.abs(want).max() > 1e-3
+    assert np.abs(got - want).max() <= _tolerance(layer.cfg.dtype, want)
+    for name in cache_want:       # the write is the same write
+        np.testing.assert_array_equal(np.asarray(cache_got[name]),
+                                      np.asarray(cache_want[name]))
+
+
+@pytest.mark.parametrize("kind", [afmoe.FULL, afmoe.SLIDING])
+def test_slots_under_vmap_each_walk_to_their_own_bound(kind, monkeypatch):
+    """The speculative step maps a call of several tokens over slots:
+    the bound is then one a slot."""
+    layer, params, cache = _chunk_layer(kind, "float32")
+    starts = (2, 9, 31)
+    caches = [_used(cache, 1, idx, seed=idx) for idx in starts]
+    stacked = jax.tree.map(lambda *leaves: jnp.stack(leaves), *caches)
+    xs = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (len(starts), 1, CHUNK, layer.cfg.dim)), jnp.float32)
+    with monkeypatch.context() as m:
+        m.setattr(afmoe, "KEY_BLOCK", 4)
+        got = jax.vmap(lambda c, x: layer.apply(
+            {"params": params, "cache": c}, x, mutable=["cache"])[0])(
+                stacked, xs)
+    for i, c in enumerate(caches):
+        want, _ = _attend_a_chunk(layer, params, c, xs[i], monkeypatch,
+                                  plain=True)
+        assert np.abs(np.asarray(got[i]) - want).max() \
+            <= _tolerance(jnp.float32, want)
+
+
+@pytest.mark.parametrize("kind, idx, live_rows", [
+    (afmoe.FULL, 9, 16),          # 13 positions written: 4 blocks of 4
+    (afmoe.SLIDING, 2, 8),        # 6 written, the ring not yet wrapped
+])
+def test_a_chunk_reads_no_row_past_the_last_block_written(
+        kind, idx, live_rows, monkeypatch):
+    """Keys AND values of every row past the bound are NaN: the answer
+    is the clean cache's, so those blocks were never read (``attend``
+    multiplies them by a weight of 0 and answers NaN)."""
+    layer, params, cache = _chunk_layer(kind, "float32")
+    clean = _used(cache, 1, idx, seed=7)
+    size = WINDOW + CHUNK if kind == afmoe.SLIDING else MAX_LEN
+    dead = (jnp.arange(size) >= live_rows)[None, None, :, None]
+    poisoned = {name: leaf if name == "cache_index"
+                else jnp.where(dead, jnp.nan, leaf)
+                for name, leaf in clean.items()}
+    x = jnp.asarray(np.random.default_rng(8).standard_normal(
+        (1, CHUNK, layer.cfg.dim)), jnp.float32)
+    want, _ = _attend_a_chunk(layer, params, clean, x, monkeypatch,
+                              plain=True)
+    got, _ = _attend_a_chunk(layer, params, poisoned, x, monkeypatch,
+                             plain=False)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() <= _tolerance(jnp.float32, want)
+    parent, _ = _attend_a_chunk(layer, params, poisoned, x, monkeypatch,
+                                plain=True)
+    assert np.isnan(parent).any()
+
+
+def test_the_block_rule_on_the_host_is_the_programs():
+    cfg = FAMILY.model_config(SZ).serving_layout(16384, chunk=512)
+    cfg = dataclasses.replace(cfg, window=4096)
+    assert (afmoe.key_block(16384), afmoe.key_block(cfg.ring_len)) \
+        == (512, 512)
+    assert afmoe.key_block(12) == 12 and afmoe.key_block(1030) == 206
+    for written in (1, 512, 513, 4608, 4609, 16384):
+        traced = jax.jit(lambda w: (afmoe.live_blocks(w, 16384),
+                                    afmoe.live_blocks(w, cfg.ring_len)))(
+            jnp.int32(written))
+        by_hand = (-(-written // 512), min(-(-written // 512), 9))
+        assert tuple(int(n) for n in traced) == by_hand == (
+            afmoe.live_blocks(written, 16384),
+            afmoe.live_blocks(written, cfg.ring_len))
+    # 4 window layers and 1 full: a chunk at 1,024 reads 3 blocks of each
+    assert cfg.chunk_streamed_positions(1024, 512) == (
+        ("window", 4 * 3 * 512), ("full", 3 * 512))
+    assert cfg.chunk_streamed_positions(8192, 512) == (
+        ("window", 4 * 4608), ("full", 17 * 512))
+    # a single-token step reads every row, as ``streamed_positions`` says
+    assert cfg.chunk_streamed_positions(1024, 1) \
+        == cfg.streamed_positions((1024,))
+
+
+# ------------------------------------------------------------------ #
 # (c) the shares add up to the uncut layer
 # ------------------------------------------------------------------ #
 def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_reference():
@@ -442,6 +601,46 @@ def test_the_counters_of_a_served_run():
     assert reg.gauge("bf_serving_cache_bytes", "", kind="window").value \
         == eng.pool.cache_bytes()["window"]
     assert all(r.state == "completed" for r in reqs)
+
+
+def test_the_rows_a_chunk_reads_are_counted_by_kind(monkeypatch):
+    from bluefog_tpu import models
+    from bluefog_tpu.observe.registry import MetricsRegistry
+
+    monkeypatch.setattr(afmoe, "KEY_BLOCK", 4)
+    name = "bf_serving_chunk_streamed_positions_total"
+    reg = MetricsRegistry()
+    rng = np.random.default_rng(6)
+    # 60 rows: a leaf no other test compiles a program for
+    eng = ServingEngine({"params": _params()}, FAMILY.model_config(SZ),
+                        capacity=2, max_len=60, prefill_chunk=4,
+                        registry=reg)
+    reqs = [eng.submit(Request(rng.integers(0, 128, n), 3))
+            for n in (30, 3)]
+    eng.run()
+    for r in reqs:
+        _assert_served_is_the_references_greedy(eng._params, r)
+    # chunks of 4 cover prompt[:-1]: they start at 0, 4, ...; a chunk
+    # that ends at e reads ceil(e / 4) blocks of 4 rows, a ring (12
+    # rows) at most its 3; 1 full layer, 4 window layers
+    ends = [start + 4 for n in (30, 3) for start in range(0, n - 1, 4)]
+    assert reg.counter("bf_serving_prefill_chunks_total", "").value \
+        == len(ends) == 9
+    assert reg.counter(name, "", kind="full").value \
+        == sum(4 * (e // 4) for e in ends)
+    assert reg.counter(name, "", kind="window").value \
+        == 4 * sum(4 * min(e // 4, 3) for e in ends)
+    # a model that declares nothing counts nothing
+    reg = MetricsRegistry()
+    cfg = models.LlamaConfig.tiny(dtype=jnp.float32)
+    variables = models.Llama(cfg).init(jax.random.PRNGKey(1),
+                                       jnp.zeros((2, 4), jnp.int32))
+    eng = ServingEngine(variables, cfg, capacity=2, max_len=32,
+                        prefill_chunk=4, registry=reg)
+    eng.submit(Request(rng.integers(0, 128, 11), 2))
+    eng.run()
+    assert reg.counter("bf_serving_prefill_chunks_total", "").value == 3
+    assert name not in reg.snapshot()
 
 
 def test_the_expert_rows_counters_of_a_served_run():
