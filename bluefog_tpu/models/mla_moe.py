@@ -1,12 +1,22 @@
-"""A decoder with LATENT attention and softmax-routed experts beside a
-shared one (``model_type: mistral4``; the layer's keys are
-DeepSeek-V3's and are read that way).
+"""A decoder with LATENT attention and routed experts beside a shared
+one (the layer's keys are DeepSeek-V3's and are read that way).  Two
+published models are computed here, and the config's fields select each
+path, nothing else does:
+
+* ``model_type: mistral4`` (Mistral-Small-4-119B-2603's language model):
+  ``score_func="softmax"`` (no bias), a plain residual (``hc_mult`` 1),
+  no dense layer (``n_dense_layers`` 0), ``query_scale_beta`` 0.1.
+* ``model_type: xing4_0`` (Xing4.0-29B-A4B): ``score_func="sigmoid"``
+  (a bias that selects only), ``n_dense_layers`` leading layers whose
+  feed-forward is a SwiGLU of ``dense_hidden_dim``, and a residual of
+  ``hc_mult`` = 4 streams mixed a token at a time around every sublayer
+  (``models/hyper_connections.py``); no position scale on the query.
 
 Imported lazily (nothing on ``import bluefog_tpu``'s path names it); it
 reuses ``RMSNorm`` of ``models/llama.py`` and the expert layer of
-``models/experts.py`` (``score_func`` "softmax": no bias), and is served
-by the same ``ServingEngine`` through the protocol of
-``serving/protocol.py``, which :class:`MlaMoeConfig` implements.
+``models/experts.py``, and is served by the same ``ServingEngine``
+through the protocol of ``serving/protocol.py``, which
+:class:`MlaMoeConfig` implements.
 
 One layer on the residual stream ``h`` (``h0 = E[tok]``), ``H`` heads,
 position ``p``::
@@ -21,8 +31,19 @@ position ``p``::
     o = softmax(s [q_n ; q_r] [k_n ; k_r]^T, j <= i) v
     h = h + [o_1 .. o_H] W_o
     m = norm2(h);  h = h + shared(m) + sum_{e in top_k, e held} w_e expert_e(m)
+                   (a leading dense layer: h = h + W2(silu(W1 m) * W3 m))
 
 with ``s = (dn + dr)^-1/2 * yarn_mscale(factor, mscale_all_dim)^2``.
+With ``hc_mult`` = n > 1 the residual is n streams ``X`` (all of them
+``E[tok]`` at the entry, summed before the final norm), and each of the
+two sublayers ``F`` above (its norm included) reads and writes them
+through the token's own coefficients (``hyper_connections.hc_pre`` /
+``hc_post``)::
+
+    y = F(sum_i H_pre[i] X[i]);  X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y
+
+The streams live and die inside one call: the cache, and so the serving
+layer, never sees one.
 
 What a position leaves in the cache is ``[c ; k_r]``: ``dc + dr``
 values, 640 bytes in bfloat16 at the published widths where the
@@ -57,7 +78,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from bluefog_tpu.models.experts import ExpertLayer, _dense
+from bluefog_tpu.models import hyper_connections as hc
+from bluefog_tpu.models.experts import ExpertLayer, SwiGLU, _dense
 from bluefog_tpu.models.llama import RMSNorm
 
 __all__ = ["MlaMoeConfig", "MlaMoe", "yarn_frequencies", "yarn_mscale",
@@ -83,7 +105,15 @@ class MlaMoeConfig:
     n_experts: int = 16              # the router's outputs
     top_k: int = 4
     route_scale: float = 1.0
-    score_func = "softmax"           # the expert layer's (models/experts.py)
+    score_func: str = "softmax"      # the expert layer's (models/experts.py)
+    n_dense_layers: int = 0          # leading layers with a dense FFN
+    dense_hidden_dim: int = 128
+    # the residual path: streams (1: the plain ``h + f(norm(h))``), and
+    # how their mixing matrix is made (models/hyper_connections.py)
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
     # (first, count) of the experts this layer holds; None: all of them
     experts_held: Optional[Tuple[int, int]] = None
     # rope_parameters (rope_type yarn)
@@ -112,6 +142,10 @@ class MlaMoeConfig:
         if first < 0 or count < 1 or first + count > self.n_experts:
             raise ValueError(f"experts_held {self.experts_held} lies "
                              f"outside the {self.n_experts} experts")
+        if self.hc_mult < 1 or not 0 <= self.n_dense_layers <= self.n_layers:
+            raise ValueError(
+                f"hc_mult {self.hc_mult} must be at least 1 and "
+                f"n_dense_layers {self.n_dense_layers} at most n_layers")
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -127,6 +161,16 @@ class MlaMoeConfig:
         m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
         return m * m / math.sqrt(self.qk_nope_head_dim
                                  + self.qk_rope_head_dim)
+
+    @property
+    def mixed_sublayers(self) -> int:
+        """Sublayers a token's streams are mixed around (optional in the
+        serving protocol: 0 for a plain residual, which counts none)."""
+        return 2 * self.n_layers if self.hc_mult > 1 else 0
+
+    @property
+    def residual_streams(self) -> int:
+        return self.hc_mult
 
     # -- the serving protocol (serving/protocol.py) -------------------- #
     def serving_layout(self, max_len: int, *, chunk: int = 1,
@@ -372,14 +416,35 @@ class LatentAttention(nn.Module):
 
 class Block(nn.Module):
     cfg: MlaMoeConfig
+    index: int = 0
 
     @nn.compact
     def __call__(self, x, live=None):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.norm_eps, name=name)
-        x = x + LatentAttention(cfg, name="attention")(
-            norm("attention_norm")(x))
-        return x + ExpertLayer(cfg, name="moe")(norm("ffn_norm")(x), live)
+
+        def attend(a):
+            return LatentAttention(cfg, name="attention")(a)
+
+        def feed(m):
+            if self.index < cfg.n_dense_layers:
+                return SwiGLU(cfg, cfg.dense_hidden_dim,
+                              name="feed_forward")(m)
+            return ExpertLayer(cfg, name="moe")(m, live)
+
+        if cfg.hc_mult == 1:
+            x = x + attend(norm("attention_norm")(x))
+            return x + feed(norm("ffn_norm")(x))
+        # x: the token's streams, flat [B, T, hc_mult * dim]
+        mixing = dict(n=cfg.hc_mult, iters=cfg.hc_sinkhorn_iters,
+                      eps=cfg.hc_eps, clamp=cfg.hc_res_clamp,
+                      norm_eps=cfg.norm_eps)
+        for sublayer, name in ((attend, "attention"), (feed, "ffn")):
+            y_in, h_post, h_res = hc.hc_pre(
+                x, hc.Mixing(cfg, name=f"{name}_hc")(), **mixing)
+            x = hc.hc_post(x, sublayer(norm(f"{name}_norm")(y_in)), h_post,
+                           h_res, n=cfg.hc_mult)
+        return x
 
 
 class MlaMoe(nn.Module):
@@ -395,8 +460,12 @@ class MlaMoe(nn.Module):
                      param_dtype=jnp.float32, name="tok_embeddings",
                      embedding_init=nn.initializers.normal(
                          cfg.initializer_range))(tokens)
+        if cfg.hc_mult > 1:
+            x = hc.expand(x, cfg.hc_mult)
         for i in range(cfg.n_layers):
-            x = Block(cfg, name=f"layer_{i}")(x, live)
+            x = Block(cfg, i, name=f"layer_{i}")(x, live)
+        if cfg.hc_mult > 1:
+            x = hc.collapse(x, cfg.hc_mult)
         x = RMSNorm(cfg.norm_eps, name="norm")(x)
         if cfg.decode and not all_logits:
             x = x[:, -1:]

@@ -519,6 +519,8 @@ class ServingEngine:
         # and what it reads to attend, by kind of leaf
         self._chunk_streamed = getattr(self.cfg, "chunk_streamed_positions",
                                        None)
+        # and around how many sublayers a token's residual streams are mixed
+        self._mixed_sublayers = getattr(self.cfg, "mixed_sublayers", 0)
         self._spec = speculative
         self._draft_pool: Optional[SlotPool] = None
         self._draft_params = None
@@ -543,6 +545,8 @@ class ServingEngine:
         self.scheduler = FifoScheduler(max_queue=max_queue)
         self.metrics = ServingMetrics(registry=registry)
         self.metrics.on_pool(self.pool.cache_bytes())
+        if self._mixed_sublayers:
+            self.metrics.on_residual_streams(self.cfg.residual_streams)
         self.prefill_chunk = prefill_chunk
         self.decode_horizon = decode_horizon
         self.prefill_budget = prefill_budget
@@ -939,7 +943,8 @@ class ServingEngine:
                     cfg=self.draft_cfg)
             self.metrics.on_prefill_chunk(
                 int(valid), self._rebuilt(pos, c) if self._rebuilt else 0,
-                self._chunk_streamed(pos, c) if self._chunk_streamed else ())
+                self._chunk_streamed(pos, c) if self._chunk_streamed else (),
+                mixed=int(valid) * self._mixed_sublayers)
             if (valid == c and req._prefix_keys
                     and pos // c < len(req._prefix_keys)):
                 # a FULL cold chunk just landed on the chunk grid —
@@ -1033,7 +1038,9 @@ class ServingEngine:
                     self.cfg.held)
                 self.metrics.on_expert_rows(stats.get("stat_expert_rows", ()))
         self._emit(decoding, lambda slot: hist[:, slot])
-        self.metrics.on_decode_step(len(decoding), attended, streamed)
+        self.metrics.on_decode_step(
+            len(decoding), attended, streamed,
+            mixed=len(decoding) * self._mixed_sublayers)
 
     def _spec_decode_step(self, decoding: Dict[int, Request]) -> None:
         """The speculative twin of :meth:`_decode_step`: one resident

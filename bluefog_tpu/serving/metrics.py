@@ -213,7 +213,7 @@ class ServingMetrics:
                         "requests handed off to another replica").inc()
 
     def on_prefill_chunk(self, n_tokens: int, rebuilt: int = 0,
-                         streamed=()):
+                         streamed=(), mixed: int = 0):
         """One cold prefill chunk ran (a model forward over one chunk)
         with ``n_tokens`` valid positions; the rest of the chunk's
         width was padding.  Together with :meth:`on_prefix_restore`
@@ -225,7 +225,8 @@ class ServingMetrics:
         stands).  ``streamed``: ``((kind, rows), ...)``, the cache rows
         the chunk read to attend, summed over the kind's layers (the
         model's ``chunk_streamed_positions``, from the same lengths;
-        empty for a model that declares none)."""
+        empty for a model that declares none).  ``mixed``: see
+        :meth:`on_mixed_tokens`."""
         self.n_prefill_chunks += 1
         reg = self._reg()
         if reg is not None:
@@ -245,8 +246,10 @@ class ServingMetrics:
                     "bf_serving_chunk_streamed_positions_total",
                     "cache rows prefill chunks read to attend, summed "
                     "over the layers of the kind", kind=kind).inc(rows)
+        self.on_mixed_tokens(mixed)
 
-    def on_decode_step(self, n_slots: int, attended=(), streamed=()):
+    def on_decode_step(self, n_slots: int, attended=(), streamed=(),
+                       mixed: int = 0):
         """One decode program call (plain or speculative) advanced
         ``n_slots`` active slots: slots / steps is the batch size a
         decode step.  ``attended``: ``((kind, positions), ...)``, the
@@ -255,7 +258,8 @@ class ServingMetrics:
         holds; ``streamed``: the positions the program fetched to
         attend them (``ServedModel.streamed_positions``, every slot of
         the pool), from the same lengths.  With ``decode_horizon`` > 1
-        both are the call's first token step."""
+        both are the call's first token step.  ``mixed``: see
+        :meth:`on_mixed_tokens`."""
         reg = self._reg()
         if reg is not None:
             reg.counter("bf_serving_decode_steps_total",
@@ -275,6 +279,28 @@ class ServingMetrics:
                     "cache positions decode steps fetched, summed over "
                     "every slot of the pool and the layers of the kind",
                     kind=kind).inc(positions)
+        self.on_mixed_tokens(mixed)
+
+    def on_mixed_tokens(self, mixed: int):
+        """A call mixed ``mixed`` = live tokens x sublayers of residual
+        streams (a model that declares ``mixed_sublayers``: the valid
+        tokens of a chunk, the slots a step decodes; padding is mixed
+        on the device too and not counted).  0: no counter."""
+        reg = self._reg()
+        if reg is not None and mixed:
+            reg.counter(
+                "bf_hc_mixed_tokens_total",
+                "live tokens times the sublayers their residual streams "
+                "were mixed around (hyper-connections), over prefill "
+                "chunks and decode steps").inc(mixed)
+
+    def on_residual_streams(self, streams: int):
+        """The served model's residual path has ``streams`` streams."""
+        reg = self._reg()
+        if reg is not None:
+            reg.gauge("bf_hc_streams",
+                      "streams of the served model's residual path "
+                      "(hc_mult)").set(streams)
 
     def on_pool(self, cache_bytes: dict):
         """The slot pool was built: ``{"full" | "window": bytes}`` it

@@ -43,6 +43,14 @@ MAY declare ``chunk_streamed_positions(start, tokens) -> ((kind, rows),
 kind's layers, from the same two lengths; the engine counts them a
 prefill chunk (``bf_serving_chunk_streamed_positions_total{kind}``).  A
 model that declares neither counts neither.
+
+A model whose residual path is several streams, mixed a token at a time
+around its sublayers, MAY declare ``residual_streams`` (how many) and
+``mixed_sublayers`` (around how many sublayers a token is mixed; 0 for a
+plain residual); the engine sets ``bf_hc_streams`` and counts the live
+tokens of every chunk and decode step times the sublayers
+(``bf_hc_mixed_tokens_total``).  The streams themselves live and die
+inside one ``apply_cached``: no leaf holds one.
 """
 
 from __future__ import annotations

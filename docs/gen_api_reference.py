@@ -72,8 +72,12 @@ MODULES = [
      "the expert layer of both expert models: float32 routing (sigmoid "
      "with a bias, or softmax), a shared expert, the held experts in tiles"),
     ("bluefog_tpu.models.mla_moe",
-     "decoder of latent attention (a 640-byte-a-token cache, absorbed "
-     "decode), YaRN rotation and softmax-routed experts"),
+     "decoder of latent attention (a 640- or 1,152-byte-a-token cache, "
+     "absorbed decode), YaRN rotation and routed experts; a plain "
+     "residual or several mixed streams, leading dense layers"),
+    ("bluefog_tpu.models.hyper_connections",
+     "a residual of several streams mixed a token at a time "
+     "(manifold-constrained hyper-connections): hc_pre, hc_post"),
     ("bluefog_tpu.serving.protocol",
      "what the serving layer needs of a model (config methods, cache "
      "leaf kinds)"),

@@ -45,6 +45,11 @@ def bf_ctx():
 # * PR 30's two want the list to END with PR 30's three entries, and the
 #   latent cell's metrics to be the Trinity cell's less two and plus those
 #   three (PR 31 appended three entries of the Trinity cell alone).
+# * PR 23's test of the cells still to come writes the all-reduce cell's
+#   ``exchanges/allreduce_gradients.py`` into a copy of the benchmark as a
+#   NEW file (``open(..., "x")``); PR 32 brought the cell, so the file is
+#   there and the case for it cannot create it (its other two cases stand;
+#   ``test_perfbench_mhc.py`` holds the cell that arrived).
 # The form an append keeps, a PREFIX from PR 29's first entry on, is
 # ``test_perfbench_chunk_attend.py::test_an_append_moves_nothing_of_the_entries_before_it``.
 _PINNED_AS_LAST = (
@@ -53,6 +58,8 @@ _PINNED_AS_LAST = (
     "test_an_append_moves_nothing_of_the_entries_before_it",
     "test_perfbench_mla_moe.py::"
     "test_the_new_cell_loads_with_its_files_and_metrics",
+    "test_perfbench_runners.py::"
+    "test_a_listed_later_cell_arrives_as_files_and_entries_only[allreduce]",
 )
 
 
@@ -60,7 +67,7 @@ def pytest_collection_modifyitems(items):
     for item in items:
         if any(pinned in item.nodeid for pinned in _PINNED_AS_LAST):
             item.add_marker(pytest.mark.xfail(
-                reason="pins the tail of per_layer as an earlier PR left "
-                       "it; the benchmark has grown since (PERF.md "
-                       "section 7)",
+                reason="pins the benchmark as an earlier PR left it (the "
+                       "tail of per_layer, a file not yet there); it has "
+                       "grown since (PERF.md section 7)",
                 strict=False))

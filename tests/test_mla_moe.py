@@ -420,3 +420,264 @@ def test_yarn_frequencies_and_query_scale_are_pinned():
     assert abs(cfg.softmax_scale - m * m / math.sqrt(128)) < 1e-9
     assert abs(REF.softmax_scale(FAMILY.sizes(published, "serve"))
                - cfg.softmax_scale) < 1e-9
+
+
+# ------------------------------------------------------------------ #
+# (h) four residual streams, a leading dense layer, sigmoid routing
+#     (model_type xing4_0)
+# ------------------------------------------------------------------ #
+HC_REF = loader.load_module(REPO, "references", "mhc_mla_moe_decoder")
+HC_FAMILY = loader.load_module(REPO, "families", "mhc_mla_moe_decoder")
+# one dense and two expert layers; phi at 0.05 gives xh phi a deviation of
+# 0.05 sqrt(256) = 0.8: the coefficients differ from token to token
+HC_SZ = {
+    "hidden_size": 64, "intermediate_size": 96, "moe_intermediate_size": 32,
+    "num_attention_heads": 4, "q_lora_rank": 24, "kv_lora_rank": 16,
+    "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 12,
+    "num_hidden_layers": 3, "first_k_dense_replace": 1, "vocab_size": 128,
+    "rms_norm_eps": 1e-6, "rope_theta": 10000, "moe_layer_freq": 1,
+    "rope_scaling": {"beta_fast": 4, "beta_slow": 0.25, "factor": 8,
+                     "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": ORIGINAL,
+                     "type": "yarn"},
+    "n_routed_experts": 16, "router_outputs": 16, "experts_held_from": 0,
+    "num_experts_per_tok": 4, "n_shared_experts": 1, "n_group": 1,
+    "topk_group": 1, "norm_topk_prob": True, "routed_scaling_factor": 2,
+    "scoring_func": "sigmoid", "hc_mult": 4, "hc_sinkhorn_iters": 20,
+    "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30,
+    "initializer_range": 0.2, "router_bias_std": 0.01, "hc_phi_std": 0.05,
+    "hc_alpha": 1.0, "compute_dtype": "float32", "param_dtype": "float32",
+}
+
+
+def _hc_params(seed=0):
+    return jax.jit(lambda k: HC_FAMILY.make_params(
+        HC_SZ, k, jnp.float32)[0])(jax.random.PRNGKey(seed))
+
+
+_hc_reference = jax.jit(lambda p, t: HC_REF.logits(p, t, HC_SZ))
+
+
+def _chunks_then_steps(cfg, params, tokens, chunk, prefill):
+    """Logits ``[len(tokens), vocab]`` of ``tokens`` through the cache:
+    ``prefill`` of them in chunks of ``chunk``, the rest one token a
+    call (two programs, as the engine has)."""
+    cfg = cfg.serving_layout(72, chunk=chunk)
+    cache = cfg.init_cache(1, 72)
+    call = jax.jit(lambda p, c, t: cfg.apply_cached(p, c, t,
+                                                    all_logits=True))
+    out, at = [], 0
+    while at < tokens.size:
+        width = chunk if at < prefill else 1
+        logits, cache = call(params, cache,
+                             jnp.asarray(tokens[None, at:at + width]))
+        out.append(np.asarray(logits[0]))
+        at += width
+    return np.concatenate(out)
+
+
+def _hc_gap(cfg, params, tokens):
+    """The widest gap between the logits through the cache (chunks of 6
+    up to position 36, then steps) and the reference's full forward
+    pass, in deviations of the reference's logits."""
+    got = _chunks_then_steps(cfg, params, tokens, 6, 36)
+    want = np.asarray(_hc_reference(params, jnp.asarray(tokens)))
+    assert got.shape == want.shape == (tokens.size, HC_SZ["vocab_size"])
+    return np.abs(got - want).max() / want.std()
+
+
+def test_four_streams_through_the_cache_match_the_reference(monkeypatch):
+    """Prefill in chunks, then decode through the cache, against the
+    reference's full forward pass: logits, not tokens.  The same
+    comparison FAILS with the mixing left out (H_res = I, H_pre = 1/n,
+    H_post = 1) and with 2 Sinkhorn turns in place of 20."""
+    params = _hc_params()
+    tokens = np.random.default_rng(8).integers(0, HC_SZ["vocab_size"], 52)
+    cfg = HC_FAMILY.model_config(HC_SZ, key_block=8)
+    assert (cfg.hc_mult, cfg.n_dense_layers, cfg.score_func) \
+        == (4, 1, "sigmoid")
+    assert _hc_gap(cfg, params, tokens) < TOL
+    # two turns of Sinkhorn in the program, twenty in the reference
+    two = dataclasses.replace(cfg, hc_sinkhorn_iters=2)
+    assert _hc_gap(two, params, tokens) > 50 * TOL
+    # no mixing at all: a plain average in, the identity back
+    hc = mla_moe.hc
+
+    def unmixed(x, p, *, n, **kw):
+        y_in = sum(hc._stream(x, i, n) for i in range(n)) / n
+        eye = jnp.broadcast_to(jnp.eye(n).reshape(-1),
+                               x.shape[:-1] + (n * n,))
+        return y_in, jnp.ones(x.shape[:-1] + (n,)), eye
+
+    monkeypatch.setattr(hc, "hc_pre", unmixed)
+    assert _hc_gap(cfg, params, tokens) > 50 * TOL
+
+
+def test_every_part_of_the_four_stream_model_is_seen_by_the_tolerance():
+    """Spoil one thing in the REFERENCE's sizes or weights and the
+    program no longer agrees: the dense layer, the sigmoid, the bias
+    that selects, the route scale, the clamp."""
+    params = _hc_params(1)
+    tokens = np.random.default_rng(9).integers(0, HC_SZ["vocab_size"], 40)
+    model = mla_moe.MlaMoe(HC_FAMILY.model_config(HC_SZ))
+    program = jax.jit(lambda p, t: model.apply({"params": p}, t)[0])
+    got = np.asarray(program(params, tokens[None]))
+    want = np.asarray(_hc_reference(params, jnp.asarray(tokens)))
+    assert np.abs(got - want).max() < TOL * want.std()
+    biased = jax.tree.map(lambda x: x, params)
+    for i in (1, 2):
+        moe = biased[f"layer_{i}"]["moe"]
+        moe["router_bias"] = 30.0 * moe["router_bias"]
+    hot = jax.tree.map(lambda x: x, params)
+    for i in range(3):
+        for name in ("attention_hc", "ffn_hc"):
+            mix = hot[f"layer_{i}"][name]
+            mix["alpha"] = mix["alpha"].at[2].set(40.0)
+    spoiled = {
+        "route scale": (dict(HC_SZ, routed_scaling_factor=1), params),
+        "bias": (HC_SZ, biased),
+        "clamp": (dict(HC_SZ, mhc_h_res_clamp_max=3), hot),
+        "eps": (dict(HC_SZ, hc_eps=1e-2), params),
+    }
+    for name, (sz, p) in spoiled.items():
+        other = np.asarray(jax.jit(lambda p, t: HC_REF.logits(p, t, sz))(
+            p, jnp.asarray(tokens)))
+        assert np.abs(got - other).max() > 50 * TOL * other.std(), name
+    # and a program told the same (the clamp, the bias) agrees again
+    for p in (hot, biased):
+        again = np.asarray(program(p, tokens[None]))
+        want = np.asarray(_hc_reference(p, jnp.asarray(tokens)))
+        assert np.abs(again - want).max() < TOL * want.std()
+
+
+def test_one_stream_and_no_dense_layer_is_the_jaxpr_of_before():
+    """``hc_mult=1, n_dense_layers=0`` (the defaults) trace to the
+    program the module built before it knew streams: the parent's
+    ``Block`` and ``MlaMoe``, transcribed here, give the same jaxpr for
+    the training layout, a chunk and a single-token step."""
+    import flax.linen as nn
+
+    from bluefog_tpu.models.llama import RMSNorm
+
+    class Block(nn.Module):
+        cfg: mla_moe.MlaMoeConfig
+
+        @nn.compact
+        def __call__(self, x, live=None):
+            cfg = self.cfg
+            norm = lambda name: RMSNorm(cfg.norm_eps, name=name)
+            x = x + mla_moe.LatentAttention(cfg, name="attention")(
+                norm("attention_norm")(x))
+            return x + experts.ExpertLayer(cfg, name="moe")(
+                norm("ffn_norm")(x), live)
+
+    class Before(nn.Module):
+        cfg: mla_moe.MlaMoeConfig
+
+        @nn.compact
+        def __call__(self, tokens, all_logits=False, live=None):
+            cfg = self.cfg
+            x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
+                         param_dtype=jnp.float32, name="tok_embeddings",
+                         embedding_init=nn.initializers.normal(
+                             cfg.initializer_range))(tokens)
+            for i in range(cfg.n_layers):
+                x = Block(cfg, name=f"layer_{i}")(x, live)
+            x = RMSNorm(cfg.norm_eps, name="norm")(x)
+            if cfg.decode and not all_logits:
+                x = x[:, -1:]
+            w_out = self.param("output", nn.initializers.normal(
+                cfg.initializer_range), (cfg.dim, cfg.vocab_size),
+                jnp.float32)
+            return jnp.einsum("btd,dv->btv", x, w_out.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
+
+    params = _params()
+    cfg = FAMILY.model_config(SZ, dtype=jnp.bfloat16, key_block=8)
+    assert (cfg.hc_mult, cfg.n_dense_layers, cfg.score_func) \
+        == (1, 0, "softmax")
+    tokens = jnp.zeros((1, 16), jnp.int32)
+    text = lambda model: str(jax.make_jaxpr(
+        lambda p, t: model.apply({"params": p}, t))(params, tokens))
+    assert text(mla_moe.MlaMoe(cfg)) == text(Before(cfg))
+    served = cfg.serving_layout(72)
+    cache = served.init_cache(1, 72)
+    for width in (8, 1):
+        step = lambda model: str(jax.make_jaxpr(
+            lambda p, c, t: model.apply(
+                {"params": p, "cache": c}, t, mutable=["cache"]))(
+                    params, cache, tokens[:, :width]))
+        assert step(mla_moe.MlaMoe(served)) == step(Before(served))
+
+
+def test_score_func_is_a_field_of_the_config():
+    cfg = mla_moe.MlaMoeConfig(score_func="sigmoid")
+    assert cfg.score_func == "sigmoid"
+    assert "score_func" in {f.name for f in dataclasses.fields(cfg)}
+    assert mla_moe.MlaMoeConfig().score_func == "softmax"
+    with pytest.raises(ValueError):
+        mla_moe.MlaMoeConfig(hc_mult=0)
+    with pytest.raises(ValueError):
+        mla_moe.MlaMoeConfig(n_layers=2, n_dense_layers=3)
+    # the sigmoid path of the shared expert layer is reached: a bias
+    layer = experts.ExpertLayer(cfg)
+    shapes = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 3, cfg.dim), cfg.dtype)))
+    assert shapes["params"]["router_bias"].shape == (cfg.n_experts,)
+
+
+def test_the_engine_serves_four_streams_with_a_slot_freed_and_reused():
+    """Four requests through two slots; the served tokens are the
+    reference's greedy ones; the pool holds latent leaves and no stream;
+    the two counters read what the host can count by hand."""
+    from bluefog_tpu.observe.registry import MetricsRegistry
+    from bluefog_tpu.serving import protocol
+
+    params = _hc_params(2)
+    rng = np.random.default_rng(10)
+    lengths, budgets = (27, 9, 33, 5), (6, 9, 4, 12)
+    reg = MetricsRegistry()
+    eng = ServingEngine({"params": params},
+                        HC_FAMILY.model_config(HC_SZ, key_block=8),
+                        capacity=2, max_len=72, prefill_chunk=4,
+                        registry=reg)
+    reqs = [eng.submit(Request(rng.integers(0, 128, n), b))
+            for n, b in zip(lengths, budgets)]
+    eng.run()
+    assert all(r.state == "completed" for r in reqs)
+    for r in reqs:
+        # causal: zeros behind the sequence change no row before them,
+        # and one length is one compile of the reference
+        seq = np.zeros((48,), np.int32)
+        seq[:r.output().size - 1] = r.output()[:-1]
+        want = np.asarray(_hc_reference(params, jnp.asarray(seq)))[
+            :r.output().size - 1]
+        p, g = r.prompt.size, len(r.tokens)
+        rows = want[p - 1:p - 1 + g]
+        gap = rows.max(-1) - rows[np.arange(g), np.asarray(r.tokens)]
+        assert gap.max() < TOL * want.std(), (p, g, gap.max())
+    # nothing in the pool knows a stream: the other latent model's leaves
+    cfg = eng.cfg
+    assert cfg.cache_kinds() == {"full": (3, None)}
+    assert cfg.latent_width == 24
+    assert cfg.streamed_positions([3, -1]) == (("full", 3 * 2 * 72),)
+    assert cfg.rebuilt_positions(8, 4) == 3 * 16
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            eng.pool.cache)[0]:
+        if protocol.leaf_kind(path) == protocol.FULL:
+            assert leaf.shape == (2, 1, 72, 24)
+    # every prompt token but the last is prefilled, every served token
+    # but a request's first comes from a decode step; six sublayers
+    mixed = sum(n - 1 for n in lengths) + sum(budgets)
+    assert (cfg.mixed_sublayers, cfg.residual_streams) == (6, 4)
+    assert reg.counter("bf_hc_mixed_tokens_total", "").value == 6 * mixed
+    assert reg.gauge("bf_hc_streams", "").value == 4
+    # a plain residual counts neither
+    plain = MetricsRegistry()
+    eng = ServingEngine({"params": _params()}, FAMILY.model_config(SZ),
+                        capacity=1, max_len=72, prefill_chunk=4,
+                        registry=plain)
+    eng.submit(Request(rng.integers(0, 128, 6), 2))
+    eng.run()
+    assert not any(name.startswith("bf_hc_")
+                   for name, *_ in plain.collect())
