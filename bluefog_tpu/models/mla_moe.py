@@ -56,7 +56,13 @@ equal in exact arithmetic, and the call's length says which:
   directly, ``score_j = s ([qa_h ; q_r,h] . [c_j ; k_r,j])``, the
   weighted latent ``u_h = sum_j p_j c_j`` goes through ``W_uv,h``.
   Nothing is expanded: every head reads the one ``dc + dr`` row a
-  position holds, over every reserved row behind a mask.
+  position holds.  Which rows are read is the layout's ``decode_attn``:
+  the einsums (``"xla"``: what a CPU and the tests run, and the
+  kernel's reference) read every reserved row behind a mask; the kernel
+  of ``parallel/pallas_decode.py`` (``"pallas"``: what ``"auto"``
+  resolves to on a TPU) fetches the blocks at or before the slot's
+  position, once for both contractions, and nothing for a slot that
+  does not decode.
 * EXPANDED, a call of several tokens (a prefill chunk, the training
   layout): ``[k_n ; v] = c W_ukv`` is rebuilt for the cached rows and
   attention is the plain one.  It walks the cache in blocks of
@@ -134,6 +140,10 @@ class MlaMoeConfig:
     # the serving layout (``serving_layout``)
     decode: bool = False
     max_seq_len: int = 2048
+    # how a single-token step reads the cached latent: "xla" (einsums
+    # over every reserved row) or "pallas" (parallel/pallas_decode.py:
+    # the blocks at or before the position); ``serving_layout`` chooses
+    decode_attn: str = "xla"
 
     def __post_init__(self):
         if self.qk_rope_head_dim % 2:
@@ -146,6 +156,9 @@ class MlaMoeConfig:
             raise ValueError(
                 f"hc_mult {self.hc_mult} must be at least 1 and "
                 f"n_dense_layers {self.n_dense_layers} at most n_layers")
+        if self.decode_attn not in ("xla", "pallas"):
+            raise ValueError(f"decode_attn {self.decode_attn!r} not in "
+                             "('xla', 'pallas')")
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -182,10 +195,19 @@ class MlaMoeConfig:
                 "the latent-attention model serves full-precision weights "
                 f"and caches only (kv_quant={kv_quant!r}, weight_quant="
                 f"{weight_quant!r})")
-        # no fused decode kernel reads a latent: "auto" is the XLA lowering
-        if decode_attn not in ("xla", "auto"):
-            raise NotImplementedError(f"decode_attn={decode_attn!r}")
-        return dataclasses.replace(self, decode=True, max_seq_len=max_len)
+        if decode_attn == "auto":
+            # as ``generate.decode_config`` decides for the dense model,
+            # from what is known when the program is traced: the kernel
+            # on a real TPU (off one it is interpreted) where it reads
+            # the leaf as the chip lays it out; how much of the cache is
+            # live is the kernel's business at run time
+            from bluefog_tpu.parallel.pallas_decode import latent_tileable
+
+            decode_attn = ("pallas" if jax.default_backend() == "tpu"
+                           and latent_tileable(max_len, self.latent_width)
+                           else "xla")
+        return dataclasses.replace(self, decode=True, max_seq_len=max_len,
+                                   decode_attn=decode_attn)
 
     def init_cache(self, batch_size: int, max_len: int):
         """Zero caches of ``batch_size`` sequences, from shapes alone."""
@@ -212,10 +234,15 @@ class MlaMoeConfig:
         return {"full": (self.n_layers, None)}
 
     def streamed_positions(self, positions) -> tuple:
-        """The single-token step reads every reserved row of every
-        layer's leaf behind its mask, whatever is live."""
-        return (("full", self.n_layers * len(positions)
-                 * self.max_seq_len),)
+        """Rows of the latent a single-token step fetches, summed over
+        layers: the blocks the kernel's plan names, or every reserved
+        row of every slot under the einsums, whatever is live."""
+        from bluefog_tpu.parallel import pallas_decode
+
+        return (("full", self.n_layers * pallas_decode.streamed_positions(
+            positions, self.max_seq_len,
+            fused=self.decode_attn == "pallas",
+            block_s=pallas_decode.latent_block(self.max_seq_len))),)
 
     def rebuilt_positions(self, start: int, tokens: int) -> int:
         """Cached positions whose keys and values a call of ``tokens``
@@ -287,22 +314,33 @@ def _divisor(s: int, most: int) -> int:
     return block
 
 
-def absorbed_step(q_n, q_r, latent, pos, w_ukv, dc: int, dn: int):
-    """One query a sequence over every row of its cache, absorbed.  q_n
-    ``[B, 1, H, dn]`` and q_r ``[B, 1, H, dr]`` (scaled); latent ``[B,
-    S, dc + dr]``; pos ``[1]``; w_ukv ``[dc, H, dn + dv]``.  Rows above
-    ``pos`` hold positions no query has reached.  ``[B, 1, H, dv]``."""
+def absorbed_step(q_n, q_r, latent, pos, w_ukv, dc: int, dn: int,
+                  live=None, fused: bool = False):
+    """One query a sequence over its cache, absorbed.  q_n ``[B, 1, H,
+    dn]`` and q_r ``[B, 1, H, dr]`` (scaled); latent ``[B, S, dc +
+    dr]``; pos ``[1]``; w_ukv ``[dc, H, dn + dv]``.  Rows above ``pos``
+    hold positions no query has reached: the einsums read every row
+    behind a mask; ``fused`` hands the weighted latent to the kernel of
+    ``parallel/pallas_decode.py``, which fetches the blocks at or before
+    ``pos`` and none for a sequence whose ``live [B]`` is False (its
+    output is zeros: nobody reads it).  ``[B, 1, H, dv]``."""
     dtype = latent.dtype
     # [q_n W_uk^T ; q_r]: a head's query against the latent's own columns
     qa = jnp.einsum("bthn,chn->bthc", q_n, w_ukv[..., :dn],
                     preferred_element_type=jnp.float32)
     qcat = jnp.concatenate([qa.astype(dtype), q_r], axis=-1)
-    scores = jnp.einsum("bthc,bsc->bhts", qcat, latent,
-                        preferred_element_type=jnp.float32)
-    seen = jnp.arange(latent.shape[1])[None, :] <= pos[:, None]
-    p = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30), axis=-1)
-    u = jnp.einsum("bhts,bsc->bthc", p.astype(dtype), latent,
-                   preferred_element_type=jnp.float32)[..., :dc]
+    if fused:
+        from bluefog_tpu.parallel.pallas_decode import latent_decode_attention
+
+        u = latent_decode_attention(qcat, latent, pos[0], dc=dc, live=live)
+    else:
+        scores = jnp.einsum("bthc,bsc->bhts", qcat, latent,
+                            preferred_element_type=jnp.float32)
+        seen = jnp.arange(latent.shape[1])[None, :] <= pos[:, None]
+        p = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30),
+                           axis=-1)
+        u = jnp.einsum("bhts,bsc->bthc", p.astype(dtype), latent,
+                       preferred_element_type=jnp.float32)[..., :dc]
     return jnp.einsum("bthc,chv->bthv", u.astype(dtype), w_ukv[..., dn:],
                       preferred_element_type=jnp.float32)
 
@@ -358,7 +396,9 @@ class LatentAttention(nn.Module):
     cfg: MlaMoeConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, live=None):
+        """``live [B, T]``: False where a token is padding (its output
+        is never read); only the fused single-token step looks at it."""
         cfg = self.cfg
         b, t, _ = x.shape
         h, dc = cfg.n_heads, cfg.kv_lora_rank
@@ -403,7 +443,10 @@ class LatentAttention(nn.Module):
                 cl.value, ci.value = latent, idx + t
             if cfg.decode and t == 1:
                 with jax.named_scope(SCOPE_ATTN_ABSORB):
-                    out = absorbed_step(q_n, q_r, latent, pos, w_ukv, dc, dn)
+                    out = absorbed_step(
+                        q_n, q_r, latent, pos, w_ukv, dc, dn,
+                        live=None if live is None else live[:, 0],
+                        fused=cfg.decode_attn == "pallas")
             else:
                 # the training layout's cache is the call: every block
                 kb = _divisor(latent.shape[1], cfg.key_block)
@@ -424,7 +467,7 @@ class Block(nn.Module):
         norm = lambda name: RMSNorm(cfg.norm_eps, name=name)
 
         def attend(a):
-            return LatentAttention(cfg, name="attention")(a)
+            return LatentAttention(cfg, name="attention")(a, live)
 
         def feed(m):
             if self.index < cfg.n_dense_layers:

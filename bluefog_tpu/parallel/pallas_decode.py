@@ -40,6 +40,35 @@ in, a grid step that is skipped 0.35 us, hence blocks of 512.
 ``generate.decode_config`` takes the kernel for a full-precision cache
 on that evidence; the int8 variant shares the body and was not measured
 again.
+
+The LATENT variant (PR 33, ``latent_decode_attention``) serves the
+absorbed single-token step of ``models/mla_moe.py``: a position's cached
+row ``[c ; k_r]`` is ONE "KV head" that every query head scores whole
+and whose first ``dc`` values are the head's values, so one cache
+operand feeds both contractions and is fetched once (the einsums read
+the pool twice).  It shares the plan, the index map, the block fit, the
+host's count and the ``vmap`` fold with the dense kernel and has a body
+of its own.  The leaf is read AS THE CHIP LAYS IT OUT: a width that is
+no multiple of the 128 lanes (320 and 576 at the published widths) lies
+position-minor, so the kernel streams ``[dc + dr, block_s]`` tiles of
+the transposed view and no copy of the pool is made on the way in (a
+row-major kernel compiled too, behind a transposed copy of every leaf
+in and out: 0.39 GiB of temporaries a layer at 32 x 16,384 x 320);
+``latent_tileable`` says where that holds.  Readings on a v5e, a
+step's five calls inside one program, ms (PERF.md section 6, PR 33):
+32 rows x 16,384 x 320 in bf16 with 5 rows live at 700-9,900, blocks of
+512 / 1,024 / 2,048 / 4,096: 0.65 / 0.41 / 0.32 / 0.35 against the
+einsums' 4.58; every row full 4.32 / 2.84 / 2.29 / 2.28 against 4.58
+(the dense kernel lost that row to XLA; this one reads half the bytes);
+64 x 4,096 x 576 with 23 rows live at 40-1,400: 0.56 / 0.55 / 0.75 /
+1.15 against 4.12, full 2.68 / 2.15 / 2.09 / 2.08.  A grid step that is
+skipped costs 0.07 us, a call 16 us before its first step, a block that
+computes 0.85 / 1.11 / 1.79 us at 512 / 1,024 / 2,048 rows of 640 bytes
+and 1.05 / 1.68 / 3.26 at as many of 1,152 (700-730 GB/s from 1.2 MB a
+block up): hence ``latent_block``, a quarter of the cache between 512
+and 2,048 rows.  Probabilities rounded to the cache's dtype before the
+value contraction, as the einsums round them, bought no time; they stay
+float32.
 """
 
 from __future__ import annotations
@@ -53,6 +82,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["decode_attention", "decode_attention_int8",
+           "latent_block", "latent_decode_attention", "latent_tileable",
            "streamed_positions", "tileable"]
 
 _NEG_INF = -1e30
@@ -103,6 +133,27 @@ def _named_block(bk, sj, plan):
     """The K/V index map: the block grid step ``(bk, sj)`` names."""
     return plan[_SRC, bk], 0, jnp.clip(sj, plan[_FIRST, bk],
                                        plan[_LAST, bk]), 0
+
+
+def _row_plan(idx, live, b: int, s_len: int, want: int):
+    """``(block, plan)`` of a call over ``b`` rows of ``s_len``
+    positions: the block that fits under ``want`` and ``_stream_plan``
+    over ``idx`` and ``live``, each one scalar for every row or ``[b]``
+    per row."""
+    block = _fit_block(s_len, want)
+    if block < 8 and s_len >= 8:
+        # no viable tiling (e.g. a prime cache length > the wanted
+        # block): a 1-position block would run one grid step per cache
+        # position — refuse loudly instead of being silently 100x slow
+        raise ValueError(
+            f"cache length {s_len} has no block divisor in [8, "
+            f"{min(want, s_len)}]; pad max_len to a multiple of 8 or "
+            "use decode_attn='xla'")
+    per_row = lambda x, dtype: jnp.broadcast_to(
+        jnp.asarray(x, dtype).reshape(-1), (b,))
+    return block, _stream_plan(
+        jnp.clip(per_row(idx, jnp.int32), 0, s_len - 1),
+        per_row(live, bool), block)
 
 
 def streamed_positions(positions, s_len: int, *, fused: bool = True,
@@ -234,21 +285,8 @@ def _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, live, *, block_s,
     assert t == 1, "the fused decode kernel serves single-token steps"
     n_kv, s_len = k_all.shape[1], k_all.shape[2]
     quantized = ks_all is not None
-    block = _fit_block(s_len, block_s or _BLOCK_S)
-    if block < 8 and s_len >= 8:
-        # no viable tiling (e.g. a prime cache length > the wanted
-        # block): a 1-position block would run one grid step per cache
-        # position — refuse loudly instead of being silently 100x slow
-        raise ValueError(
-            f"cache length {s_len} has no block divisor in [8, "
-            f"{min(_BLOCK_S, s_len)}]; pad max_len to a multiple of 8 or "
-            "use decode_attn='xla'")
-
     q3 = q.reshape(b, n_q, d)  # kv-major head order matches the cache
-    per_row = lambda x, dtype: jnp.broadcast_to(
-        jnp.asarray(x, dtype).reshape(-1), (b,))
-    plan = _stream_plan(jnp.clip(per_row(idx, jnp.int32), 0, s_len - 1),
-                        per_row(live, bool), block)
+    block, plan = _row_plan(idx, live, b, s_len, block_s or _BLOCK_S)
 
     def row(bk, sj, plan_ref):
         return bk, 0, 0
@@ -283,21 +321,15 @@ def _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, live, *, block_s,
     return out.reshape(b, 1, n_q, d)
 
 
-@functools.lru_cache(maxsize=None)
-def _row_batched(block_s: Optional[int], interpret: bool):
-    """``_decode_impl`` as ``call(idx, live, q, k_all, v_all[, ks_all,
-    vs_all])`` with its own ``vmap`` rule: a mapped axis of independent
-    rows FOLDS into the kernel's batch grid axis, each row keeping its
-    own position.  The serving engine maps the decode step over its
-    cache slots; Pallas's generic batching would instead batch the SMEM
-    position operand into a squeezed ``[slots, 1]`` block, which the
-    TPU lowering refuses (SMEM blocks must span the whole array)."""
-
-    @jax.custom_batching.custom_vmap
-    def call(idx, live, q, k_all, v_all, *scales):
-        ks_all, vs_all = scales or (None, None)
-        return _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, live,
-                            block_s=block_s, interpret=interpret)
+def _row_batched(impl):
+    """``impl(idx, live, *arrays)`` with its own ``vmap`` rule: a mapped
+    axis of independent rows FOLDS into the kernel's batch grid axis,
+    each row keeping its own position.  The serving engine maps the
+    decode step over its cache slots; Pallas's generic batching would
+    instead batch the SMEM position operand into a squeezed ``[slots,
+    1]`` block, which the TPU lowering refuses (SMEM blocks must span
+    the whole array)."""
+    call = jax.custom_batching.custom_vmap(impl)
 
     @call.def_vmap
     def _fold(axis_size, in_batched, idx, live, *arrays):
@@ -324,6 +356,19 @@ def _row_batched(block_s: Optional[int], interpret: bool):
     return call
 
 
+@functools.lru_cache(maxsize=None)
+def _dense_call(block_s: Optional[int], interpret: bool):
+    """``_decode_impl`` as ``call(idx, live, q, k_all, v_all[, ks_all,
+    vs_all])`` under ``_row_batched``."""
+
+    def call(idx, live, q, k_all, v_all, *scales):
+        ks_all, vs_all = scales or (None, None)
+        return _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, live,
+                            block_s=block_s, interpret=interpret)
+
+    return _row_batched(call)
+
+
 def decode_attention(q, k_all, v_all, idx, *, live=None,
                      block_s: Optional[int] = None,
                      interpret: Optional[bool] = None):
@@ -336,7 +381,7 @@ def decode_attention(q, k_all, v_all, idx, *, live=None,
     decode-step case of ``models.llama._cached_attention`` (reference
     has no counterpart — decode itself is a new capability,
     docs/parity.md)."""
-    return _row_batched(block_s, _auto_interpret(interpret))(
+    return _dense_call(block_s, _auto_interpret(interpret))(
         idx, jnp.asarray(True if live is None else live), q, k_all, v_all)
 
 
@@ -351,6 +396,149 @@ def decode_attention_int8(q, kq_all, ks_all, vq_all, vs_all, idx, *,
     models/llama.py).  Replaces the decode-step case of both
     ``_cached_attention_int8`` (whose probability re-quantization cost
     it the long-context crown) and the dequant-then-attend path."""
-    return _row_batched(block_s, _auto_interpret(interpret))(
+    return _dense_call(block_s, _auto_interpret(interpret))(
         idx, jnp.asarray(True if live is None else live), q, kq_all,
         vq_all, ks_all, vs_all)
+
+
+# ------------------------------------------------------------------ #
+# the latent variant: one cached row serves every head, and both
+# contractions (models/mla_moe.py, the absorbed single-token step)
+# ------------------------------------------------------------------ #
+# Cache positions one grid step of the latent kernel streams: a quarter
+# of the cache, between the dense kernel's block and four of them (the
+# module docstring's readings: 2,048 of 16,384 and 1,024 of 4,096).
+_LATENT_BLOCKS = (_BLOCK_S, 4 * _BLOCK_S)
+
+
+def latent_block(s_len: int) -> int:
+    """The latent kernel's block for a cache of ``s_len`` positions (or
+    the largest divisor of the length under it): the kernel and the
+    host's count of what it streams both ask here."""
+    least, most = _LATENT_BLOCKS
+    return _fit_block(s_len, min(most, max(least, s_len // 4)))
+
+
+def latent_tileable(s_len: int, width: int) -> bool:
+    """Whether the latent kernel reads a leaf of ``s_len`` rows of
+    ``width`` values AS IT LIES on a TPU.  The chip lays a leaf whose
+    width is no multiple of its 128 lanes position-minor (no published
+    latent is one: 320, 576), and the kernel streams it that way, in
+    blocks of whole lanes; a leaf of whole lanes lies row-major, and the
+    kernel's view of it would cost a transposed copy of the pool a call
+    (sandbox, described v5e: 0.16 GiB of temporaries at 32 x 4,096 x
+    640)."""
+    return width % 128 != 0 and latent_block(s_len) % 128 == 0
+
+
+def _latent_kernel(plan_ref, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                   dc: int):
+    """Grid = (B, S blocks), the plan and the recurrence of
+    ``_decode_kernel``.  One row's ``[H, dc + dr]`` query tile is
+    resident; the latent streams POSITION-MINOR, as ``[dc + dr,
+    block_s]`` tiles that every head scores whole and whose first ``dc``
+    rows are the values.  The tiles enter the MXU as the cache holds
+    them (the einsums of ``mla_moe.absorbed_step`` do the same), sums
+    and probabilities are float32."""
+    sj = pl.program_id(1)
+    idx = plan_ref[_POS, pl.program_id(0)]
+    block_s = c_ref.shape[3]
+
+    @pl.when(sj == 0)
+    def _():
+        m_ref[:] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(sj * block_s <= idx)
+    def _():
+        q = q_ref[0]                                  # [H, dc + dr]
+        c_t = c_ref[0, 0]                             # [dc + dr, block_s]
+        # the column groups as two dots: dc + dr (320, 576 at the
+        # published widths) is no multiple of the 128 lanes, dc is
+        s = jnp.dot(q[:, :dc], c_t[:dc],
+                    preferred_element_type=jnp.float32)
+        s += jnp.dot(q[:, dc:], c_t[dc:],
+                     preferred_element_type=jnp.float32)
+        pos = sj * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos <= idx, s, _NEG_INF)
+
+        m, l = m_ref[:], l_ref[:]
+        new_m = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - new_m[:, None])
+        p = jnp.where(s <= _NEG_INF / 2, 0.0, p)
+        corr = jnp.exp(m - new_m)
+        m_ref[:] = new_m
+        l_ref[:] = l * corr + jnp.sum(p, axis=-1)
+        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
+            p, c_t[:dc].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(sj == pl.num_programs(1) - 1)
+    def _():
+        safe_l = jnp.maximum(l_ref[:], 1e-30)
+        o_ref[0] = acc_ref[:] / safe_l[:, None]
+
+
+@functools.partial(jax.jit, static_argnames=("dc", "block_s", "interpret"))
+def _latent_impl(idx, live, qcat, latent_t, *, dc, block_s, interpret):
+    """qcat: [B, 1, H, dc + dr]; latent_t: [B, 1, dc + dr, S] (one "KV
+    head" for all ``H``, position-minor); idx, live: as
+    ``_decode_impl``.  Returns float32 [B, 1, H, dc].  Jitted, so that
+    the layers of a model share one trace and one Mosaic lowering."""
+    b, t, h, width = qcat.shape
+    assert t == 1, "the fused decode kernel serves single-token steps"
+    s_len = latent_t.shape[3]
+    block, plan = _row_plan(idx, live, b, s_len,
+                            block_s or latent_block(s_len))
+
+    def row(bk, sj, plan_ref):
+        return bk, 0, 0
+
+    def named_columns(bk, sj, plan_ref):
+        src, head, blk, _ = _named_block(bk, sj, plan_ref)
+        return src, head, 0, blk
+
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, dc=dc),
+        name="latent_decode_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, s_len // block),
+            in_specs=[pl.BlockSpec((1, h, width), row),
+                      pl.BlockSpec((1, 1, width, block), named_columns)],
+            out_specs=pl.BlockSpec((1, h, dc), row),
+            scratch_shapes=[
+                pltpu.VMEM((h,), jnp.float32),
+                pltpu.VMEM((h,), jnp.float32),
+                pltpu.VMEM((h, dc), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, h, dc), jnp.float32),
+        interpret=interpret,
+    )(plan, qcat.reshape(b, h, width), latent_t)
+    return out.reshape(b, 1, h, dc)
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_call(dc: int, block_s: Optional[int], interpret: bool):
+    return _row_batched(functools.partial(
+        _latent_impl, dc=dc, block_s=block_s, interpret=interpret))
+
+
+def latent_decode_attention(qcat, latent, idx, *, dc: int, live=None,
+                            block_s: Optional[int] = None,
+                            interpret: Optional[bool] = None):
+    """The absorbed decode step of latent attention over the cache as
+    it lies, up to the value up-projection.
+
+    qcat: [B, 1, H, dc + dr], a head's ``[q_n W_uk^T ; q_r]`` with every
+    scale already on it; latent: [B, S, dc + dr], the position's ``[c ;
+    k_r]`` that all heads share; idx, live: as ``decode_attention``.
+    Returns ``u_h = sum_j p_j c_j``, float32 [B, 1, H, dc]: what
+    ``models.mla_moe.absorbed_step`` hands to ``W_uv``."""
+    # a TPU lays a leaf whose width is no multiple of 128 lanes (none
+    # published is) position-minor: the transposed view is the leaf as
+    # it lies, and no copy of the pool is made on the way in
+    return _latent_call(dc, block_s, _auto_interpret(interpret))(
+        idx, jnp.asarray(True if live is None else live), qcat,
+        jnp.swapaxes(latent, 1, 2)[:, None])

@@ -204,11 +204,59 @@ def test_the_positions_a_chunk_rebuilds_are_counted():
 
 def test_quantised_serving_refuses_loudly():
     cfg = FAMILY.model_config(SZ)
-    for kw in ({"kv_quant": "int8"}, {"weight_quant": "int8"},
-               {"decode_attn": "pallas"}):
+    for kw in ({"kv_quant": "int8"}, {"weight_quant": "int8"}):
         with pytest.raises(NotImplementedError):
             cfg.serving_layout(64, **kw)
+    with pytest.raises(ValueError):
+        cfg.serving_layout(64, decode_attn="mosaic")
     assert cfg.serving_layout(64, decode_attn="auto").decode
+
+
+@pytest.mark.parametrize("backend,max_len,rope,resolved", [
+    ("cpu", 2048, 8, "xla"), ("tpu", 2048, 8, "pallas"),
+    ("tpu", 16384, 8, "pallas"), ("tpu", 1031, 8, "xla"),
+    ("tpu", 896, 8, "xla"), ("tpu", 2048, 112, "xla")])
+def test_auto_is_decided_from_the_platform_the_cache_length_and_the_width(
+        monkeypatch, backend, max_len, rope, resolved):
+    """As ``generate.decode_config`` decides for the dense model: the
+    kernel on a real TPU where it reads the leaf as the chip lays it
+    out, the einsums otherwise: off the chip; a cache that has no block
+    of whole lanes (1031 is a prime, 896 splits into two of 448); a
+    latent of whole lanes (16 + 112), which lies row-major.  Asked for
+    by name the kernel is taken."""
+    cfg = FAMILY.model_config(SZ, qk_rope_head_dim=rope)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert cfg.decode_attn == "xla" and not cfg.decode
+    assert cfg.serving_layout(max_len, decode_attn="auto").decode_attn \
+        == resolved
+    assert cfg.serving_layout(max_len).decode_attn == "xla"
+    assert cfg.serving_layout(max_len, decode_attn="pallas").decode_attn \
+        == "pallas"
+
+
+@pytest.mark.parametrize("positions", [
+    [5, -1, 9], [0], [71], [-1, -1], [8, 9, 17, 18, 70], [-1, 40, -1]])
+def test_streamed_positions_under_the_kernel_are_the_blocks_the_plan_names(
+        monkeypatch, positions):
+    """Blocks of 9 rows at ``max_len`` 72 (the tests' small block): the
+    host's count a layer is the distinct blocks the index map names."""
+    from bluefog_tpu.parallel import pallas_decode
+
+    monkeypatch.setattr(pallas_decode, "_LATENT_BLOCKS", (9, 9))
+    block = pallas_decode.latent_block(72)
+    assert block == 9
+    cfg = FAMILY.model_config(SZ).serving_layout(72, decode_attn="pallas")
+    live = jnp.asarray([p >= 0 for p in positions])
+    plan = np.asarray(pallas_decode._stream_plan(
+        jnp.clip(jnp.asarray(positions, jnp.int32), 0, 71), live, block))
+    named = {tuple(int(x) for x in pallas_decode._named_block(b, sj, plan))
+             for b in range(len(positions)) for sj in range(72 // block)}
+    layers = SZ["num_hidden_layers"]
+    assert cfg.streamed_positions(positions) \
+        == (("full", layers * len(named) * block),)
+    # the einsums read every reserved row of every slot, whatever is live
+    assert dataclasses.replace(cfg, decode_attn="xla").streamed_positions(
+        positions) == (("full", layers * len(positions) * 72),)
 
 
 # ------------------------------------------------------------------ #
@@ -681,3 +729,64 @@ def test_the_engine_serves_four_streams_with_a_slot_freed_and_reused():
     eng.run()
     assert not any(name.startswith("bf_hc_")
                    for name, *_ in plain.collect())
+
+
+# ------------------------------------------------------------------ #
+# the decode step through the kernel that reads the live blocks (PR 33)
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("streams", [1, 4])
+def test_the_kernel_engine_serves_what_the_einsum_engine_serves(
+        streams, monkeypatch):
+    """Four requests through two slots (a slot freed and reused, one
+    still prefilling while the other decodes), eight blocks of nine rows
+    a slot: the same tokens from both lowerings of the single-token
+    step, and the counter of streamed positions reads every reserved
+    row under the einsums and the plan's blocks under the kernel."""
+    from bluefog_tpu.observe.registry import MetricsRegistry
+    from bluefog_tpu.parallel import pallas_decode
+
+    monkeypatch.setattr(pallas_decode, "_LATENT_BLOCKS", (9, 9))
+    # two Sinkhorn turns: both engines compile here, and twenty turns
+    # around six sublayers are two minutes a program
+    family, sz, params, few = (FAMILY, SZ, _params(), {}) if streams == 1 \
+        else (HC_FAMILY, HC_SZ, _hc_params(2), {"hc_sinkhorn_iters": 2})
+    lengths, budgets = (27, 9, 33, 5), (6, 9, 4, 12)
+    asked = []
+
+    def recorded(positions, s_len, **kw):
+        asked.append(streamed_positions(positions, s_len, **kw))
+        return asked[-1]
+
+    streamed_positions = pallas_decode.streamed_positions
+    monkeypatch.setattr(pallas_decode, "streamed_positions", recorded)
+
+    def serve(decode_attn):
+        del asked[:]
+        rng = np.random.default_rng(10)
+        reg = MetricsRegistry()
+        eng = ServingEngine({"params": params},
+                            family.model_config(sz, key_block=8, **few),
+                            capacity=2, max_len=72, prefill_chunk=4,
+                            decode_attn=decode_attn, registry=reg)
+        assert eng.cfg.decode_attn == decode_attn
+        assert eng.cfg.residual_streams == streams
+        reqs = [eng.submit(Request(rng.integers(0, 128, n), b))
+                for n, b in zip(lengths, budgets)]
+        eng.run()
+        assert all(r.state == "completed" for r in reqs)
+        value = lambda name, **labels: reg.counter(name, "", **labels).value
+        return ([list(r.tokens) for r in reqs],
+                value("bf_serving_decode_steps_total"),
+                value("bf_serving_streamed_positions_total", kind="full"),
+                list(asked))
+
+    want, steps, every, _ = serve("xla")
+    got, steps_k, live, counts = serve("pallas")
+    assert got == want and steps_k == steps == len(counts)
+    layers = sz["num_hidden_layers"]
+    assert every == steps * layers * 2 * 72
+    assert live == layers * sum(counts)
+    # a slot's rows are fetched in blocks of 9 up to its position, never
+    # the 72 reserved: under half of what the einsums read
+    assert all(n % 9 == 0 and 9 <= n <= 2 * 72 for n in counts)
+    assert live < every / 2

@@ -363,3 +363,97 @@ def test_auto_is_decided_from_platform_cache_dtype_and_tiling(
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert generate.decode_config(cfg, max_len, kv_quant=kv_quant,
                                   decode_attn="auto").decode_attn == "xla"
+
+
+# ------------------------------------------------------------------ #
+# the latent variant (PR 33): one cached row for every head, scores
+# over its dc + dr columns, values its first dc
+# ------------------------------------------------------------------ #
+def _latent_rows(b, dc, dr, dtype, heads=4, dn=8, seed=21):
+    """Queries, a cache and up-projections whose value half is the
+    identity, so that ``mla_moe.absorbed_step`` returns the weighted
+    latent itself: the kernel's output, up to ``W_uv``."""
+    rng = np.random.RandomState(seed)
+    q_n = jnp.asarray(rng.randn(b, 1, heads, dn) * 0.3, dtype)
+    q_r = jnp.asarray(rng.randn(b, 1, heads, dr) * 0.3, dtype)
+    latent = jnp.asarray(rng.randn(b, S, dc + dr), dtype)
+    w_uk = jnp.asarray(rng.randn(dc, heads, dn) * 0.3, dtype)
+    w_uv = jnp.broadcast_to(jnp.eye(dc, dtype=dtype)[:, None],
+                            (dc, heads, dc))
+    w_ukv = jnp.concatenate([w_uk, w_uv], axis=-1)
+    qa = jnp.einsum("bthn,chn->bthc", q_n, w_uk,
+                    preferred_element_type=jnp.float32)
+    qcat = jnp.concatenate([qa.astype(dtype), q_r], axis=-1)
+    return q_n, q_r, qcat, latent, w_ukv, dn
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("how", ["rows", "scalar", "vmap"])
+@pytest.mark.parametrize("dc,dr", [(32, 16), (256, 64), (512, 64)])
+def test_latent_kernel_is_the_absorbed_step_up_to_the_value_projection(
+        dc, dr, how, dtype):
+    """Rows at 0, a block's last row, a block's first, one that does not
+    decode and the cache's end; one position for every row; and the
+    engine's map over slots, each at its own position.  The cache is NaN
+    wherever the plan names no block (past a row's own, and all of a row
+    that does not decode), so a block fetched and only masked shows."""
+    from bluefog_tpu.models.mla_moe import absorbed_step
+    from bluefog_tpu.parallel.pallas_decode import latent_decode_attention
+
+    if how == "scalar":
+        positions, live = [BLOCK] * 3, None
+    else:
+        positions = [0, BLOCK - 1, BLOCK, 40, S - 1]
+        live = jnp.asarray([True, True, True, False, True])
+    b = len(positions)
+    positions = jnp.asarray(positions, jnp.int32)
+    q_n, q_r, qcat, latent, w_ukv, dn = _latent_rows(b, dc, dr, dtype)
+    want = jax.vmap(lambda qn, qr, c, p: absorbed_step(
+        qn[None], qr[None], c[None], p[None], w_ukv, dc, dn)[0])(
+            q_n, q_r, latent, positions)
+    dirty = jnp.where(_past(positions, live)[..., None], jnp.nan, latent)
+    if how == "rows":
+        got = latent_decode_attention(qcat, dirty, positions, dc=dc,
+                                      live=live, block_s=BLOCK)
+        # the row that does not decode names the block the row before
+        # it ended on, none of its own
+        plan = np.asarray(pallas_decode._stream_plan(positions, live, BLOCK))
+        assert plan[pallas_decode._SRC, 3] == 2 and plan[
+            pallas_decode._FIRST, 3] == plan[pallas_decode._LAST, 3] == 1
+    elif how == "scalar":
+        got = latent_decode_attention(qcat, dirty, positions[0], dc=dc,
+                                      block_s=BLOCK)
+    else:
+        def one(q, c, idx, alive):       # a slot of the pool: batch 1
+            return latent_decode_attention(q[None], c[None], idx, dc=dc,
+                                           live=alive[None],
+                                           block_s=BLOCK)[0]
+
+        mapped = jax.vmap(one)
+        assert str(jax.make_jaxpr(mapped)(qcat, dirty, positions, live)
+                   ).count("pallas_call") == 1
+        got = jax.jit(mapped)(qcat, dirty, positions, live)
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == np.float32 and np.isfinite(got).all()
+    alive = np.ones(b, bool) if live is None else np.asarray(live)
+    # bfloat16: the einsums round the probabilities to the cache's
+    # dtype before the value contraction, the kernel keeps them float32
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[alive], want[alive], atol=tol, rtol=tol)
+    assert not got[~alive].any()         # a dead row: zeros
+
+
+def test_the_latent_block_follows_the_cache_length():
+    """No keyword chooses it in the model: a function of the length
+    alone, shared by the kernel and the host's count: a quarter of the
+    cache between the dense kernel's block and four of them."""
+    from bluefog_tpu.parallel.pallas_decode import latent_block
+
+    assert [latent_block(s) for s in (16384, 4096, 2048, 1024, 72)] \
+        == [2048, 1024, 512, 512, 72]
+    assert latent_block(4 * 1031) == 1031      # the largest divisor under
+    with pytest.raises(ValueError, match="no block divisor"):
+        pallas_decode.latent_decode_attention(
+            jnp.zeros((1, 1, 4, 24)), jnp.zeros((1, 1031, 24)),
+            jnp.int32(3), dc=16)

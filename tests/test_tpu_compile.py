@@ -121,6 +121,31 @@ def test_decode_kernel_compiles_for_v5e(chip, kv_quant, slots, positions,
     assert "tpu_custom_call" in text
 
 
+# the pools of mistral-small4-serve-long-prompt and of
+# xing4-serve-long-answer (BENCHMARK.json), 32 heads: latent rows of
+# 256 + 64 and of 512 + 64 values
+@pytest.mark.parametrize("slots,positions,dc,dr", [
+    (32, 16384, 256, 64), (64, 4096, 512, 64)])
+def test_latent_decode_kernel_compiles_for_v5e_with_no_copy_of_the_pool(
+        chip, slots, positions, dc, dr):
+    """A width that is no multiple of 128 lanes: the TPU lays the leaf
+    position-minor, and the kernel reads it as it lies.  A kernel that
+    asked for the leaf row-major compiled too, behind a transposed copy
+    of the whole leaf (0.38 and 0.31 GiB of temporaries a call)."""
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=chip)
+    compiled = jax.jit(
+        lambda q, c, i, alive: pallas_decode.latent_decode_attention(
+            q, c, i, dc=dc, live=alive, interpret=False)).lower(
+        sds((slots, 1, 32, dc + dr), jnp.bfloat16),
+        sds((slots, positions, dc + dr), jnp.bfloat16),
+        sds((slots,), jnp.int32), sds((slots,), bool)).compile()
+    assert pallas_decode.latent_tileable(positions, dc + dr)
+    assert "tpu_custom_call" in compiled.as_text()
+    leaf = slots * positions * (dc + dr) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf // 16
+
+
 def test_engine_decode_step_holds_the_kernel_on_v5e(chip, monkeypatch):
     """The serving engine's resident decode step maps the model over
     its slots, each at its own position: the program the TPU lowering
@@ -147,3 +172,40 @@ def test_engine_decode_step_holds_the_kernel_on_v5e(chip, monkeypatch):
         slots(jnp.int32), slots(jnp.float32), chip=chip, cfg=cfg,
         horizon=1)
     assert "tpu_custom_call" in text
+
+
+def test_engine_decode_step_reads_the_latent_pool_in_place_on_v5e(
+        chip, monkeypatch):
+    """The latent model's resident decode step at Mistral-Small-4's
+    attention widths and pool (32 slots x 16,384 positions of 320), one
+    layer of few experts: the cache write and the kernel both take the
+    leaf as the program's argument lies, so the program's temporaries
+    stay far under one leaf (a row-major kernel made 0.39 GiB of them a
+    layer: a transposed copy in, another out)."""
+    from bluefog_tpu.models.mla_moe import MlaMoe, MlaMoeConfig
+
+    monkeypatch.setattr(pallas_decode, "_auto_interpret",
+                        lambda interpret: False)
+    slots_n, max_len = 32, 16384
+    cfg = MlaMoeConfig(
+        vocab_size=1024, dim=1024, n_layers=1, n_heads=32, q_lora_rank=256,
+        kv_lora_rank=256, qk_nope_head_dim=64, qk_rope_head_dim=64,
+        v_head_dim=128, expert_hidden_dim=256, n_experts=8, top_k=2,
+        rope_factor=128.0).serving_layout(max_len, decode_attn="pallas")
+    variables = jax.eval_shape(
+        lambda: MlaMoe(cfg).init(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 1), jnp.int32)))
+    pool = jax.eval_shape(lambda: SlotPool(cfg, slots_n, max_len).cache)
+
+    def slots(dtype, *tail):
+        return jax.ShapeDtypeStruct((slots_n,) + tail, dtype, sharding=chip)
+
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        (variables["params"], pool))
+    compiled = engine._decode_step_prog.lower(
+        *args, slots(jnp.int32), slots(bool), slots(jnp.uint32, 2),
+        slots(jnp.int32), slots(jnp.float32), cfg=cfg, horizon=1).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    leaf = slots_n * max_len * cfg.latent_width * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf // 4
