@@ -32,7 +32,7 @@ import threading
 from bluefog_tpu.observe.registry import enabled, get_registry
 from bluefog_tpu.observe.tracer import get_tracer
 
-__all__ = ["install", "COMPILE_TRACK"]
+__all__ = ["install", "backend_compiles", "COMPILE_TRACK"]
 
 COMPILE_TRACK = "compile"
 
@@ -45,6 +45,7 @@ _STAGES = {
 _CACHE_MISS = "/jax/compilation_cache/cache_misses"
 
 _tracing = threading.local()   # .depth: traces open on this thread
+_backend_compiles = 0          # what bf_compiles_total counts, as an int
 _installed = False
 _install_lock = threading.Lock()
 
@@ -63,6 +64,9 @@ def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
         _tracing.depth = depth = max(getattr(_tracing, "depth", 1) - 1, 0)
         if depth:
             return      # inside another trace, whose seconds hold these
+    if stage == "backend":
+        global _backend_compiles
+        _backend_compiles += 1
     if not enabled():
         return
     reg = get_registry()
@@ -75,6 +79,14 @@ def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
                 "backend compiles (persistent-cache reads included)").inc()
     get_tracer().instant(f"compile.{kwargs.get('fun_name', '')}",
                          COMPILE_TRACK)
+
+
+def backend_compiles() -> int:
+    """What ``bf_compiles_total`` counts, as a plain integer (and counted
+    whether or not anything publishes): what a producer that wants
+    "compiles since the step before" reads a step without a registry
+    lookup."""
+    return _backend_compiles
 
 
 def _on_event(event: str, **kwargs) -> None:

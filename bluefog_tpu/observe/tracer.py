@@ -124,6 +124,17 @@ class Span:
     def seconds(self) -> float:
         return (self.end_us - self.begin_us) * 1e-6
 
+    @property
+    def name(self) -> str:
+        return self._name
+
+    @property
+    def recording(self) -> bool:
+        """Whether a tracer takes the span: a producer opens the spans
+        that only split this one (children) where somebody records
+        them, and takes its plain path where nobody does."""
+        return self._tracer is not None
+
 
 class Tracer:
     """Span/event recorder with pluggable sinks and a bounded buffer.

@@ -555,6 +555,10 @@ class ServingEngine:
         self._running: Dict[int, Request] = {}   # slot -> request
         self._admitting: Optional[Request] = None  # mid-prefill request
         self._draining = False     # drain(): admission permanently off
+        # device programs dispatched so far (``launch=`` of the spans
+        # that dispatch one: the k-th span is the k-th execution)
+        self._launches = 0
+        self._step_spans: list = []  # the current step's phase spans
         self._drain_flushed = 0    # KV chunks flushed to the prefix
         # cache on behalf of migrating/completing drain residents
         self._resident = self._build_resident()
@@ -629,19 +633,33 @@ class ServingEngine:
         ``emit`` (``bf.engine.*`` in a profiler trace): per step, never
         per token or per slot."""
         now = self.clock()
+        spans = self._step_spans
+        spans.clear()
         with self.metrics.span("step") as step_span:
-            self._step_phases(now)
+            decoding = self._step_phases(now)
         # the step's wall time is the span's own two stamps (real time:
         # the injected clock may be virtual) — feeds the fleet
-        # step-time view
+        # step-time view; so are its phases' seconds
+        phases: Dict[str, float] = {}
+        for sp in spans:
+            phases[sp.name] = phases.get(sp.name, 0.0) + sp.seconds
         self.metrics.on_step(self.pool.occupancy(),
                              self.scheduler.queue_depth,
-                             step_span.seconds, now=now)
+                             step_span.seconds, now=now, phases=phases,
+                             decoding=decoding)
         return bool(self._running or self._admitting
                     or self.scheduler.queue_depth)
 
-    def _step_phases(self, now: float) -> None:
-        span = self.metrics.span
+    def _span(self, name: str, **args):
+        """A phase of the current step: ``metrics.span``, kept so that
+        the step's end can read the phase's seconds off its stamps."""
+        sp = self.metrics.span(name, **args)
+        self._step_spans.append(sp)
+        return sp
+
+    def _step_phases(self, now: float) -> int:
+        """The step's phases; returns the slots it decoded."""
+        span = self._span
         # 1-4. shedding, cancellations, admission + chunked prefill,
         #      bounded by the per-step chunk budget (prefill work is
         #      what stalls running decodes, so IT is what gets budgeted
@@ -666,6 +684,7 @@ class ServingEngine:
                 self._spec_decode_step(decoding)
             else:
                 self._decode_step(decoding)
+        return len(decoding)
 
     def _shed(self, now: float) -> None:
         # 1. deadline shedding in the queue (zero device cost)
@@ -921,8 +940,10 @@ class ServingEngine:
         # split the one-shot path computes inside one big call)
         c = self.prefill_chunk
         pos = req._prefill_pos
-        with self.metrics.span("prefill_chunk", rid=req.rid,
-                               slot=req.slot) as chunk_span:
+        with self._span("prefill_chunk", rid=req.rid, slot=req.slot,
+                        start=int(pos),
+                        launch=self._launches) as chunk_span:
+            self._launches += 1 if self._draft_pool is None else 2
             ctx = self._context(req)
             n_prefill = ctx.size - 1
             valid = min(c, n_prefill - pos)
@@ -988,7 +1009,7 @@ class ServingEngine:
         retired slot are discarded (its cache index is reset on free, so
         their cache writes are unobservable).  Returns the tokens
         emitted."""
-        with self.metrics.span("emit") as emit_span:
+        with self._span("emit") as emit_span:
             now = self.clock()
             emitted = 0
             for slot, req in decoding.items():
@@ -1002,27 +1023,54 @@ class ServingEngine:
                         self.metrics.on_token(req.rid, now)
                     if self._maybe_finish(req):
                         break
+            self.metrics.on_tokens(emitted)
             emit_span.set(tokens=emitted)
         return emitted
 
+    def _fetch(self, fetch_span, first, tree):
+        """``jax.device_get(tree)``, the step's one host sync, inside
+        the open ``token_fetch`` span.  Where a tracer takes the span
+        it is split in two: ``device_wait``, the wait for ``first`` (the
+        step's token array), and ``host_copy``, the transfer and
+        conversion of every leaf.  The copies are queued before the
+        host blocks, as ``device_get`` queues them, so the split adds
+        no round trip of its own."""
+        if not fetch_span.recording:
+            return jax.device_get(tree)
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        for leaf in leaves:
+            leaf.copy_to_host_async()
+        with self._span("device_wait"):
+            first.block_until_ready()
+        with self._span("host_copy") as copy_span:
+            # (leaf by leaf, and the bytes off the host's arrays: a
+            # tree_map and eleven ``nbytes`` of device arrays cost more
+            # than the two spans)
+            host = [np.asarray(leaf) for leaf in leaves]
+            copy_span.set(leaves=len(host),
+                          bytes=sum(a.nbytes for a in host))
+        return treedef.unflatten(host)
+
     def _decode_step(self, decoding: Dict[int, Request]) -> None:
-        span = self.metrics.span
+        span = self._span
         with span("decode_inputs", slots=len(decoding)):
             operands = self._decode_inputs(decoding)
-        with span("decode_dispatch"):
+        with span("decode_dispatch", launch=self._launches):
+            self._launches += 1
             self.pool.cache, hist = _decode_step_prog(
                 self._params, self.pool.cache, *operands, cfg=self.cfg,
                 horizon=self.decode_horizon)
         observed = self.metrics.publishing
         stats, attended, streamed = None, (), ()
-        with span("token_fetch"):
+        with span("token_fetch") as fetch_span:
             # [horizon, cap] — the per-step host sync: tokens stream; a
             # model's stat_* leaves (the step's own outputs) come with
             # them where somebody counts them
             if observed and self.pool.has_stats:
-                hist, stats = jax.device_get((hist, self.pool.stats()))
+                hist, stats = self._fetch(fetch_span, hist,
+                                          (hist, self.pool.stats()))
             else:
-                hist = np.asarray(hist)
+                hist = self._fetch(fetch_span, hist, hist)
         if observed:
             # the query of a slot sits on its last token and sees every
             # position up to itself
@@ -1048,18 +1096,19 @@ class ServingEngine:
         ``lookahead+1`` tokens.  The host appends each slot's emitted
         run with the same EOS/budget truncation the plain path
         applies."""
-        span = self.metrics.span
+        span = self._span
         with span("decode_inputs", slots=len(decoding)):
             operands = self._decode_inputs(decoding)
-        with span("decode_dispatch"):
+        with span("decode_dispatch", launch=self._launches):
+            self._launches += 1
             (self.pool.cache, self._draft_pool.cache, hist,
              n_emit) = _spec_step_prog(
                 self._params, self._draft_params, self.pool.cache,
                 self._draft_pool.cache, *operands, cfg_t=self.cfg,
                 cfg_d=self.draft_cfg, k=self._spec.lookahead)
-        with span("token_fetch"):
-            hist = np.asarray(hist)      # [cap, lookahead+1]
-            n_emit = np.asarray(n_emit)  # [cap]
+        with span("token_fetch") as fetch_span:
+            # [cap, lookahead+1] and [cap]
+            hist, n_emit = self._fetch(fetch_span, hist, (hist, n_emit))
         emitted = self._emit(
             decoding, lambda slot: hist[slot, :int(n_emit[slot])])
         self.metrics.on_decode_step(len(decoding))

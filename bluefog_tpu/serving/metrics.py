@@ -39,11 +39,15 @@ virtual time and percentiles are deterministic.
 
 from __future__ import annotations
 
+import gc
+from collections import deque
 from typing import Dict, List, Optional
 
 import numpy as np
 
+from bluefog_tpu import logging_util
 from bluefog_tpu import timeline as timeline_mod
+from bluefog_tpu.observe import compiles as obs_compiles
 from bluefog_tpu.observe import registry as obs_registry
 from bluefog_tpu.observe import tracer as obs_tracer
 from bluefog_tpu.observe.registry import percentile  # noqa: F401  (moved
@@ -53,6 +57,21 @@ __all__ = ["ServingMetrics", "percentile"]
 
 #: the track of the engine's own spans (``bf.engine.*`` in a profile)
 ENGINE_TRACK = "engine"
+
+#: a step is SLOW (one ``engine.slow_step`` instant, one WARNING line)
+#: when it is longer than this multiple of the running median step AND
+#: than the floor: the first decode step after a burst of prefill
+#: drains the chunks queued on the device (150-224 ms where the median
+#: is 7-20) and is no stall; a stall holds a step for seconds
+SLOW_STEP_FACTOR = 20.0
+SLOW_STEP_FLOOR_S = 0.5
+#: the steps the running median is taken over, and the fewest that make
+#: one (an engine's first steps compile its programs)
+SLOW_STEP_WINDOW = 64
+SLOW_STEP_MIN_HISTORY = 8
+#: the spans inside ``token_fetch`` (where a tracer takes them): the
+#: wait for the step's tokens, and their transfer and conversion
+FETCH_PARTS = ("device_wait", "host_copy")
 
 
 class _RequestRecord:
@@ -96,6 +115,13 @@ class ServingMetrics:
         self.n_spec_emitted = 0
         # the stat_expert_rows leaves as last read (``on_expert_rows``)
         self._expert_rows_seen = None
+        # the stalled step: the longest step so far, and what a step is
+        # held against (``on_step``)
+        self.n_steps = 0
+        self.longest_step: Optional[dict] = None
+        self._recent_steps: deque = deque(maxlen=SLOW_STEP_WINDOW)
+        self._gc2_seen = gc.get_stats()[2]["collections"]
+        self._compiles_seen = obs_compiles.backend_compiles()
 
     # -- observe plumbing --------------------------------------------- #
     def _reg(self):
@@ -172,15 +198,18 @@ class ServingMetrics:
             reg.histogram("bf_serving_ttft_seconds",
                           "submit -> first token").observe(
                               now - rec.submit_t)
-            reg.counter("bf_serving_tokens_total",
-                        "tokens generated").inc()
 
     def on_token(self, rid, now: float):
         self._req[rid].n_tokens += 1
+
+    def on_tokens(self, n_tokens: int):
+        """A decode step emitted ``n_tokens`` over all its slots (first
+        tokens included): the counter moves once a step, not once a
+        token."""
         reg = self._reg()
-        if reg is not None:
+        if reg is not None and n_tokens:
             reg.counter("bf_serving_tokens_total",
-                        "tokens generated").inc()
+                        "tokens generated").inc(n_tokens)
 
     def on_retire(self, rid, now: float, outcome: str):
         rec = self._req[rid]
@@ -404,7 +433,7 @@ class ServingMetrics:
         ``n_emitted`` tokens total: the accepted-tokens-per-step ratio
         speculation is judged by, in :meth:`summary`.  The registry has
         it already — per-token accounting flows through
-        ``on_first_token``/``on_token`` and the step's slots through
+        :meth:`on_tokens` and the step's slots through
         :meth:`on_decode_step`, so it is ``bf_serving_tokens_total`` over
         ``bf_serving_decode_slots_total``."""
         self.n_spec_steps += 1
@@ -413,7 +442,14 @@ class ServingMetrics:
 
     def on_step(self, occupancy: float, queue_depth: int,
                 step_seconds: Optional[float] = None,
-                now: Optional[float] = None):
+                now: Optional[float] = None,
+                phases: Optional[Dict[str, float]] = None,
+                decoding: int = 0):
+        """One engine step ended.  ``phases``: the seconds of each of
+        its phases, summed by the engine from the stamps its spans hold
+        (``device_wait`` and ``host_copy`` lie inside ``token_fetch``
+        where a tracer took the spans); ``decoding``: the slots it
+        decoded."""
         self._occupancy.append(occupancy)
         self._queue_depth.append(queue_depth)
         if now is not None:
@@ -440,6 +476,69 @@ class ServingMetrics:
                 reg.histogram("bf_step_wall_seconds",
                               "train/engine step wall time",
                               loop="serving").observe(step_seconds)
+        if step_seconds is not None:
+            self._on_step_seconds(step_seconds, phases or {}, decoding, reg)
+        self.n_steps += 1
+
+    def _on_step_seconds(self, seconds: float, phases: Dict[str, float],
+                         decoding: int, reg) -> None:
+        """The stalled step: keep the longest step's record, and say so
+        once, where it happens, when a step is SLOW (the constants at
+        the top of the module) with the phase that held it and what the
+        interpreter did since the step before."""
+        gc2 = gc.get_stats()[2]["collections"]
+        compiles = obs_compiles.backend_compiles()
+        gc2_since = gc2 - self._gc2_seen
+        compiles_since = compiles - self._compiles_seen
+        self._gc2_seen, self._compiles_seen = gc2, compiles
+        recent = self._recent_steps
+        longest = self.longest_step
+        # a step that compiled is long for a reason that has its own
+        # account (bf_compile_seconds_total) and is not the record
+        if not compiles_since and (longest is None
+                                   or seconds > longest["seconds"]):
+            self.longest_step = {
+                "seconds": seconds, "phases": dict(phases),
+                "step": self.n_steps, "decoding": decoding,
+                "chunk": "prefill_chunk" in phases}
+            if reg is not None:
+                gone = set(longest["phases"]) - set(phases) if longest else ()
+                for phase, value in (("step", seconds), *phases.items(),
+                                     *((p, 0.0) for p in gone)):
+                    reg.gauge("bf_serving_longest_step_seconds",
+                              "the longest engine step that compiled "
+                              "nothing, and the seconds of each of its "
+                              "phases", phase=phase).set(value)
+        if seconds >= SLOW_STEP_FLOOR_S \
+                and len(recent) >= SLOW_STEP_MIN_HISTORY:
+            median = float(np.median(recent))
+            if seconds > SLOW_STEP_FACTOR * median:
+                self._on_slow_step(seconds, median, phases, decoding,
+                                   gc2_since, compiles_since)
+        recent.append(seconds)
+
+    def _on_slow_step(self, seconds, median, phases, decoding, gc2,
+                      compiles) -> None:
+        split = " ".join(f"{k}={v:.4f}" for k, v in phases.items())
+        # the innermost span that held it: a part of token_fetch where
+        # the parts were taken (token_fetch then stands for what they
+        # leave of it), ``self`` for what no phase covers
+        own = dict(phases)
+        parts = sum(own.get(part, 0.0) for part in FETCH_PARTS)
+        own["self"] = seconds - (sum(own.values()) - parts)
+        if parts:
+            own["token_fetch"] = own.get("token_fetch", 0.0) - parts
+        held = max(own, key=own.get)
+        tr = self._tracer()
+        if tr is not None:
+            tr.instant("engine.slow_step", ENGINE_TRACK, step=self.n_steps,
+                       seconds=seconds, held=held, gc2=gc2,
+                       compiles=compiles, **phases)
+        logging_util.get_logger().warning(
+            "engine.slow_step step=%d seconds=%.4f median=%.4f held=%s "
+            "decoding=%d chunk=%d gc2=%d compiles=%d phases: %s",
+            self.n_steps, seconds, median, held, decoding,
+            int("prefill_chunk" in phases), gc2, compiles, split)
 
     # -- summaries ----------------------------------------------------- #
     def ttfts(self) -> List[float]:
@@ -501,4 +600,8 @@ class ServingMetrics:
             "accepted_per_step": ((self.n_spec_emitted
                                    / self.n_spec_active)
                                   if self.n_spec_active else 0.0),
+            # the process's longest step: seconds, the seconds of each
+            # phase, its index, the slots it decoded, whether it held a
+            # prefill chunk (None before the first timed step)
+            "longest_step": self.longest_step,
         }

@@ -50,8 +50,12 @@ def bf_ctx():
 #   NEW file (``open(..., "x")``); PR 32 brought the cell, so the file is
 #   there and the case for it cannot create it (its other two cases stand;
 #   ``test_perfbench_mhc.py`` holds the cell that arrived).
+# * PR 32's test wants the list to END with PR 32's four entries and to be
+#   55 long (PR 34 appended five entries of the five serve cells).
 # The form an append keeps, a PREFIX from PR 29's first entry on, is
-# ``test_perfbench_chunk_attend.py::test_an_append_moves_nothing_of_the_entries_before_it``.
+# ``test_perfbench_chunk_attend.py::test_an_append_moves_nothing_of_the_entries_before_it``,
+# and from the list's first entry on, every ``workloads`` list with it,
+# ``test_perfbench_step_timeline.py::test_the_append_keeps_what_was_there``.
 _PINNED_AS_LAST = (
     "test_perfbench_prefill_chunk.py::test_the_entries_of_the_two_metrics",
     "test_perfbench_mla_moe.py::"
@@ -60,6 +64,8 @@ _PINNED_AS_LAST = (
     "test_the_new_cell_loads_with_its_files_and_metrics",
     "test_perfbench_runners.py::"
     "test_a_listed_later_cell_arrives_as_files_and_entries_only[allreduce]",
+    "test_perfbench_mhc.py::"
+    "test_an_append_moved_nothing_that_was_there[per_layer]",
 )
 
 

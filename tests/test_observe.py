@@ -648,6 +648,7 @@ def test_serving_metrics_publish_and_opt_out(monkeypatch):
     m.on_admit(1, 0.5)
     m.on_first_token(1, 1.0)
     m.on_token(1, 1.25)
+    m.on_tokens(2)          # the counter moves once a step (PR 34)
     m.on_retire(1, 1.5, "completed")
     m.on_step(0.5, 3)
     snap = reg.snapshot()
@@ -667,7 +668,8 @@ def test_serving_metrics_publish_and_opt_out(monkeypatch):
         "mean_queue_depth", "max_queue_depth", "prefill_chunks",
         "prefix_chunks_restored", "prefix_tokens_restored",
         "prefix_hit_rate", "spec_steps", "accepted_per_step",
-        "n_failovers"}
+        "n_failovers", "longest_step"}
+    assert s["longest_step"] is None        # no step was timed
 
     monkeypatch.setenv("BLUEFOG_OBSERVE", "0")
     global_before = observe.get_registry().snapshot()
