@@ -16,6 +16,12 @@ arrival pattern:
   running decodes), then advances EVERY active slot ``decode_horizon``
   tokens in a single vmapped program with a per-slot active mask and
   per-slot cache index;
+* that program is dispatched ONE STEP AHEAD of the host's knowledge of
+  the tokens: a step dispatches the next decode program (its input
+  tokens are the previous program's output, still on the device) and
+  only then reads the tokens of the program the step before
+  dispatched, so the host's work of a step runs beside the device's
+  (:meth:`~ServingEngine.step` says what a caller may rely on);
 * slots retire on EOS / token budget / deadline / cancellation and are
   zeroed for reuse.
 
@@ -68,7 +74,7 @@ import numpy as np
 from jax import lax
 
 from bluefog_tpu.serving import protocol
-from bluefog_tpu.serving.kv_pool import SlotPool
+from bluefog_tpu.serving.kv_pool import SlotPool, pack_stats
 from bluefog_tpu.serving.metrics import ServingMetrics
 from bluefog_tpu.serving.scheduler import FifoScheduler, RequestRejected
 
@@ -209,7 +215,7 @@ def _prefill_chunk_prog(params, pool, slot, chunk, valid_len, cfg):
 
 @partial(jax.jit, static_argnames=("cfg", "horizon"), donate_argnums=(1,))
 def _decode_step_prog(params, pool, toks, active, keys, counts, temps,
-                      cfg, horizon: int):
+                      fresh=None, prev=None, *, cfg, horizon: int):
     """Advance EVERY slot ``horizon`` decode tokens (vmapped
     single-token steps inside one ``lax.scan`` — each slot carries its
     own cache index, so rotary/mask positions are per-request) and
@@ -222,7 +228,24 @@ def _decode_step_prog(params, pool, toks, active, keys, counts, temps,
     folds in the per-request token index), so the emitted stream is
     identical for every horizon — the host truncates a retiring slot's
     surplus tail, and the slot's zero-on-free makes its overrun cache
-    writes unobservable.  Returns ``(pool, tokens [horizon, n_slots])``.
+    writes unobservable.
+
+    The engine dispatches this program before the host has read the
+    tokens of the one before it, so a slot's input token comes from
+    either side: ``prev`` is the previous program's output, still on
+    the device, whose last token row feeds every slot that goes on;
+    ``fresh [n_slots]`` marks the slots that take the host's ``toks``
+    instead (a slot that joins; every slot when the host knows all
+    tokens).  The merge is inside the program: no launch of its own,
+    and one executable whatever the mix.  (Without the two operands
+    every slot takes ``toks``: the form a caller that lowers the
+    program by hand uses.)
+
+    Returns ``(pool, out [horizon + n, n_slots])``: ``horizon`` rows of
+    tokens, then the pool's ``stat_*`` leaves as this program left them
+    (``kv_pool.pack_stats``: ``n`` rows, none for a model that declares
+    no such leaf) in an array that is no leaf of the pool, so the host
+    can read them after the next program took the pool by donation.
     """
     def keep_index(path, new, old):
         # Freezing an inactive slot needs only its cache_index: the
@@ -253,9 +276,12 @@ def _decode_step_prog(params, pool, toks, active, keys, counts, temps,
         return (jax.tree_util.tree_map_with_path(keep_index, new_pool,
                                                  pool), nxt), nxt
 
+    device_side = prev
+    if device_side is not None:
+        toks = jnp.where(fresh, toks, prev[horizon - 1])
     (pool, _), hist = lax.scan(hstep, (pool, toks),
                                jnp.arange(horizon, dtype=jnp.int32))
-    return pool, hist
+    return pool, jnp.concatenate([hist, pack_stats(pool)])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -372,6 +398,22 @@ def _spec_step_prog(params_t, params_d, pool_t, pool_d, toks, active,
 
     return jax.vmap(one)(pool_t, pool_d, toks, active, keys, counts,
                          temps)
+
+
+@dataclasses.dataclass(eq=False)
+class _Flight:
+    """A decode program the device holds and the host has not read:
+    the requests it advances by slot, its small output (token rows,
+    then ``stat_*`` rows; still on the device), its ``launch=`` number,
+    whether another program was in flight when it was dispatched, and
+    the positions it attended and streamed at the lengths it ran at
+    (``on_decode_step``'s; empty where no registry counts)."""
+    decoding: Dict[int, Request]
+    out: jax.Array
+    launch: int
+    ahead: bool
+    attended: tuple = ()
+    streamed: tuple = ()
 
 
 class ServingEngine:
@@ -558,6 +600,12 @@ class ServingEngine:
         # device programs dispatched so far (``launch=`` of the spans
         # that dispatch one: the k-th span is the k-th execution)
         self._launches = 0
+        # the plain decode program the device holds and the host has
+        # not read (None: the host knows every token), and the output
+        # of the newest one, the next one's ``prev`` operand
+        self._flight: Optional[_Flight] = None
+        self._newest_out = jnp.zeros(
+            (decode_horizon + self.pool.stat_rows, capacity), jnp.int32)
         self._step_spans: list = []  # the current step's phase spans
         self._drain_flushed = 0    # KV chunks flushed to the prefix
         # cache on behalf of migrating/completing drain residents
@@ -624,8 +672,25 @@ class ServingEngine:
     # -- the serving loop --------------------------------------------- #
     def step(self) -> bool:
         """One engine iteration: shed/cancel, admit + one prefill chunk,
-        one decode step over all active slots.  Returns True while there
-        is live work (queued, prefilling, or decoding).
+        dispatch the next decode program over all active slots, then
+        read the tokens of the decode program the call before
+        dispatched.  Returns True while there is live work (queued,
+        prefilling, decoding, or a decode program in flight).
+
+        A plain engine runs ONE DECODE PROGRAM AHEAD of what the host
+        knows: the program dispatched here takes the tokens of the one
+        in flight off the device, so the host's work of a step (this
+        method, and the caller's between two calls) runs beside the
+        device's and the step lasts the longer of the two.  A call
+        therefore returns with a program in flight, and the tokens it
+        emitted are the program's before: ``req.tokens`` is complete
+        once ``step`` returns False, or after :meth:`collect`.  What
+        only the tokens can say (an EOS) the host learns one program
+        late: the slot has run once more, that token is dropped
+        (``bf_serving_decode_overrun_slots_total``) and the cache write
+        sits above an index that the slot's release resets.  A
+        speculative engine dispatches and reads within the call: how
+        far a slot advanced is itself an output of its program.
 
         The whole iteration is one ``step`` span on the ``engine`` track
         with its phases inside — ``admit``, ``prefill_chunk``,
@@ -647,8 +712,27 @@ class ServingEngine:
                              self.scheduler.queue_depth,
                              step_span.seconds, now=now, phases=phases,
                              decoding=decoding)
+        return self.busy
+
+    @property
+    def busy(self) -> bool:
+        """Whether there is live work: a request queued, prefilling or
+        decoding, or a decode program in flight."""
         return bool(self._running or self._admitting
-                    or self.scheduler.queue_depth)
+                    or self.scheduler.queue_depth
+                    or self._flight is not None)
+
+    def collect(self) -> None:
+        """Read the decode program in flight, if any: emit its tokens
+        and retire what it finished.  Afterwards the host knows every
+        token the device has computed (``req.tokens`` of a live request
+        is as long as its cache).  :meth:`step` does this for the
+        program before the one it dispatches; :meth:`drain`, failover
+        and a caller that reads ``req.tokens`` between steps do it for
+        the last one."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self._collect(flight)
 
     def _span(self, name: str, **args):
         """A phase of the current step: ``metrics.span``, kept so that
@@ -676,15 +760,14 @@ class ServingEngine:
             if self._admitting is None and chunks < self.prefill_budget:
                 with span("admit") as admit_span:
                     admit_span.set(admitted=self._admit(now))
-        # 5. one decode token for every active slot
-        decoding = {s: r for s, r in self._running.items()
-                    if r.state == DECODE}
-        if decoding:
-            if self._spec is not None:
+        # 5. one decode program over every active slot
+        if self._spec is not None:
+            decoding = {s: r for s, r in self._running.items()
+                        if r.state == DECODE}
+            if decoding:
                 self._spec_decode_step(decoding)
-            else:
-                self._decode_step(decoding)
-        return len(decoding)
+            return len(decoding)
+        return self._decode_step()
 
     def _shed(self, now: float) -> None:
         # 1. deadline shedding in the queue (zero device cost)
@@ -772,8 +855,12 @@ class ServingEngine:
         Returns a summary dict (``handed_off`` / ``completed`` /
         ``rejected_queue`` / ``cancelled_queue`` / ``flushed_chunks``).
         """
-        now = self.clock()
         self._draining = True
+        if handoff is not None:
+            # the residents leave with every token the device computed
+            # for them (and _flush_resident with the chunks they wrote)
+            self.collect()
+        now = self.clock()
         summary = {"handed_off": 0, "completed": 0, "rejected_queue": 0,
                    "cancelled_queue": 0, "flushed_chunks": 0}
         # queue: deadline-expired requests shed exactly as step() would
@@ -836,12 +923,11 @@ class ServingEngine:
         O(slot) bookkeeping, not the serving data plane.)"""
         cap = self.pool.capacity
 
-        def decode_args(pool):
-            return lambda: (
-                self._params, pool.cache, jnp.zeros((cap,), jnp.int32),
-                jnp.zeros((cap,), bool), jnp.zeros((cap, 2), jnp.uint32),
-                jnp.zeros((cap,), jnp.int32), jnp.zeros((cap,),
-                                                        jnp.float32))
+        def slot_args():
+            return (jnp.zeros((cap,), jnp.int32), jnp.zeros((cap,), bool),
+                    jnp.zeros((cap, 2), jnp.uint32),
+                    jnp.zeros((cap,), jnp.int32),
+                    jnp.zeros((cap,), jnp.float32))
 
         resident: Dict[str, tuple] = {
             "prefill_chunk": (
@@ -853,7 +939,9 @@ class ServingEngine:
         }
         if self._spec is None:
             resident["decode_step"] = (
-                _decode_step_prog, decode_args(self.pool),
+                _decode_step_prog,
+                lambda: (self._params, self.pool.cache, *slot_args(),
+                         jnp.zeros((cap,), bool), self._newest_out),
                 {"cfg": self.cfg, "horizon": self.decode_horizon})
         else:
             resident["draft_prefill_chunk"] = (
@@ -867,11 +955,7 @@ class ServingEngine:
                 _spec_step_prog,
                 lambda: (self._params, self._draft_params,
                          self.pool.cache, self._draft_pool.cache,
-                         jnp.zeros((cap,), jnp.int32),
-                         jnp.zeros((cap,), bool),
-                         jnp.zeros((cap, 2), jnp.uint32),
-                         jnp.zeros((cap,), jnp.int32),
-                         jnp.zeros((cap,), jnp.float32)),
+                         *slot_args()),
                 {"cfg_t": self.cfg, "cfg_d": self.draft_cfg,
                  "k": self._spec.lookahead})
         return resident
@@ -981,38 +1065,56 @@ class ServingEngine:
         self._running[req.slot] = req
         req.state = DECODE
 
-    def _decode_inputs(self, decoding: Dict[int, Request]) -> tuple:
+    def _decode_inputs(self, decoding: Dict[int, Request],
+                       lengths: Optional[Dict[int, int]] = None) -> tuple:
         """The decode programs' per-slot operands, on the device:
-        (tokens, active, rng keys, token counts, temperatures)."""
+        (tokens, active, rng keys, token counts, temperatures).
+        ``lengths`` (the plain program): each slot's token count WITH
+        the tokens of the program in flight, which the host does not
+        hold yet.  A slot that is further than ``req.tokens`` says has
+        its input token on the device; a sixth operand marks every
+        OTHER slot as taking the host's."""
         cap = self.pool.capacity
         toks = np.zeros((cap,), np.int32)
         active = np.zeros((cap,), bool)
         keys = np.zeros((cap, 2), np.uint32)
         counts = np.zeros((cap,), np.int32)
         temps = np.zeros((cap,), np.float32)
+        fresh = np.zeros((cap,), bool)
         for slot, req in decoding.items():
-            # first step after prefill consumes the LAST prompt token
-            # (writing its K/V and sampling the first generated token);
-            # afterwards the request's own stream feeds back
-            toks[slot] = req.tokens[-1] if req.tokens else req.prompt[-1]
             active[slot] = True
             keys[slot] = _rng_key(req.seed)
-            counts[slot] = len(req.tokens)
+            known = len(req.tokens)
+            counts[slot] = known if lengths is None else lengths[slot]
             temps[slot] = req.temperature
-        return (jnp.asarray(toks), jnp.asarray(active), jnp.asarray(keys),
-                jnp.asarray(counts), jnp.asarray(temps))
+            if counts[slot] == known:
+                # first step after prefill consumes the LAST prompt
+                # token (writing its K/V and sampling the first
+                # generated token); afterwards the request's own stream
+                # feeds back
+                toks[slot] = req.tokens[-1] if known else req.prompt[-1]
+                fresh[slot] = True
+        operands = (toks, active, keys, counts, temps)
+        if lengths is not None:
+            operands += (fresh,)
+        return tuple(jnp.asarray(a) for a in operands)
 
     def _emit(self, decoding: Dict[int, Request], tokens_of) -> int:
         """The per-token loop of a decode step: append each slot's run
         (``tokens_of(slot)``), publish, finish and retire.  A run stops
         at a retirement: surplus horizon or accepted tokens for a
         retired slot are discarded (its cache index is reset on free, so
-        their cache writes are unobservable).  Returns the tokens
-        emitted."""
+        their cache writes are unobservable).  So is the whole run of a
+        slot whose request left it while the program was in flight (an
+        EOS in the program before, a cancel, a shed): an overrun.
+        Returns the tokens emitted."""
         with self._span("emit") as emit_span:
             now = self.clock()
-            emitted = 0
+            emitted = overrun = 0
             for slot, req in decoding.items():
+                if self._running.get(slot) is not req:
+                    overrun += 1
+                    continue
                 for token in tokens_of(slot):
                     first = not req.tokens
                     req.tokens.append(int(token))
@@ -1023,7 +1125,7 @@ class ServingEngine:
                         self.metrics.on_token(req.rid, now)
                     if self._maybe_finish(req):
                         break
-            self.metrics.on_tokens(emitted)
+            self.metrics.on_tokens(emitted, overrun)
             emit_span.set(tokens=emitted)
         return emitted
 
@@ -1051,44 +1153,73 @@ class ServingEngine:
                           bytes=sum(a.nbytes for a in host))
         return treedef.unflatten(host)
 
-    def _decode_step(self, decoding: Dict[int, Request]) -> None:
+    def _decode_step(self) -> int:
+        """The plain decode path, one program ahead: dispatch the next
+        program, THEN read the one in flight.  Returns the slots of the
+        program it read."""
+        flight = self._flight
+        self._flight = self._launch(flight)
+        if flight is None:
+            return 0
+        self._collect(flight)
+        return len(flight.decoding)
+
+    def _launch(self, flight: Optional[_Flight]) -> Optional[_Flight]:
+        """Dispatch a decode program over every slot that goes on, from
+        what the host knows without the tokens of ``flight`` (the
+        program the device still holds, or None): a request goes on
+        unless ``flight`` takes it to ``max_new_tokens``, which the host
+        can count.  None when no slot goes on."""
+        ahead = flight.decoding if flight is not None else {}
+        horizon = self.decode_horizon
+        decoding, lengths = {}, {}
+        for slot, req in self._running.items():
+            n = len(req.tokens) + (horizon if ahead.get(slot) is req else 0)
+            if req.state == DECODE and n < req.max_new_tokens:
+                decoding[slot], lengths[slot] = req, n
+        if not decoding:
+            return None
         span = self._span
         with span("decode_inputs", slots=len(decoding)):
-            operands = self._decode_inputs(decoding)
-        with span("decode_dispatch", launch=self._launches):
+            operands = self._decode_inputs(decoding, lengths)
+        launch = self._launches
+        with span("decode_dispatch", launch=launch):
             self._launches += 1
-            self.pool.cache, hist = _decode_step_prog(
-                self._params, self.pool.cache, *operands, cfg=self.cfg,
-                horizon=self.decode_horizon)
-        observed = self.metrics.publishing
-        stats, attended, streamed = None, (), ()
-        with span("token_fetch") as fetch_span:
-            # [horizon, cap] — the per-step host sync: tokens stream; a
-            # model's stat_* leaves (the step's own outputs) come with
-            # them where somebody counts them
-            if observed and self.pool.has_stats:
-                hist, stats = self._fetch(fetch_span, hist,
-                                          (hist, self.pool.stats()))
-            else:
-                hist = self._fetch(fetch_span, hist, hist)
-        if observed:
+            self.pool.cache, out = _decode_step_prog(
+                self._params, self.pool.cache, *operands,
+                self._newest_out, cfg=self.cfg, horizon=horizon)
+            self._newest_out = out
+        launched = _Flight(decoding, out, launch, ahead=flight is not None)
+        if self.metrics.publishing:
             # the query of a slot sits on its last token and sees every
             # position up to itself
             positions = [-1] * self.pool.capacity
-            for slot, r in decoding.items():
-                positions[slot] = r.prompt.size + len(r.tokens) - 1
-            attended = protocol.attended_positions(
+            for slot, req in decoding.items():
+                positions[slot] = req.prompt.size + lengths[slot] - 1
+            launched.attended = protocol.attended_positions(
                 self._kinds, [p + 1 for p in positions if p >= 0])
-            streamed = self.cfg.streamed_positions(positions)
-            if stats is not None:
-                self.metrics.on_expert_choices(
-                    stats.get("stat_experts", ()), sorted(decoding),
-                    self.cfg.held)
-                self.metrics.on_expert_rows(stats.get("stat_expert_rows", ()))
+            launched.streamed = self.cfg.streamed_positions(positions)
+        return launched
+
+    def _collect(self, flight: _Flight) -> None:
+        """Wait for ``flight``'s tokens, count it, emit."""
+        decoding = flight.decoding
+        with self._span("token_fetch", launch=flight.launch) as fetch_span:
+            # [horizon + stat rows, cap] — the per-step host sync:
+            # tokens stream; a model's stat_* leaves come below them
+            out = self._fetch(fetch_span, flight.out, flight.out)
+        hist = out[:self.decode_horizon]
+        if self.pool.has_stats and self.metrics.publishing:
+            stats = self.pool.unpack_stats(out[self.decode_horizon:])
+            self.metrics.on_expert_choices(
+                stats.get("stat_experts", ()), sorted(decoding),
+                self.cfg.held)
+            self.metrics.on_expert_rows(stats.get("stat_expert_rows", ()))
         self._emit(decoding, lambda slot: hist[:, slot])
         self.metrics.on_decode_step(
-            len(decoding), attended, streamed,
-            mixed=len(decoding) * self._mixed_sublayers)
+            len(decoding), flight.attended, flight.streamed,
+            mixed=len(decoding) * self._mixed_sublayers,
+            ahead=flight.ahead)
 
     def _spec_decode_step(self, decoding: Dict[int, Request]) -> None:
         """The speculative twin of :meth:`_decode_step`: one resident

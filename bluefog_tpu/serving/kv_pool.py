@@ -33,7 +33,29 @@ import numpy as np
 
 from bluefog_tpu.serving import protocol
 
-__all__ = ["SlotPool"]
+__all__ = ["SlotPool", "pack_stats"]
+
+
+def _stat_leaves(cache):
+    """``[(name, leaf)]``: the model's ``stat_*`` leaves in the order
+    the tree flattens them."""
+    return [(path[-1].key, leaf) for path, leaf in
+            jax.tree_util.tree_flatten_with_path(cache)[0]
+            if protocol.leaf_kind(path) == protocol.STAT]
+
+
+def pack_stats(cache):
+    """The ``stat_*`` leaves of a pool ``cache`` as int32 rows ``[n,
+    capacity]``, a row a number a slot holds (traced inside the decode
+    program, which returns them below its token rows: one small array
+    to copy, and none of the pool's own buffers, which the next program
+    takes by donation).  :meth:`SlotPool.unpack_stats` undoes it on the
+    host."""
+    leaves = [leaf.astype(jnp.int32).reshape(leaf.shape[0], -1).T
+              for _, leaf in _stat_leaves(cache)]
+    capacity = jax.tree.leaves(cache)[0].shape[0]
+    return (jnp.concatenate(leaves) if leaves
+            else jnp.zeros((0, capacity), jnp.int32))
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -117,12 +139,11 @@ class SlotPool:
             self._seq_axes = seq_axes(cfg, max_len, kv_quant)
         self._free: List[int] = list(range(capacity - 1, -1, -1))
         self._in_use: set = set()
-        # (name, position among the leaves) of the model's stat_* leaves
-        self._stat_leaves = [
-            (path[-1].key, i) for i, (path, _) in enumerate(
-                jax.tree_util.tree_flatten_with_path(self.cache)[0])
-            if protocol.leaf_kind(path) == protocol.STAT]
-        self.has_stats = bool(self._stat_leaves)
+        # (name, shape) of the model's stat_* leaves, as pack_stats
+        # lays them out
+        self._stat_shapes = [(name, leaf.shape)
+                             for name, leaf in _stat_leaves(self.cache)]
+        self.has_stats = bool(self._stat_shapes)
 
     def cache_bytes(self) -> dict:
         """``{"full" | "window": bytes}`` the pool reserves in leaves of
@@ -136,13 +157,20 @@ class SlotPool:
                 out[kind] = out.get(kind, 0) + leaf.size * leaf.dtype.itemsize
         return out
 
-    def stats(self) -> dict:
-        """The ``stat_*`` leaves by name, ``{name: [leaf [capacity, ...]
-        a layer that declares it]}``."""
-        leaves = jax.tree.leaves(self.cache)
-        out = {}
-        for name, i in self._stat_leaves:
-            out.setdefault(name, []).append(leaves[i])
+    @property
+    def stat_rows(self) -> int:
+        """The rows :func:`pack_stats` makes of this pool's leaves."""
+        return sum(int(np.prod(shape[1:])) for _, shape in self._stat_shapes)
+
+    def unpack_stats(self, rows: np.ndarray) -> dict:
+        """A decode program's packed ``stat_*`` leaves (``pack_stats``,
+        on the host) by name, ``{name: [leaf [capacity, ...] a layer
+        that declares it]}``."""
+        out, at = {}, 0
+        for name, shape in self._stat_shapes:
+            n = int(np.prod(shape[1:]))
+            out.setdefault(name, []).append(rows[at:at + n].T.reshape(shape))
+            at += n
         return out
 
     @property

@@ -113,6 +113,9 @@ class ServingMetrics:
         self.n_spec_steps = 0
         self.n_spec_active = 0
         self.n_spec_emitted = 0
+        # one-program-ahead accounting (``on_decode_step``, ``on_tokens``)
+        self.n_decode_ahead = 0
+        self.n_decode_overrun_slots = 0
         # the stat_expert_rows leaves as last read (``on_expert_rows``)
         self._expert_rows_seen = None
         # the stalled step: the longest step so far, and what a step is
@@ -202,14 +205,24 @@ class ServingMetrics:
     def on_token(self, rid, now: float):
         self._req[rid].n_tokens += 1
 
-    def on_tokens(self, n_tokens: int):
+    def on_tokens(self, n_tokens: int, overrun: int = 0):
         """A decode step emitted ``n_tokens`` over all its slots (first
         tokens included): the counter moves once a step, not once a
-        token."""
+        token.  ``overrun``: the slots the program advanced for a
+        request that had left them by the time the host read it (an EOS
+        in the program before, a cancel, a shed, while this one was in
+        flight): device work that emitted nothing."""
+        self.n_decode_overrun_slots += overrun
         reg = self._reg()
         if reg is not None and n_tokens:
             reg.counter("bf_serving_tokens_total",
                         "tokens generated").inc(n_tokens)
+        if reg is not None and overrun:
+            reg.counter(
+                "bf_serving_decode_overrun_slots_total",
+                "slots a decode program advanced whose request had "
+                "retired while the program was in flight (the tokens "
+                "are dropped)").inc(overrun)
 
     def on_retire(self, rid, now: float, outcome: str):
         rec = self._req[rid]
@@ -278,7 +291,7 @@ class ServingMetrics:
         self.on_mixed_tokens(mixed)
 
     def on_decode_step(self, n_slots: int, attended=(), streamed=(),
-                       mixed: int = 0):
+                       mixed: int = 0, ahead: bool = False):
         """One decode program call (plain or speculative) advanced
         ``n_slots`` active slots: slots / steps is the batch size a
         decode step.  ``attended``: ``((kind, positions), ...)``, the
@@ -288,11 +301,20 @@ class ServingMetrics:
         attend them (``ServedModel.streamed_positions``, every slot of
         the pool), from the same lengths.  With ``decode_horizon`` > 1
         both are the call's first token step.  ``mixed``: see
-        :meth:`on_mixed_tokens`."""
+        :meth:`on_mixed_tokens`.  ``ahead``: the program was dispatched
+        while the one before it was in flight (the host had not read
+        its tokens): the share of such calls is how often the engine's
+        host work ran beside the device's."""
+        self.n_decode_ahead += bool(ahead)
         reg = self._reg()
         if reg is not None:
             reg.counter("bf_serving_decode_steps_total",
                         "decode program calls").inc()
+            if ahead:
+                reg.counter(
+                    "bf_serving_decode_ahead_total",
+                    "decode program calls dispatched while the call "
+                    "before was in flight").inc()
             reg.counter("bf_serving_decode_slots_total",
                         "active slots summed over decode program calls"
                         ).inc(n_slots)

@@ -169,6 +169,9 @@ def failover_stranded(engine, resubmit: Callable[[Request], object], *,
     Returns ``(moved, expired)`` request lists.
     """
     eng = getattr(engine, "engine", engine)
+    # what the device computed before the replica went is not lost: the
+    # requests leave with the tokens of the program in flight
+    eng.collect()
     if now is None:
         now = eng.clock()
     stranded = sorted(eng._running.values(), key=lambda r: r.slot)

@@ -5,7 +5,8 @@ the control plane — so :class:`SimReplica` keeps the
 :class:`~bluefog_tpu.serving.engine.ServingEngine`'s exact host
 bookkeeping (the same :class:`~bluefog_tpu.serving.scheduler
 .FifoScheduler`, the same LIFO slot pool discipline, the same
-admit → chunked-prefill → decode-horizon step order, the same metric
+admit → chunked-prefill → decode-horizon step order with the decode
+program dispatched one step before its tokens are read, the same metric
 publication points) and deletes only the device work, charging the
 calibrated :class:`~bluefog_tpu.sim.cost.CostModel` instead.  Every
 family lands in the replica's own
@@ -198,6 +199,8 @@ class SimReplica:
         self._free: List[int] = list(range(capacity - 1, -1, -1))
         self._running: Dict[int, SimRequest] = {}
         self._admitting: Optional[SimRequest] = None
+        # the decode program dispatched and not read: {slot: request}
+        self._flight: Optional[Dict[int, SimRequest]] = None
         self.dead = False
         self.reject_submits = False
         self.n_steps = 0
@@ -213,7 +216,7 @@ class SimReplica:
     @property
     def busy(self) -> bool:
         return bool(self._running or self._admitting
-                    or self.scheduler.queue_depth)
+                    or self.scheduler.queue_depth or self._flight)
 
     # -- the engine surface the router uses ----------------------------- #
     def submit(self, request: SimRequest) -> SimRequest:
@@ -250,8 +253,9 @@ class SimReplica:
     # -- the serving loop ---------------------------------------------- #
     def step(self) -> bool:
         """One engine iteration, the real step's exact order: shed and
-        cancel, admit + budgeted prefill chunks, decode one horizon for
-        every active slot, publish the step gauges.  Device time is the
+        cancel, admit + budgeted prefill chunks, dispatch one decode
+        horizon for every active slot and read the one dispatched the
+        step before, publish the step gauges.  Device time is the
         DRIVER's to charge (``cost.step_s`` per lockstep tick)."""
         now = self.clock()
         # 1. deadline shedding in the queue
@@ -287,30 +291,48 @@ class SimReplica:
                     continue
             self._prefill_one_chunk(self._admitting)
             chunks += 1
-        # 5. one decode horizon for every active slot
-        decoding = [r for r in self._running.values()
-                    if r.state == DECODE]
-        if decoding:
-            now2 = self.clock()
-            for req in decoding:
-                emitted = 0
-                for _ in range(self.decode_horizon):
-                    first = req.n_tokens == 0
-                    req.n_tokens += 1
-                    if first:
-                        req.first_token_t = now2
-                        self.metrics.on_first_token(req, now2)
-                    else:
-                        emitted += 1
-                    if req.n_tokens >= req.max_new_tokens:
-                        self._retire(req, COMPLETED, now2)
-                        break
-                self.metrics.on_tokens(emitted)
+        # 5. one decode horizon for every active slot, ONE PROGRAM
+        #    AHEAD as the real engine runs it: this step's program goes
+        #    out over the slots the host cannot count out (a request
+        #    the program in flight takes to its budget does not go on),
+        #    then the tokens of the program the step before dispatched
+        #    are read
+        flight, ahead = self._flight, self._flight or {}
+        self._flight = {
+            slot: r for slot, r in self._running.items()
+            if r.state == DECODE and r.n_tokens + (
+                self.decode_horizon if ahead.get(slot) is r else 0)
+            < r.max_new_tokens} or None
+        self._collect(flight)
         self.n_steps += 1
         self.metrics.on_step(self.occupancy(),
                              self.scheduler.queue_depth,
                              self.cost.step_s, now=now)
         return self.busy
+
+    def _collect(self, flight: Optional[Dict[int, "SimRequest"]]) -> None:
+        """Read a dispatched program's tokens (the engine's ``_collect``
+        + ``_emit``): a slot whose request left it while the program
+        was in flight emits nothing."""
+        if not flight:
+            return
+        now = self.clock()
+        for slot, req in flight.items():
+            if self._running.get(slot) is not req:
+                continue
+            emitted = 0
+            for _ in range(self.decode_horizon):
+                first = req.n_tokens == 0
+                req.n_tokens += 1
+                if first:
+                    req.first_token_t = now
+                    self.metrics.on_first_token(req, now)
+                else:
+                    emitted += 1
+                if req.n_tokens >= req.max_new_tokens:
+                    self._retire(req, COMPLETED, now)
+                    break
+            self.metrics.on_tokens(emitted)
 
     def _prefill_one_chunk(self, req: SimRequest) -> None:
         n_prefill = req.prompt_len + req.n_tokens - 1
@@ -341,6 +363,10 @@ class SimReplica:
         count intact — the token-exact failover contract.  Residents
         that held a slot retire here with outcome ``failover``; each
         departing request counts one ``bf_serving_failovers_total``."""
+        # the requests leave with the tokens of the program in flight
+        # (``failover_stranded`` collects it first)
+        flight, self._flight = self._flight, None
+        self._collect(flight)
         now = self.clock()
         out: List[SimRequest] = []
         for req in self.scheduler.drain():

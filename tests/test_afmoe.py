@@ -560,8 +560,34 @@ def test_the_pool_reports_window_and_full_bytes_of_its_leaves():
             by_hand["full"] += leaf.nbytes
     assert by_hand == pool.cache_bytes()
     # an expert layer's last choices and what its expert loop has cost
-    assert pool.has_stats and {k: len(v) for k, v in pool.stats().items()} \
-        == {"stat_experts": 4, "stat_expert_rows": 4}
+    from bluefog_tpu.serving.kv_pool import pack_stats
+
+    assert pool.stat_rows == 4 * (4 + 2)
+    packed = np.arange(pool.stat_rows * 3, dtype=np.int32).reshape(-1, 3)
+    assert pack_stats(pool.cache).shape == packed.shape
+    stats = pool.unpack_stats(packed)
+    assert pool.has_stats and {k: [a.shape for a in v]
+                               for k, v in stats.items()} \
+        == {"stat_experts": [(3, 1, 4)] * 4,
+            "stat_expert_rows": [(3, 2)] * 4}
+    # a row a number a slot holds, leaf by leaf as the tree flattens
+    # them: a layer's rows, then its choices
+    np.testing.assert_array_equal(stats["stat_expert_rows"][0],
+                                  packed[:2].T)
+    np.testing.assert_array_equal(stats["stat_experts"][0],
+                                  packed[2:6].T.reshape(3, 1, 4))
+    # and pack_stats lays the device's leaves out the same way
+    cache = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: (jnp.arange(leaf.size, dtype=leaf.dtype)
+                            .reshape(leaf.shape)
+                            if path[-1].key.startswith("stat_") else leaf),
+        pool.cache)
+    back = pool.unpack_stats(np.asarray(pack_stats(cache)))
+    want = [leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(cache)[0]
+            if path[-1].key == "stat_experts"]
+    for got, leaf in zip(back["stat_experts"], want):
+        np.testing.assert_array_equal(got, leaf)
     assert cfg.cache_kinds() == {"window": (4, WINDOW), "full": (1, None)}
 
 
@@ -601,6 +627,61 @@ def test_the_counters_of_a_served_run():
     assert reg.gauge("bf_serving_cache_bytes", "", kind="window").value \
         == eng.pool.cache_bytes()["window"]
     assert all(r.state == "completed" for r in reqs)
+
+
+def test_the_counters_of_a_run_ahead_are_the_synchronous_count():
+    """A model with ``stat_*`` leaves, served one program ahead: the
+    leaves of program k are read (from the program's own packed output)
+    after program k+1 took the pool by donation, and every counter
+    (the expert choices and rows, the attended and streamed positions,
+    the decode slots) ends where it ends when every program is read
+    before the next is dispatched."""
+    from bluefog_tpu.observe.registry import MetricsRegistry
+
+    params = {"params": _params()}
+
+    def counters(sync):
+        reg = MetricsRegistry()
+        rng = np.random.default_rng(8)
+        # a slot each: which program a request joins then hangs on the
+        # prefill before it alone, and both orders dispatch the same
+        # programs (a request that waits for a slot is admitted when
+        # the host READS the retirement, a program later run ahead)
+        eng = ServingEngine(params, FAMILY.model_config(SZ), capacity=3,
+                            max_len=64, prefill_chunk=4, registry=reg)
+        reqs = [Request(rng.integers(0, 128, n), new)
+                for n, new in ((20, 5), (3, 7), (9, 4))]
+        for r in reqs[:2]:
+            eng.submit(r)
+        k = 0
+        while eng.busy:
+            if k == 3:
+                eng.submit(reqs[2])
+            eng.step()
+            if sync:
+                eng.collect()
+            k += 1
+        assert all(r.state == "completed" for r in reqs)
+        ahead = eng.metrics.n_decode_ahead
+        assert (ahead == 0) if sync else (ahead > 0)
+        out = {}
+        for name, rows in reg.snapshot().items():
+            if name.endswith("_total") and name not in (
+                    "bf_serving_decode_ahead_total",
+                    "bf_serving_steps_total"):
+                for row in rows:
+                    out[name, tuple(sorted(row["labels"].items()))] = \
+                        row["value"]
+        return [list(r.tokens) for r in reqs], out
+
+    (sync_tokens, want), (tokens, got) = counters(True), counters(False)
+    assert tokens == sync_tokens
+    assert {name for name, _ in want} >= {
+        "bf_moe_assignments_total", "bf_moe_experts_hit_total",
+        "bf_moe_expert_rows_total", "bf_moe_expert_assignments_total",
+        "bf_serving_attended_positions_total",
+        "bf_serving_streamed_positions_total"}
+    assert got == want
 
 
 def test_the_rows_a_chunk_reads_are_counted_by_kind(monkeypatch):
@@ -715,7 +796,7 @@ def test_with_no_registry_the_step_fetches_no_stat_leaf(monkeypatch):
     def boom(*_):
         raise AssertionError("counted with nobody to count for")
 
-    monkeypatch.setattr(eng.pool, "stats", boom)
+    monkeypatch.setattr(eng.pool, "unpack_stats", boom)
     monkeypatch.setattr("bluefog_tpu.serving.protocol.attended_positions",
                         boom)
     req = eng.submit(Request(np.arange(9), 4))

@@ -165,6 +165,185 @@ def test_decode_horizon_invariant():
         np.testing.assert_array_equal(y, _one_shot(variables, cfg, p, n))
 
 
+# --------------------------------------------------------------------- #
+# one decode program ahead of the host's knowledge of the tokens
+# --------------------------------------------------------------------- #
+AHEAD_LEN = 40      # a max_len no other test uses: these programs are
+# traced here, so the executables can be counted
+
+
+def _drive(eng, arrivals, sync=False, on_step=None):
+    """Step ``eng`` until idle, submitting ``arrivals[k]`` before step
+    ``k``.  ``sync``: read every program right after the step that
+    dispatched it, so that the host knows every token before the next
+    dispatch: the order the engine had before it ran ahead, and the
+    reference its streams are held to."""
+    k = 0
+    while True:
+        for r in arrivals.get(k, ()):
+            eng.submit(r)
+        busy = eng.step()
+        if sync:
+            eng.collect()
+            busy = eng.busy
+        if on_step is not None:
+            on_step(k)
+        k += 1
+        assert k < 200
+        if not busy and k > max(arrivals):
+            return k
+
+
+def _ahead_engine(variables, cfg, horizon, **kw):
+    return ServingEngine(variables, cfg, capacity=2, max_len=AHEAD_LEN,
+                         prefill_chunk=4, decode_horizon=horizon, **kw)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.9])
+@pytest.mark.parametrize("horizon", [1, 2])
+def test_an_eos_learnt_one_program_late_and_the_slot_reused(horizon,
+                                                            temperature):
+    """The streams of an engine that runs one program ahead are the
+    streams of one that reads every program before the next dispatch
+    (and, greedy, of ``generate``), for every horizon: with an EOS that
+    lands while the next program is in flight and holds the slot, the
+    slot then reused by a request that PREFILLS into it beside a third
+    that decodes throughout; a request whose prefill ends while a
+    program is in flight; sampled streams too (the rng's token count
+    includes the tokens in flight).  One decode executable serves every
+    mix of slots that go on and slots that join, and the two counters
+    say how often the host ran ahead and what the late EOS cost."""
+    from bluefog_tpu.observe import MetricsRegistry
+    from bluefog_tpu.serving.engine import _decode_step_prog
+
+    cfg, variables = _setup()
+    prompts = _prompts((5, 9, 3, 11), seed=21)
+
+    def requests(eos=None):
+        return [Request(prompts[0], 12, eos_id=eos, temperature=temperature,
+                        seed=3),
+                Request(prompts[1], 14, temperature=temperature, seed=4),
+                Request(prompts[2], 5, temperature=temperature, seed=5),
+                Request(prompts[3], 4, temperature=temperature, seed=6)]
+
+    def serve(eos, sync):
+        reg = MetricsRegistry()
+        eng = _ahead_engine(variables, cfg, horizon, registry=reg)
+        reqs = requests(eos)
+        # r2 and r3 wait for a slot: r0's, when its EOS lands
+        _drive(eng, {0: reqs[:2], 2: reqs[2:]}, sync=sync)
+        assert all(r.state == "completed" for r in reqs)
+        assert eng.pool.n_free == 2 and not eng.busy
+        return eng, reg, [list(r.tokens) for r in reqs]
+
+    n_before = _decode_step_prog._cache_size()
+    _, _, free_run = serve(None, sync=True)
+    n_traced = _decode_step_prog._cache_size()
+    eos = free_run[0][4]                      # r0 stops at its 5th token
+    assume_first = free_run[0].index(eos)
+    want = [free_run[0][:assume_first + 1]] + free_run[1:]
+    _, sync_reg, sync_run = serve(eos, sync=True)
+    eng, reg, ahead_run = serve(eos, sync=False)
+    assert sync_run == want
+    assert ahead_run == want
+    if temperature == 0.0:
+        for toks, p in zip(free_run, prompts):
+            np.testing.assert_array_equal(
+                np.concatenate([p, toks]),
+                llama_generate(variables, cfg, jnp.asarray(p[None]),
+                               len(toks), max_len=AHEAD_LEN)[0])
+    # (g) one executable (traced by the first of these tests to run at
+    # this horizon): the first program of a stretch, programs ahead,
+    # slots that join, an overrun lane and the synchronous order alike
+    assert n_traced - n_before <= 1
+    assert _decode_step_prog._cache_size() == n_traced
+    # (h) the counters
+    value = lambda r, name: (r.snapshot().get(name) or [{"value": 0}])[0][
+        "value"]
+    steps = value(reg, "bf_serving_decode_steps_total")
+    assert value(sync_reg, "bf_serving_decode_ahead_total") == 0
+    assert value(sync_reg, "bf_serving_decode_overrun_slots_total") == 0
+    assert 0 < steps - value(reg, "bf_serving_decode_ahead_total") <= 2
+    assert eng.metrics.n_decode_ahead == value(
+        reg, "bf_serving_decode_ahead_total")
+    # the program in flight when r0's EOS was read held r0
+    assert value(reg, "bf_serving_decode_overrun_slots_total") == 1
+    assert eng.metrics.n_decode_overrun_slots == 1
+    # an overrun lane is device work and no token
+    emitted = sum(len(t) for t in want)
+    assert value(reg, "bf_serving_tokens_total") == emitted
+    lanes = value(reg, "bf_serving_decode_slots_total")
+    sync_lanes = value(sync_reg, "bf_serving_decode_slots_total")
+    assert lanes == sync_lanes + 1
+    if horizon == 1:
+        assert sync_lanes == emitted
+
+
+def test_a_prefill_that_ends_beside_a_program_in_flight_joins_the_next():
+    """The request's first token comes from the program dispatched in
+    the step its last chunk ran in: it takes the host's token (the
+    prompt's last) while the slot beside it takes the device's."""
+    cfg, variables = _setup()
+    prompts = _prompts((3, 10), seed=5)
+    eng = _ahead_engine(variables, cfg, 1)
+    r0, r1 = Request(prompts[0], 12), Request(prompts[1], 4)
+    seen = []
+
+    def watch(k):
+        flight = eng._flight
+        seen.append((r1.state, len(r1.tokens),
+                     sorted(flight.decoding) if flight else None))
+
+    _drive(eng, {0: [r0], 2: [r1]}, on_step=watch)
+    joined = next(i for i, (state, _, _) in enumerate(seen)
+                  if state == "decode")
+    # the step before: r0 alone in flight, r1 still prefilling
+    assert seen[joined - 1] == ("prefill", 0, [0])
+    # the step its prefill ended: both slots dispatched, no token yet
+    assert seen[joined] == ("decode", 0, [0, 1])
+    assert seen[joined + 1][:2] == ("decode", 1)
+    for r, p in zip((r0, r1), prompts):
+        np.testing.assert_array_equal(
+            r.output(), llama_generate(
+                variables, cfg, jnp.asarray(p[None]), r.max_new_tokens,
+                max_len=AHEAD_LEN)[0])
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_a_request_that_leaves_while_a_program_holds_it(how):
+    """``cancel()`` and a deadline shed of a request the program in
+    flight advances: its lane is dropped (an overrun), its stream is a
+    prefix of what it would have been, the slot beside it and the
+    request that takes the freed slot are exact."""
+    cfg, variables = _setup()
+    clock = VirtualClock()
+    prompts = _prompts((5, 7, 6), seed=9)
+    eng = _ahead_engine(variables, cfg, 1, clock=clock)
+    full = [np.asarray(llama_generate(
+        variables, cfg, jnp.asarray(p[None]), 10, max_len=AHEAD_LEN)[0])
+        for p in prompts]
+    r0 = Request(prompts[0], 10, deadline=3.5 if how == "deadline" else None)
+    r1, r2 = Request(prompts[1], 10), Request(prompts[2], 10)
+
+    def tick(k):
+        clock.advance(1.0)
+        if k == 3:
+            assert eng._flight.decoding[r0.slot] is r0 and len(r0.tokens) >= 2
+            if how == "cancel":
+                eng.cancel(r0)
+
+    _drive(eng, {0: [r0, r1], 1: [r2]}, on_step=tick)
+    assert r0.state == "cancelled" and r0.slot is None
+    n = len(r0.tokens)
+    assert 2 <= n < 10
+    np.testing.assert_array_equal(r0.output(), full[0][:prompts[0].size + n])
+    assert eng.metrics.n_decode_overrun_slots == 1
+    for r, want in ((r1, full[1]), (r2, full[2])):
+        assert r.state == "completed"
+        np.testing.assert_array_equal(r.output(), want)
+    assert eng.pool.n_free == 2 and not eng.busy
+
+
 def test_temperature_sampling_deterministic_and_in_range():
     """Per-request sampling is a function of (seed, token index) only —
     re-serving the same request reproduces the stream, independent of
@@ -205,8 +384,9 @@ def test_deadline_cancels_running_and_queued():
     prompts = _prompts((4, 4), seed=2)
     eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
                         prefill_chunk=4, clock=clock)
-    # r0 runs but can never finish 20 tokens by t=1.0 (1s per step)
-    r0 = eng.submit(Request(prompts[0], 20, deadline=1.0))
+    # r0 runs but can never finish 20 tokens by t=2.0 (1s per step;
+    # its first decode program is dispatched at t=0 and read at t=1)
+    r0 = eng.submit(Request(prompts[0], 20, deadline=2.0))
     # r1 is stuck behind r0 and expires in the queue
     r1 = eng.submit(Request(prompts[1], 2, deadline=0.5))
     steps = 0
@@ -218,6 +398,8 @@ def test_deadline_cancels_running_and_queued():
     assert 0 < len(r0.tokens) < 20  # partial stream delivered
     assert r1.state == "cancelled" and r1.tokens == []
     assert eng.pool.n_free == 1  # cancelled slots come back
+    # the program in flight at the deadline held r0: its token is dropped
+    assert eng.metrics.n_decode_overrun_slots == 1
 
 
 def test_deadline_cancels_mid_prefill():
@@ -433,6 +615,10 @@ def _engine_spans(events):
 
 PHASE_ORDER = ["admit", "prefill_chunk", "decode_inputs",
                "decode_dispatch", "token_fetch", "emit"]
+#: what a step holds of the decode path: the next program's dispatch,
+#: then the read of the one the step before dispatched; the first step
+#: of a busy stretch has nothing to read, the last nothing to dispatch
+DECODE_PHASES = ([], PHASE_ORDER[2:4], PHASE_ORDER[2:], PHASE_ORDER[4:])
 
 
 @pytest.mark.parametrize("budget", [1, 2])
@@ -442,7 +628,9 @@ def test_every_engine_step_holds_its_phases_in_order(budget):
     the default prefill budget (a larger budget may admit again after a
     prefill that ended inside the step), durations summing to no more
     than the step's; ``prefill_chunk`` names the request it worked
-    for."""
+    for.  A step dispatches the next decode program and THEN reads the
+    one before: ``token_fetch`` carries the ``launch=`` of the
+    ``decode_dispatch`` it waits for, which lies in the step before."""
     from bluefog_tpu import observe
 
     cfg, variables = _setup()
@@ -464,6 +652,7 @@ def test_every_engine_step_holds_its_phases_in_order(budget):
     steps = [sp for sp in spans if sp[0] == "step"]
     assert len(steps) == n_steps
     chunk_rids, emitted, admitted = set(), 0, 0
+    dispatched, in_flight = [], None
     for i, (_, s0, s1, _) in enumerate(steps):
         nxt = steps[i + 1][1] if i + 1 < len(steps) else float("inf")
         held = [sp for sp in spans
@@ -482,7 +671,12 @@ def test_every_engine_step_holds_its_phases_in_order(budget):
         names = [sp[0] for sp in held]
         assert names[0] == "admit"
         decode = [n for n in names if n not in ("admit", "prefill_chunk")]
-        assert decode in ([], PHASE_ORDER[2:])
+        assert decode in DECODE_PHASES
+        # a read exactly where a program was in flight at the step's start
+        assert ("token_fetch" in decode) == (in_flight is not None)
+        if "token_fetch" in decode:
+            assert fetch[0][3]["launch"] == in_flight
+        in_flight = None
         before = names[:len(names) - len(decode)]
         assert set(before) <= {"admit", "prefill_chunk"}
         assert before.count("prefill_chunk") <= budget
@@ -499,6 +693,11 @@ def test_every_engine_step_holds_its_phases_in_order(budget):
                 admitted += args["admitted"]
             elif name == "decode_inputs":
                 assert 1 <= args["slots"] <= 2
+            elif name == "decode_dispatch":
+                in_flight = args["launch"]
+                dispatched.append(in_flight)
+    assert in_flight is None and len(dispatched) == len(
+        [sp for sp in spans if sp[0] == "emit"])
     assert chunk_rids == {r.rid for r in reqs if r.prompt.size > 1}
     assert emitted == sum(len(r.tokens) for r in reqs) == 15
     assert admitted == len(reqs)

@@ -180,12 +180,15 @@ def test_faulty_replica_death_stall_and_reject():
 # --------------------------------------------------------------------- #
 # token-exact failover on replica death
 # --------------------------------------------------------------------- #
-def test_failover_is_token_exact_and_zero_recompile():
+@pytest.mark.parametrize("steps", [6, 7])
+def test_failover_is_token_exact_and_zero_recompile(steps):
     """Kill a replica holding a mid-decode request (with emitted
     tokens), a mid-prefill request (no tokens yet), and a queued one;
     fail everything over to a survivor sharing the prefix cache.  Every
     output must be bit-equal to a fault-free run, and the resident jit
-    caches must not grow across the whole exercise."""
+    caches must not grow across the whole exercise.  The replica dies
+    with a decode program in flight: its requests leave with that
+    program's tokens, once."""
     cfg, variables = _setup()
     rs = np.random.RandomState(11)
     reqs = _requests(rs, n=3, prompt_len=(9, 14))
@@ -207,17 +210,20 @@ def test_failover_is_token_exact_and_zero_recompile():
     live = [e0.submit(_clone(r)) for r in reqs]
     # step until the first resident has emitted tokens but nobody is
     # done — capacity 2 keeps the third request queued
-    for _ in range(6):
+    for _ in range(steps):
         clock.advance(0.01)
         e0.step()
     assert any(r.tokens and not r.done for r in live)
     assert any(r.state == "queued" for r in live)
-    pre_counts = {r.rid: len(r.tokens) for r in live}
+    in_flight = {r.rid for r in e0._flight.decoding.values()}
+    assert in_flight
+    pre_counts = {r.rid: len(r.tokens) + (r.rid in in_flight) for r in live}
     moved, expired = failover_stranded(e0, e1.submit)
-    assert expired == []
+    assert expired == [] and e0._flight is None
     assert sorted(r.rid for r in moved) == sorted(r.rid for r in live)
     assert e0.metrics.summary()["n_failovers"] == 3
-    # tokens survived the move; nothing was re-emitted or lost
+    # tokens survived the move, the program's in flight with them;
+    # nothing was re-emitted or lost
     for r in live:
         assert len(r.tokens) == pre_counts[r.rid]
         assert r.state == "queued" and r.slot is None
@@ -416,11 +422,14 @@ def test_drain_completes_mixed_residents_in_place():
     assert eng.metrics.summary()["outcomes"].get("rejected") == 1
 
 
-def test_drain_hands_off_token_exact():
+@pytest.mark.parametrize("steps", [4, 5, 6, 7])
+def test_drain_hands_off_token_exact(steps):
     """With a handoff target: mixed prefill/decode residents and the
     queue all migrate, and the drained replica's flushed K/V makes the
     target restore rather than recompute — outputs bit-equal to a
-    fault-free run."""
+    fault-free run.  The drain finds a decode program in flight: it is
+    read first, so no token is lost or doubled and the chunks flushed are
+    the chunks written, the program's own position among them."""
     cfg, variables = _setup()
     rs = np.random.RandomState(21)
     reqs = _requests(rs, n=3, prompt_len=(9, 14))
@@ -434,14 +443,26 @@ def test_drain_hands_off_token_exact():
     e0 = _engine(variables, cfg, clock, prefix=prefix)
     e1 = _engine(variables, cfg, clock, prefix=prefix)
     live = [e0.submit(_clone(r)) for r in reqs]
-    for _ in range(5):
+    for _ in range(steps):
         clock.advance(0.01)
         e0.step()
     assert any(r.tokens for r in live)
-    summary = e0.drain(handoff=e1.submit)
+    in_flight = {r.rid for r in e0._flight.decoding.values()}
+    assert in_flight
+    pre_counts = {r.rid: len(r.tokens) + (r.rid in in_flight) for r in live}
+    handed = []
+    summary = e0.drain(handoff=lambda r: handed.append(r) or e1.submit(r))
     assert summary["handed_off"] == 3 and summary["completed"] == 0
     assert not e0._running and e0._admitting is None
-    assert e0.scheduler.queue_depth == 0
+    assert e0.scheduler.queue_depth == 0 and not e0.busy
+    assert {r.rid: len(r.tokens) for r in live} == pre_counts
+    for r in handed:
+        if r.rid in in_flight:
+            # every chunk the resident WROTE is flushed, the one the
+            # program in flight completed among them (steps 5: the 13th
+            # token's program wrote position 11, the third chunk's last)
+            ctx = np.concatenate([r.prompt, np.asarray(r.tokens, np.int32)])
+            assert all(k in prefix for k in prefix.chunk_keys(ctx))
     while e1.step():
         clock.advance(0.01)
     for r, want in zip(live, ref_out):
