@@ -25,13 +25,17 @@ logits, the one thing the published models differ by (``score_func``)::
     "softmax": s = softmax(m Wr);  T = top_k(s)       no bias at all
     either:    w_e = route_scale * s_e / (sum_T s + 1e-20)
 
+and, where it has ``n_group`` > 1, that ``T`` is chosen among the experts
+of the ``topk_group`` best groups only (``route``).
+
 In the serving layout the layer keeps two ``stat_*`` cache leaves (see
 ``serving/protocol.py``) for the ``bf_moe_*`` counters of
 ``serving/metrics.py``.
 
 The config (any frozen dataclass) gives: ``n_experts``, ``top_k``,
 ``route_scale``, ``score_func``, ``held``, ``expert_hidden_dim``,
-``dim``, ``initializer_range``, ``dtype``, ``decode``.
+``dim``, ``initializer_range``, ``dtype``, ``decode``; and may give
+``n_group`` and ``topk_group`` (absent: 1, no groups).
 """
 
 from __future__ import annotations
@@ -73,12 +77,24 @@ class SwiGLU(nn.Module):
         return _dense(cfg, cfg.dim, "w2")(nn.silu(gate) * up)
 
 
-def route(scores, bias, top_k: int, route_scale: float):
+def route(scores, bias, top_k: int, route_scale: float, n_group: int = 1,
+          topk_group: int = 1):
     """``(chosen [N, top_k], weights [N, top_k])`` from the scores ``[N,
     E]``: the bias (``None``: the model has none) enters the choice and
     not the weights, which are the chosen scores over their sum, times
-    ``route_scale``."""
-    _, chosen = lax.top_k(scores if bias is None else scores + bias, top_k)
+    ``route_scale``.  With ``n_group`` > 1 the outputs are that many
+    groups of neighbours, a group scores the sum of its two largest
+    biased scores, and the choice is among the ``topk_group`` best
+    groups' experts alone."""
+    biased = scores if bias is None else scores + bias
+    if n_group > 1:
+        rows, outputs = biased.shape
+        grouped = biased.reshape(rows, n_group, outputs // n_group)
+        _, kept = lax.top_k(lax.top_k(grouped, 2)[0].sum(-1), topk_group)
+        keep = jax.nn.one_hot(kept, n_group, dtype=bool).any(1)
+        biased = jnp.where(keep[..., None], grouped, -jnp.inf).reshape(
+            rows, outputs)
+    _, chosen = lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
     return chosen, picked * route_scale
@@ -226,7 +242,9 @@ class ExpertLayer(nn.Module):
             else:
                 scores, bias = jax.nn.softmax(logits, axis=-1), None
             chosen, weights = route(scores, bias, cfg.top_k,
-                                    cfg.route_scale)
+                                    cfg.route_scale,
+                                    getattr(cfg, "n_group", 1),
+                                    getattr(cfg, "topk_group", 1))
             # [N, held]: a token's weight on each held expert, zero where
             # it chose another (one_hot of a row outside is all zeros)
             combine = (jax.nn.one_hot(chosen - first, count,

@@ -1,5 +1,5 @@
 """A decoder with LATENT attention and routed experts beside a shared
-one (the layer's keys are DeepSeek-V3's and are read that way).  Two
+one (the layer's keys are DeepSeek-V3's and are read that way).  Three
 published models are computed here, and the config's fields select each
 path, nothing else does:
 
@@ -11,6 +11,13 @@ path, nothing else does:
   feed-forward is a SwiGLU of ``dense_hidden_dim``, and a residual of
   ``hc_mult`` = 4 streams mixed a token at a time around every sublayer
   (``models/hyper_connections.py``); no position scale on the query.
+* ``Ling-3.0-flash-VL``'s language model: ``layer_types`` names each
+  layer's mixer, ``"kda"`` (the recurrent mixer of ``models/kda.py``,
+  whose cache is a state and not positions) or ``"latent"``; the latent
+  layers take the query straight from the input (``q_lora_rank`` None)
+  and gate each head's output (``head_gate``); the experts are chosen
+  inside the best ``topk_group`` of ``n_group`` groups
+  (``models/experts.py:route``).
 
 Imported lazily (nothing on ``import bluefog_tpu``'s path names it); it
 reuses ``RMSNorm`` of ``models/llama.py`` and the expert layer of
@@ -94,6 +101,7 @@ __all__ = ["MlaMoeConfig", "MlaMoe", "yarn_frequencies", "yarn_mscale",
 SCOPE_ATTN_LATENT = "bf.attn.latent"
 SCOPE_ATTN_ABSORB = "bf.attn.latent_absorb"
 SCOPE_ATTN_EXPAND = "bf.attn.latent_expand"
+LAYER_TYPES = ("latent", "kda")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,7 +110,7 @@ class MlaMoeConfig:
     dim: int = 64
     n_layers: int = 2
     n_heads: int = 4
-    q_lora_rank: int = 32
+    q_lora_rank: Optional[int] = 32   # None: q = a W_q, no query latent
     kv_lora_rank: int = 16
     qk_nope_head_dim: int = 8
     qk_rope_head_dim: int = 8
@@ -112,6 +120,19 @@ class MlaMoeConfig:
     top_k: int = 4
     route_scale: float = 1.0
     score_func: str = "softmax"      # the expert layer's (models/experts.py)
+    # the experts are chosen among the best ``topk_group`` of ``n_group``
+    # groups of the router's outputs (1: among all of them)
+    n_group: int = 1
+    topk_group: int = 1
+    # each layer's mixer, "latent" or "kda"; None: every layer latent
+    layer_types: Optional[Tuple[str, ...]] = None
+    # the latent layer scales head h's output by sigmoid(a W_gate)_h
+    head_gate: bool = False
+    # the recurrent mixer (models/kda.py): keys and values of a head,
+    # taps of the convolution, the floor of the log decay
+    kda_head_dim: int = 8
+    kda_conv_kernel: int = 4
+    kda_lower_bound: float = -5.0
     n_dense_layers: int = 0          # leading layers with a dense FFN
     dense_hidden_dim: int = 128
     # the residual path: streams (1: the plain ``h + f(norm(h))``), and
@@ -159,10 +180,37 @@ class MlaMoeConfig:
         if self.decode_attn not in ("xla", "pallas"):
             raise ValueError(f"decode_attn {self.decode_attn!r} not in "
                              "('xla', 'pallas')")
+        if self.layer_types is not None and (
+                len(self.layer_types) != self.n_layers
+                or set(self.layer_types) - set(LAYER_TYPES)):
+            raise ValueError(
+                f"layer_types {self.layer_types} must name one of "
+                f"{LAYER_TYPES} for each of the {self.n_layers} layers")
+        if self.n_experts % self.n_group \
+                or not 1 <= self.topk_group <= self.n_group \
+                or self.topk_group * (self.n_experts // self.n_group) \
+                < self.top_k:
+            raise ValueError(
+                f"n_group {self.n_group} must divide the {self.n_experts} "
+                f"experts and topk_group {self.topk_group} groups must "
+                f"hold the {self.top_k} experts of a token")
 
     @property
     def held(self) -> Tuple[int, int]:
         return self.experts_held or (0, self.n_experts)
+
+    @property
+    def latent_layers(self) -> int:
+        """Layers whose cache is positions of the latent."""
+        return self.n_layers if self.layer_types is None \
+            else self.layer_types.count("latent")
+
+    @property
+    def state_layers(self) -> int:
+        """Layers whose cache is a recurrent state (optional in the
+        serving protocol: the engine counts the live tokens through
+        them)."""
+        return self.n_layers - self.latent_layers
 
     @property
     def latent_width(self) -> int:
@@ -230,8 +278,9 @@ class MlaMoeConfig:
         return logits, mut["cache"]
 
     def cache_kinds(self) -> dict:
-        """A latent position is a position: every layer is "full"."""
-        return {"full": (self.n_layers, None)}
+        """A latent position is a position: every latent layer is
+        "full"; a recurrent layer attends no position."""
+        return {"full": (self.latent_layers, None)}
 
     def streamed_positions(self, positions) -> tuple:
         """Rows of the latent a single-token step fetches, summed over
@@ -239,7 +288,7 @@ class MlaMoeConfig:
         row of every slot under the einsums, whatever is live."""
         from bluefog_tpu.parallel import pallas_decode
 
-        return (("full", self.n_layers * pallas_decode.streamed_positions(
+        return (("full", self.latent_layers * pallas_decode.streamed_positions(
             positions, self.max_seq_len,
             fused=self.decode_attn == "pallas",
             block_s=pallas_decode.latent_block(self.max_seq_len))),)
@@ -252,7 +301,7 @@ class MlaMoeConfig:
         if tokens == 1:
             return 0
         kb = _divisor(self.max_seq_len, self.key_block)
-        return self.n_layers * ((start + tokens - 1) // kb + 1) * kb
+        return self.latent_layers * ((start + tokens - 1) // kb + 1) * kb
 
 
 # ------------------------------------------------------------------ #
@@ -405,10 +454,13 @@ class LatentAttention(nn.Module):
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
         with jax.named_scope(SCOPE_ATTN_LATENT):
-            c_q = RMSNorm(cfg.norm_eps, name="q_norm")(
-                _dense(cfg, cfg.q_lora_rank, "wq_a")(x))
-            q = _dense(cfg, h * (dn + dr), "wq_b")(c_q).reshape(
-                b, t, h, dn + dr)
+            if cfg.q_lora_rank is None:
+                q = _dense(cfg, h * (dn + dr), "wq")(x)
+            else:
+                c_q = RMSNorm(cfg.norm_eps, name="q_norm")(
+                    _dense(cfg, cfg.q_lora_rank, "wq_a")(x))
+                q = _dense(cfg, h * (dn + dr), "wq_b")(c_q)
+            q = q.reshape(b, t, h, dn + dr)
             ckr = _dense(cfg, dc + dr, "wkv_a")(x)
             c = RMSNorm(cfg.norm_eps, name="kv_norm")(ckr[..., :dc])
             w_ukv = self.param(
@@ -453,6 +505,10 @@ class LatentAttention(nn.Module):
                 out = blocked_attention(
                     q_n, q_r, latent, pos, w_ukv, dc, dn, kb,
                     blocks=None if cfg.decode else t // kb)
+            if cfg.head_gate:
+                gate = jax.nn.sigmoid(
+                    _dense(cfg, h, "wgate")(x).astype(jnp.float32))
+                out = out * gate[..., None]
             out = out.astype(cfg.dtype).reshape(b, t, h * dv)
             return _dense(cfg, cfg.dim, "wo")(out)
 
@@ -467,6 +523,11 @@ class Block(nn.Module):
         norm = lambda name: RMSNorm(cfg.norm_eps, name=name)
 
         def attend(a):
+            if cfg.layer_types is not None \
+                    and cfg.layer_types[self.index] == "kda":
+                from bluefog_tpu.models.kda import KimiDeltaAttention
+
+                return KimiDeltaAttention(cfg, name="attention")(a, live)
             return LatentAttention(cfg, name="attention")(a, live)
 
         def feed(m):

@@ -182,7 +182,9 @@ def _corrected_index(new_cache, old_cache, valid_len):
     model advanced the index by the full (padded) chunk length; the
     request only wrote ``valid_len`` real tokens.  The pad tail's K/V
     stays in the cache but above the index, where the causal mask hides
-    it until real tokens overwrite it — exactness needs only the index."""
+    it until real tokens overwrite it — exactness needs only the index.
+    (A ``state_*`` leaf has no such tail: the model itself left it at
+    the last LIVE token, ``serving/protocol.py``.)"""
     def fix(path, new, old):
         if protocol.leaf_kind(path) == protocol.INDEX:
             return old + jnp.asarray(valid_len, old.dtype)
@@ -253,7 +255,9 @@ def _decode_step_prog(params, pool, toks, active, keys, counts, temps,
         # mask hides it until something real overwrites it — the next
         # prefill chunk (mid-admission slots), the next real decode
         # write, or the zero-on-free (free slots).  Masking just the
-        # index leaves skips two whole-pool copies per token.
+        # index leaves skips two whole-pool copies per token.  (A
+        # recurrent layer's state_* leaf comes back as it went in: the
+        # slot's token was not ``live``, and the model froze it.)
         if protocol.leaf_kind(path) != protocol.INDEX:
             return new
         m = active.reshape(active.shape + (1,) * (new.ndim - 1))
@@ -563,6 +567,8 @@ class ServingEngine:
                                        None)
         # and around how many sublayers a token's residual streams are mixed
         self._mixed_sublayers = getattr(self.cfg, "mixed_sublayers", 0)
+        # and how many of its layers keep a recurrent state
+        self._state_layers = getattr(self.cfg, "state_layers", 0)
         self._spec = speculative
         self._draft_pool: Optional[SlotPool] = None
         self._draft_params = None
@@ -584,9 +590,19 @@ class ServingEngine:
                                         zero_on_free=zero_on_free,
                                         prefix=dprefix, chunk=call)
             self._draft_params = speculative.variables["params"]
+            for pool in (self.pool, self._draft_pool):
+                if pool.state_leaves:
+                    raise ValueError(
+                        f"cache leaf {pool.state_leaves[0]} is a recurrent "
+                        "state: the speculative step rolls a slot's index "
+                        "back over the drafts it rejects, and a state leaf "
+                        "does not roll back with it")
         self.scheduler = FifoScheduler(max_queue=max_queue)
         self.metrics = ServingMetrics(registry=registry)
-        self.metrics.on_pool(self.pool.cache_bytes())
+        self.metrics.on_pool(self.pool.cache_bytes(), capacity)
+        if getattr(self.cfg, "n_group", 1) > 1:
+            self.metrics.on_expert_groups(self.cfg.n_group,
+                                          self.cfg.topk_group)
         if self._mixed_sublayers:
             self.metrics.on_residual_streams(self.cfg.residual_streams)
         self.prefill_chunk = prefill_chunk
@@ -1049,7 +1065,8 @@ class ServingEngine:
             self.metrics.on_prefill_chunk(
                 int(valid), self._rebuilt(pos, c) if self._rebuilt else 0,
                 self._chunk_streamed(pos, c) if self._chunk_streamed else (),
-                mixed=int(valid) * self._mixed_sublayers)
+                mixed=int(valid) * self._mixed_sublayers,
+                state=int(valid) * self._state_layers)
             if (valid == c and req._prefix_keys
                     and pos // c < len(req._prefix_keys)):
                 # a FULL cold chunk just landed on the chunk grid —
@@ -1219,7 +1236,7 @@ class ServingEngine:
         self.metrics.on_decode_step(
             len(decoding), flight.attended, flight.streamed,
             mixed=len(decoding) * self._mixed_sublayers,
-            ahead=flight.ahead)
+            ahead=flight.ahead, state=len(decoding) * self._state_layers)
 
     def _spec_decode_step(self, decoding: Dict[int, Request]) -> None:
         """The speculative twin of :meth:`_decode_step`: one resident

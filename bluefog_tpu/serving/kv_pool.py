@@ -12,7 +12,9 @@ Allocation is host-side bookkeeping (a free list); the device tree is
 mutated only through the engine's jitted programs.  Freeing a slot
 resets its ``cache_index`` leaves (one tiny jitted scatter) — that alone
 makes reuse exact, because everything above the index sits behind the
-causal mask and the next request overwrites positions as it writes them.
+causal mask and the next request overwrites positions as it writes them
+(and a recurrent layer's ``state_*`` leaf is read as zeros by a call at
+index 0: ``serving/protocol.py``).
 ``BLUEFOG_KV_ZERO_ON_FREE=1`` (or ``zero_on_free=True``) additionally
 zeroes the slot's contents: a whole-slot HBM write per retirement that
 buys nothing for correctness (tests assert bit-exactness BOTH ways) but
@@ -103,8 +105,9 @@ class SlotPool:
         prefill chunk): the slack a window layer's ring leaf needs.
 
     The pool stacks whatever leaves the model declares: a window
-    layer's ring is about a window long whatever ``max_len`` is
-    (:meth:`cache_bytes` reports both kinds).
+    layer's ring is about a window long and a recurrent layer's state
+    of one size whatever ``max_len`` is (:meth:`cache_bytes` reports
+    each kind).
     """
 
     def __init__(self, cfg, capacity: int, max_len: int,
@@ -144,16 +147,21 @@ class SlotPool:
         self._stat_shapes = [(name, leaf.shape)
                              for name, leaf in _stat_leaves(self.cache)]
         self.has_stats = bool(self._stat_shapes)
+        # the recurrent layers' state_* leaves, by path
+        self.state_leaves = [
+            jax.tree_util.keystr(path) for path, _ in
+            jax.tree_util.tree_flatten_with_path(self.cache)[0]
+            if protocol.leaf_kind(path) == protocol.STATE]
 
     def cache_bytes(self) -> dict:
-        """``{"full" | "window": bytes}`` the pool reserves in leaves of
-        that kind, all slots together (index and stat leaves left
-        out)."""
+        """``{"full" | "window" | "state": bytes}`` the pool reserves in
+        leaves of that kind, all slots together (index and stat leaves
+        left out)."""
         out = {}
         for path, leaf in jax.tree_util.tree_flatten_with_path(
                 self.cache)[0]:
             kind = protocol.leaf_kind(path)
-            if kind in (protocol.FULL, protocol.WINDOW):
+            if kind in (protocol.FULL, protocol.WINDOW, protocol.STATE):
                 out[kind] = out.get(kind, 0) + leaf.size * leaf.dtype.itemsize
         return out
 

@@ -255,7 +255,7 @@ class ServingMetrics:
                         "requests handed off to another replica").inc()
 
     def on_prefill_chunk(self, n_tokens: int, rebuilt: int = 0,
-                         streamed=(), mixed: int = 0):
+                         streamed=(), mixed: int = 0, state: int = 0):
         """One cold prefill chunk ran (a model forward over one chunk)
         with ``n_tokens`` valid positions; the rest of the chunk's
         width was padding.  Together with :meth:`on_prefix_restore`
@@ -268,7 +268,9 @@ class ServingMetrics:
         the chunk read to attend, summed over the kind's layers (the
         model's ``chunk_streamed_positions``, from the same lengths;
         empty for a model that declares none).  ``mixed``: see
-        :meth:`on_mixed_tokens`."""
+        :meth:`on_mixed_tokens`.  ``state``: the chunk's valid tokens
+        times the model's ``state_layers``, the tokens that went through
+        a recurrent layer's chunked form (0: no counter)."""
         self.n_prefill_chunks += 1
         reg = self._reg()
         if reg is not None:
@@ -288,10 +290,16 @@ class ServingMetrics:
                     "bf_serving_chunk_streamed_positions_total",
                     "cache rows prefill chunks read to attend, summed "
                     "over the layers of the kind", kind=kind).inc(rows)
+            if state:
+                reg.counter(
+                    "bf_serving_state_chunk_tokens_total",
+                    "valid tokens of prefill chunks times the layers "
+                    "that keep a recurrent state").inc(state)
         self.on_mixed_tokens(mixed)
 
     def on_decode_step(self, n_slots: int, attended=(), streamed=(),
-                       mixed: int = 0, ahead: bool = False):
+                       mixed: int = 0, ahead: bool = False,
+                       state: int = 0):
         """One decode program call (plain or speculative) advanced
         ``n_slots`` active slots: slots / steps is the batch size a
         decode step.  ``attended``: ``((kind, positions), ...)``, the
@@ -304,7 +312,9 @@ class ServingMetrics:
         :meth:`on_mixed_tokens`.  ``ahead``: the program was dispatched
         while the one before it was in flight (the host had not read
         its tokens): the share of such calls is how often the engine's
-        host work ran beside the device's."""
+        host work ran beside the device's.  ``state``: the slots that
+        decoded times the model's ``state_layers``, the single-token
+        steps of a recurrent state (0: no counter)."""
         self.n_decode_ahead += bool(ahead)
         reg = self._reg()
         if reg is not None:
@@ -330,6 +340,11 @@ class ServingMetrics:
                     "cache positions decode steps fetched, summed over "
                     "every slot of the pool and the layers of the kind",
                     kind=kind).inc(positions)
+            if state:
+                reg.counter(
+                    "bf_serving_state_steps_total",
+                    "decoding slots of decode program calls times the "
+                    "layers that keep a recurrent state").inc(state)
         self.on_mixed_tokens(mixed)
 
     def on_mixed_tokens(self, mixed: int):
@@ -353,17 +368,35 @@ class ServingMetrics:
                       "streams of the served model's residual path "
                       "(hc_mult)").set(streams)
 
-    def on_pool(self, cache_bytes: dict):
-        """The slot pool was built: ``{"full" | "window": bytes}`` it
-        reserves (``SlotPool.cache_bytes``)."""
+    def on_pool(self, cache_bytes: dict, capacity: int = 1):
+        """The slot pool of ``capacity`` slots was built: ``{"full" |
+        "window" | "state": bytes}`` it reserves
+        (``SlotPool.cache_bytes``)."""
         reg = self._reg()
         if reg is not None:
             for kind, nbytes in cache_bytes.items():
                 reg.gauge(
                     "bf_serving_cache_bytes",
                     "bytes the slot pool reserves, by kind of cache leaf "
-                    "(full: max_len positions; window: a ring)",
+                    "(full: max_len positions; window: a ring; state: a "
+                    "recurrent layer's memory)",
                     kind=kind).set(nbytes)
+            if "state" in cache_bytes:
+                reg.gauge(
+                    "bf_serving_state_bytes_per_slot",
+                    "bytes of recurrent state one slot holds, whatever "
+                    "its length").set(cache_bytes["state"] // capacity)
+
+    def on_expert_groups(self, groups: int, kept: int):
+        """The served model chooses a token's experts inside the best
+        ``kept`` of ``groups`` groups of the router's outputs."""
+        reg = self._reg()
+        if reg is not None:
+            reg.gauge("bf_moe_groups_kept",
+                      "groups of router outputs a token's experts are "
+                      "chosen in").set(kept)
+            reg.gauge("bf_moe_groups",
+                      "groups the router's outputs fall in").set(groups)
 
     def on_expert_choices(self, chosen, slots, held):
         """A decode program call of a model with expert layers:

@@ -78,6 +78,13 @@ def seq_axes(cfg, max_len: int,
                 f"cache leaf {jax.tree_util.keystr(path)} is a ring of "
                 "about a window of positions; a prefix cache cannot "
                 "restore chunks into a model with window layers")
+        if protocol.leaf_kind(path) == protocol.STATE:
+            # the state after a prefix is no row of any chunk: copying
+            # the prefix's rows back would leave the state behind
+            raise ValueError(
+                f"cache leaf {jax.tree_util.keystr(path)} is a recurrent "
+                "state, not positions; a prefix cache cannot restore "
+                "chunks into a model with a state leaf")
     axes: List[Optional[int]] = []
     for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         diff = [i for i, (sa, sb) in enumerate(zip(la.shape, lb.shape))

@@ -26,6 +26,21 @@ serving layer reads a leaf's KIND off its name (:func:`leaf_kind`):
   over every call that wrote the slot, the rows the expert loop
   computed and the held assignments they were computed for
   (``ServingMetrics.on_expert_rows`` counts what it grew by).
+* ``state_*``: what a recurrent layer remembers of the sequence, of a
+  fixed size whatever ``max_len`` is, read whole by every call.  It has
+  no "above the index" to hide anything in, so the model keeps this
+  rule, and the engine's shortcuts lean on it: **a state leaf is what
+  the model left after the LIVE tokens behind ``cache_index``, and a
+  call that starts at index 0 starts from zero state whatever the leaf
+  holds.**  A token whose ``live`` is False (a chunk's padded tail, a
+  slot that sits a decode step out, a slot nobody holds) leaves the
+  leaf as it was: the model freezes it, the engine masks nothing.  A
+  freed slot's index is reset and nothing else, so the next admission
+  starts from nothing; a step a slot ran past its request's end (the
+  program one ahead) lands in a state nobody reads again.  Restoring
+  rows does not restore a state and an index rolled back does not roll
+  one back: a prefix cache and the speculative step refuse a model
+  that declares one.
 * anything else: ``max_len`` positions along one axis ("full"),
   whatever a position holds: a key or a value of every head, or one
   latent that all heads share.
@@ -51,13 +66,18 @@ plain residual); the engine sets ``bf_hc_streams`` and counts the live
 tokens of every chunk and decode step times the sublayers
 (``bf_hc_mixed_tokens_total``).  The streams themselves live and die
 inside one ``apply_cached``: no leaf holds one.
+
+A model with recurrent layers MAY declare ``state_layers`` (how many):
+the engine counts the live tokens of every chunk and decode step times
+the layers (``bf_serving_state_chunk_tokens_total``,
+``bf_serving_state_steps_total``).
 """
 
 from __future__ import annotations
 
 from typing import Protocol, Tuple
 
-INDEX, WINDOW, FULL, STAT = "index", "window", "full", "stat"
+INDEX, WINDOW, FULL, STAT, STATE = "index", "window", "full", "stat", "state"
 
 
 class ServedModel(Protocol):
@@ -108,6 +128,8 @@ def leaf_kind(path) -> str:
         return WINDOW
     if name.startswith("stat_"):
         return STAT
+    if name.startswith("state_"):
+        return STATE
     return FULL
 
 

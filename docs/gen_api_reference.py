@@ -78,6 +78,9 @@ MODULES = [
     ("bluefog_tpu.models.hyper_connections",
      "a residual of several streams mixed a token at a time "
      "(manifold-constrained hyper-connections): hc_pre, hc_post"),
+    ("bluefog_tpu.models.kda",
+     "Kimi Delta Attention: a recurrent mixer with a matrix of state a "
+     "head; a single-token step and a chunked form"),
     ("bluefog_tpu.serving.protocol",
      "what the serving layer needs of a model (config methods, cache "
      "leaf kinds)"),

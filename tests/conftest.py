@@ -35,6 +35,19 @@ def bf_ctx():
     bf.shutdown()
 
 
+@pytest.fixture(autouse=True)
+def _no_profiler_session_left_open():
+    """A traced serving run whose tiny schedule ends before its window
+    does leaves the profiler's session open (``tests/perfbench/``'s traced
+    runs), and the next test of the worker that starts one then fails:
+    close what a test left."""
+    yield
+    try:
+        jax.profiler.stop_trace()
+    except RuntimeError:
+        pass
+
+
 # tests/perfbench/ is one of BENCHMARK.json's ``paths``: a PR that appends to
 # the benchmark may not edit a test there.  These pin what no later append
 # can keep, and are expected to fail until a ``benchmark`` PR relaxes them
@@ -52,6 +65,11 @@ def bf_ctx():
 #   ``test_perfbench_mhc.py`` holds the cell that arrived).
 # * PR 32's test wants the list to END with PR 32's four entries and to be
 #   55 long (PR 34 appended five entries of the five serve cells).
+# * the same test's other two cases want ``configs`` to END with PR 32's
+#   configuration and ``workloads`` with PR 32's two cells (PR 38 appended
+#   one configuration and one cell; ``test_perfbench_kda.py::
+#   test_the_append_of_this_cell_moved_nothing_that_was_there`` holds the
+#   prefix that an append keeps, from PR 38's side).
 # The form an append keeps, a PREFIX from PR 29's first entry on, is
 # ``test_perfbench_chunk_attend.py::test_an_append_moves_nothing_of_the_entries_before_it``,
 # and from the list's first entry on, every ``workloads`` list with it,
@@ -66,6 +84,10 @@ _PINNED_AS_LAST = (
     "test_a_listed_later_cell_arrives_as_files_and_entries_only[allreduce]",
     "test_perfbench_mhc.py::"
     "test_an_append_moved_nothing_that_was_there[per_layer]",
+    "test_perfbench_mhc.py::"
+    "test_an_append_moved_nothing_that_was_there[configs]",
+    "test_perfbench_mhc.py::"
+    "test_an_append_moved_nothing_that_was_there[workloads]",
 )
 
 

@@ -860,3 +860,195 @@ def test_serving_bench_smoke(tmp_path):
     for side in ("continuous", "static"):
         assert rec[side]["tokens_per_sec"] > 0
         assert rec[side]["ttft_p99"] >= rec[side]["ttft_p50"] >= 0
+
+
+# ------------------------------------------------------------------ #
+# a model with a STATE leaf (serving/protocol.py): recurrent layers
+# beside a latent one, through the same engine
+# ------------------------------------------------------------------ #
+STATE_LEN = 48
+
+
+def _state_model(seed=3):
+    """A tiny decoder of two recurrent layers around a latent one
+    (``models/mla_moe.py`` with ``layer_types``), float32."""
+    from bluefog_tpu.models import mla_moe
+
+    cfg = mla_moe.MlaMoeConfig(
+        vocab_size=64, dim=32, n_layers=3, n_heads=2, q_lora_rank=None,
+        kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=8, kda_head_dim=8, head_gate=True,
+        layer_types=("kda", "latent", "kda"), n_experts=8, top_k=2,
+        n_group=2, topk_group=1, score_func="sigmoid",
+        expert_hidden_dim=16, initializer_range=0.3, dtype=jnp.float32,
+        key_block=8)
+    variables = mla_moe.MlaMoe(cfg).init(jax.random.PRNGKey(seed),
+                                         jnp.zeros((1, 4), jnp.int32))
+    return cfg, {"params": variables["params"]}
+
+
+def _state_prompts(sizes, seed=0):
+    rs = np.random.RandomState(seed)
+    return [rs.randint(0, 64, (n,)).astype(np.int32) for n in sizes]
+
+
+def _alone(variables, cfg, prompt, budget, **kw):
+    """The request's stream from an engine nothing else has touched."""
+    eng = ServingEngine(variables, cfg, capacity=1, max_len=STATE_LEN,
+                        prefill_chunk=4, **kw)
+    req = eng.submit(Request(prompt, budget))
+    eng.run()
+    assert req.state == "completed"
+    return list(req.tokens)
+
+
+def _state_leaves(eng, slot):
+    from bluefog_tpu.serving import protocol
+
+    return [np.asarray(leaf[slot]) for path, leaf in
+            jax.tree_util.tree_flatten_with_path(eng.pool.cache)[0]
+            if protocol.leaf_kind(path) == protocol.STATE]
+
+
+@pytest.mark.parametrize("zero_on_free", [False, True])
+def test_a_state_leaf_of_a_slot_freed_and_taken_again(zero_on_free):
+    """Three requests through ONE slot: a freed slot's index is reset
+    and its state left as it was (unless the pool zeroes), and the next
+    request, whose first call is at index 0, starts from nothing."""
+    cfg, variables = _state_model()
+    prompts = _state_prompts((9, 1, 14), seed=2)
+    budgets = (5, 6, 4)
+    eng = ServingEngine(variables, cfg, capacity=1, max_len=STATE_LEN,
+                        prefill_chunk=4, zero_on_free=zero_on_free)
+    assert len(eng.pool.state_leaves) == 4       # two leaves a kda layer
+    reqs = [eng.submit(Request(p, b)) for p, b in zip(prompts, budgets)]
+    eng.run()
+    # a single-token prompt decodes at index 0: no prefill wiped the slot
+    for r, p, b in zip(reqs, prompts, budgets):
+        assert list(r.tokens) == _alone(variables, cfg, p, b)
+    # the last request's state is still in the freed slot, unless the
+    # pool zeroes: nothing but the index was reset
+    dirty = any(np.abs(leaf).max() > 0 for leaf in _state_leaves(eng, 0))
+    assert dirty is not zero_on_free
+
+
+@pytest.mark.parametrize("chunk", [1, 6, 8])
+def test_a_padded_chunk_tail_leaves_a_state_leaf_alone(chunk):
+    """Prompts whose last chunk is part-full (and one that fills its
+    chunks): the stream is the stream of chunks of 4, whatever the
+    chunk; the padded tail decayed nothing and shifted no input of the
+    convolution."""
+    cfg, variables = _state_model()
+    prompts = _state_prompts((7, 17, 12), seed=4)
+    eng = ServingEngine(variables, cfg, capacity=2, max_len=STATE_LEN,
+                        prefill_chunk=chunk)
+    reqs = [eng.submit(Request(p, 6)) for p in prompts]
+    eng.run()
+    for r, p in zip(reqs, prompts):
+        assert list(r.tokens) == _alone(variables, cfg, p, 6)
+
+
+def test_a_slot_that_sits_a_decode_step_out_keeps_its_state():
+    """A request prefills over several steps while the slot beside it
+    decodes (its own slot computes in every one of those decode
+    programs, not live), and a free slot sits beside a decoding one:
+    both streams are those of an engine to themselves."""
+    cfg, variables = _state_model()
+    prompts = _state_prompts((3, 22), seed=6)
+    eng = ServingEngine(variables, cfg, capacity=2, max_len=STATE_LEN,
+                        prefill_chunk=4)
+    r0, r1 = Request(prompts[0], 16), Request(prompts[1], 5)
+    mid = []
+
+    def watch(k):
+        if r1.state == "prefill" and 0 < r1._prefill_pos < 21:
+            mid.append(k)
+
+    _drive(eng, {0: [r0], 3: [r1]}, on_step=watch)
+    assert len(mid) >= 3        # decode programs ran over the half-filled slot
+    assert list(r0.tokens) == _alone(variables, cfg, prompts[0], 16)
+    assert list(r1.tokens) == _alone(variables, cfg, prompts[1], 5)
+
+
+def test_an_overrun_step_on_a_state_leaf_is_never_observed():
+    """An EOS learnt one program late: the slot ran once more and its
+    state took a token nobody asked for; the request that takes the
+    slot starts at index 0 and its stream is a fresh engine's."""
+    cfg, variables = _state_model()
+    prompts = _state_prompts((5, 8, 6), seed=8)
+    free_run = _alone(variables, cfg, prompts[0], 12)
+    eos = free_run[4]
+    stop = free_run.index(eos)
+    eng = ServingEngine(variables, cfg, capacity=2, max_len=STATE_LEN,
+                        prefill_chunk=4)
+    r0 = Request(prompts[0], 12, eos_id=eos)
+    r1, r2 = Request(prompts[1], 14), Request(prompts[2], 7)
+    _drive(eng, {0: [r0, r1], 2: [r2]})
+    assert eng.metrics.n_decode_overrun_slots >= 1
+    assert list(r0.tokens) == free_run[:stop + 1]
+    assert list(r1.tokens) == _alone(variables, cfg, prompts[1], 14)
+    assert list(r2.tokens) == _alone(variables, cfg, prompts[2], 7)
+
+
+def test_a_prefix_cache_and_the_speculative_step_refuse_a_state_leaf():
+    from bluefog_tpu.serving import SpeculativeConfig
+    from bluefog_tpu.serving.prefix_cache import PrefixCache, seq_axes
+
+    cfg, variables = _state_model()
+    with pytest.raises(ValueError, match=r"state_conv.*recurrent state"):
+        seq_axes(cfg, STATE_LEN)
+    with pytest.raises(ValueError, match="state leaf"):
+        ServingEngine(variables, cfg, capacity=1, max_len=STATE_LEN,
+                      prefill_chunk=4, prefix_cache=True)
+    with pytest.raises(ValueError, match="state leaf"):
+        SlotPool(cfg, 1, STATE_LEN, prefix=PrefixCache(4, 1 << 20))
+    with pytest.raises(ValueError,
+                       match=r"state_conv.*does not roll back"):
+        ServingEngine(variables, cfg, capacity=1, max_len=STATE_LEN,
+                      prefill_chunk=4, speculative=SpeculativeConfig(
+                          variables=variables, cfg=cfg, lookahead=2))
+    # a draft with a state leaf under a target without one is refused too
+    dense, dense_vars = _setup(vocab_size=64)
+    with pytest.raises(ValueError, match="does not roll back"):
+        ServingEngine(dense_vars, dense, capacity=1, max_len=STATE_LEN,
+                      prefill_chunk=4, speculative=SpeculativeConfig(
+                          variables=variables, cfg=cfg, lookahead=2))
+
+
+def test_the_pool_and_the_counters_report_the_state():
+    from bluefog_tpu.observe import MetricsRegistry
+
+    cfg, variables = _state_model()
+    reg = MetricsRegistry()
+    eng = ServingEngine(variables, cfg, capacity=3, max_len=STATE_LEN,
+                        prefill_chunk=4, registry=reg)
+    # a kda layer a slot: 2 heads x 8 x 8 float32 and 3 x 48 inputs
+    per_slot = 2 * (2 * 8 * 8 * 4 + 3 * 48 * 4)
+    assert eng.pool.cache_bytes() == {
+        "state": 3 * per_slot, "full": 3 * STATE_LEN * 12 * 4}
+    assert reg.gauge("bf_serving_state_bytes_per_slot", "").value == per_slot
+    assert reg.gauge("bf_serving_cache_bytes", "", kind="state").value \
+        == 3 * per_slot
+    assert (reg.gauge("bf_moe_groups", "").value,
+            reg.gauge("bf_moe_groups_kept", "").value) == (2, 1)
+    lengths, budgets = (9, 5, 1), (4, 6, 3)
+    for p, b in zip(_state_prompts(lengths, seed=1), budgets):
+        eng.submit(Request(p, b))
+    eng.run()
+    # every prompt token but the last is prefilled, every served token
+    # comes from a decode step; two recurrent layers
+    assert eng.cfg.state_layers == 2 and eng.cfg.latent_layers == 1
+    assert reg.counter("bf_serving_state_chunk_tokens_total", "").value \
+        == 2 * sum(n - 1 for n in lengths)
+    assert reg.counter("bf_serving_state_steps_total", "").value \
+        == 2 * sum(budgets)
+    assert eng.cfg.cache_kinds() == {"full": (1, None)}
+    # a model without a state leaf sets and counts none of it
+    plain = MetricsRegistry()
+    dense, dense_vars = _setup()
+    eng = ServingEngine(dense_vars, dense, capacity=1, max_len=MAX_LEN,
+                        prefill_chunk=4, registry=plain)
+    eng.submit(Request(_prompts((6,))[0], 2))
+    eng.run()
+    assert not any("state" in name or "groups" in name
+                   for name, *_ in plain.collect())
