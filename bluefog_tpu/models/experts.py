@@ -11,7 +11,12 @@ held expert costs what the rows that chose it cost: one that more rows
 chose than a tile holds takes as many tiles as it needs, one that no row
 chose takes none and is never read, padding makes no assignment; where
 the whole call fits one tile (a decode step's slots) a hit expert's tile
-is the call.  The path follows from the call's shape alone.  What the
+is the call.  A taller call (a prefill chunk) orders its assignments by
+expert once, and a turn then reads a tile of that order and its expert's
+three matrices and writes a tile of results, nothing of the call's
+height: a turn's operations run one after another on a TPU, and each
+that is not a product with the expert's matrices adds to every turn
+(``_experts_hit``).  The path follows from the call's shape alone.  What the
 absent experts would add is left out; nothing stands in for their chips
 or their traffic.  The sum of every share's routed part, plus the shared
 expert once, is the whole layer (``tests/test_afmoe.py``,
@@ -40,6 +45,7 @@ The config (any frozen dataclass) gives: ``n_experts``, ``top_k``,
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -56,6 +62,9 @@ SCOPE_MOE_EXPERTS = "bf.moe.experts"
 SCORE_FUNCS = ("sigmoid", "softmax")
 # rows of one turn of the expert loop (``_experts_hit``)
 EXPERT_TILE = 128
+# rows of a float32 tile of a TPU's memory (8 sublanes of 128 lanes);
+# divides EXPERT_TILE
+_ALIGN = 8
 
 
 def _dense(cfg, feats: int, name: str):
@@ -113,51 +122,130 @@ def _tiles(combine):
     return rows, chose, count, -(-count // rows)
 
 
-def _experts_hit(m, combine, w1, w3, w2):
+def _packed(combine, cut, top_k: int):
+    """The call's held assignments in the order the tiled loop takes them,
+    by expert and then by row, an expert's run next to the one before
+    (no run is padded to a tile), and where their results land:
+    ``(reads [held], writes [held], row_of [A + rows], share_of [A +
+    rows], spot [k, N], held_at [k, N], room)`` with ``k = min(top_k,
+    held)`` and ``A = N * k``, a static bound on the assignments (a row
+    has at most ``top_k`` non-zero weights).
+
+    The assignment at packed position ``p`` is row ``row_of[p]``'s with
+    weight ``share_of[p]`` (zeros past the last assignment, and a tile's
+    worth of them for the last turn's tail); turn ``i``, one of expert
+    ``e``'s, takes the ``rows`` positions from ``reads[e] + i * rows``.
+    Its results land at ``_ALIGN * (writes[e] + i * (rows // _ALIGN))``
+    of ``room`` rows: the runs again, each started on a multiple of
+    ``_ALIGN`` rows, because a product whose result starts on a whole
+    tile of the chip's memory is written in place, and is copied once
+    more where it does not.  Row ``n``'s ``j``-th held assignment lands
+    at ``spot[j, n]`` where ``held_at[j, n]``.  ``cut`` is
+    ``_tiles(combine)``."""
+    rows, chose, count, tiles = cut
+    n, held = combine.shape
+    k = min(top_k, held)
+    aligned = -(-count // _ALIGN) * _ALIGN
+    start = jnp.cumsum(count, dtype=jnp.int32) - count
+    lands = jnp.cumsum(aligned, dtype=jnp.int32) - aligned
+    # a row's place among its expert's rows, and an assignment's among
+    # its row's: running counts down a column and along a row
+    place = jnp.cumsum(chose, axis=0, dtype=jnp.int32) - 1
+    nth = jnp.cumsum(chose, axis=1, dtype=jnp.int32) - 1
+    mine = chose & (nth == jnp.arange(k)[:, None, None])       # [k, N, held]
+    of_row = lambda x: jnp.where(mine, x, 0).sum(2, dtype=x.dtype)   # [k, N]
+    held_at = mine.any(2)
+    # by expert, then by row: one sort of the rows' lists, a slot that
+    # holds no assignment last
+    key = jnp.where(held_at, of_row(jnp.arange(held, dtype=jnp.int32)) * n
+                    + jnp.arange(n, dtype=jnp.int32), held * n)
+    key, share_of = lax.sort((key.reshape(-1), of_row(combine).reshape(-1)),
+                             num_keys=1)
+    row_of = jnp.where(key < held * n, key % n, 0)
+    # the turns before an expert's first took this many positions
+    before = (jnp.cumsum(tiles, dtype=jnp.int32) - tiles) * rows
+    room = -(-n * k // _ALIGN) * _ALIGN + _ALIGN * min(held, n * k) + rows
+    return start - before, (lands - before) // _ALIGN, \
+        jnp.pad(row_of, (0, rows)), jnp.pad(share_of, (0, rows)), \
+        of_row(lands + place), held_at, room
+
+
+def _experts_hit(m, combine, w1, w3, w2, top_k: int):
     """``sum_e combine[:, e] * expert_e(m)`` over the assignments this
     share holds, taken expert by expert in tiles of rows (``_tiles``),
     one loop turn a tile: an expert costs what the rows that chose it
     cost, one that more rows chose than a tile holds takes as many tiles
-    as it needs, and one no row chose is never read.  A row's place
-    among its expert's rows is a running count down the expert's column;
-    a turn picks the rows whose place falls in its tile with a one-hot
-    matrix (a matmul gathers them, exactly), and the transposed matrix
-    adds the tile's result back onto their rows.  Where the whole call
-    fits one tile a hit expert's tile IS the call, and the turn takes
-    ``m`` as it stands.  m ``[N, d]``, combine ``[N, held]`` float32, w1
-    and w3 ``[held, d, f]``, w2 ``[held, f, d]``; float32 ``[N, d]``."""
-    rows, chose, _, tiles = _tiles(combine)
+    as it needs, and one no row chose is never read.
+
+    Where the whole call fits one tile (a decode step's slots) a hit
+    expert's tile IS the call: the turn takes ``m`` as it stands and adds
+    onto a ``[N, d]`` sum, which is small there.
+
+    A taller call (a prefill chunk) is TILED, and a turn moves its tile
+    and its expert, nothing of the call's height: the rows are gathered
+    once before the loop into the order the turns take them
+    (``_packed``), a turn slices its ``rows`` rows and weights out of
+    that order, and its last product writes its ``[rows, d]`` result
+    into a float32 buffer of landing places.  Turns run in ascending
+    order, so what a part-full tile computes past its expert's run (the
+    next expert's rows, under the wrong expert) is overwritten by the
+    next expert's first tile, or lies past the last assignment and is
+    never read: no mask, no read-modify-write.  After the loop a row sums
+    its own ``k`` landing places.
+
+    Why (TPU v5e, PR 39, the newest cell's chunk: N 512, d 2560, experts
+    of 768): a turn's operations run one after another, so whatever is
+    not one of the three products with the expert's matrices (23 us) adds
+    to it in full.  Until PR 39 a turn gathered its rows with a one-hot
+    ``[rows, N]`` product (1.9 us), scattered with its transpose at six
+    passes into an ``[N, d]`` float32 sum carried through the loop (7.5
+    us) and looked three numbers up (1.4 us): 34 us.
+
+    m ``[N, d]``, combine ``[N, held]`` float32 with at most ``top_k``
+    non-zero weights a row, w1 and w3 ``[held, d, f]``, w2 ``[held, f,
+    d]``; float32 ``[N, d]``."""
+    cut = _tiles(combine)
+    rows, _, _, tiles = cut
     tiled = m.shape[0] > rows
     ends = jnp.cumsum(tiles, dtype=jnp.int32)
-    if tiled:
-        place = jnp.cumsum(chose, axis=0, dtype=jnp.int32) - 1  # [N, held]
+    expert_of = lambda i: jnp.sum(ends <= i)         # the expert of turn i
 
-    def turn(i, acc):
-        e = jnp.sum(ends <= i)                   # the expert of turn i
-        column = lambda x: lax.dynamic_index_in_dim(x, e, 1, keepdims=False)
+    def expert(e, x, share):
         pick = lambda w: lax.dynamic_index_in_dim(
             w, e, 0, keepdims=False).astype(m.dtype)
-        x, share = m, column(combine)
-        if tiled:
-            # this turn's tile of the expert's rows: places first ..
-            # first + rows - 1
-            first = (i - ends[e] + tiles[e]) * rows
-            take = (column(place) - first == jnp.arange(rows)[:, None]) \
-                & column(chose)                                 # [rows, N]
-            x = jnp.dot(take.astype(m.dtype), m,
-                        precision=lax.Precision.HIGHEST)
-            share = jnp.where(take, share, 0.0).sum(1)
         gate = jnp.dot(x, pick(w1), preferred_element_type=jnp.float32)
         up = jnp.dot(x, pick(w3), preferred_element_type=jnp.float32)
         act = (nn.silu(gate) * up * share[:, None]).astype(m.dtype)
-        out = jnp.dot(act, pick(w2), preferred_element_type=jnp.float32)
-        if tiled:
-            out = jnp.einsum("rn,rd->nd", take.astype(jnp.float32), out,
-                             precision=lax.Precision.HIGHEST)
-        return acc + out
+        return jnp.dot(act, pick(w2), preferred_element_type=jnp.float32)
 
-    return lax.fori_loop(0, ends[-1], turn,
-                         jnp.zeros((m.shape[0], w2.shape[-1]), jnp.float32))
+    if not tiled:
+        def turn(i, acc):
+            e = expert_of(i)
+            share = lax.dynamic_index_in_dim(combine, e, 1, keepdims=False)
+            return acc + expert(e, m, share)
+
+        return lax.fori_loop(
+            0, ends[-1], turn,
+            jnp.zeros((m.shape[0], w2.shape[-1]), jnp.float32))
+
+    reads, writes, row_of, share_of, spot, held_at, room = _packed(
+        combine, cut, top_k)
+    x_of = m[row_of]
+
+    def turn(i, landed):
+        e = expert_of(i)
+        first = reads[e] + i * rows
+        tile = lambda x: lax.dynamic_slice_in_dim(x, first, rows)
+        out = expert(e, tile(x_of), tile(share_of))
+        return lax.dynamic_update_slice_in_dim(
+            landed, out.reshape(rows // _ALIGN, _ALIGN, -1),
+            writes[e] + i * (rows // _ALIGN), 0)
+
+    landed = lax.fori_loop(
+        0, ends[-1], turn,
+        jnp.zeros((room // _ALIGN, _ALIGN, w2.shape[-1]), jnp.float32))
+    return jnp.where(held_at[..., None], landed.reshape(room, -1)[spot],
+                     0.0).sum(0)
 
 
 def _joint(fn, axis_size, in_batched, *args):
@@ -170,24 +258,35 @@ def _joint(fn, axis_size, in_batched, *args):
                                for x in args))
 
 
-@jax.custom_batching.custom_vmap
-def held_experts(m, combine, w1, w3, w2):
+@functools.cache
+def _held_experts(top_k: int):
+    """``held_experts`` for a model of ``top_k`` experts a token (static:
+    it bounds the tiled path's buffers)."""
+    @jax.custom_batching.custom_vmap
+    def held_experts(m, combine, w1, w3, w2):
+        return _experts_hit(m, combine, w1, w3, w2, top_k)
+
+    @held_experts.def_vmap
+    def _held_experts_vmap(axis_size, in_batched, m, combine, w1, w3, w2):
+        if any(in_batched[2:]):
+            raise NotImplementedError("held_experts: vmap over the weights")
+        shape, out = _joint(
+            lambda x, c: _experts_hit(x, c, w1, w3, w2, top_k),
+            axis_size, in_batched[:2], m, combine)
+        return out.reshape(shape[:-1] + out.shape[-1:]), True
+
+    return held_experts
+
+
+def held_experts(m, combine, w1, w3, w2, top_k: int):
     """The routed part of an expert layer's output that the held
-    experts give (``_experts_hit``).  Under ``vmap`` over sequences
-    (the engine's decode step: one token a slot) the slots' tokens are
-    taken TOGETHER, so that the loop still runs over the experts the
-    whole step hit; ``vmap``'s own rule would turn the loop's bound
-    into a mask and read every held expert for every slot."""
-    return _experts_hit(m, combine, w1, w3, w2)
-
-
-@held_experts.def_vmap
-def _held_experts_vmap(axis_size, in_batched, m, combine, w1, w3, w2):
-    if any(in_batched[2:]):
-        raise NotImplementedError("held_experts: vmap over the weights")
-    shape, out = _joint(lambda x, c: _experts_hit(x, c, w1, w3, w2),
-                        axis_size, in_batched[:2], m, combine)
-    return out.reshape(shape[:-1] + out.shape[-1:]), True
+    experts give (``_experts_hit``); ``top_k`` (static) is the most
+    non-zero weights a row of ``combine`` has.  Under ``vmap`` over
+    sequences (the engine's decode step: one token a slot) the slots'
+    tokens are taken TOGETHER, so that the loop still runs over the
+    experts the whole step hit; ``vmap``'s own rule would turn the loop's
+    bound into a mask and read every held expert for every slot."""
+    return _held_experts(top_k)(m, combine, w1, w3, w2)
 
 
 def _experts_cost(combine):
@@ -259,7 +358,8 @@ class ExpertLayer(nn.Module):
             w1 = self.param("w1", init, (count, d, f), jnp.float32)
             w3 = self.param("w3", init, (count, d, f), jnp.float32)
             w2 = self.param("w2", init, (count, f, d), jnp.float32)
-            routed = held_experts(m.astype(cfg.dtype), combine, w1, w3, w2)
+            routed = held_experts(m.astype(cfg.dtype), combine, w1, w3, w2,
+                                  cfg.top_k)
         if cfg.decode:
             stat = self.variable("cache", "stat_experts", jnp.zeros,
                                  (b, cfg.top_k), jnp.int32)

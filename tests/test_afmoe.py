@@ -339,7 +339,7 @@ def test_an_expert_no_token_chose_is_never_read_even_under_vmap():
     dead = np.array([1, 3, 4])
     w1, w3, w2 = (w.at[dead].set(jnp.nan) for w in (w1, w3, w2))
     got = jax.jit(jax.vmap(
-        lambda x, c: afmoe.held_experts(x, c, w1, w3, w2)))(
+        lambda x, c: afmoe.held_experts(x, c, w1, w3, w2, 1)))(
             m, jnp.asarray(combine))
     assert got.shape == (slots, 1, d) and bool(jnp.isfinite(got).all())
     for s, e in enumerate([0, 2, 2, 5, 0]):
@@ -410,52 +410,87 @@ def test_padding_chooses_no_expert_and_none_is_read_for_it(call):
 TILE = afmoe.EXPERT_TILE
 
 
-def _routing(case, rng, held):
-    """``(combine [..., N, held], slots)``: the held weights of a call's
-    rows, as the expert layer makes them; ``slots`` where the engine
-    maps the call over them."""
+def _routing(case, rng):
+    """``(combine [..., N, held], slots, top_k)``: the held weights of a
+    call's rows, as the expert layer makes them; ``slots`` where the
+    engine maps the call over them; the most experts a row chose.
+    Nobody's choice is expert 1."""
+    held = 8
+    weight = lambda *shape: rng.uniform(0.2, 1.5, shape)
+
     def rows(n, top_k=2, of=3 * held):
         # each row chooses top_k of ``of`` experts; the first ``held``
         # are held
         c = np.zeros((n, held), np.float32)
         for r in range(n):
             for e in rng.choice(of, top_k, replace=False):
-                if e < held and e != 1:     # nobody's choice is 1
-                    c[r, e] = rng.uniform(0.2, 1.5)
+                if e < held and e != 1:
+                    c[r, e] = weight()
         return c
 
     if case == "one_row":
         c = rows(1)
         c[0, 3] = 0.7       # at least one held assignment
-        return c, None
+        return c, None, 2
     if case == "slots_24_vmap":
-        return rows(24)[:, None], 24
+        return rows(24)[:, None], 24, 2
     if case == "chunk_512_padded_tail":
         c = rows(512, top_k=4, of=2 * held)
         c[389:] = 0.0       # the chunk's tail is padding
-        return c, None
-    assert case == "skewed_512"
-    # every row chose expert 2 (four tiles of it: nothing is dropped),
-    # a third of them expert 5 as well (more than one tile, the last
-    # one part full), nobody any other
+        return c, None, 4
+    if case == "skewed_512":
+        # every row chose expert 2 (four tiles of it: nothing is
+        # dropped), a third of them expert 5 as well (more than one
+        # tile, the last one part full), nobody any other
+        c = np.zeros((512, held), np.float32)
+        c[:, 2] = weight(512)
+        c[::3, 5] = weight(171)
+        return c, None, 2
+    if case == "sparse_512":
+        # the newest cell's shape of the problem: every held expert but
+        # the dead one is hit by 1-3 of 512 rows, so every tile is part
+        # full and its tail is the next expert's to overwrite; the hit
+        # rows are few, so that some hold several experts (4 at most)
+        c = np.zeros((512, 16), np.float32)
+        pool = rng.choice(512, 12, replace=False)
+        for e in set(range(16)) - {1}:
+            free = pool[(c[pool] != 0).sum(1) < 4]
+            c[rng.choice(free, rng.integers(1, 4), replace=False), e] = \
+                weight()
+        return c, None, 4
+    if case == "tile_edges_512":
+        # a run that ends on a tile's edge and one that ends one past
+        # it, each followed by a hit expert
+        c = np.zeros((512, held), np.float32)
+        c[rng.choice(512, TILE, replace=False), 0] = weight(TILE)
+        c[rng.choice(512, TILE + 1, replace=False), 2] = weight(TILE + 1)
+        c[rng.choice(512, 7, replace=False), 3] = weight(7)
+        return c, None, 3
+    assert case == "full_512_last_tile_part_full"
+    # every row chose one expert, so the packed order is as long as its
+    # bound, and the last hit expert's part-full tile is the last turn:
+    # its tail lies past the last assignment
     c = np.zeros((512, held), np.float32)
-    c[:, 2] = rng.uniform(0.2, 1.5, 512)
-    c[::3, 5] = rng.uniform(0.2, 1.5, 171)
-    return c, None
+    of = np.repeat([0, 2, 5, 7], [200, 130, 100, 82])
+    c[np.arange(512), rng.permutation(of)] = weight(512)
+    return c, None, 1
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("case", ["one_row", "slots_24_vmap",
-                                  "chunk_512_padded_tail", "skewed_512"])
+                                  "chunk_512_padded_tail", "skewed_512",
+                                  "sparse_512", "tile_edges_512",
+                                  "full_512_last_tile_part_full"])
 def test_the_tiled_sum_is_the_dense_sum_and_takes_a_turn_a_tile(case, dtype):
     """``held_experts`` against ``sum_e combine[:, e] * expert_e(m)``
     in float64, over the same rounded operands; an expert nobody chose
     holds NaNs; the loop's turns, read from its count of the rows it
     computed (``experts_cost``), are ``sum_e ceil(count_e / TILE)``."""
     rng = np.random.default_rng(len(case))
-    held, d, f = 8, 32, 16
+    d, f = 32, 16
     dtype = jnp.dtype(dtype)
-    combine, slots = _routing(case, rng, held)
+    combine, slots, top_k = _routing(case, rng)
+    held = combine.shape[-1]
     flat = combine.reshape(-1, held)
     n = flat.shape[0]
     m = jnp.asarray(rng.normal(size=combine.shape[:-1] + (d,)), dtype)
@@ -466,7 +501,7 @@ def test_the_tiled_sum_is_the_dense_sum_and_takes_a_turn_a_tile(case, dtype):
     dead = np.flatnonzero(count == 0)
     assert dead.size
     poisoned = [w.at[dead].set(jnp.nan) for w in (w1, w3, w2)]
-    call = lambda x, c: (afmoe.held_experts(x, c, *poisoned),
+    call = lambda x, c: (afmoe.held_experts(x, c, *poisoned, top_k),
                          afmoe.experts_cost(c))
     got, stat = jax.jit(jax.vmap(call) if slots else call)(
         m, jnp.asarray(combine))
@@ -496,6 +531,58 @@ def test_the_tiled_sum_is_the_dense_sum_and_takes_a_turn_a_tile(case, dtype):
         assert rows == TILE * np.ceil(count / TILE).sum()
         if case == "skewed_512":
             assert rows == TILE * (4 + 2)
+
+
+def _equations(jaxpr, in_loop=False):
+    """``(equation, whether a loop holds it)`` of a jaxpr and of every
+    jaxpr its equations hold."""
+    from bluefog_tpu.analysis.jaxpr_check import _sub_jaxprs
+
+    for eqn in jaxpr.eqns:
+        yield eqn, in_loop
+        for sub in _sub_jaxprs(eqn):
+            yield from _equations(
+                getattr(sub, "jaxpr", sub),
+                in_loop or eqn.primitive.name == "while")
+
+
+def _held_experts_equations(rows, vmapped):
+    held, d, f = 16, 32, 16     # every width under a tile's rows
+    weights = [jnp.zeros(shape) for shape in
+               ((held, d, f), (held, d, f), (held, f, d))]
+    call = lambda m, c: afmoe.held_experts(m, c, *weights, 4)
+    shape = (rows, 1) if vmapped else (rows,)
+    jaxpr = jax.make_jaxpr(jax.vmap(call) if vmapped else call)(
+        jnp.zeros(shape + (d,)), jnp.zeros(shape + (held,)))
+    return list(_equations(jaxpr.jaxpr))
+
+
+def test_a_turn_of_the_tiled_loop_moves_a_tile_and_nothing_of_the_call():
+    """What a prefill chunk's expert loop is about, held where a CPU can
+    hold it: inside the loop's body nothing as tall as the call (512
+    rows) or as long as the packed order is computed on; such an array
+    is only ever cut (a tile in) or written into (a tile out)."""
+    moves = {"dynamic_slice", "dynamic_update_slice", "gather"}
+    body = [eqn for eqn, in_loop in _held_experts_equations(512, False)
+            if in_loop]
+    assert sum(eqn.primitive.name == "dot_general" for eqn in body) == 3
+    assert any(eqn.primitive.name == "dynamic_update_slice" for eqn in body)
+    for eqn in body:
+        if eqn.primitive.name in moves:
+            continue
+        for var in list(eqn.invars) + list(eqn.outvars):
+            assert max(var.aval.shape, default=0) <= TILE, (
+                f"{eqn.primitive.name} on {var.aval.shape} inside a turn")
+
+
+def test_the_decode_steps_loop_packs_nothing():
+    """The mirror: a call that fits one tile (24 slots under ``vmap``)
+    keeps its loop over the hit experts and has no sort, no gather and
+    no packed buffer."""
+    names = {eqn.primitive.name
+             for eqn, _ in _held_experts_equations(24, True)}
+    assert "while" in names and "dot_general" in names
+    assert not names & {"sort", "gather", "pad", "dynamic_update_slice"}
 
 
 # ------------------------------------------------------------------ #

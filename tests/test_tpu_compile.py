@@ -209,3 +209,41 @@ def test_engine_decode_step_reads_the_latent_pool_in_place_on_v5e(
     assert "tpu_custom_call" in compiled.as_text()
     leaf = slots_n * max_len * cfg.latent_width * 2
     assert compiled.memory_analysis().temp_size_in_bytes < leaf // 4
+
+
+# the chunk of ling3-flash-serve-doc-reasoning and of
+# mistral-small4-serve-long-prompt (BENCHMARK.json): rows, width, an
+# expert's width, experts held, experts a token
+@pytest.mark.parametrize("n,d,f,held,top_k", [
+    (512, 2560, 768, 128, 8), (512, 4096, 2048, 32, 4)])
+def test_a_turn_of_the_tiled_expert_loop_is_three_products_on_v5e(
+        chip, n, d, f, held, top_k):
+    """A turn's operations run one after another on the chip, so each
+    one that is not a product with the expert's matrices adds to the
+    turn in full (3.2 us of 27 for a tile's result copied into the
+    buffer, PERF.md section 6, PR 39).  The last product writes its
+    tile in place because the tile lands on whole (8, 128) tiles of the
+    buffer: the loop's body holds the three products, no copy of a tile
+    and nothing as tall as the call."""
+    import re
+
+    from bluefog_tpu.models import experts
+
+    sds = jax.ShapeDtypeStruct
+    text = _compiled_text(
+        lambda m, c, w1, w3, w2: experts._experts_hit(m, c, w1, w3, w2,
+                                                      top_k),
+        sds((n, d), jnp.bfloat16), sds((n, held), jnp.float32),
+        sds((held, d, f), jnp.bfloat16), sds((held, d, f), jnp.bfloat16),
+        sds((held, f, d), jnp.bfloat16), chip=chip)
+    computations = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \([^\n]*\) -> )", text)
+    bodies = [c for c in computations if "/while/body/" in c
+              and not c.startswith(("%fused", "%region"))]
+    assert len(bodies) == 1
+    ops = re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+\[\d*)\S* ([\w\-]+)\(",
+                     bodies[0], flags=re.M)
+    kinds = [kind for _, kind in ops]
+    assert kinds.count("fusion") == 4     # the turn's expert; the products
+    assert not {"dynamic-update-slice", "copy", "gather",
+                "scatter"} & set(kinds), kinds
+    assert not [shape for shape, _ in ops if shape.endswith(f"[{n}")]
