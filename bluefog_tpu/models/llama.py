@@ -501,10 +501,13 @@ def _llama3_scaled_freqs(freqs: jax.Array, factor: float,
 
 
 def rotary_embed(x: jax.Array, positions: jax.Array, theta: float,
-                 scaling=None) -> jax.Array:
+                 scaling=None, halves: bool = False) -> jax.Array:
     """Apply rotary position embedding.  x: [B, T, H, D], positions: [T].
     ``scaling``: optional ``(factor, low_freq_factor, high_freq_factor,
-    original_max_len)`` tuple enabling llama3-style frequency scaling."""
+    original_max_len)`` tuple enabling llama3-style frequency scaling.
+    ``halves``: the pair that frequency ``i`` rotates is ``(x[i], x[i +
+    D/2])``, half against half (the Hugging Face layout), and not the
+    interleaved ``(x[2i], x[2i+1])``."""
     d = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     if scaling is not None:
@@ -512,6 +515,10 @@ def rotary_embed(x: jax.Array, positions: jax.Array, theta: float,
     angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # [T, D/2]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
+    if halves:
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                               axis=-1).astype(x.dtype)
     x1, x2 = x[..., ::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
     out1 = x1 * cos - x2 * sin
     out2 = x1 * sin + x2 * cos
@@ -784,7 +791,10 @@ class Attention(nn.Module):
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, pos_offset, live=None):
+    def __call__(self, x, pos_offset, live=None, attend=None):
+        """``attend(q, k, v) -> [B, T, n_q, D]``, where given, stands in
+        for the rotation and the attention between the projections (a
+        model that keeps its own cache: ``models/looped.py``)."""
         cfg = self.cfg
         hd = cfg.head_dim
         dense = lambda feats, name: _dense(cfg, feats, name)
@@ -801,7 +811,9 @@ class Attention(nn.Module):
         q = dense(n_q * hd, "wq")(x).reshape(b, t, n_q, hd)
         k = dense(n_kv * hd, "wk")(x).reshape(b, t, n_kv, hd)
         v = dense(n_kv * hd, "wv")(x).reshape(b, t, n_kv, hd)
-        if cfg.decode:
+        if attend is not None:
+            out = attend(q, k, v)
+        elif cfg.decode:
             # rotary happens inside, at the cache-index positions
             out = self._decode_attend(q, k, v, live)
         else:

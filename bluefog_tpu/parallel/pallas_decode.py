@@ -69,6 +69,33 @@ block up): hence ``latent_block``, a quarter of the cache between 512
 and 2,048 rows.  Probabilities rounded to the cache's dtype before the
 value contraction, as the einsums round them, bought no time; they stay
 float32.
+
+The STACKED form (PR 40, ``decode_attention(leaf=, fresh=)``) serves a
+loop that is ROLLED over its layers (``models/looped.py``: 48 layers
+run four times over shared weights, a cache for every pass).  Such a
+loop carries ONE pair of leaves ``[B, N, KV, S, D]`` for its ``N``
+(pass, layer) caches; a slice of it handed to a custom call would be a
+copy (two ``[slots, 16, 768, 128]`` slices a layer application, 9.4 GB
+a step).  So the call takes the whole pair, the leaf rides in scalar
+prefetch beside the position and the index map names its blocks inside
+the stack (the stack's axis is a squeezed block dimension: the body is
+the dense kernel's).  With ``fresh`` the call also WRITES: the step's
+own key and value rows come in beside the query, stand in for the
+cache's row while the block that holds the position is scored, and
+leave in the ``_WRITE_ROWS`` = 16 rows around it through an output that
+aliases the cache (``_decode_write_kernel``).  Readings on a v5e, the
+whole decode program of Ouro-2.6B at 8 slots x 768 positions, 16 KV
+heads of 128 under 16 query heads, 192 calls a step, rows at 100-700
+(PERF.md section 6, PR 40): rows written by XLA first (a
+``dynamic_update_slice`` under the engine's map over slots: a scatter,
+a (slot, leaf) at a time) and the kernel after, 57.7 ms a step, of
+which the scatter 12.8 and the kernel 15.0; the einsums in the
+kernel's place 55.9; the kernel writing, 45.5 (blocks of 384: the
+largest divisor of 768 under ``_BLOCK_S``), 44.8 at 128, 43.7 at 768;
+four of eight rows live 38.3.  A call is 76 us: 16 before its first
+step and about 5.6 a block that computes, because each of 16 heads has
+ONE query row and its two products are a row against a block (at 8
+heads under 32, a block computed in 3).
 """
 
 from __future__ import annotations
@@ -108,8 +135,13 @@ def tileable(s_len: int) -> bool:
     return s_len < 8 or _fit_block(s_len, _BLOCK_S) >= 8
 
 
-# rows of the kernel's scalar-prefetch operand
-_POS, _SRC, _FIRST, _LAST = range(4)
+# rows of the kernel's scalar-prefetch operand (the fifth only where the
+# cache operands are stacks of leaves: the leaf a row reads; the sixth
+# only where the call also writes: the tile of rows it writes back)
+_POS, _SRC, _FIRST, _LAST, _LEAF, _TILE = range(6)
+# Cache rows of the tile a writing call puts back around a row's new
+# position: the sublanes of one bfloat16 tile (two float32 ones).
+_WRITE_ROWS = 16
 
 
 def _stream_plan(idx, live, block_s: int):
@@ -135,11 +167,30 @@ def _named_block(bk, sj, plan):
                                        plan[_LAST, bk]), 0
 
 
-def _row_plan(idx, live, b: int, s_len: int, want: int):
+def _named_leaf_block(bk, sj, plan):
+    """``_named_block`` in leaf ``plan[_LEAF]`` of a stack ``[B, N, KV,
+    S, D]``."""
+    src, head, block, last = _named_block(bk, sj, plan)
+    return src, plan[_LEAF, bk], head, block, last
+
+
+def _written_tile(bk, sj, plan):
+    """The output index map of a writing call: the tile around the new
+    position of the row whose blocks grid step ``(bk, sj)`` names (a
+    row that does not decode names the tile the row before it wrote,
+    and the pipeline writes nothing back for a tile it already
+    holds)."""
+    return plan[_SRC, bk], plan[_LEAF, bk], 0, plan[_TILE, bk], 0
+
+
+def _row_plan(idx, live, b: int, s_len: int, want: int, leaf=None,
+              writes: bool = False):
     """``(block, plan)`` of a call over ``b`` rows of ``s_len``
     positions: the block that fits under ``want`` and ``_stream_plan``
     over ``idx`` and ``live``, each one scalar for every row or ``[b]``
-    per row."""
+    per row; with ``leaf`` (the same), a fifth row that names it, and
+    with ``writes`` a sixth, the ``_WRITE_ROWS`` tile of the position of
+    the row a step names."""
     block = _fit_block(s_len, want)
     if block < 8 and s_len >= 8:
         # no viable tiling (e.g. a prime cache length > the wanted
@@ -151,9 +202,15 @@ def _row_plan(idx, live, b: int, s_len: int, want: int):
             "use decode_attn='xla'")
     per_row = lambda x, dtype: jnp.broadcast_to(
         jnp.asarray(x, dtype).reshape(-1), (b,))
-    return block, _stream_plan(
-        jnp.clip(per_row(idx, jnp.int32), 0, s_len - 1),
-        per_row(live, bool), block)
+    idx = jnp.clip(per_row(idx, jnp.int32), 0, s_len - 1)
+    live = per_row(live, bool)
+    plan = _stream_plan(idx, live, block)
+    if leaf is not None:
+        plan = jnp.concatenate([plan, per_row(leaf, jnp.int32)[None]])
+    if writes:
+        tile = (jnp.where(live, idx, 0) // _WRITE_ROWS)[plan[_SRC]]
+        plan = jnp.concatenate([plan, tile[None]])
+    return block, plan
 
 
 def streamed_positions(positions, s_len: int, *, fused: bool = True,
@@ -172,6 +229,108 @@ def streamed_positions(positions, s_len: int, *, fused: bool = True,
     if len(positions) and positions[0] < 0:
         blocks += 1
     return blocks * block_s
+
+
+def _fold_block(s, v_blk, sl, m_ref, l_ref, acc_ref, v_scale=None):
+    """One block's masked scores ``s [rep, block_s]`` and values ``v_blk
+    [block_s, D]`` folded into the running softmax of the head rows
+    ``sl`` (the flash recurrence).  ``v_scale``: a quantized block's
+    value scales; the softmax denominator uses the UNSCALED
+    probabilities, so they only rescale the values."""
+    m, l, acc = m_ref[sl], l_ref[sl], acc_ref[sl]
+    blk_m = jnp.max(s, axis=-1)
+    new_m = jnp.maximum(m, blk_m)
+    p = jnp.exp(s - new_m[:, None])
+    p = jnp.where(s <= _NEG_INF / 2, 0.0, p)
+    corr = jnp.exp(m - new_m)
+    m_ref[sl] = new_m
+    l_ref[sl] = l * corr + jnp.sum(p, axis=-1)
+    if v_scale is not None:
+        p = p * v_scale
+    acc_ref[sl] = acc * corr[:, None] + jax.lax.dot_general(
+        p, v_blk, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _softmax_start(sj, m_ref, l_ref, acc_ref):
+    """A row's first grid step: the running softmax starts empty."""
+    @pl.when(sj == 0)
+    def _():
+        m_ref[:] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+
+def _softmax_end(sj, o_ref, l_ref, acc_ref):
+    """A row's last grid step: the sum over its probabilities."""
+    @pl.when(sj == pl.num_programs(1) - 1)
+    def _():
+        safe_l = jnp.maximum(l_ref[:], 1e-30)
+        o_ref[0] = (acc_ref[:] / safe_l[:, None]).astype(o_ref.dtype)
+
+
+def _decode_write_kernel(plan_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
+                         o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref, *,
+                         scale: float, n_kv: int):
+    """``_decode_kernel`` over a cache that does NOT hold the step's own
+    key and value rows yet: they come in beside the query (``kn_ref``,
+    ``vn_ref``: [1, KV, D]), stand in for the cache's row at the
+    position while the block that holds it is scored, and leave in the
+    ``_WRITE_ROWS`` tile around it, which is put back through an output
+    that IS the cache (``ko_ref``, ``vo_ref``: [1, KV, _WRITE_ROWS, D],
+    aliased; ``_written_tile``).  The write costs one small tile a row
+    where XLA's scatter of a step's rows into a mapped pool cost more
+    than the attention (PERF.md section 6, PR 40)."""
+    sj, bk = pl.program_id(1), pl.program_id(0)
+    idx = plan_ref[_POS, bk]
+    rep = q_ref.shape[1] // n_kv
+    block_s = k_ref.shape[2]
+    _softmax_start(sj, m_ref, l_ref, acc_ref)
+
+    @pl.when(sj * block_s <= idx)
+    def _():
+        at = sj * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (block_s, 1), 0)
+        pos = sj * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (rep, block_s), 1)
+        for kv in range(n_kv):
+            sl = slice(kv * rep, (kv + 1) * rep)
+            q = q_ref[0, sl].astype(jnp.float32)
+            k_blk = jnp.where(at == idx,
+                              kn_ref[0, kv:kv + 1].astype(jnp.float32),
+                              k_ref[0, kv].astype(jnp.float32))
+            v_blk = jnp.where(at == idx,
+                              vn_ref[0, kv:kv + 1].astype(jnp.float32),
+                              v_ref[0, kv].astype(jnp.float32))
+            s = jax.lax.dot_general(
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            _fold_block(jnp.where(pos <= idx, s, _NEG_INF), v_blk, sl,
+                        m_ref, l_ref, acc_ref)
+
+    # the block that holds the position: its tile, the new row in place
+    @pl.when((sj * block_s <= idx) & (idx < (sj + 1) * block_s))
+    def _():
+        start = pl.multiple_of(
+            (idx - sj * block_s) // _WRITE_ROWS * _WRITE_ROWS, _WRITE_ROWS)
+        new = (sj * block_s + start + jax.lax.broadcasted_iota(
+            jnp.int32, (_WRITE_ROWS, 1), 0)) == idx
+        for kv in range(n_kv):
+            ko_ref[0, kv] = jnp.where(
+                new, kn_ref[0, kv:kv + 1],
+                k_ref[0, kv, pl.ds(start, _WRITE_ROWS), :])
+            vo_ref[0, kv] = jnp.where(
+                new, vn_ref[0, kv:kv + 1],
+                v_ref[0, kv, pl.ds(start, _WRITE_ROWS), :])
+
+    # a first row that does not decode names its own first tile, which
+    # no row before it wrote: put back what is there
+    @pl.when((bk == 0) & (idx < 0) & (sj == 0))
+    def _():
+        ko_ref[0] = k_ref[0, :, :_WRITE_ROWS, :]
+        vo_ref[0] = v_ref[0, :, :_WRITE_ROWS, :]
+
+    _softmax_end(sj, o_ref, l_ref, acc_ref)
 
 
 def _decode_kernel(plan_ref, q_ref, k_ref, v_ref, *refs, scale: float,
@@ -193,17 +352,11 @@ def _decode_kernel(plan_ref, q_ref, k_ref, v_ref, *refs, scale: float,
         ks_ref, vs_ref = refs[:2]
     o_ref, m_ref, l_ref, acc_ref = refs[-4:]
     sj = pl.program_id(1)
-    n_s = pl.num_programs(1)
     idx = plan_ref[_POS, pl.program_id(0)]
     heads = q_ref.shape[1]
     rep = heads // n_kv
     block_s = k_ref.shape[2]
-
-    @pl.when(sj == 0)
-    def _():
-        m_ref[:] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+    _softmax_start(sj, m_ref, l_ref, acc_ref)
 
     # a block wholly past the row's position was not fetched (the index
     # map named a block already held): no arithmetic either
@@ -240,29 +393,13 @@ def _decode_kernel(plan_ref, q_ref, k_ref, v_ref, *refs, scale: float,
                 jnp.int32, (rep, block_s), 1)
             s = jnp.where(pos <= idx, s, _NEG_INF)
 
-            m, l, acc = m_ref[sl], l_ref[sl], acc_ref[sl]
-            blk_m = jnp.max(s, axis=-1)
-            new_m = jnp.maximum(m, blk_m)
-            p = jnp.exp(s - new_m[:, None])
-            p = jnp.where(s <= _NEG_INF / 2, 0.0, p)
-            corr = jnp.exp(m - new_m)
-            m_ref[sl] = new_m
-            l_ref[sl] = l * corr + jnp.sum(p, axis=-1)
-            if quantized:
-                # value scale varies along the contracted position
-                # axis: fold into the probabilities (kept float — NEVER
-                # re-quantized, the round-4 w8a8 long-context
-                # regression); the softmax denominator above uses the
-                # UNSCALED p, so this only rescales the values
-                p = p * vs_ref[0, kv][:, 0][None, :]
-            acc_ref[sl] = acc * corr[:, None] + jax.lax.dot_general(
-                p, v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            # value scale varies along the contracted position axis:
+            # folded into the probabilities (kept float — NEVER
+            # re-quantized, the round-4 w8a8 long-context regression)
+            _fold_block(s, v_blk, sl, m_ref, l_ref, acc_ref,
+                        vs_ref[0, kv][:, 0][None, :] if quantized else None)
 
-    @pl.when(sj == n_s - 1)
-    def _():
-        safe_l = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = (acc_ref[:] / safe_l[:, None]).astype(o_ref.dtype)
+    _softmax_end(sj, o_ref, l_ref, acc_ref)
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
@@ -272,26 +409,39 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
-def _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, live, *, block_s,
-                 interpret):
+def _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, live, leaf=None,
+                 fresh=None, *, block_s, interpret):
     """q: [B, 1, n_q, D]; k_all/v_all: KV-HEAD-MAJOR [B, KV, S, D]
     (int8 when quantized); ks_all/vs_all: [B, KV, S] f32 scales or None;
     idx: the current position, and live: whether the row decodes (the
     output of one that does not is zeros), each one scalar for every
-    row or [B] per row.  Returns [B, 1, n_q, D] in q's dtype.  Jitted,
-    so that the layers of a model (same shapes) share one trace and one
+    row or [B] per row.  With ``leaf`` (the same; a rolled loop's
+    counter), k_all/v_all are STACKS [B, N, KV, S, D] of which the call
+    reads leaf ``leaf`` through its index map: no slice of the stack is
+    made.  With ``fresh = (k, v)`` ([B, KV, D] each: the step's own key
+    and value rows, which the stacks do not hold yet) the call attends
+    over them too and WRITES them at ``(leaf, idx)``
+    (``_decode_write_kernel``): ``(out, k_all', v_all')``, the stacks
+    updated in place.  Returns [B, 1, n_q, D] in q's dtype.  Jitted, so
+    that the layers of a model (same shapes) share one trace and one
     Mosaic lowering."""
     b, t, n_q, d = q.shape
     assert t == 1, "the fused decode kernel serves single-token steps"
-    n_kv, s_len = k_all.shape[1], k_all.shape[2]
+    n_kv, s_len = k_all.shape[-3], k_all.shape[-2]
     quantized = ks_all is not None
+    stacked, writes = leaf is not None, fresh is not None
     q3 = q.reshape(b, n_q, d)  # kv-major head order matches the cache
-    block, plan = _row_plan(idx, live, b, s_len, block_s or _BLOCK_S)
+    block, plan = _row_plan(idx, live, b, s_len, block_s or _BLOCK_S, leaf,
+                            writes=writes)
 
     def row(bk, sj, plan_ref):
         return bk, 0, 0
 
-    kv_spec = pl.BlockSpec((1, n_kv, block, d), _named_block)
+    if stacked:
+        # the stack's axis is squeezed: the body sees [1, KV, block, D]
+        kv_spec = pl.BlockSpec((1, None, n_kv, block, d), _named_leaf_block)
+    else:
+        kv_spec = pl.BlockSpec((1, n_kv, block, d), _named_block)
     in_specs = [pl.BlockSpec((1, n_q, d), row), kv_spec, kv_spec]
     args = [plan, q3, k_all, v_all]
     if quantized:
@@ -302,38 +452,59 @@ def _decode_impl(q, k_all, v_all, ks_all, vs_all, idx, live, *, block_s,
         in_specs += [scale_spec, scale_spec]
         args += [ks_all[..., None], vs_all[..., None]]
 
+    kernel = _decode_kernel
+    out_specs = pl.BlockSpec((1, n_q, d), row)
+    out_shape = jax.ShapeDtypeStruct((b, n_q, d), q.dtype)
+    aliases = {}
+    if writes:
+        # the rows ride beside the query; the stacks are outputs too
+        kernel = _decode_write_kernel
+        in_specs[1:1] = [pl.BlockSpec((1, n_kv, d), row)] * 2
+        args[2:2] = [x.astype(k_all.dtype) for x in fresh]
+        tile_spec = pl.BlockSpec((1, None, n_kv, _WRITE_ROWS, d),
+                                 _written_tile)
+        out_specs = [out_specs, tile_spec, tile_spec]
+        out_shape = [out_shape] + [
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (k_all, v_all)]
+        aliases = {4: 1, 5: 2}    # operands, the plan counted: k_all, v_all
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=1.0 / d ** 0.5, n_kv=n_kv),
+        functools.partial(kernel, scale=1.0 / d ** 0.5, n_kv=n_kv),
         name="decode_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, s_len // block),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, n_q, d), row),
+            out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((n_q,), jnp.float32),
                 pltpu.VMEM((n_q,), jnp.float32),
                 pltpu.VMEM((n_q, d), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, n_q, d), q.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
     )(*args)
+    if writes:
+        return out[0].reshape(b, 1, n_q, d), out[1], out[2]
     return out.reshape(b, 1, n_q, d)
 
 
-def _row_batched(impl):
+def _row_batched(impl, scalars=(jnp.int32, bool)):
     """``impl(idx, live, *arrays)`` with its own ``vmap`` rule: a mapped
     axis of independent rows FOLDS into the kernel's batch grid axis,
     each row keeping its own position.  The serving engine maps the
     decode step over its cache slots; Pallas's generic batching would
     instead batch the SMEM position operand into a squeezed ``[slots,
     1]`` block, which the TPU lowering refuses (SMEM blocks must span
-    the whole array)."""
+    the whole array).  ``scalars``: the dtypes of the leading operands
+    that hold one number a row (``idx``, ``live``, and whatever a
+    variant adds)."""
     call = jax.custom_batching.custom_vmap(impl)
 
     @call.def_vmap
-    def _fold(axis_size, in_batched, idx, live, *arrays):
-        idx_batched, live_batched, *arrays_batched = in_batched
+    def _fold(axis_size, in_batched, *operands):
+        n = len(scalars)
+        arrays, arrays_batched = operands[n:], in_batched[n:]
         b = arrays[0].shape[1 if arrays_batched[0] else 0]
 
         def fold(x, batched):
@@ -348,10 +519,10 @@ def _row_batched(impl):
             return jnp.broadcast_to(x.reshape(axis_size, -1),
                                     (axis_size, b)).reshape(-1)
 
-        out = call(rows(idx, idx_batched, jnp.int32),
-                   rows(live, live_batched, bool),
+        out = call(*map(rows, operands[:n], in_batched[:n], scalars),
                    *map(fold, arrays, arrays_batched))
-        return out.reshape((axis_size, b) + out.shape[1:]), True
+        unfold = lambda x: x.reshape((axis_size, b) + x.shape[1:])
+        return jax.tree.map(unfold, out), jax.tree.map(lambda x: True, out)
 
     return call
 
@@ -369,8 +540,21 @@ def _dense_call(block_s: Optional[int], interpret: bool):
     return _row_batched(call)
 
 
-def decode_attention(q, k_all, v_all, idx, *, live=None,
-                     block_s: Optional[int] = None,
+@functools.lru_cache(maxsize=None)
+def _stacked_call(block_s: Optional[int], interpret: bool):
+    """``_decode_impl`` over stacks of leaves, as ``call(idx, live,
+    leaf, q, k_all, v_all[, k, v])`` under ``_row_batched``."""
+
+    def call(idx, live, leaf, q, k_all, v_all, *fresh):
+        return _decode_impl(q, k_all, v_all, None, None, idx, live, leaf,
+                            fresh or None, block_s=block_s,
+                            interpret=interpret)
+
+    return _row_batched(call, (jnp.int32, bool, jnp.int32))
+
+
+def decode_attention(q, k_all, v_all, idx, *, live=None, leaf=None,
+                     fresh=None, block_s: Optional[int] = None,
                      interpret: Optional[bool] = None):
     """Fused GQA decode-attention step over a full-precision cache.
 
@@ -380,9 +564,29 @@ def decode_attention(q, k_all, v_all, idx, *, live=None,
     cache is fetched and it returns zeros.  Drop-in for the
     decode-step case of ``models.llama._cached_attention`` (reference
     has no counterpart — decode itself is a new capability,
-    docs/parity.md)."""
+    docs/parity.md).
+
+    ``leaf`` (a scalar, traced or not): k_all/v_all are stacks of ``N``
+    such caches, [B, N, KV, S, D], and the step attends over leaf
+    ``leaf`` alone.  The leaf rides in scalar prefetch beside the
+    position and the index map names its blocks inside the stack: a
+    loop that is rolled over its layers (``models/looped.py``) hands the
+    kernel the whole stack it carries and no copy of a slice.
+
+    ``fresh = (k, v)`` ([B, KV, D] each; with ``leaf``): the stacks do
+    not hold the step's own key and value rows yet; the call attends
+    over them as the rows at ``idx``, writes them there (a row that is
+    not ``live`` writes nothing) and returns ``(out, k_all', v_all')``:
+    the cache write of a decode step inside the kernel that reads the
+    cache, for a step whose rows XLA would scatter into a mapped pool
+    one (slot, leaf) at a time."""
+    live = jnp.asarray(True if live is None else live)
+    if leaf is not None:
+        return _stacked_call(block_s, _auto_interpret(interpret))(
+            idx, live, jnp.asarray(leaf, jnp.int32), q, k_all, v_all,
+            *(fresh or ()))
     return _dense_call(block_s, _auto_interpret(interpret))(
-        idx, jnp.asarray(True if live is None else live), q, k_all, v_all)
+        idx, live, q, k_all, v_all)
 
 
 def decode_attention_int8(q, kq_all, ks_all, vq_all, vs_all, idx, *,
@@ -443,12 +647,7 @@ def _latent_kernel(plan_ref, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *,
     sj = pl.program_id(1)
     idx = plan_ref[_POS, pl.program_id(0)]
     block_s = c_ref.shape[3]
-
-    @pl.when(sj == 0)
-    def _():
-        m_ref[:] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+    _softmax_start(sj, m_ref, l_ref, acc_ref)
 
     @pl.when(sj * block_s <= idx)
     def _():
@@ -474,10 +673,7 @@ def _latent_kernel(plan_ref, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *,
             p, c_t[:dc].astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(sj == pl.num_programs(1) - 1)
-    def _():
-        safe_l = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = acc_ref[:] / safe_l[:, None]
+    _softmax_end(sj, o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("dc", "block_s", "interpret"))

@@ -203,11 +203,23 @@ def _prefill_chunk_prog(params, pool, slot, chunk, valid_len, cfg):
     the regular decode step, whose output IS the first generated token.
     Shapes depend on ``(cfg, chunk_len)`` alone.  ``cfg`` is the
     model's serving layout (``serving/protocol.py``)."""
+    # the chunk's padded tail is no token: no expert is read for it
+    live = jnp.arange(chunk.shape[-1])[None] < valid_len
+    in_pool = getattr(cfg, "apply_in_pool", None)
+    if in_pool is not None:
+        # the model writes its rows where they lie in the pool: no
+        # slot's tree is cut out and put back (serving/protocol.py)
+        _, new_pool = in_pool(params, pool, slot, chunk, live=live)
+
+        def fix(path, new, old):
+            if protocol.leaf_kind(path) == protocol.INDEX:
+                return old.at[slot].add(valid_len.astype(old.dtype))
+            return new
+
+        return jax.tree_util.tree_map_with_path(fix, new_pool, pool)
     cache = jax.tree.map(
         lambda leaf: lax.dynamic_index_in_dim(leaf, slot, 0,
                                               keepdims=False), pool)
-    # the chunk's padded tail is no token: no expert is read for it
-    live = jnp.arange(chunk.shape[-1])[None] < valid_len
     _, new_cache = cfg.apply_cached(params, cache, chunk, live=live)
     new_cache = _corrected_index(new_cache, cache, valid_len)
     return jax.tree.map(
@@ -569,6 +581,11 @@ class ServingEngine:
         self._mixed_sublayers = getattr(self.cfg, "mixed_sublayers", 0)
         # and how many of its layers keep a recurrent state
         self._state_layers = getattr(self.cfg, "state_layers", 0)
+        # and how many layer applications a token makes, where it makes
+        # several passes through its layers
+        self._loop_steps = getattr(self.cfg, "loop_steps", 1)
+        self._loop_layers = (self._loop_steps * self.cfg.n_layers
+                             if self._loop_steps > 1 else 0)
         self._spec = speculative
         self._draft_pool: Optional[SlotPool] = None
         self._draft_params = None
@@ -605,6 +622,8 @@ class ServingEngine:
                                           self.cfg.topk_group)
         if self._mixed_sublayers:
             self.metrics.on_residual_streams(self.cfg.residual_streams)
+        if self._loop_layers:
+            self.metrics.on_loop_steps(self._loop_steps)
         self.prefill_chunk = prefill_chunk
         self.decode_horizon = decode_horizon
         self.prefill_budget = prefill_budget
@@ -1066,7 +1085,8 @@ class ServingEngine:
                 int(valid), self._rebuilt(pos, c) if self._rebuilt else 0,
                 self._chunk_streamed(pos, c) if self._chunk_streamed else (),
                 mixed=int(valid) * self._mixed_sublayers,
-                state=int(valid) * self._state_layers)
+                state=int(valid) * self._state_layers,
+                looped=int(valid) * self._loop_layers)
             if (valid == c and req._prefix_keys
                     and pos // c < len(req._prefix_keys)):
                 # a FULL cold chunk just landed on the chunk grid —
@@ -1228,15 +1248,18 @@ class ServingEngine:
         hist = out[:self.decode_horizon]
         if self.pool.has_stats and self.metrics.publishing:
             stats = self.pool.unpack_stats(out[self.decode_horizon:])
-            self.metrics.on_expert_choices(
-                stats.get("stat_experts", ()), sorted(decoding),
-                self.cfg.held)
+            if "stat_experts" in stats:
+                self.metrics.on_expert_choices(
+                    stats["stat_experts"], sorted(decoding), self.cfg.held)
             self.metrics.on_expert_rows(stats.get("stat_expert_rows", ()))
+            self.metrics.on_exit_pdf(stats.get("stat_exit_pdf", ()),
+                                     sorted(decoding))
         self._emit(decoding, lambda slot: hist[:, slot])
         self.metrics.on_decode_step(
             len(decoding), flight.attended, flight.streamed,
             mixed=len(decoding) * self._mixed_sublayers,
-            ahead=flight.ahead, state=len(decoding) * self._state_layers)
+            ahead=flight.ahead, state=len(decoding) * self._state_layers,
+            looped=len(decoding) * self._loop_layers)
 
     def _spec_decode_step(self, decoding: Dict[int, Request]) -> None:
         """The speculative twin of :meth:`_decode_step`: one resident

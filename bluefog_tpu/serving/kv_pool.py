@@ -51,9 +51,11 @@ def pack_stats(cache):
     capacity]``, a row a number a slot holds (traced inside the decode
     program, which returns them below its token rows: one small array
     to copy, and none of the pool's own buffers, which the next program
-    takes by donation).  :meth:`SlotPool.unpack_stats` undoes it on the
-    host."""
-    leaves = [leaf.astype(jnp.int32).reshape(leaf.shape[0], -1).T
+    takes by donation); a float32 leaf travels as its bits.
+    :meth:`SlotPool.unpack_stats` undoes it on the host."""
+    as_int = lambda leaf: jax.lax.bitcast_convert_type(leaf, jnp.int32) \
+        if leaf.dtype == jnp.float32 else leaf.astype(jnp.int32)
+    leaves = [as_int(leaf).reshape(leaf.shape[0], -1).T
               for _, leaf in _stat_leaves(cache)]
     capacity = jax.tree.leaves(cache)[0].shape[0]
     return (jnp.concatenate(leaves) if leaves
@@ -142,9 +144,9 @@ class SlotPool:
             self._seq_axes = seq_axes(cfg, max_len, kv_quant)
         self._free: List[int] = list(range(capacity - 1, -1, -1))
         self._in_use: set = set()
-        # (name, shape) of the model's stat_* leaves, as pack_stats
-        # lays them out
-        self._stat_shapes = [(name, leaf.shape)
+        # (name, shape, dtype) of the model's stat_* leaves, as
+        # pack_stats lays them out
+        self._stat_shapes = [(name, leaf.shape, leaf.dtype)
                              for name, leaf in _stat_leaves(self.cache)]
         self.has_stats = bool(self._stat_shapes)
         # the recurrent layers' state_* leaves, by path
@@ -168,16 +170,19 @@ class SlotPool:
     @property
     def stat_rows(self) -> int:
         """The rows :func:`pack_stats` makes of this pool's leaves."""
-        return sum(int(np.prod(shape[1:])) for _, shape in self._stat_shapes)
+        return sum(int(np.prod(shape[1:]))
+                   for _, shape, _ in self._stat_shapes)
 
     def unpack_stats(self, rows: np.ndarray) -> dict:
         """A decode program's packed ``stat_*`` leaves (``pack_stats``,
         on the host) by name, ``{name: [leaf [capacity, ...] a layer
         that declares it]}``."""
         out, at = {}, 0
-        for name, shape in self._stat_shapes:
+        for name, shape, dtype in self._stat_shapes:
             n = int(np.prod(shape[1:]))
-            out.setdefault(name, []).append(rows[at:at + n].T.reshape(shape))
+            leaf = np.ascontiguousarray(rows[at:at + n].T).reshape(shape)
+            out.setdefault(name, []).append(
+                leaf.view(np.float32) if dtype == jnp.float32 else leaf)
             at += n
         return out
 

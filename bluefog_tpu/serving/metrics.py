@@ -118,6 +118,10 @@ class ServingMetrics:
         self.n_decode_overrun_slots = 0
         # the stat_expert_rows leaves as last read (``on_expert_rows``)
         self._expert_rows_seen = None
+        # decoded tokens' expected exit passes, summed, and the tokens
+        # (``on_exit_pdf``)
+        self._exit_pass_sum = 0.0
+        self._exit_pass_tokens = 0
         # the stalled step: the longest step so far, and what a step is
         # held against (``on_step``)
         self.n_steps = 0
@@ -255,7 +259,8 @@ class ServingMetrics:
                         "requests handed off to another replica").inc()
 
     def on_prefill_chunk(self, n_tokens: int, rebuilt: int = 0,
-                         streamed=(), mixed: int = 0, state: int = 0):
+                         streamed=(), mixed: int = 0, state: int = 0,
+                         looped: int = 0):
         """One cold prefill chunk ran (a model forward over one chunk)
         with ``n_tokens`` valid positions; the rest of the chunk's
         width was padding.  Together with :meth:`on_prefix_restore`
@@ -270,7 +275,8 @@ class ServingMetrics:
         empty for a model that declares none).  ``mixed``: see
         :meth:`on_mixed_tokens`.  ``state``: the chunk's valid tokens
         times the model's ``state_layers``, the tokens that went through
-        a recurrent layer's chunked form (0: no counter)."""
+        a recurrent layer's chunked form (0: no counter).  ``looped``:
+        see :meth:`on_loop_layer_tokens`."""
         self.n_prefill_chunks += 1
         reg = self._reg()
         if reg is not None:
@@ -296,10 +302,11 @@ class ServingMetrics:
                     "valid tokens of prefill chunks times the layers "
                     "that keep a recurrent state").inc(state)
         self.on_mixed_tokens(mixed)
+        self.on_loop_layer_tokens(looped)
 
     def on_decode_step(self, n_slots: int, attended=(), streamed=(),
                        mixed: int = 0, ahead: bool = False,
-                       state: int = 0):
+                       state: int = 0, looped: int = 0):
         """One decode program call (plain or speculative) advanced
         ``n_slots`` active slots: slots / steps is the batch size a
         decode step.  ``attended``: ``((kind, positions), ...)``, the
@@ -314,7 +321,8 @@ class ServingMetrics:
         its tokens): the share of such calls is how often the engine's
         host work ran beside the device's.  ``state``: the slots that
         decoded times the model's ``state_layers``, the single-token
-        steps of a recurrent state (0: no counter)."""
+        steps of a recurrent state (0: no counter).  ``looped``: see
+        :meth:`on_loop_layer_tokens`."""
         self.n_decode_ahead += bool(ahead)
         reg = self._reg()
         if reg is not None:
@@ -346,6 +354,51 @@ class ServingMetrics:
                     "decoding slots of decode program calls times the "
                     "layers that keep a recurrent state").inc(state)
         self.on_mixed_tokens(mixed)
+        self.on_loop_layer_tokens(looped)
+
+    def on_loop_layer_tokens(self, looped: int):
+        """A call ran ``looped`` = live tokens x passes x layers layer
+        applications (a model that declares ``loop_steps`` > 1: the
+        valid tokens of a chunk, the slots a step decodes; padding runs
+        on the device too and is not counted).  0: no counter."""
+        reg = self._reg()
+        if reg is not None and looped:
+            reg.counter(
+                "bf_serving_loop_layer_tokens_total",
+                "live tokens times the passes they make times the layers "
+                "of a pass (a looped stack), over prefill chunks and "
+                "decode steps").inc(looped)
+
+    def on_loop_steps(self, steps: int):
+        """A token of the served model makes ``steps`` passes through
+        its layers."""
+        reg = self._reg()
+        if reg is not None:
+            reg.gauge("bf_serving_loop_steps",
+                      "passes a token makes through the served model's "
+                      "layers (total_ut_steps)").set(steps)
+
+    def on_exit_pdf(self, pdfs, slots):
+        """A decode program call of a looped model: ``pdfs``, its
+        ``stat_exit_pdf`` leaves (one ``[capacity, 1, passes]`` array:
+        the exit gate's distribution over the passes for each slot's
+        LAST token), and the ``slots`` that decoded.  Keeps the mean,
+        over the decoded tokens so far, of the expected exit pass
+        ``sum_t t p_t`` (passes count from 1): what an exit below the
+        served threshold of 1 would leave of the loop."""
+        reg = self._reg()
+        if reg is None or not len(pdfs) or not len(slots):
+            return
+        pdf = np.asarray(pdfs[0], np.float64)[np.asarray(slots)]
+        pdf = pdf.reshape(-1, pdf.shape[-1])
+        self._exit_pass_sum += float(
+            (pdf * np.arange(1, pdf.shape[-1] + 1)).sum())
+        self._exit_pass_tokens += pdf.shape[0]
+        reg.gauge(
+            "bf_serving_exit_pass_mean",
+            "mean over decoded tokens of the pass the exit gate expects "
+            "a token to leave at (sum_t t p_t); every token is served "
+            "every pass").set(self._exit_pass_sum / self._exit_pass_tokens)
 
     def on_mixed_tokens(self, mixed: int):
         """A call mixed ``mixed`` = live tokens x sublayers of residual

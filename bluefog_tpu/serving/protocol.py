@@ -4,8 +4,8 @@ imports a model class or asks a model's name.
 A model is served through its CONFIG: a frozen, hashable dataclass (it
 is the static argument of the engine's resident programs) with the
 methods of :class:`ServedModel`.  ``models.llama.LlamaConfig``,
-``models.afmoe.AfmoeConfig`` and ``models.mla_moe.MlaMoeConfig``
-implement it.
+``models.afmoe.AfmoeConfig``, ``models.mla_moe.MlaMoeConfig`` and
+``models.looped.LoopedConfig`` implement it.
 
 The cache of one sequence is a tree of leaves per layer, and the pool
 stacks whatever it is given (``SlotPool``: ``[capacity, *leaf]``).  The
@@ -20,12 +20,16 @@ serving layer reads a leaf's KIND off its name (:func:`leaf_kind`):
   prefix, so a prefix cache refuses a model that declares one.
 * ``stat_*``: small observations of the model's own calls; where a
   registry counts, they leave the device with a decode step's tokens.
+  A float32 leaf travels as its bits (``kv_pool.pack_stats``).
   ``stat_experts [top_k]`` holds the experts a sequence's last token
   chose (``ServingMetrics.on_expert_choices`` counts them against the
   model's ``held = (first, count)``); ``stat_expert_rows [2]`` adds up,
   over every call that wrote the slot, the rows the expert loop
   computed and the held assignments they were computed for
-  (``ServingMetrics.on_expert_rows`` counts what it grew by).
+  (``ServingMetrics.on_expert_rows`` counts what it grew by);
+  ``stat_exit_pdf [loop_steps]`` the distribution a looped model's exit
+  gate puts over the passes for the sequence's last token
+  (``ServingMetrics.on_exit_pdf`` keeps its mean expected pass).
 * ``state_*``: what a recurrent layer remembers of the sequence, of a
   fixed size whatever ``max_len`` is, read whole by every call.  It has
   no "above the index" to hide anything in, so the model keeps this
@@ -71,6 +75,31 @@ A model with recurrent layers MAY declare ``state_layers`` (how many):
 the engine counts the live tokens of every chunk and decode step times
 the layers (``bf_serving_state_chunk_tokens_total``,
 ``bf_serving_state_steps_total``).
+
+A model whose layers run several times over the same weights MAY
+declare ``loop_steps`` (the passes a token makes through its layers;
+absent means 1) beside ``n_layers`` (the layers of one pass).  Every
+pass of every layer keeps keys and values of its own, so such a model's
+``cache_kinds()`` and ``streamed_positions()`` count ``loop_steps x
+n_layers`` layers, and every counter and gauge built on them
+(``bf_serving_streamed_positions_total``, ``bf_serving_cache_bytes``)
+with them; the engine sets the gauge ``bf_serving_loop_steps`` and
+counts the live tokens of every chunk and decode step times the passes
+times the layers (``bf_serving_loop_layer_tokens_total``).  Its leaves
+may be STACKS over (pass, layer), rolled loops carry them, and one
+``cache_index`` serves them all: the engine, the pool and the prefix
+cache find a leaf's kind by its name and its position axis by what
+scales with ``max_len``, and ask nothing else of a leaf's shape.
+
+A model whose cache of ONE sequence is large MAY declare
+``apply_in_pool(params, pool, slot, tokens, live=None) -> (logits,
+pool')``: ``apply_cached`` on slot ``slot`` of the pool's stacked leaves
+(``[capacity, *leaf]``), its rows written where they lie.  The engine's
+prefill-chunk program then calls it in place of cutting the slot's tree
+out of the pool and putting it back, which holds a second copy of the
+slot while the call runs (1.125 GiB at 192 layer applications x 768
+positions), and corrects the slot's index as it does after
+``apply_cached``.
 """
 
 from __future__ import annotations

@@ -81,6 +81,9 @@ MODULES = [
     ("bluefog_tpu.models.kda",
      "Kimi Delta Attention: a recurrent mixer with a matrix of state a "
      "head; a single-token step and a chunked form"),
+    ("bluefog_tpu.models.looped",
+     "a dense stack that runs several times over shared weights, a "
+     "cache for every pass: one rolled program over stacked leaves"),
     ("bluefog_tpu.serving.protocol",
      "what the serving layer needs of a model (config methods, cache "
      "leaf kinds)"),

@@ -88,6 +88,20 @@ _PINNED_AS_LAST = (
     "test_an_append_moved_nothing_that_was_there[configs]",
     "test_perfbench_mhc.py::"
     "test_an_append_moved_nothing_that_was_there[workloads]",
+    # PR 38's four pin every list as PR 38 left it: the names, and each
+    # ``workloads`` list grown by PR 38's cell alone (PR 40 appended a
+    # configuration, a cell, four entries, and its cell to the lists the
+    # dense serve cell is on, the end-to-end three among them).  The
+    # prefix that an append keeps, from PR 40's side:
+    # ``test_perfbench_looped.py::test_the_append_of_this_cell_moved_nothing_that_was_there``.
+    "test_perfbench_kda.py::"
+    "test_the_append_of_this_cell_moved_nothing_that_was_there[configs]",
+    "test_perfbench_kda.py::"
+    "test_the_append_of_this_cell_moved_nothing_that_was_there[workloads]",
+    "test_perfbench_kda.py::"
+    "test_the_append_of_this_cell_moved_nothing_that_was_there[end_to_end]",
+    "test_perfbench_kda.py::"
+    "test_the_append_of_this_cell_moved_nothing_that_was_there[per_layer]",
 )
 
 

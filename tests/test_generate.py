@@ -188,14 +188,18 @@ def test_eos_freezes_finished_rows():
     cfg, _, variables, prompt = _setup(False)
     plain = np.asarray(llama_generate(variables, cfg, jnp.asarray(prompt),
                                       NEW))
-    # force row 0 to stop after its 3rd generated token
-    eos = int(plain[0, T_PROMPT + 2])
-    assert eos not in plain[0, T_PROMPT:T_PROMPT + 2]
+    # force row 0 to stop in mid-stream: at its first generated token,
+    # from the third on, that no earlier generated position of the row
+    # holds (the row would stop at the earlier one)
+    row = plain[0, T_PROMPT:]
+    stop = next(j for j in range(2, NEW - 1) if row[j] not in row[:j])
+    eos = int(row[stop])
+    assert eos not in row[:stop]
     got = np.asarray(llama_generate(variables, cfg, jnp.asarray(prompt),
                                     NEW, eos_id=eos))
-    np.testing.assert_array_equal(got[0, :T_PROMPT + 3],
-                                  plain[0, :T_PROMPT + 3])
-    assert np.all(got[0, T_PROMPT + 3:] == eos)
+    np.testing.assert_array_equal(got[0, :T_PROMPT + stop + 1],
+                                  plain[0, :T_PROMPT + stop + 1])
+    assert np.all(got[0, T_PROMPT + stop + 1:] == eos)
     for r in range(1, prompt.shape[0]):
         if eos not in plain[r, T_PROMPT:]:
             np.testing.assert_array_equal(got[r], plain[r])

@@ -211,6 +211,65 @@ def test_engine_decode_step_reads_the_latent_pool_in_place_on_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes < leaf // 4
 
 
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_the_looped_models_programs_hold_one_cache_on_v5e(
+        chip, monkeypatch, program):
+    """Ouro-2.6B's two resident programs at the sizes of
+    ouro-2.6b-serve-short-answer (BENCHMARK.json): 48 layers x 4 passes
+    rolled, 8 slots x 768 positions, a pool of 9.0 GiB in two stacked
+    leaves beside 4.97 GiB of bf16 weights.  The loops carry the pool
+    and write it in place; the decode step's kernel finds its (pass,
+    layer) inside the stack and the chunk writes its slot where it lies,
+    so what the compiler adds to the arguments stays under 64 MiB (the
+    forms that do not: a transposed copy of wq, wk and wv of every
+    layer, 1.125 GiB; a chunk that cuts its slot out of the pool, 1.125
+    GiB; a key or value leaf laid out anew for the chunk's einsums, 4.5
+    GiB and no fit)."""
+    from bluefog_tpu.models.looped import LoopedConfig, init_params
+
+    monkeypatch.setattr(pallas_decode, "_auto_interpret",
+                        lambda interpret: False)
+    slots_n, max_len, chunk = 8, 768, 256
+    cfg = LoopedConfig(models.LlamaConfig(
+        vocab_size=49152, dim=2048, n_layers=48, n_heads=16, n_kv_heads=16,
+        hidden_dim=5632, rope_theta=1e6, norm_eps=1e-6,
+        dtype=jnp.bfloat16)).serving_layout(max_len, chunk=chunk,
+                                            decode_attn="pallas")
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        tree)
+    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16),
+        init_params(cfg, jax.random.PRNGKey(0)))))
+    pool = on_chip(jax.eval_shape(
+        lambda: SlotPool(cfg, slots_n, max_len, chunk=chunk).cache))
+    sds = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=chip)
+    if program == "decode_step":
+        lowered = engine._decode_step_prog.lower(
+            params, pool, sds(jnp.int32, slots_n), sds(bool, slots_n),
+            sds(jnp.uint32, slots_n, 2), sds(jnp.int32, slots_n),
+            sds(jnp.float32, slots_n), cfg=cfg, horizon=1)
+    else:
+        lowered = engine._prefill_chunk_prog.lower(
+            params, pool, sds(jnp.int32), sds(jnp.int32, 1, chunk),
+            sds(jnp.int32), cfg=cfg)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 9 * 2 ** 30      # the pool, once
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
+    need = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert need < 14.1 * 2 ** 30
+    assert ("tpu_custom_call" in compiled.as_text()) \
+        == (program == "decode_step")
+    # one copy of the block: the seven projections and the gate; the
+    # head where its logits are read (the step), the attention's two
+    # products where no kernel stands for them (the chunk)
+    assert lowered.as_text().count("dot_general") \
+        == (9 if program == "decode_step" else 10)
+
+
 # the chunk of ling3-flash-serve-doc-reasoning and of
 # mistral-small4-serve-long-prompt (BENCHMARK.json): rows, width, an
 # expert's width, experts held, experts a token
