@@ -875,6 +875,10 @@ class Attention(nn.Module):
         b, t, n_kv, hd = k.shape
         max_len = cfg.max_seq_len
         row_live = None if live is None else live[:, 0]
+        # the fused single-token step (parallel/pallas_decode.py)
+        fused = cfg.decode_attn == "pallas" and t == 1
+        if fused:
+            from bluefog_tpu.parallel import pallas_decode
         ci = self.variable("cache", "cache_index",
                            lambda: jnp.zeros((), jnp.int32))
         idx = ci.value
@@ -917,13 +921,10 @@ class Attention(nn.Module):
             ck.value, cks.value = kq_all, ks_all
             cv.value, cvs.value = vq_all, vs_all
             ci.value = idx + t
-            if cfg.decode_attn == "pallas" and t == 1:
-                # fused single-launch decode step: in-kernel dequant,
-                # probabilities kept float (parallel/pallas_decode.py)
-                from bluefog_tpu.parallel.pallas_decode import (
-                    decode_attention_int8)
-                return decode_attention_int8(q, kq_all, ks_all, vq_all,
-                                             vs_all, idx, live=row_live)
+            if fused:
+                # in-kernel dequant, probabilities kept float
+                return pallas_decode.decode_attention_int8(
+                    q, kq_all, ks_all, vq_all, vs_all, idx, live=row_live)
             if cfg.param_quant == "w8a8" and max_len <= 1024:
                 # fully-integer attention: both contractions run s8xs8
                 # on the MXU against the raw int8 cache — the cache
@@ -946,14 +947,25 @@ class Attention(nn.Module):
                                (b, n_kv, max_len, hd), cfg.dtype)
             cv = self.variable("cache", "cached_value", jnp.zeros,
                                (b, n_kv, max_len, hd), cfg.dtype)
+            if fused and pallas_decode.writable(max_len):
+                # the kernel writes the step's rows itself (a row that
+                # is not live writes nothing): under the engine's map
+                # over slots XLA scatters them a slot at a time, a loop
+                # for K and one for V a layer (4.4 of 14.8 ms a step,
+                # PERF.md section 6, PR 41)
+                out, ck.value, cv.value = pallas_decode.decode_attention(
+                    q, ck.value, cv.value, idx, live=row_live,
+                    fresh=(k[:, :, 0], v[:, :, 0]))
+                ci.value = idx + 1
+                return out
             k_all = lax.dynamic_update_slice(
                 ck.value, k.astype(cfg.dtype), (zero, zero, idx, zero))
             v_all = lax.dynamic_update_slice(
                 cv.value, v.astype(cfg.dtype), (zero, zero, idx, zero))
             ck.value, cv.value, ci.value = k_all, v_all, idx + t
-        if cfg.decode_attn == "pallas" and t == 1:
-            from bluefog_tpu.parallel.pallas_decode import decode_attention
-            return decode_attention(q, k_all, v_all, idx, live=row_live)
+        if fused:
+            return pallas_decode.decode_attention(q, k_all, v_all, idx,
+                                                  live=row_live)
         # queries live at global positions [idx, idx+t); the causal mask
         # there also excludes the cache's unwritten (zero) tail
         return _cached_attention(q, k_all, v_all, idx)

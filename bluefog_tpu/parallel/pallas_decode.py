@@ -96,6 +96,22 @@ four of eight rows live 38.3.  A call is 76 us: 16 before its first
 step and about 5.6 a block that computes, because each of 16 heads has
 ONE query row and its two products are a row against a block (at 8
 heads under 32, a block computed in 3).
+
+The DENSE writing form (PR 41, ``decode_attention(fresh=)`` without
+``leaf``) is the stacked one over a plain pair ``[B, KV, S, D]`` taken as
+a stack of one leaf: a unit axis, a bitcast in the compiled program and
+no copy of a leaf.  ``models/llama.py`` hands it the single-token step's
+rows wherever the cache's blocks are whole tiles (``writable``).
+Readings on a v5e, the whole decode program of the Mistral-7B serving
+cut traced in its cells (16 layers, 32 slots x 2048 positions, 8 KV
+heads of 128 under 32 query heads in bf16, 16 calls a step; PERF.md
+section 6, PR 41): rows written by XLA first, 14.76 ms a step with 6.0
+slots live, of which 32 loops of 0.137 ms (4.38: the scatter, a loop
+over the slots for K and one for V a layer) and the kernel 0.77 (48 us a
+call); the kernel writing, 11.41 ms with 4.7 slots live, no loop, the
+kernel 0.99 (62 us a call: alone, the writing form costs 14 us a call
+before any row is live and 0-0.45 us a block).  With 20-30 slots live
+16.91 -> 12.97 ms, the kernel 2.99 -> 2.82 (187 -> 176 us, fewer rows).
 """
 
 from __future__ import annotations
@@ -110,7 +126,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["decode_attention", "decode_attention_int8",
            "latent_block", "latent_decode_attention", "latent_tileable",
-           "streamed_positions", "tileable"]
+           "streamed_positions", "tileable", "writable"]
 
 _NEG_INF = -1e30
 # Cache positions one grid step streams, or the largest divisor of the
@@ -133,6 +149,13 @@ def tileable(s_len: int) -> bool:
     least 8 rows (a prime length past ``_BLOCK_S`` does not: a 1-row
     block would run one grid step a position)."""
     return s_len < 8 or _fit_block(s_len, _BLOCK_S) >= 8
+
+
+def writable(s_len: int) -> bool:
+    """Whether a call that also writes (``decode_attention(fresh=)``)
+    serves a cache of ``s_len`` positions: the tile it puts back lies
+    inside one block, so a block is whole ``_WRITE_ROWS`` tiles."""
+    return _fit_block(s_len, _BLOCK_S) % _WRITE_ROWS == 0
 
 
 # rows of the kernel's scalar-prefetch operand (the fifth only where the
@@ -208,6 +231,11 @@ def _row_plan(idx, live, b: int, s_len: int, want: int, leaf=None,
     if leaf is not None:
         plan = jnp.concatenate([plan, per_row(leaf, jnp.int32)[None]])
     if writes:
+        if block % _WRITE_ROWS:
+            raise ValueError(
+                f"a cache of {s_len} positions in blocks of {block} is no "
+                f"whole number of {_WRITE_ROWS}-row tiles: the kernel "
+                "cannot write the step's rows (fresh=); write them first")
         tile = (jnp.where(live, idx, 0) // _WRITE_ROWS)[plan[_SRC]]
         plan = jnp.concatenate([plan, tile[None]])
     return block, plan
@@ -573,20 +601,27 @@ def decode_attention(q, k_all, v_all, idx, *, live=None, leaf=None,
     loop that is rolled over its layers (``models/looped.py``) hands the
     kernel the whole stack it carries and no copy of a slice.
 
-    ``fresh = (k, v)`` ([B, KV, D] each; with ``leaf``): the stacks do
-    not hold the step's own key and value rows yet; the call attends
-    over them as the rows at ``idx``, writes them there (a row that is
-    not ``live`` writes nothing) and returns ``(out, k_all', v_all')``:
-    the cache write of a decode step inside the kernel that reads the
-    cache, for a step whose rows XLA would scatter into a mapped pool
-    one (slot, leaf) at a time."""
+    ``fresh = (k, v)`` ([B, KV, D] each): the cache does not hold the
+    step's own key and value rows yet; the call attends over them as
+    the rows at ``idx``, writes them there (a row that is not ``live``
+    writes nothing) and returns ``(out, k_all', v_all')``: the cache
+    write of a decode step inside the kernel that reads the cache, for
+    a step whose rows XLA would scatter into a mapped pool one slot (and
+    leaf) at a time.  Without ``leaf`` the pair is a stack of one leaf.
+    A cache whose blocks are no whole tiles (``writable``) raises."""
     live = jnp.asarray(True if live is None else live)
+    interpret = _auto_interpret(interpret)
+    if fresh is not None and leaf is None:
+        # a unit axis: a bitcast on the way in and on the way out
+        out, k_all, v_all = _stacked_call(block_s, interpret)(
+            idx, live, jnp.zeros((), jnp.int32), q, k_all[:, None],
+            v_all[:, None], *fresh)
+        return out, k_all[:, 0], v_all[:, 0]
     if leaf is not None:
-        return _stacked_call(block_s, _auto_interpret(interpret))(
+        return _stacked_call(block_s, interpret)(
             idx, live, jnp.asarray(leaf, jnp.int32), q, k_all, v_all,
             *(fresh or ()))
-    return _dense_call(block_s, _auto_interpret(interpret))(
-        idx, live, q, k_all, v_all)
+    return _dense_call(block_s, interpret)(idx, live, q, k_all, v_all)
 
 
 def decode_attention_int8(q, kq_all, ks_all, vq_all, vs_all, idx, *,

@@ -267,7 +267,10 @@ def _decode_step_prog(params, pool, toks, active, keys, counts, temps,
         # mask hides it until something real overwrites it — the next
         # prefill chunk (mid-admission slots), the next real decode
         # write, or the zero-on-free (free slots).  Masking just the
-        # index leaves skips two whole-pool copies per token.  (A
+        # index leaves skips two whole-pool copies per token.  A step
+        # that writes its rows inside the decode kernel writes NOTHING
+        # for a slot that is not ``live``: the same cache to every
+        # reader, since nothing read the hidden row.  (A
         # recurrent layer's state_* leaf comes back as it went in: the
         # slot's token was not ``live``, and the model froze it.)
         if protocol.leaf_kind(path) != protocol.INDEX:

@@ -114,13 +114,18 @@ def test_decode_attention_blocked_softmax_is_stable():
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("kv_quant,weight_quant", [
-    ("none", "none"), ("int8", "int8")])
-def test_generate_pallas_decode_token_parity(kv_quant, weight_quant):
+@pytest.mark.parametrize("kv_quant,weight_quant,max_len", [
+    ("none", "none", None), ("int8", "int8", None), ("none", "none", 32)],
+    ids=["none-none", "int8-int8", "none-none-writes"])
+def test_generate_pallas_decode_token_parity(kv_quant, weight_quant,
+                                             max_len):
     """llama_generate with decode_attn='pallas' emits the same tokens as
     the XLA path: for the full-precision cache both compute identical
     f32 attention; for kv int8 + weight-only int8 the XLA path dequants
-    the cache into float attention — the exact math the kernel fuses."""
+    the cache into float attention — the exact math the kernel fuses.
+    At 32 positions (two 16-row tiles) the full-precision step writes
+    its rows inside the kernel; at the default 19 XLA writes them."""
+    assert pallas_decode.writable(32) and not pallas_decode.writable(19)
     cfg = models.LlamaConfig.tiny(dtype=jnp.float32)
     model = models.Llama(cfg)
     rng = np.random.RandomState(6)
@@ -130,7 +135,7 @@ def test_generate_pallas_decode_token_parity(kv_quant, weight_quant):
     if weight_quant != "none":
         from bluefog_tpu.models import quantize_llama_params
         variables = jax.jit(quantize_llama_params)(variables)
-    kw = dict(kv_quant=kv_quant, weight_quant=weight_quant)
+    kw = dict(kv_quant=kv_quant, weight_quant=weight_quant, max_len=max_len)
     # pin the reference to the XLA lowering: the default decode_attn=
     # "auto" resolves to pallas for short full-precision caches, which
     # would make this parity check compare pallas against itself
@@ -279,6 +284,82 @@ def test_the_block_follows_the_cache_length():
 
 
 # ------------------------------------------------------------------ #
+# the step that writes its own rows (PR 41): the stacked form's kernel
+# (tests/test_looped.py) over a plain pair, a stack of one leaf
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("live", [
+    [True, True, True, True], [True, False, True, False],
+    [False, True, False, True], [False, False, False, False]],
+    ids=["all", "even", "odd", "none"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_dense_writing_step_is_the_write_then_the_step(dtype, live):
+    """``decode_attention(fresh=)`` over a plain ``[B, KV, S, D]`` pair:
+    the output and the caches of the rows written first and the kernel
+    after.  Rows at a block's last and first position (blocks of 32),
+    at a 16-row tile's last and first; a row that does not decode
+    writes nothing (its cache bit for bit, its output zeros); and the
+    same under the engine's map over rows, each at its own position."""
+    keys = jax.random.split(jax.random.PRNGKey(41), 5)
+    k_all, v_all = (jax.random.normal(kk, (4, 2, S, 16)).astype(dtype)
+                    for kk in keys[:2])
+    q = jax.random.normal(keys[2], (4, 1, 4, 16)).astype(dtype)
+    k_new, v_new = (jax.random.normal(kk, (4, 2, 16)).astype(dtype)
+                    for kk in keys[3:])
+    idx = jnp.asarray([31, 32, 15, 48], jnp.int32)
+    live = jnp.asarray(live)
+
+    def written(c, new):
+        at = (jnp.arange(S)[None] == idx[:, None]) & live[:, None]
+        return jnp.where(at[:, None, :, None], new[:, :, None, :], c)
+
+    want_k, want_v = written(k_all, k_new), written(v_all, v_new)
+    want = decode_attention(q, want_k, want_v, idx, live=live, block_s=32)
+    assert np.all(np.asarray(want, np.float32)[~np.asarray(live)] == 0)
+
+    def same(got, rows=lambda x: x):
+        out, got_k, got_v = got
+        assert out.dtype == q.dtype and got_k.dtype == got_v.dtype == dtype
+        assert np.array_equal(np.asarray(rows(got_k)), np.asarray(want_k))
+        assert np.array_equal(np.asarray(rows(got_v)), np.asarray(want_v))
+        assert np.allclose(np.asarray(rows(out), np.float32),
+                           np.asarray(want, np.float32), atol=1e-6)
+
+    same(decode_attention(q, k_all, v_all, idx, live=live,
+                          fresh=(k_new, v_new), block_s=32))
+    step = jax.vmap(lambda qq, kk, vv, ii, ll, kn, vn: decode_attention(
+        qq[None], kk[None], vv[None], ii, live=ll,
+        fresh=(kn[None], vn[None]), block_s=32))
+    operands = (q, k_all, v_all, idx, live, k_new, v_new)
+    assert str(jax.make_jaxpr(step)(*operands)).count("pallas_call") == 1
+    same(step(*operands), rows=lambda x: x[:, 0])
+
+
+@pytest.mark.parametrize("s_len,block_s,leaf", [
+    (24, None, None), (S, 8, None), (592, None, None), (24, None, 0)],
+    ids=["one-block-of-24", "blocks-of-8", "blocks-of-296", "stacked"])
+def test_fresh_rows_where_the_kernel_cannot_write_them_raise(
+        s_len, block_s, leaf):
+    """A block that is no whole number of 16-row tiles cannot hold the
+    tile a writing call puts back: ``fresh=`` raises there, it is never
+    dropped (``writable`` says so beforehand, which is what
+    ``models/llama.py`` asks)."""
+    # by its default blocks; a caller's ``block_s`` is checked at the call
+    assert pallas_decode.writable(s_len) == (block_s is not None)
+    assert pallas_decode.tileable(s_len)     # the reading kernel serves it
+    q = jnp.zeros((2, 1, 4, 16))
+    cache = jnp.zeros((2, 2, s_len, 16))
+    if leaf is not None:
+        cache = cache[:, None]
+    new = jnp.zeros((2, 2, 16))
+    assert decode_attention(q, cache, cache, jnp.int32(3), leaf=leaf,
+                            block_s=block_s).shape == q.shape
+    with pytest.raises(ValueError, match="cannot write"):
+        decode_attention(q, cache, cache, jnp.int32(3), leaf=leaf,
+                         fresh=(new, new), block_s=block_s)
+
+
+# ------------------------------------------------------------------ #
 # through the serving engine
 # ------------------------------------------------------------------ #
 ENGINE = dict(capacity=5, max_len=1024, prefill_chunk=32)
@@ -347,6 +428,44 @@ def test_the_engine_serves_the_same_tokens_and_counts_what_it_streams(
                for p, _ in asked_p)
     assert max(n for _, n in asked_p) <= 5 * block
     assert sum(n for _, n in asked_p) < steps * cap * max_len / 3
+
+
+def _serve_reusing_slots(decode_attn):
+    """Five greedy requests through a TWO-slot engine of 64 positions
+    (one block, four 16-row tiles): one decodes across three tile
+    edges; the other slot finishes a short answer, stays free for five
+    steps (an inactive row at a frozen index beside a live one), is
+    taken by a prompt of three chunks (inactive again while it
+    prefills), and both slots are reused by what queued behind them."""
+    cfg = models.LlamaConfig.tiny(dtype=jnp.float32, max_seq_len=64)
+    variables = models.Llama(cfg).init(jax.random.PRNGKey(2),
+                                       jnp.zeros((2, 4), jnp.int32))
+    eng = ServingEngine(variables, cfg, decode_attn=decode_attn, capacity=2,
+                        max_len=64, prefill_chunk=16)
+    assert eng.cfg.decode_attn == decode_attn
+    rs = np.random.RandomState(41)
+    reqs = [Request(rs.randint(0, 256, (n,)).astype(np.int32), new)
+            for n, new in ((12, 40), (5, 4), (37, 10), (17, 12), (3, 20))]
+    eng.submit(reqs[0])
+    eng.submit(reqs[1])
+    while reqs[1].state != "completed":
+        eng.step()
+    for _ in range(5):
+        eng.step()
+    assert reqs[0].state != "completed"
+    for r in reqs[2:]:
+        eng.submit(r)
+    eng.run()
+    assert all(r.state == "completed" for r in reqs)
+    return [list(r.tokens) for r in reqs]
+
+
+def test_the_engine_serves_the_same_tokens_from_slots_it_reuses():
+    """The step that writes inside the kernel leaves the pool the write
+    through XLA left, as far as anything reads it: a row that is not
+    live writes nothing where XLA wrote behind the causal mask."""
+    assert pallas_decode.writable(64)
+    assert _serve_reusing_slots("pallas") == _serve_reusing_slots("xla")
 
 
 @pytest.mark.parametrize("kv_quant,max_len,resolved", [

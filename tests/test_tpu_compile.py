@@ -174,6 +174,55 @@ def test_engine_decode_step_holds_the_kernel_on_v5e(chip, monkeypatch):
     assert "tpu_custom_call" in text
 
 
+def test_the_mistral_decode_step_writes_its_rows_inside_the_kernel_on_v5e(
+        chip, monkeypatch):
+    """The resident decode step of mistral7b-serve-steady and
+    -saturated (BENCHMARK.json) at the serving cut's widths and pool,
+    two layers of the sixteen: 32 slots x 2,048 positions, 8 KV heads of
+    128 under 32 query heads, bf16.  The step's key and value rows go
+    into the cache inside the kernel that reads it: XLA's write under
+    the engine's map over slots was a scatter, lowered as a loop over
+    the slots for K and one for V a layer (32 loops, 4.4 of 14.8 ms a
+    step; PERF.md section 6, PR 41).  The program holds no scatter, no
+    loop, and no copy of a pool leaf on the way into the kernel or out
+    of it (the plain pair enters as a stack of one leaf: a bitcast),
+    and the pool is donated through."""
+    import re
+
+    monkeypatch.setattr(pallas_decode, "_auto_interpret",
+                        lambda interpret: False)
+    slots_n, max_len, layers = 32, 2048, 2
+    cfg = generate.decode_config(models.LlamaConfig(
+        vocab_size=32000, dim=4096, n_layers=layers, n_heads=32,
+        n_kv_heads=8, hidden_dim=14336, rope_theta=1e4, norm_eps=1e-5,
+        dtype=jnp.bfloat16), max_len, decode_attn="pallas")
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        tree)
+    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16),
+        models.Llama(cfg).init(jax.random.PRNGKey(0),
+                               jnp.zeros((1, 1), jnp.int32))["params"])))
+    pool = on_chip(jax.eval_shape(
+        lambda: SlotPool(cfg, slots_n, max_len).cache))
+    sds = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=chip)
+    compiled = engine._decode_step_prog.lower(
+        params, pool, sds(jnp.int32, slots_n), sds(bool, slots_n),
+        sds(jnp.uint32, slots_n, 2), sds(jnp.int32, slots_n),
+        sds(jnp.float32, slots_n), cfg=cfg, horizon=1).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == layers
+    assert "scatter" not in text
+    assert not re.search(r"\bwhile\(", text)
+    leaf = r"bf16\[32,(1,)?8,2048,128\]"
+    assert re.search(leaf, text)
+    assert not re.search(leaf + r"\S* copy\(", text)
+    leaf_bytes = slots_n * 8 * max_len * 128 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * layers * leaf_bytes
+
+
 def test_engine_decode_step_reads_the_latent_pool_in_place_on_v5e(
         chip, monkeypatch):
     """The latent model's resident decode step at Mistral-Small-4's
