@@ -38,7 +38,16 @@ chooses):
   under ``vmap``): with ``u = beta (v - S^T (alpha k))`` the new state is
   ``alpha S + k u^T`` and the read-out ``S^T (alpha q) + u (k . q)``:
   both products with the OLD state in one pass over it, and one pass to
-  write the new one.
+  write the new one.  Two walks over memory compute it, and the layout
+  says which (``steps_in_kernel``).  ``delta_step`` is the plain form:
+  the training layout, a CPU run, ``decode_attn="xla"`` and the tests'
+  reference; mapped over the engine's slots it reads EVERY slot's state
+  twice and writes it once.  Where the serving layout's cache reads are
+  kernels (``decode_attn="pallas"``, what ``"auto"`` resolves to on a
+  TPU) it is ``parallel/pallas_kda.py:delta_step``: one kernel over the
+  rows that are live, a row's state read once and written once where the
+  leaf lies, a row that is not live untouched, the index-0 rule a flag a
+  row in place of a select over the leaf.
 * CHUNKED, a call of several tokens (a prefill chunk, the training
   layout): blocks of ``KDA_BLOCK`` positions.  With ``G`` the running sum
   of ``g`` from the block's start, ``A[t, j] = sum_c k_t[c] k_j[c]
@@ -70,7 +79,7 @@ from bluefog_tpu.models.experts import _dense
 from bluefog_tpu.models.llama import RMSNorm
 
 __all__ = ["KimiDeltaAttention", "delta_step", "delta_chunked", "causal_conv",
-           "KDA_BLOCK"]
+           "steps_in_kernel", "KDA_BLOCK"]
 
 SCOPE_ATTN_KDA = "bf.attn.kda"
 SCOPE_KDA_STATE = "bf.attn.kda_state"
@@ -95,6 +104,17 @@ def causal_conv(x, history, filters, n_live):
     kept = jax.vmap(lambda row, n: lax.dynamic_slice_in_dim(
         row, n, taps - 1, axis=0))(behind, n_live)
     return y, kept.astype(history.dtype)
+
+
+def steps_in_kernel(cfg) -> bool:
+    """Whether ``cfg``'s single-token step is the kernel over the live
+    rows (``parallel/pallas_kda.py``): the serving layout whose cache
+    reads are kernels too, at a width the kernel tiles."""
+    if not (cfg.decode and cfg.decode_attn == "pallas"):
+        return False
+    from bluefog_tpu.parallel import pallas_kda
+
+    return pallas_kda.steppable(cfg.kda_head_dim)
 
 
 def delta_step(q, k, v, g, beta, state):
@@ -183,6 +203,7 @@ class KimiDeltaAttention(nn.Module):
         init = nn.initializers.normal(cfg.initializer_range)
         if live is None:
             live = jnp.ones((b, t), bool)
+        stepped = t == 1 and steps_in_kernel(cfg)
         with jax.named_scope(SCOPE_ATTN_KDA):
             qkv = jnp.concatenate(
                 [_dense(cfg, h * d, name)(x) for name in ("wq", "wk", "wv")],
@@ -212,9 +233,11 @@ class KimiDeltaAttention(nn.Module):
                 sc = self.variable("cache", "state_conv", jnp.zeros,
                                    history.shape, cfg.dtype)
                 # a call at index 0 starts from nothing, whatever the
-                # slot's last request left in the leaves
+                # slot's last request left in the leaves (the kernel
+                # takes the flag: no select over the leaf)
                 fresh = ci.value == 0
-                state = jnp.where(fresh, 0.0, ss.value)
+                state = ss.value if stepped else jnp.where(fresh, 0.0,
+                                                           ss.value)
                 history = jnp.where(fresh, 0, sc.value).astype(cfg.dtype)
             with jax.named_scope(SCOPE_KDA_CONV):
                 mixed, history = causal_conv(
@@ -226,8 +249,14 @@ class KimiDeltaAttention(nn.Module):
                 q, k = unit(q) * d ** -0.5, unit(k)
             with jax.named_scope(SCOPE_KDA_STATE):
                 if t == 1:
-                    o, state = delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                          beta[:, 0], state)
+                    token = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                    if stepped:
+                        from bluefog_tpu.parallel import pallas_kda
+
+                        o, state = pallas_kda.delta_step(
+                            *token, state, live=live[:, 0], fresh=fresh)
+                    else:
+                        o, state = delta_step(*token, state)
                     o = o[:, None]
                 else:
                     o, state = delta_chunked(q, k, v, g, beta, state)

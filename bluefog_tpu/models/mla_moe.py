@@ -293,6 +293,16 @@ class MlaMoeConfig:
             fused=self.decode_attn == "pallas",
             block_s=pallas_decode.latent_block(self.max_seq_len))),)
 
+    def state_streamed_steps(self, decoding: int, capacity: int) -> int:
+        """Slots whose recurrent state a single-token step reads, times
+        the layers that keep one: the ``decoding`` slots where the step
+        is the kernel over the live rows, every one of the pool's
+        ``capacity`` under the XLA step, whoever decodes."""
+        from bluefog_tpu.models import kda
+
+        return self.state_layers * (decoding if kda.steps_in_kernel(self)
+                                    else capacity)
+
     def rebuilt_positions(self, start: int, tokens: int) -> int:
         """Cached positions whose keys and values a call of ``tokens``
         tokens at cache index ``start`` rebuilds from the latent, summed

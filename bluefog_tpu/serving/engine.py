@@ -584,6 +584,9 @@ class ServingEngine:
         self._mixed_sublayers = getattr(self.cfg, "mixed_sublayers", 0)
         # and how many of its layers keep a recurrent state
         self._state_layers = getattr(self.cfg, "state_layers", 0)
+        # and the slots x layers whose state a single-token step reads
+        self._state_streamed = getattr(self.cfg, "state_streamed_steps",
+                                       None)
         # and how many layer applications a token makes, where it makes
         # several passes through its layers
         self._loop_steps = getattr(self.cfg, "loop_steps", 1)
@@ -1262,6 +1265,9 @@ class ServingEngine:
             len(decoding), flight.attended, flight.streamed,
             mixed=len(decoding) * self._mixed_sublayers,
             ahead=flight.ahead, state=len(decoding) * self._state_layers,
+            state_streamed=(self._state_streamed(len(decoding),
+                                                 self.pool.capacity)
+                            if self._state_streamed else 0),
             looped=len(decoding) * self._loop_layers)
 
     def _spec_decode_step(self, decoding: Dict[int, Request]) -> None:

@@ -306,7 +306,8 @@ class ServingMetrics:
 
     def on_decode_step(self, n_slots: int, attended=(), streamed=(),
                        mixed: int = 0, ahead: bool = False,
-                       state: int = 0, looped: int = 0):
+                       state: int = 0, looped: int = 0,
+                       state_streamed: int = 0):
         """One decode program call (plain or speculative) advanced
         ``n_slots`` active slots: slots / steps is the batch size a
         decode step.  ``attended``: ``((kind, positions), ...)``, the
@@ -321,8 +322,12 @@ class ServingMetrics:
         its tokens): the share of such calls is how often the engine's
         host work ran beside the device's.  ``state``: the slots that
         decoded times the model's ``state_layers``, the single-token
-        steps of a recurrent state (0: no counter).  ``looped``: see
-        :meth:`on_loop_layer_tokens`."""
+        steps of a recurrent state (0: no counter).  ``state_streamed``:
+        the slots whose state the program READ times those layers (the
+        model's ``state_streamed_steps``: the decoding slots under a
+        kernel over the live rows, the pool's capacity under the XLA
+        step; over ``state`` it is 1.0 where the kernel engages).
+        ``looped``: see :meth:`on_loop_layer_tokens`."""
         self.n_decode_ahead += bool(ahead)
         reg = self._reg()
         if reg is not None:
@@ -353,6 +358,12 @@ class ServingMetrics:
                     "bf_serving_state_steps_total",
                     "decoding slots of decode program calls times the "
                     "layers that keep a recurrent state").inc(state)
+            if state_streamed:
+                reg.counter(
+                    "bf_serving_state_streamed_steps_total",
+                    "slots whose recurrent state decode program calls "
+                    "read times the layers that keep one"
+                ).inc(state_streamed)
         self.on_mixed_tokens(mixed)
         self.on_loop_layer_tokens(looped)
 

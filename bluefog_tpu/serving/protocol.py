@@ -74,7 +74,13 @@ inside one ``apply_cached``: no leaf holds one.
 A model with recurrent layers MAY declare ``state_layers`` (how many):
 the engine counts the live tokens of every chunk and decode step times
 the layers (``bf_serving_state_chunk_tokens_total``,
-``bf_serving_state_steps_total``).
+``bf_serving_state_steps_total``).  It MAY also declare
+``state_streamed_steps(decoding, capacity) -> int``: the slots whose
+state a single-token step READS times those layers, from the count of
+decoding slots and the pool's capacity (the decoding slots under a
+kernel over the live rows, every slot where the step is mapped over the
+pool); the engine counts it a decode step
+(``bf_serving_state_streamed_steps_total``).
 
 A model whose layers run several times over the same weights MAY
 declare ``loop_steps`` (the passes a token makes through its layers;

@@ -125,6 +125,8 @@ MODULES = [
     ("bluefog_tpu.parallel.pallas_attention", "Pallas flash attention"),
     ("bluefog_tpu.parallel.pallas_decode",
      "Pallas fused decode-attention step"),
+    ("bluefog_tpu.parallel.pallas_kda",
+     "Pallas single-token step of the delta rule over the live rows"),
     ("bluefog_tpu.windows", "one-sided window ops (win_put/get/update)"),
     ("bluefog_tpu.compressor", "gradient compression (TopK/RandomK/int8)"),
     ("bluefog_tpu.checkpoint", "orbax checkpoint/resume wrappers"),

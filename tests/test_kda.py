@@ -61,6 +61,25 @@ def test_chunked_steps_and_the_token_loop_agree(t):
     np.testing.assert_allclose(s1[0], want_s, atol=2e-5)
 
 
+def test_twenty_steps_through_the_kernel_are_the_chunked_form():
+    """The serving layout's single-token step (``parallel/pallas_kda``,
+    interpreted, at the kernel's width of 128: the first from zero state
+    by the index-0 rule, over a leaf that holds something else) twenty
+    times, against ``delta_chunked`` over the same twenty tokens."""
+    from bluefog_tpu.parallel import pallas_kda
+
+    q, k, v, g, beta, stale = draw(11, 20, d=128)
+    want_o, want_s = kda.delta_chunked(
+        *as_f32(q, k, v, g, beta), jnp.zeros((1,) + stale.shape))
+    s1, outs = as_f32(stale)[0], []
+    for i in range(20):
+        o1, s1 = pallas_kda.delta_step(
+            *as_f32(q[i], k[i], v[i], g[i], beta[i]), s1, fresh=i == 0)
+        outs.append(o1[0])
+    np.testing.assert_allclose(np.stack(outs), want_o[0], atol=2e-5)
+    np.testing.assert_allclose(s1[0], want_s[0], atol=2e-5)
+
+
 def test_a_decay_at_the_lower_bound_keeps_the_block_finite():
     """Every channel at g = -4.999 for a whole block: exp(-G) reaches
     e^80 inside the block, under float32's e^88."""

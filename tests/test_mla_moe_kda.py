@@ -271,3 +271,42 @@ def test_the_engine_serves_two_layer_kinds_with_slots_freed_and_reused():
     assert eng.cfg.cache_kinds() == {"full": (1, None)}
     assert eng.cfg.streamed_positions([3, -1]) == (("full", 2 * 72),)
     assert eng.cfg.rebuilt_positions(8, 4) == 16
+
+
+def test_the_engine_serves_the_kernels_tokens_and_counts_what_it_reads():
+    """The two-kind model at the kernel's width (a state of 128 x 128 a
+    head) through three slots, five requests, with the single-token
+    steps as kernels (``decode_attn="pallas"``, interpreted: the
+    recurrent layers follow the layout's resolved field) and as XLA's:
+    the same tokens, though a reused slot's leaf holds the state its
+    last request left (the index-0 rule rides in the kernel's plan, and
+    a slot that does not decode is not written).  The slots whose state
+    a decode program READS are the decoding ones under the kernel and
+    every one of the pool under XLA."""
+    from bluefog_tpu.observe import MetricsRegistry
+
+    sz = dict(KDA_SZ, head_dim=128, qk_nope_head_dim=128, v_head_dim=128,
+              num_hidden_layers=3, published_layers=[0, 1, 2])
+    params = _kda_params(sz, seed=3)
+    rng = np.random.default_rng(12)
+    lengths, budgets = (9, 5, 13, 1, 6), (5, 8, 3, 6, 4)
+    prompts = [rng.integers(0, 128, n) for n in lengths]
+    served, read = {}, {}
+    for attn in ("xla", "pallas"):
+        reg = MetricsRegistry()
+        eng = ServingEngine({"params": params},
+                            KDA_FAMILY.model_config(sz, key_block=8),
+                            capacity=3, max_len=24, prefill_chunk=4,
+                            decode_attn=attn, registry=reg)
+        assert eng.cfg.decode_attn == attn and eng.cfg.state_layers == 2
+        reqs = [eng.submit(Request(p, b)) for p, b in zip(prompts, budgets)]
+        eng.run()
+        assert all(r.state == "completed" for r in reqs)
+        served[attn] = [list(r.tokens) for r in reqs]
+        count = lambda name: reg.counter(name, "").value
+        assert count("bf_serving_state_steps_total") == 2 * sum(budgets)
+        read[attn] = (count("bf_serving_state_streamed_steps_total"),
+                      count("bf_serving_decode_steps_total"))
+    assert served["pallas"] == served["xla"]
+    assert read["pallas"][0] == 2 * sum(budgets)
+    assert read["xla"][0] == 2 * 3 * read["xla"][1]

@@ -1042,6 +1042,10 @@ def test_the_pool_and_the_counters_report_the_state():
         == 2 * sum(n - 1 for n in lengths)
     assert reg.counter("bf_serving_state_steps_total", "").value \
         == 2 * sum(budgets)
+    # the XLA step reads the state of every slot of the pool, whoever
+    # decodes: three slots x two layers a decode program
+    assert reg.counter("bf_serving_state_streamed_steps_total", "").value \
+        == 2 * 3 * reg.counter("bf_serving_decode_steps_total", "").value
     assert eng.cfg.cache_kinds() == {"full": (1, None)}
     # a model without a state leaf sets and counts none of it
     plain = MetricsRegistry()

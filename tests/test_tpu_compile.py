@@ -223,24 +223,11 @@ def test_the_mistral_decode_step_writes_its_rows_inside_the_kernel_on_v5e(
         >= 2 * layers * leaf_bytes
 
 
-def test_engine_decode_step_reads_the_latent_pool_in_place_on_v5e(
-        chip, monkeypatch):
-    """The latent model's resident decode step at Mistral-Small-4's
-    attention widths and pool (32 slots x 16,384 positions of 320), one
-    layer of few experts: the cache write and the kernel both take the
-    leaf as the program's argument lies, so the program's temporaries
-    stay far under one leaf (a row-major kernel made 0.39 GiB of them a
-    layer: a transposed copy in, another out)."""
-    from bluefog_tpu.models.mla_moe import MlaMoe, MlaMoeConfig
+def _latent_decode_step(cfg, slots_n, max_len, chip):
+    """``engine._decode_step_prog`` of a ``models/mla_moe.py`` config
+    over a pool of ``slots_n`` x ``max_len``, compiled from shapes."""
+    from bluefog_tpu.models.mla_moe import MlaMoe
 
-    monkeypatch.setattr(pallas_decode, "_auto_interpret",
-                        lambda interpret: False)
-    slots_n, max_len = 32, 16384
-    cfg = MlaMoeConfig(
-        vocab_size=1024, dim=1024, n_layers=1, n_heads=32, q_lora_rank=256,
-        kv_lora_rank=256, qk_nope_head_dim=64, qk_rope_head_dim=64,
-        v_head_dim=128, expert_hidden_dim=256, n_experts=8, top_k=2,
-        rope_factor=128.0).serving_layout(max_len, decode_attn="pallas")
     variables = jax.eval_shape(
         lambda: MlaMoe(cfg).init(jax.random.PRNGKey(0),
                                  jnp.zeros((1, 1), jnp.int32)))
@@ -252,12 +239,88 @@ def test_engine_decode_step_reads_the_latent_pool_in_place_on_v5e(
     args = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
         (variables["params"], pool))
-    compiled = engine._decode_step_prog.lower(
+    return engine._decode_step_prog.lower(
         *args, slots(jnp.int32), slots(bool), slots(jnp.uint32, 2),
         slots(jnp.int32), slots(jnp.float32), cfg=cfg, horizon=1).compile()
+
+
+def test_engine_decode_step_reads_the_latent_pool_in_place_on_v5e(
+        chip, monkeypatch):
+    """The latent model's resident decode step at Mistral-Small-4's
+    attention widths and pool (32 slots x 16,384 positions of 320), one
+    layer of few experts: the cache write and the kernel both take the
+    leaf as the program's argument lies, so the program's temporaries
+    stay far under one leaf (a row-major kernel made 0.39 GiB of them a
+    layer: a transposed copy in, another out)."""
+    from bluefog_tpu.models.mla_moe import MlaMoeConfig
+
+    monkeypatch.setattr(pallas_decode, "_auto_interpret",
+                        lambda interpret: False)
+    slots_n, max_len = 32, 16384
+    cfg = MlaMoeConfig(
+        vocab_size=1024, dim=1024, n_layers=1, n_heads=32, q_lora_rank=256,
+        kv_lora_rank=256, qk_nope_head_dim=64, qk_rope_head_dim=64,
+        v_head_dim=128, expert_hidden_dim=256, n_experts=8, top_k=2,
+        rope_factor=128.0).serving_layout(max_len, decode_attn="pallas")
+    compiled = _latent_decode_step(cfg, slots_n, max_len, chip)
     assert "tpu_custom_call" in compiled.as_text()
     leaf = slots_n * max_len * cfg.latent_width * 2
     assert compiled.memory_analysis().temp_size_in_bytes < leaf // 4
+
+
+def test_engine_decode_step_advances_the_live_states_in_place_on_v5e(
+        chip, monkeypatch):
+    """The two-kind model's resident decode step at Ling3's recurrent
+    widths (ling3-flash-serve-doc-reasoning, BENCHMARK.json: 64 slots,
+    32 heads of a 128 x 128 float32 state, 2 MiB a slot a layer), one
+    recurrent layer and one latent, few experts.  The step of the state
+    is ONE kernel over the leaf as the program's argument lies: the leaf
+    is aliased from argument to result and the program's temporaries
+    stay far under one leaf of 128 MiB (``delta_step`` under the
+    engine's map over slots walked it three times: a select for the
+    index-0 rule, a reduction and a ``multiply_add_fusion`` as tall as
+    the leaf).  A model with no recurrent layer never loads the kernel's
+    module."""
+    import re
+    import sys
+
+    from bluefog_tpu import parallel
+    from bluefog_tpu.models.mla_moe import MlaMoeConfig
+
+    monkeypatch.setattr(pallas_decode, "_auto_interpret",
+                        lambda interpret: False)
+    slots_n, max_len, heads, head_dim = 64, 4096, 32, 128
+    widths = dict(
+        vocab_size=1024, dim=1024, n_layers=2, n_heads=heads,
+        q_lora_rank=None, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, expert_hidden_dim=256,
+        n_experts=8, top_k=2, head_gate=True, kda_head_dim=head_dim)
+    decode_step = lambda cfg: _latent_decode_step(
+        cfg.serving_layout(max_len, decode_attn="pallas"), slots_n, max_len,
+        chip)
+
+    # as in a process that never loaded it
+    monkeypatch.delitem(sys.modules, "bluefog_tpu.parallel.pallas_kda",
+                        raising=False)
+    monkeypatch.delattr(parallel, "pallas_kda", raising=False)
+    decode_step(MlaMoeConfig(**widths))
+    assert "bluefog_tpu.parallel.pallas_kda" not in sys.modules
+
+    compiled = decode_step(MlaMoeConfig(**widths,
+                                        layer_types=("kda", "latent")))
+    text = compiled.as_text()
+    # the recurrent layer's kernel and the latent layer's
+    assert text.count("tpu_custom_call") == 2
+    leaf = slots_n * heads * head_dim * head_dim * 4
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < leaf // 4
+    assert memory.alias_size_in_bytes >= leaf
+    state = rf"f32\[{slots_n},(?:1,)?{heads},{head_dim},{head_dim}\]"
+    assert re.search(state, text)
+    # nothing but the kernel makes an array as tall as the leaf
+    made = re.findall(state + r"\S* (\w[\w-]*)\(", text)
+    assert set(made) <= {"parameter", "custom-call", "bitcast",
+                         "get-tuple-element"}, made
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
