@@ -6,6 +6,14 @@ story: ``--xla_force_host_platform_device_count`` provides N devices in one
 process, so "ranks" are devices and the whole suite is single-process
 (SURVEY.md §4).  This must run before jax initializes a backend, hence the
 env mutation at import time.
+
+The driver's command (``commands`` in ``/root/TESTS_LAST_RUN.json``) is the
+truth about how the suite is run: six ``xdist`` workers under ``--dist
+loadfile`` on 8 cores, each a process that holds these 8 devices, under one
+time limit for the whole run.  A file is a worker's unit: the longest file
+is the least the run can take whatever the other five workers do, and a case
+under the suite's own load is about twice as slow as alone (``ROADMAP.md``
+Design 9: no file over 350 s on its worker).
 """
 
 import os

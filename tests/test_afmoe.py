@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import served_model
 from bluefog_tpu.models import afmoe
 from bluefog_tpu.serving import Request, ServingEngine, SlotPool
 from bluefog_tpu.serving.prefix_cache import PrefixCache
@@ -47,13 +48,15 @@ SZ = {
 }
 
 
+CHUNK, MAX_LEN = 4, 64            # the ring: WINDOW + CHUNK = 12 rows
+
+
 def _params(sz=SZ, seed=0):
-    return jax.jit(lambda k: FAMILY.make_params(sz, k, jnp.float32)[0])(
-        jax.random.PRNGKey(seed))
+    return served_model.params(FAMILY, sz, seed)
 
 
 def _reference(params, tokens, sz=SZ):
-    return np.asarray(REF.logits(params, jnp.asarray(tokens), sz))
+    return served_model.reference(REF, sz, params, tokens)
 
 
 # ------------------------------------------------------------------ #
@@ -64,35 +67,23 @@ def test_full_forward_matches_the_reference(held):
     sz = dict(SZ, experts_held_from=held[0], num_experts=held[1])
     params = _params(sz)
     tokens = np.random.default_rng(1).integers(0, sz["vocab_size"], 40)
-    cfg = FAMILY.model_config(sz)
-    got = afmoe.Afmoe(cfg).apply({"params": params}, tokens[None])[0]
+    model = afmoe.Afmoe(FAMILY.model_config(sz))
+    got = jax.jit(lambda p, t: model.apply({"params": p}, t)[0])(
+        params, tokens[None])
     want = _reference(params, tokens, sz)
     assert got.shape == want.shape == (40, sz["vocab_size"])
     assert np.abs(np.asarray(got) - want).max() < TOL * want.std()
+    if held == (0, 16):
+        assert served_model.padding_moves(REF, sz, params, tokens) \
+            < 0.25 * TOL
 
 
 # ------------------------------------------------------------------ #
 # (b) through the engine
 # ------------------------------------------------------------------ #
 def _serve(params, prompts, budgets, **engine):
-    engine = dict(dict(capacity=2, max_len=64, prefill_chunk=4), **engine)
-    eng = ServingEngine({"params": params}, FAMILY.model_config(SZ),
-                        **engine)
-    reqs = [eng.submit(Request(p, n)) for p, n in zip(prompts, budgets)]
-    eng.run()
-    assert all(r.state == "completed" for r in reqs)
-    return eng, reqs
-
-
-def _assert_served_is_the_references_greedy(params, req):
-    """Teacher-forced: at every served position the reference's best
-    token is the served one (or ties with it inside the tolerance)."""
-    seq = req.output()
-    want = _reference(params, seq[:-1])
-    p, g = req.prompt.size, len(req.tokens)
-    rows = want[p - 1:p - 1 + g]
-    gap = rows.max(-1) - rows[np.arange(g), np.asarray(req.tokens)]
-    assert gap.max() < TOL * want.std(), (p, g, gap.max())
+    return served_model.serve(FAMILY.model_config(SZ), params, prompts,
+                              budgets, **{"max_len": MAX_LEN, **engine})
 
 
 @pytest.mark.parametrize("chunk, lengths, budgets", [
@@ -112,32 +103,18 @@ def test_served_tokens_match_the_reference(chunk, lengths, budgets):
     prompts = [rng.integers(0, SZ["vocab_size"], n) for n in lengths]
     _, reqs = _serve(params, prompts, budgets, prefill_chunk=chunk)
     for r in reqs:
-        _assert_served_is_the_references_greedy(params, r)
+        served_model.assert_served_is_the_references_greedy(REF, SZ, params,
+                                                            r, TOL)
 
 
 def test_no_recompile_inside_the_window_or_across_the_wrap():
-    from bluefog_tpu.serving.engine import (_decode_step_prog,
-                                            _prefill_chunk_prog)
-
-    params = _params()
-    rng = np.random.default_rng(3)
-    eng, _ = _serve(params, [rng.integers(0, 128, 5)], [3])
-    sizes = (_prefill_chunk_prog._cache_size(),
-             _decode_step_prog._cache_size())
-    reqs = [eng.submit(Request(rng.integers(0, 128, n), 6))
-            for n in (40, 2, 17)]
-    eng.run()
-    assert all(r.state == "completed" for r in reqs)
-    assert sizes == (_prefill_chunk_prog._cache_size(),
-                     _decode_step_prog._cache_size())
+    eng, _ = _serve(_params(), [np.arange(5)], [3])
+    served_model.assert_other_lengths_compile_nothing(eng)
 
 
 # ------------------------------------------------------------------ #
 # (b') a chunk's attention, block of key rows by block
 # ------------------------------------------------------------------ #
-CHUNK, MAX_LEN = 4, 64            # the ring: WINDOW + CHUNK = 12 rows
-
-
 def _chunk_layer(kind, dtype):
     """One attention layer in the serving layout, its parameters, and a
     cache whose every row holds something (a slot that was used before):
@@ -364,7 +341,8 @@ def test_padding_chooses_no_expert_and_none_is_read_for_it(call):
 
     def last_choice(tokens):
         """The experts the last of ``tokens`` chose, a layer."""
-        _, cache = cfg.apply_cached(params, one, jnp.asarray(tokens)[None])
+        _, cache = jax.jit(cfg.apply_cached)(params, one,
+                                             jnp.asarray(tokens)[None])
         return {name: set(np.asarray(
             layer["moe"]["stat_experts"]).ravel().tolist())
             for name, layer in cache.items() if "moe" in layer}
@@ -397,6 +375,7 @@ def test_padding_chooses_no_expert_and_none_is_read_for_it(call):
         poisoned[name] = dict(params[name], moe=dict(
             params[name]["moe"], **{k: params[name]["moe"][k].at[dead].set(
                 jnp.nan) for k in ("w1", "w2", "w3")}))
+    run = jax.jit(run, static_argnums=1)    # one program, as the engine's
     clean, dirty = run(params, True), run(poisoned, True)
     assert bool(jnp.isfinite(dirty).all())
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
@@ -689,12 +668,10 @@ def test_the_counters_of_a_served_run():
                       for k, v in t.items()}
     params = {k: (dict(v, moe=part(v["moe"])) if "moe" in v else v)
               for k, v in params.items()}
-    eng = ServingEngine({"params": params}, FAMILY.model_config(sz),
-                        capacity=2, max_len=64, prefill_chunk=4,
-                        registry=reg)
-    reqs = [eng.submit(Request(rng.integers(0, 128, n), 5))
-            for n in (20, 3)]
-    eng.run()
+    eng, reqs = served_model.serve(
+        FAMILY.model_config(sz), params,
+        [rng.integers(0, 128, n) for n in (20, 3)], [5, 5], max_len=MAX_LEN,
+        registry=reg)
     value = lambda name, **labels: reg.counter(name, "", **labels).value
     steps = value("bf_serving_decode_steps_total")
     slots = value("bf_serving_decode_slots_total")
@@ -713,7 +690,6 @@ def test_the_counters_of_a_served_run():
                  kind="window") == window
     assert reg.gauge("bf_serving_cache_bytes", "", kind="window").value \
         == eng.pool.cache_bytes()["window"]
-    assert all(r.state == "completed" for r in reqs)
 
 
 def test_the_counters_of_a_run_ahead_are_the_synchronous_count():
@@ -772,7 +748,6 @@ def test_the_counters_of_a_run_ahead_are_the_synchronous_count():
 
 
 def test_the_rows_a_chunk_reads_are_counted_by_kind(monkeypatch):
-    from bluefog_tpu import models
     from bluefog_tpu.observe.registry import MetricsRegistry
 
     monkeypatch.setattr(afmoe, "KEY_BLOCK", 4)
@@ -780,14 +755,11 @@ def test_the_rows_a_chunk_reads_are_counted_by_kind(monkeypatch):
     reg = MetricsRegistry()
     rng = np.random.default_rng(6)
     # 60 rows: a leaf no other test compiles a program for
-    eng = ServingEngine({"params": _params()}, FAMILY.model_config(SZ),
-                        capacity=2, max_len=60, prefill_chunk=4,
-                        registry=reg)
-    reqs = [eng.submit(Request(rng.integers(0, 128, n), 3))
-            for n in (30, 3)]
-    eng.run()
+    eng, reqs = _serve(_params(), [rng.integers(0, 128, n) for n in (30, 3)],
+                       [3, 3], max_len=60, registry=reg)
     for r in reqs:
-        _assert_served_is_the_references_greedy(eng._params, r)
+        served_model.assert_served_is_the_references_greedy(
+            REF, SZ, eng._params, r, TOL)
     # chunks of 4 cover prompt[:-1]: they start at 0, 4, ...; a chunk
     # that ends at e reads ceil(e / 4) blocks of 4 rows, a ring (12
     # rows) at most its 3; 1 full layer, 4 window layers
@@ -800,9 +772,7 @@ def test_the_rows_a_chunk_reads_are_counted_by_kind(monkeypatch):
         == 4 * sum(4 * min(e // 4, 3) for e in ends)
     # a model that declares nothing counts nothing
     reg = MetricsRegistry()
-    cfg = models.LlamaConfig.tiny(dtype=jnp.float32)
-    variables = models.Llama(cfg).init(jax.random.PRNGKey(1),
-                                       jnp.zeros((2, 4), jnp.int32))
+    cfg, variables = served_model.tiny_llama()
     eng = ServingEngine(variables, cfg, capacity=2, max_len=32,
                         prefill_chunk=4, registry=reg)
     eng.submit(Request(rng.integers(0, 128, 11), 2))
@@ -822,14 +792,9 @@ def test_the_expert_rows_counters_of_a_served_run():
     reg = MetricsRegistry()
     rng = np.random.default_rng(6)
     chunk = TILE + 64
-    eng = ServingEngine({"params": _params()}, FAMILY.model_config(SZ),
-                        capacity=2, max_len=2 * chunk, prefill_chunk=chunk,
-                        registry=reg)
     lengths, new = (chunk + 41, 3), 4
-    reqs = [eng.submit(Request(rng.integers(0, 128, n), new))
-            for n in lengths]
-    eng.run()
-    assert all(r.state == "completed" for r in reqs)
+    _serve(_params(), [rng.integers(0, 128, n) for n in lengths], [new, new],
+           max_len=2 * chunk, prefill_chunk=chunk, registry=reg)
     value = lambda name, **labels: reg.counter(name, "", **labels).value
     rows = value("bf_moe_expert_rows_total")
     assigned = value("bf_moe_expert_assignments_total")

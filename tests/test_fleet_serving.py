@@ -30,29 +30,17 @@ import jax
 import jax.numpy as jnp
 
 from bluefog_tpu import models
-from bluefog_tpu.models import llama_generate
 from bluefog_tpu.observe.registry import MetricsRegistry
 from bluefog_tpu.serving import (FleetRouter, FleetSaturated, PrefixCache,
-                                 Request, RequestRejected, ServingEngine,
+                                 Request, RequestRejected,
                                  SlotPool, SpeculativeConfig,
                                  collect_serving_signals)
+from served_model import (one_shot as _one_shot, tiny_engine as _engine,
+                          tiny_llama as _setup)
 
 pytestmark = pytest.mark.fleet_serving
 
 MAX_LEN = 48
-
-
-def _setup(**cfg_overrides):
-    cfg = models.LlamaConfig.tiny(dtype=jnp.float32, **cfg_overrides)
-    variables = models.Llama(cfg).init(jax.random.PRNGKey(1),
-                                       jnp.zeros((2, 4), jnp.int32))
-    return cfg, variables
-
-
-def _one_shot(variables, cfg, prompt, n, **kw):
-    out = llama_generate(variables, cfg, jnp.asarray(prompt[None]), n,
-                         max_len=MAX_LEN, **kw)
-    return np.asarray(out)[0]
 
 
 # --------------------------------------------------------------------- #
@@ -127,12 +115,10 @@ def test_prefix_admission_bitwise_exact_property(kv_quant):
         params = quantize_llama_params(variables)
         kw = dict(kv_quant="int8", weight_quant="int8")
     rs = np.random.RandomState(42)
-    eng = ServingEngine(params, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=4, prefix_cache=True,
-                        max_queue=64, registry=MetricsRegistry(), **kw)
-    cold = ServingEngine(params, cfg, capacity=1, max_len=MAX_LEN,
-                         prefill_chunk=4, prefix_cache=False,
-                         max_queue=64, registry=MetricsRegistry(), **kw)
+    eng = _engine(params, cfg, capacity=1, prefix_cache=True, max_queue=64,
+                  registry=MetricsRegistry(), **kw)
+    cold = _engine(params, cfg, capacity=1, prefix_cache=False, max_queue=64,
+                   registry=MetricsRegistry(), **kw)
     prompts = []
     for _ in range(3):
         # a family: one prefix, several continuations of random length
@@ -163,9 +149,8 @@ def test_prefix_restore_skips_prefill_work():
     prefill-chunk counter advances by the tail chunks alone, and the
     restored token count lands in the summary."""
     cfg, variables = _setup()
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=4, prefix_cache=True,
-                        registry=MetricsRegistry())
+    eng = _engine(variables, cfg, capacity=1, prefix_cache=True,
+                  registry=MetricsRegistry())
     rs = np.random.RandomState(7)
     prefix = rs.randint(0, 256, (16,)).astype(np.int32)
     a = np.concatenate([prefix, rs.randint(0, 256, (2,)).astype(np.int32)])
@@ -186,9 +171,7 @@ def test_prefix_restore_skips_prefill_work():
 def test_prefix_chunk_must_match_engine_chunk():
     cfg, variables = _setup()
     with pytest.raises(ValueError, match="chunk"):
-        ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                      prefill_chunk=4,
-                      prefix_cache=PrefixCache(chunk=8))
+        _engine(variables, cfg, capacity=1, prefix_cache=PrefixCache(chunk=8))
 
 
 # --------------------------------------------------------------------- #
@@ -203,8 +186,7 @@ def test_slot_reuse_exact_both_free_modes(zero_on_free):
     # reference programs compile once for the whole file
     prompts = [p.astype(np.int32) for p in
                (np.arange(5) + 3, np.arange(9) * 2 + 1)]
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=4, zero_on_free=zero_on_free)
+    eng = _engine(variables, cfg, capacity=1, zero_on_free=zero_on_free)
     assert eng.pool.zero_on_free is zero_on_free
     for p in prompts:
         r = eng.submit(Request(p, 6))
@@ -221,8 +203,7 @@ def test_free_modes_differ_only_in_retention():
     cfg, variables = _setup()
 
     def run_one(zero):
-        eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                            prefill_chunk=4, zero_on_free=zero)
+        eng = _engine(variables, cfg, capacity=1, zero_on_free=zero)
         eng.submit(Request(np.arange(9, dtype=np.int32), 4))
         eng.run()
         total = 0.0
@@ -261,9 +242,8 @@ def test_free_modes_differ_only_in_retention():
 def _spec_engine(variables, cfg, draft_vars, draft_cfg=None, **kw):
     spec = SpeculativeConfig(variables=draft_vars,
                              cfg=draft_cfg or cfg, lookahead=3)
-    return ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
-                         prefill_chunk=4, speculative=spec,
-                         registry=MetricsRegistry(), **kw)
+    return _engine(variables, cfg, speculative=spec,
+                   registry=MetricsRegistry(), **kw)
 
 
 def test_speculative_self_draft_exact_and_fast():
@@ -331,8 +311,7 @@ def test_speculative_headroom_reservation():
     CLAMP and corrupt K/V silently)."""
     cfg, variables = _setup()
     prompt = np.arange(MAX_LEN - 8, dtype=np.int32)
-    plain = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                          prefill_chunk=4)
+    plain = _engine(variables, cfg, capacity=1)
     plain.submit(Request(prompt, 8))  # exactly fits
     eng = _spec_engine(variables, cfg, variables)
     with pytest.raises(ValueError, match="headroom"):
@@ -344,8 +323,7 @@ def test_resident_program_set_fixed_at_build():
     plain, 3 speculative, unchanged by serving load, and profile()
     enumerates exactly that set."""
     cfg, variables = _setup()
-    plain = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
-                          prefill_chunk=4)
+    plain = _engine(variables, cfg)
     eng = _spec_engine(variables, cfg, variables)
     assert sorted(plain._resident) == ["decode_step", "prefill_chunk"]
     assert sorted(eng._resident) == ["draft_prefill_chunk",
@@ -389,9 +367,8 @@ def test_speculative_no_recompiles_across_arrivals():
 # --------------------------------------------------------------------- #
 def _fleet(variables, cfg, n, capacity=2, max_queue=2, **kw):
     regs = [MetricsRegistry() for _ in range(n)]
-    engines = [ServingEngine(variables, cfg, capacity=capacity,
-                             max_len=MAX_LEN, prefill_chunk=4,
-                             max_queue=max_queue, registry=r)
+    engines = [_engine(variables, cfg, capacity=capacity, max_queue=max_queue,
+                       registry=r)
                for r in regs]
     return engines, regs, FleetRouter(engines, registries=regs, **kw)
 
@@ -399,8 +376,7 @@ def _fleet(variables, cfg, n, capacity=2, max_queue=2, **kw):
 def test_collect_serving_signals():
     cfg, variables = _setup()
     reg = MetricsRegistry()
-    eng = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
-                        prefill_chunk=4, registry=reg)
+    eng = _engine(variables, cfg, registry=reg)
     sig = collect_serving_signals(reg)
     assert sig == {"occupancy": 0.0, "queue_depth": 0.0, "ttft_p50": 0.0,
                    "last_step_ts": -1.0}  # -1: never stepped (the

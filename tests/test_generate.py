@@ -13,6 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import served_model
 from bluefog_tpu import models
 from bluefog_tpu.models import llama_generate
 
@@ -20,24 +21,25 @@ B, T_PROMPT, NEW = 2, 7, 9
 
 
 def _setup(scan_layers):
-    cfg = models.LlamaConfig.tiny(dtype=jnp.float32,
-                                  scan_layers=scan_layers)
+    cfg, variables = served_model.tiny_llama(scan_layers=scan_layers)
     model = models.Llama(cfg)
-    variables = model.init(jax.random.PRNGKey(1),
-                           jnp.zeros((B, 4), jnp.int32))
     prompt = np.random.RandomState(0).randint(
         0, 256, (B, T_PROMPT)).astype(np.int32)
     return cfg, model, variables, prompt
 
 
 def _rollout_greedy(model, variables, prompt, n_new):
-    """Reference: no cache, full forward over the growing sequence."""
-    seq = jnp.asarray(prompt)
-    for _ in range(n_new):
-        logits = model.apply(variables, seq)
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
-    return np.asarray(seq)
+    """Reference: no cache, full forward over the growing sequence
+    (causal, so zeros behind it change no row before them: one program
+    at the last length, not one an operation and a length)."""
+    forward = jax.jit(model.apply)
+    t = prompt.shape[1]
+    seq = np.zeros((prompt.shape[0], t + n_new), np.int32)
+    seq[:, :t] = prompt
+    for at in range(t, t + n_new):
+        logits = forward(variables, jnp.asarray(seq))
+        seq[:, at] = np.asarray(jnp.argmax(logits[:, at - 1], axis=-1))
+    return seq
 
 
 @pytest.mark.parametrize("scan_layers", [False, True])
@@ -112,12 +114,7 @@ def test_generate_clears_model_parallel_axes():
     otherwise hit unbound-axis psums outside shard_map)."""
     cfg = models.LlamaConfig.tiny(dtype=jnp.float32, tp_axis="tp",
                                   tp_size=2)
-    plain = models.LlamaConfig.tiny(dtype=jnp.float32)
-    model = models.Llama(plain)
-    variables = model.init(jax.random.PRNGKey(1),
-                           jnp.zeros((B, 4), jnp.int32))
-    prompt = np.random.RandomState(0).randint(
-        0, 256, (B, T_PROMPT)).astype(np.int32)
+    plain, model, variables, prompt = _setup(False)
     got = np.asarray(llama_generate(variables, cfg, jnp.asarray(prompt), 4))
     want = _rollout_greedy(model, variables, prompt, 4)
     np.testing.assert_array_equal(got, want)
@@ -132,12 +129,7 @@ def test_tp_sharded_decode_matches_no_cache_rollout():
 
     cfg = models.LlamaConfig.tiny(dtype=jnp.float32, tp_axis="tp",
                                   tp_size=2)
-    plain = models.LlamaConfig.tiny(dtype=jnp.float32)
-    model = models.Llama(plain)
-    variables = model.init(jax.random.PRNGKey(1),
-                           jnp.zeros((B, 4), jnp.int32))
-    prompt = np.random.RandomState(0).randint(
-        0, 256, (B, T_PROMPT)).astype(np.int32)
+    plain, model, variables, prompt = _setup(False)
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     got = np.asarray(llama_generate(variables, cfg, jnp.asarray(prompt),
                                     NEW, mesh=mesh))
@@ -152,12 +144,7 @@ def test_tp_sharded_decode_sampling_agrees_across_shards():
 
     cfg = models.LlamaConfig.tiny(dtype=jnp.float32, tp_axis="tp",
                                   tp_size=2)
-    plain = models.LlamaConfig.tiny(dtype=jnp.float32)
-    model = models.Llama(plain)
-    variables = model.init(jax.random.PRNGKey(1),
-                           jnp.zeros((B, 4), jnp.int32))
-    prompt = np.random.RandomState(0).randint(
-        0, 256, (B, T_PROMPT)).astype(np.int32)
+    plain, model, variables, prompt = _setup(False)
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     rng = jax.random.PRNGKey(7)
     a = np.asarray(llama_generate(variables, cfg, jnp.asarray(prompt), 5,

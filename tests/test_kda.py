@@ -50,12 +50,12 @@ def test_chunked_steps_and_the_token_loop_agree(t):
     float32, so the three differ by rounding over at most 64 steps."""
     q, k, v, g, beta, state = draw(t, t)
     want_o, want_s = token_loop(q, k, v, g, beta, state)
-    o, s = kda.delta_chunked(*as_f32(q, k, v, g, beta, state))
+    o, s = jax.jit(kda.delta_chunked)(*as_f32(q, k, v, g, beta, state))
     np.testing.assert_allclose(o[0], want_o, atol=2e-5)
     np.testing.assert_allclose(s[0], want_s, atol=2e-5)
-    s1, outs = as_f32(state)[0], []
+    s1, outs, step = as_f32(state)[0], [], jax.jit(kda.delta_step)
     for i in range(t):
-        o1, s1 = kda.delta_step(*as_f32(q[i], k[i], v[i], g[i], beta[i]), s1)
+        o1, s1 = step(*as_f32(q[i], k[i], v[i], g[i], beta[i]), s1)
         outs.append(o1[0])
     np.testing.assert_allclose(np.stack(outs), want_o, atol=2e-5)
     np.testing.assert_allclose(s1[0], want_s, atol=2e-5)

@@ -5,9 +5,13 @@ a small size on the CPU: the full forward pass, the served path through
 YaRN ramp, a padded last chunk, slots reused, long beside short; key
 blocks of two sizes), absorbed against expanded for one layer,
 the shares of an expert layer, the softmax router, the pool's one latent
-leaf, and the pins of the frequencies and the query's scale."""
+leaf, and the pins of the frequencies and the query's scale.  The model
+with four residual streams is ``tests/test_mla_moe_mhc.py``'s, the one
+with two kinds of layer ``tests/test_mla_moe_kda.py``'s: a worker takes a
+file whole."""
 
 import dataclasses
+import functools
 import math
 import os
 
@@ -16,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import served_model
 from bluefog_tpu.models import experts, mla_moe
 from bluefog_tpu.serving import Request, ServingEngine, SlotPool
 from bluefog_tpu.serving.prefix_cache import PrefixCache
@@ -60,12 +65,22 @@ KEY_BLOCKS = (8, 24)
 
 
 def _params(sz=SZ, seed=0, dtype=jnp.float32):
-    return jax.jit(lambda k: FAMILY.make_params(sz, k, dtype)[0])(
-        jax.random.PRNGKey(seed))
+    return served_model.params(FAMILY, sz, seed, dtype)
 
 
 def _reference(params, tokens, sz=SZ):
-    return np.asarray(REF.logits(params, jnp.asarray(tokens), sz))
+    return served_model.reference(REF, sz, params, tokens)
+
+
+@functools.cache
+def _program(cfg):
+    model = mla_moe.MlaMoe(cfg)
+    return jax.jit(lambda p, t: model.apply({"params": p}, t)[0])
+
+
+def _forward(cfg, params, tokens):
+    """The training layout's logits of one sequence: one program a config."""
+    return np.asarray(_program(cfg)(params, tokens[None]))
 
 
 # ------------------------------------------------------------------ #
@@ -77,11 +92,13 @@ def test_full_forward_matches_the_reference(held):
     params = _params(sz)
     tokens = np.random.default_rng(1).integers(0, sz["vocab_size"], 56)
     # seven key blocks of 8: the running softmax
-    cfg = FAMILY.model_config(sz, key_block=8)
-    got = mla_moe.MlaMoe(cfg).apply({"params": params}, tokens[None])[0]
+    got = _forward(FAMILY.model_config(sz, key_block=8), params, tokens)
     want = _reference(params, tokens, sz)
-    assert got.shape == want.shape == (56, sz["vocab_size"])
-    assert np.abs(np.asarray(got) - want).max() < TOL * want.std()
+    assert got.shape == (56, sz["vocab_size"])
+    assert served_model.gap(got, want) < TOL
+    if held == (0, 16):
+        assert served_model.padding_moves(REF, sz, params, tokens) \
+            < 0.25 * TOL
 
 
 def test_every_part_of_the_mathematics_is_seen_by_the_tolerance():
@@ -90,8 +107,7 @@ def test_every_part_of_the_mathematics_is_seen_by_the_tolerance():
     each inner norm."""
     params = _params()
     tokens = np.random.default_rng(2).integers(0, SZ["vocab_size"], 56)
-    got = np.asarray(mla_moe.MlaMoe(FAMILY.model_config(SZ)).apply(
-        {"params": params}, tokens[None])[0])
+    got = _forward(FAMILY.model_config(SZ), params, tokens)
     rope = SZ["rope_parameters"]
     twos = lambda x: jax.tree.map(lambda s: 2.0 * s, x)
     spoiled = {
@@ -118,25 +134,9 @@ def test_every_part_of_the_mathematics_is_seen_by_the_tolerance():
 # (b) through the engine
 # ------------------------------------------------------------------ #
 def _serve(params, prompts, budgets, key_block=8, **engine):
-    engine = dict(dict(capacity=2, max_len=72, prefill_chunk=4), **engine)
-    eng = ServingEngine({"params": params},
-                        FAMILY.model_config(SZ, key_block=key_block),
-                        **engine)
-    reqs = [eng.submit(Request(p, n)) for p, n in zip(prompts, budgets)]
-    eng.run()
-    assert all(r.state == "completed" for r in reqs)
-    return eng, reqs
-
-
-def _assert_served_is_the_references_greedy(params, req):
-    """Teacher-forced: at every served position the reference's best
-    token is the served one (or ties with it inside the tolerance)."""
-    seq = req.output()
-    want = _reference(params, seq[:-1])
-    p, g = req.prompt.size, len(req.tokens)
-    rows = want[p - 1:p - 1 + g]
-    gap = rows.max(-1) - rows[np.arange(g), np.asarray(req.tokens)]
-    assert gap.max() < TOL * want.std(), (p, g, gap.max())
+    """Two slots of 72 rows, chunks of 4 (``served_model.serve``)."""
+    return served_model.serve(FAMILY.model_config(SZ, key_block=key_block),
+                              params, prompts, budgets, **engine)
 
 
 @pytest.mark.parametrize("key_block", KEY_BLOCKS)
@@ -160,24 +160,13 @@ def test_served_tokens_match_the_reference(chunk, lengths, budgets,
     _, reqs = _serve(params, prompts, budgets, key_block,
                      prefill_chunk=chunk)
     for r in reqs:
-        _assert_served_is_the_references_greedy(params, r)
+        served_model.assert_served_is_the_references_greedy(REF, SZ, params,
+                                                            r, TOL)
 
 
 def test_no_recompile_inside_the_window():
-    from bluefog_tpu.serving.engine import (_decode_step_prog,
-                                            _prefill_chunk_prog)
-
-    params = _params()
-    rng = np.random.default_rng(3)
-    eng, _ = _serve(params, [rng.integers(0, 128, 5)], [3])
-    sizes = (_prefill_chunk_prog._cache_size(),
-             _decode_step_prog._cache_size())
-    reqs = [eng.submit(Request(rng.integers(0, 128, n), 6))
-            for n in (40, 2, 17)]
-    eng.run()
-    assert all(r.state == "completed" for r in reqs)
-    assert sizes == (_prefill_chunk_prog._cache_size(),
-                     _decode_step_prog._cache_size())
+    eng, _ = _serve(_params(), [np.arange(5)], [3])
+    served_model.assert_other_lengths_compile_nothing(eng)
 
 
 def test_the_positions_a_chunk_rebuilds_are_counted():
@@ -186,12 +175,7 @@ def test_the_positions_a_chunk_rebuilds_are_counted():
     params = _params()
     prompt = np.random.default_rng(4).integers(0, 128, 30)
     reg = MetricsRegistry()
-    eng = ServingEngine({"params": params},
-                        FAMILY.model_config(SZ, key_block=8),
-                        capacity=1, max_len=72, prefill_chunk=6,
-                        registry=reg)
-    eng.submit(Request(prompt, 3))
-    eng.run()
+    eng, _ = _serve(params, [prompt], [3], prefill_chunk=6, registry=reg)
     # 29 prompt tokens in chunks of 6 at 0, 6, .., 24; a chunk ending at
     # position e walks e // 8 + 1 key blocks of 8, in 3 layers
     blocks = sum((start + 6 - 1) // 8 + 1 for start in range(0, 30, 6))
@@ -262,11 +246,11 @@ def test_streamed_positions_under_the_kernel_are_the_blocks_the_plan_names(
 # ------------------------------------------------------------------ #
 # (c) absorbed equals expanded, one layer
 # ------------------------------------------------------------------ #
-def _one_layer(dtype, step: bool, seed=0):
+def _one_layer(dtype, seed=0):
     """The attention sublayer of layer 0 for the LAST of 40 positions
-    behind a cache that holds the 39 before it: a single-token step
-    (absorbed) or the second token of a two-token call (walked in blocks,
-    expanded)."""
+    behind a cache that holds the 39 before it: by a single-token step
+    (absorbed) and as the second token of a two-token call (walked in
+    blocks, expanded)."""
     cfg = dataclasses.replace(
         FAMILY.model_config(SZ, dtype=dtype, key_block=16), n_layers=1)
     params = _params(seed=seed, dtype=dtype)["layer_0"]["attention"]
@@ -276,19 +260,16 @@ def _one_layer(dtype, step: bool, seed=0):
         lambda s: jnp.zeros(s.shape, s.dtype),
         jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0),
                                           x[:, :1]))["cache"])
-    _, mut = layer.apply({"params": params, "cache": cache}, x[:, :39],
-                         mutable=["cache"])
-    cache, tail = mut["cache"], x[:, 39:]
-    if not step:
-        cache = dict(cache, cache_index=cache["cache_index"] - 1)
-        tail = x[:, 38:]
-    out, _ = layer.apply({"params": params, "cache": cache}, tail,
-                         mutable=["cache"])
-    return np.asarray(out, np.float32)[0, -1]
+    call = jax.jit(lambda c, x: layer.apply({"params": params, "cache": c},
+                                            x, mutable=["cache"]))
+    cache = call(cache, x[:, :39])[1]["cache"]
+    back = dict(cache, cache_index=cache["cache_index"] - 1)
+    return [np.asarray(out, np.float32)[0, -1]
+            for out, _ in (call(cache, x[:, 39:]), call(back, x[:, 38:]))]
 
 
 def test_absorbed_equals_expanded_for_one_layer():
-    step, expand = (_one_layer(jnp.float32, s) for s in (True, False))
+    step, expand = _one_layer(jnp.float32)
     size = np.abs(expand).max()
     # float32: the same sums in another order
     assert np.abs(step - expand).max() < 1e-5 * size
@@ -297,32 +278,38 @@ def test_absorbed_equals_expanded_for_one_layer():
     # rebuilt keys and values), 2^-8 relative each, over sums of 16 to
     # 40 terms that mostly cancel: 2% of the largest output bounds it
     # with room (a wrong scale or a lost column would read 10% or more)
-    step, expand = (_one_layer(jnp.bfloat16, s) for s in (True, False))
+    step, expand = _one_layer(jnp.bfloat16)
     assert np.abs(step - expand).max() < 2e-2 * size
 
 
 # ------------------------------------------------------------------ #
 # (d) the shares add up to the uncut layer
 # ------------------------------------------------------------------ #
-def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_reference():
-    params = _params()
-    moe = params["layer_1"]["moe"]
+def _shares(ref, family, sz, moe, held):
+    """An expert layer over 24 seeded tokens: the reference's uncut
+    layer, the program's shared expert, and the routed part of every
+    share of ``held`` experts (the shared expert taken off it)."""
     m = jax.random.normal(jax.random.PRNGKey(5), (1, 24, 64), jnp.float32)
-    want = REF.swiglu(m[0], moe["shared"], REF.mm_highest) \
-        + REF.routed_part(m[0], moe, SZ, REF.mm_highest)
-    cfg = FAMILY.model_config(SZ)
-    shared = experts.SwiGLU(cfg, SZ["moe_intermediate_size"]).apply(
+    want = ref.swiglu(m[0], moe["shared"], ref.mm_highest) \
+        + ref.routed_part(m[0], moe, sz, ref.mm_highest)
+    cfg = family.model_config(sz)
+    shared = experts.SwiGLU(cfg, sz["moe_intermediate_size"]).apply(
         {"params": moe["shared"]}, m)[0]
-    total = np.asarray(shared, np.float64)
-    for first in range(0, 16, 4):
-        share = dict(moe, **{k: moe[k][first:first + 4]
+    parts = []
+    for first in range(0, sz["router_outputs"], held):
+        share = dict(moe, **{k: moe[k][first:first + held]
                              for k in ("w1", "w3", "w2")})
         layer = experts.ExpertLayer(dataclasses.replace(
-            cfg, experts_held=(first, 4)))
-        total += np.asarray(layer.apply({"params": share}, m)[0]
-                            - shared, np.float64)
-    assert np.abs(total - np.asarray(want)).max() \
-        < TOL * np.asarray(want).std()
+            cfg, experts_held=(first, held)))
+        parts.append(np.asarray(layer.apply({"params": share}, m)[0]
+                                - shared, np.float64))
+    return m, np.asarray(want), np.asarray(shared, np.float64), parts
+
+
+def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_reference():
+    _, want, shared, parts = _shares(REF, FAMILY, SZ,
+                                     _params()["layer_1"]["moe"], 4)
+    assert np.abs(shared + sum(parts) - want).max() < TOL * want.std()
 
 
 # ------------------------------------------------------------------ #
@@ -468,325 +455,3 @@ def test_yarn_frequencies_and_query_scale_are_pinned():
     assert abs(cfg.softmax_scale - m * m / math.sqrt(128)) < 1e-9
     assert abs(REF.softmax_scale(FAMILY.sizes(published, "serve"))
                - cfg.softmax_scale) < 1e-9
-
-
-# ------------------------------------------------------------------ #
-# (h) four residual streams, a leading dense layer, sigmoid routing
-#     (model_type xing4_0)
-# ------------------------------------------------------------------ #
-HC_REF = loader.load_module(REPO, "references", "mhc_mla_moe_decoder")
-HC_FAMILY = loader.load_module(REPO, "families", "mhc_mla_moe_decoder")
-# one dense and two expert layers; phi at 0.05 gives xh phi a deviation of
-# 0.05 sqrt(256) = 0.8: the coefficients differ from token to token
-HC_SZ = {
-    "hidden_size": 64, "intermediate_size": 96, "moe_intermediate_size": 32,
-    "num_attention_heads": 4, "q_lora_rank": 24, "kv_lora_rank": 16,
-    "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 12,
-    "num_hidden_layers": 3, "first_k_dense_replace": 1, "vocab_size": 128,
-    "rms_norm_eps": 1e-6, "rope_theta": 10000, "moe_layer_freq": 1,
-    "rope_scaling": {"beta_fast": 4, "beta_slow": 0.25, "factor": 8,
-                     "mscale": 1, "mscale_all_dim": 1,
-                     "original_max_position_embeddings": ORIGINAL,
-                     "type": "yarn"},
-    "n_routed_experts": 16, "router_outputs": 16, "experts_held_from": 0,
-    "num_experts_per_tok": 4, "n_shared_experts": 1, "n_group": 1,
-    "topk_group": 1, "norm_topk_prob": True, "routed_scaling_factor": 2,
-    "scoring_func": "sigmoid", "hc_mult": 4, "hc_sinkhorn_iters": 20,
-    "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30,
-    "initializer_range": 0.2, "router_bias_std": 0.01, "hc_phi_std": 0.05,
-    "hc_alpha": 1.0, "compute_dtype": "float32", "param_dtype": "float32",
-}
-
-
-def _hc_params(seed=0):
-    return jax.jit(lambda k: HC_FAMILY.make_params(
-        HC_SZ, k, jnp.float32)[0])(jax.random.PRNGKey(seed))
-
-
-_hc_reference = jax.jit(lambda p, t: HC_REF.logits(p, t, HC_SZ))
-
-
-def _chunks_then_steps(cfg, params, tokens, chunk, prefill):
-    """Logits ``[len(tokens), vocab]`` of ``tokens`` through the cache:
-    ``prefill`` of them in chunks of ``chunk``, the rest one token a
-    call (two programs, as the engine has)."""
-    cfg = cfg.serving_layout(72, chunk=chunk)
-    cache = cfg.init_cache(1, 72)
-    call = jax.jit(lambda p, c, t: cfg.apply_cached(p, c, t,
-                                                    all_logits=True))
-    out, at = [], 0
-    while at < tokens.size:
-        width = chunk if at < prefill else 1
-        logits, cache = call(params, cache,
-                             jnp.asarray(tokens[None, at:at + width]))
-        out.append(np.asarray(logits[0]))
-        at += width
-    return np.concatenate(out)
-
-
-def _hc_gap(cfg, params, tokens):
-    """The widest gap between the logits through the cache (chunks of 6
-    up to position 36, then steps) and the reference's full forward
-    pass, in deviations of the reference's logits."""
-    got = _chunks_then_steps(cfg, params, tokens, 6, 36)
-    want = np.asarray(_hc_reference(params, jnp.asarray(tokens)))
-    assert got.shape == want.shape == (tokens.size, HC_SZ["vocab_size"])
-    return np.abs(got - want).max() / want.std()
-
-
-def test_four_streams_through_the_cache_match_the_reference(monkeypatch):
-    """Prefill in chunks, then decode through the cache, against the
-    reference's full forward pass: logits, not tokens.  The same
-    comparison FAILS with the mixing left out (H_res = I, H_pre = 1/n,
-    H_post = 1) and with 2 Sinkhorn turns in place of 20."""
-    params = _hc_params()
-    tokens = np.random.default_rng(8).integers(0, HC_SZ["vocab_size"], 52)
-    cfg = HC_FAMILY.model_config(HC_SZ, key_block=8)
-    assert (cfg.hc_mult, cfg.n_dense_layers, cfg.score_func) \
-        == (4, 1, "sigmoid")
-    assert _hc_gap(cfg, params, tokens) < TOL
-    # two turns of Sinkhorn in the program, twenty in the reference
-    two = dataclasses.replace(cfg, hc_sinkhorn_iters=2)
-    assert _hc_gap(two, params, tokens) > 50 * TOL
-    # no mixing at all: a plain average in, the identity back
-    hc = mla_moe.hc
-
-    def unmixed(x, p, *, n, **kw):
-        y_in = sum(hc._stream(x, i, n) for i in range(n)) / n
-        eye = jnp.broadcast_to(jnp.eye(n).reshape(-1),
-                               x.shape[:-1] + (n * n,))
-        return y_in, jnp.ones(x.shape[:-1] + (n,)), eye
-
-    monkeypatch.setattr(hc, "hc_pre", unmixed)
-    assert _hc_gap(cfg, params, tokens) > 50 * TOL
-
-
-def test_every_part_of_the_four_stream_model_is_seen_by_the_tolerance():
-    """Spoil one thing in the REFERENCE's sizes or weights and the
-    program no longer agrees: the dense layer, the sigmoid, the bias
-    that selects, the route scale, the clamp."""
-    params = _hc_params(1)
-    tokens = np.random.default_rng(9).integers(0, HC_SZ["vocab_size"], 40)
-    model = mla_moe.MlaMoe(HC_FAMILY.model_config(HC_SZ))
-    program = jax.jit(lambda p, t: model.apply({"params": p}, t)[0])
-    got = np.asarray(program(params, tokens[None]))
-    want = np.asarray(_hc_reference(params, jnp.asarray(tokens)))
-    assert np.abs(got - want).max() < TOL * want.std()
-    biased = jax.tree.map(lambda x: x, params)
-    for i in (1, 2):
-        moe = biased[f"layer_{i}"]["moe"]
-        moe["router_bias"] = 30.0 * moe["router_bias"]
-    hot = jax.tree.map(lambda x: x, params)
-    for i in range(3):
-        for name in ("attention_hc", "ffn_hc"):
-            mix = hot[f"layer_{i}"][name]
-            mix["alpha"] = mix["alpha"].at[2].set(40.0)
-    spoiled = {
-        "route scale": (dict(HC_SZ, routed_scaling_factor=1), params),
-        "bias": (HC_SZ, biased),
-        "clamp": (dict(HC_SZ, mhc_h_res_clamp_max=3), hot),
-        "eps": (dict(HC_SZ, hc_eps=1e-2), params),
-    }
-    for name, (sz, p) in spoiled.items():
-        other = np.asarray(jax.jit(lambda p, t: HC_REF.logits(p, t, sz))(
-            p, jnp.asarray(tokens)))
-        assert np.abs(got - other).max() > 50 * TOL * other.std(), name
-    # and a program told the same (the clamp, the bias) agrees again
-    for p in (hot, biased):
-        again = np.asarray(program(p, tokens[None]))
-        want = np.asarray(_hc_reference(p, jnp.asarray(tokens)))
-        assert np.abs(again - want).max() < TOL * want.std()
-
-
-def test_one_stream_and_no_dense_layer_is_the_jaxpr_of_before():
-    """``hc_mult=1, n_dense_layers=0`` (the defaults) trace to the
-    program the module built before it knew streams: the parent's
-    ``Block`` and ``MlaMoe``, transcribed here, give the same jaxpr for
-    the training layout, a chunk and a single-token step."""
-    import flax.linen as nn
-
-    from bluefog_tpu.models.llama import RMSNorm
-
-    class Block(nn.Module):
-        cfg: mla_moe.MlaMoeConfig
-
-        @nn.compact
-        def __call__(self, x, live=None):
-            cfg = self.cfg
-            norm = lambda name: RMSNorm(cfg.norm_eps, name=name)
-            x = x + mla_moe.LatentAttention(cfg, name="attention")(
-                norm("attention_norm")(x))
-            return x + experts.ExpertLayer(cfg, name="moe")(
-                norm("ffn_norm")(x), live)
-
-    class Before(nn.Module):
-        cfg: mla_moe.MlaMoeConfig
-
-        @nn.compact
-        def __call__(self, tokens, all_logits=False, live=None):
-            cfg = self.cfg
-            x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
-                         param_dtype=jnp.float32, name="tok_embeddings",
-                         embedding_init=nn.initializers.normal(
-                             cfg.initializer_range))(tokens)
-            for i in range(cfg.n_layers):
-                x = Block(cfg, name=f"layer_{i}")(x, live)
-            x = RMSNorm(cfg.norm_eps, name="norm")(x)
-            if cfg.decode and not all_logits:
-                x = x[:, -1:]
-            w_out = self.param("output", nn.initializers.normal(
-                cfg.initializer_range), (cfg.dim, cfg.vocab_size),
-                jnp.float32)
-            return jnp.einsum("btd,dv->btv", x, w_out.astype(cfg.dtype),
-                              preferred_element_type=jnp.float32)
-
-    params = _params()
-    cfg = FAMILY.model_config(SZ, dtype=jnp.bfloat16, key_block=8)
-    assert (cfg.hc_mult, cfg.n_dense_layers, cfg.score_func) \
-        == (1, 0, "softmax")
-    tokens = jnp.zeros((1, 16), jnp.int32)
-    text = lambda model: str(jax.make_jaxpr(
-        lambda p, t: model.apply({"params": p}, t))(params, tokens))
-    assert text(mla_moe.MlaMoe(cfg)) == text(Before(cfg))
-    served = cfg.serving_layout(72)
-    cache = served.init_cache(1, 72)
-    for width in (8, 1):
-        step = lambda model: str(jax.make_jaxpr(
-            lambda p, c, t: model.apply(
-                {"params": p, "cache": c}, t, mutable=["cache"]))(
-                    params, cache, tokens[:, :width]))
-        assert step(mla_moe.MlaMoe(served)) == step(Before(served))
-
-
-def test_score_func_is_a_field_of_the_config():
-    cfg = mla_moe.MlaMoeConfig(score_func="sigmoid")
-    assert cfg.score_func == "sigmoid"
-    assert "score_func" in {f.name for f in dataclasses.fields(cfg)}
-    assert mla_moe.MlaMoeConfig().score_func == "softmax"
-    with pytest.raises(ValueError):
-        mla_moe.MlaMoeConfig(hc_mult=0)
-    with pytest.raises(ValueError):
-        mla_moe.MlaMoeConfig(n_layers=2, n_dense_layers=3)
-    # the sigmoid path of the shared expert layer is reached: a bias
-    layer = experts.ExpertLayer(cfg)
-    shapes = jax.eval_shape(lambda: layer.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 3, cfg.dim), cfg.dtype)))
-    assert shapes["params"]["router_bias"].shape == (cfg.n_experts,)
-
-
-def test_the_engine_serves_four_streams_with_a_slot_freed_and_reused():
-    """Four requests through two slots; the served tokens are the
-    reference's greedy ones; the pool holds latent leaves and no stream;
-    the two counters read what the host can count by hand."""
-    from bluefog_tpu.observe.registry import MetricsRegistry
-    from bluefog_tpu.serving import protocol
-
-    params = _hc_params(2)
-    rng = np.random.default_rng(10)
-    lengths, budgets = (27, 9, 33, 5), (6, 9, 4, 12)
-    reg = MetricsRegistry()
-    eng = ServingEngine({"params": params},
-                        HC_FAMILY.model_config(HC_SZ, key_block=8),
-                        capacity=2, max_len=72, prefill_chunk=4,
-                        registry=reg)
-    reqs = [eng.submit(Request(rng.integers(0, 128, n), b))
-            for n, b in zip(lengths, budgets)]
-    eng.run()
-    assert all(r.state == "completed" for r in reqs)
-    for r in reqs:
-        # causal: zeros behind the sequence change no row before them,
-        # and one length is one compile of the reference
-        seq = np.zeros((48,), np.int32)
-        seq[:r.output().size - 1] = r.output()[:-1]
-        want = np.asarray(_hc_reference(params, jnp.asarray(seq)))[
-            :r.output().size - 1]
-        p, g = r.prompt.size, len(r.tokens)
-        rows = want[p - 1:p - 1 + g]
-        gap = rows.max(-1) - rows[np.arange(g), np.asarray(r.tokens)]
-        assert gap.max() < TOL * want.std(), (p, g, gap.max())
-    # nothing in the pool knows a stream: the other latent model's leaves
-    cfg = eng.cfg
-    assert cfg.cache_kinds() == {"full": (3, None)}
-    assert cfg.latent_width == 24
-    assert cfg.streamed_positions([3, -1]) == (("full", 3 * 2 * 72),)
-    assert cfg.rebuilt_positions(8, 4) == 3 * 16
-    for path, leaf in jax.tree_util.tree_flatten_with_path(
-            eng.pool.cache)[0]:
-        if protocol.leaf_kind(path) == protocol.FULL:
-            assert leaf.shape == (2, 1, 72, 24)
-    # every prompt token but the last is prefilled, every served token
-    # but a request's first comes from a decode step; six sublayers
-    mixed = sum(n - 1 for n in lengths) + sum(budgets)
-    assert (cfg.mixed_sublayers, cfg.residual_streams) == (6, 4)
-    assert reg.counter("bf_hc_mixed_tokens_total", "").value == 6 * mixed
-    assert reg.gauge("bf_hc_streams", "").value == 4
-    # a plain residual counts neither
-    plain = MetricsRegistry()
-    eng = ServingEngine({"params": _params()}, FAMILY.model_config(SZ),
-                        capacity=1, max_len=72, prefill_chunk=4,
-                        registry=plain)
-    eng.submit(Request(rng.integers(0, 128, 6), 2))
-    eng.run()
-    assert not any(name.startswith("bf_hc_")
-                   for name, *_ in plain.collect())
-
-
-# ------------------------------------------------------------------ #
-# the decode step through the kernel that reads the live blocks (PR 33)
-# ------------------------------------------------------------------ #
-@pytest.mark.parametrize("streams", [1, 4])
-def test_the_kernel_engine_serves_what_the_einsum_engine_serves(
-        streams, monkeypatch):
-    """Four requests through two slots (a slot freed and reused, one
-    still prefilling while the other decodes), eight blocks of nine rows
-    a slot: the same tokens from both lowerings of the single-token
-    step, and the counter of streamed positions reads every reserved
-    row under the einsums and the plan's blocks under the kernel."""
-    from bluefog_tpu.observe.registry import MetricsRegistry
-    from bluefog_tpu.parallel import pallas_decode
-
-    monkeypatch.setattr(pallas_decode, "_LATENT_BLOCKS", (9, 9))
-    # two Sinkhorn turns: both engines compile here, and twenty turns
-    # around six sublayers are two minutes a program
-    family, sz, params, few = (FAMILY, SZ, _params(), {}) if streams == 1 \
-        else (HC_FAMILY, HC_SZ, _hc_params(2), {"hc_sinkhorn_iters": 2})
-    lengths, budgets = (27, 9, 33, 5), (6, 9, 4, 12)
-    asked = []
-
-    def recorded(positions, s_len, **kw):
-        asked.append(streamed_positions(positions, s_len, **kw))
-        return asked[-1]
-
-    streamed_positions = pallas_decode.streamed_positions
-    monkeypatch.setattr(pallas_decode, "streamed_positions", recorded)
-
-    def serve(decode_attn):
-        del asked[:]
-        rng = np.random.default_rng(10)
-        reg = MetricsRegistry()
-        eng = ServingEngine({"params": params},
-                            family.model_config(sz, key_block=8, **few),
-                            capacity=2, max_len=72, prefill_chunk=4,
-                            decode_attn=decode_attn, registry=reg)
-        assert eng.cfg.decode_attn == decode_attn
-        assert eng.cfg.residual_streams == streams
-        reqs = [eng.submit(Request(rng.integers(0, 128, n), b))
-                for n, b in zip(lengths, budgets)]
-        eng.run()
-        assert all(r.state == "completed" for r in reqs)
-        value = lambda name, **labels: reg.counter(name, "", **labels).value
-        return ([list(r.tokens) for r in reqs],
-                value("bf_serving_decode_steps_total"),
-                value("bf_serving_streamed_positions_total", kind="full"),
-                list(asked))
-
-    want, steps, every, _ = serve("xla")
-    got, steps_k, live, counts = serve("pallas")
-    assert got == want and steps_k == steps == len(counts)
-    layers = sz["num_hidden_layers"]
-    assert every == steps * layers * 2 * 72
-    assert live == layers * sum(counts)
-    # a slot's rows are fetched in blocks of 9 up to its position, never
-    # the 72 reserved: under half of what the einsums read
-    assert all(n % 9 == 0 and 9 <= n <= 2 * 72 for n in counts)
-    assert live < every / 2

@@ -5,21 +5,20 @@ size on the CPU: logits through chunks and single steps, each part of
 the mathematics left out of the reference, the four shares of an expert
 layer under the group limit, ``route`` without groups, the new config
 fields, and the engine with slots freed and reused.  A file of its own:
-``tests/test_mla_moe.py`` is the longest of the run, and a worker takes a
-file whole."""
+a worker takes a file whole."""
 
-import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import served_model
 from bluefog_tpu.models import experts, mla_moe
-from bluefog_tpu.serving import Request, ServingEngine
 from perfbench.harness import loader
-from test_mla_moe import (FAMILY, HC_FAMILY, HC_SZ, REPO, SZ, TOL,
-                          _chunks_then_steps)
+from test_mla_moe import FAMILY, REPO, SZ, TOL, _forward, _shares
+from test_mla_moe_mhc import HC_FAMILY, HC_SZ
 
 pytestmark = pytest.mark.serving
 
@@ -53,22 +52,23 @@ KDA_SZ = {
 
 
 def _kda_params(sz=KDA_SZ, seed=0):
-    return jax.jit(lambda k: KDA_FAMILY.make_params(
-        sz, k, jnp.float32)[0])(jax.random.PRNGKey(seed))
+    return served_model.params(KDA_FAMILY, sz, seed)
 
 
-_kda_reference = jax.jit(lambda p, t: KDA_REF.logits(p, t, KDA_SZ))
+def _kda_reference(params, tokens):
+    return served_model.reference(KDA_REF, KDA_SZ, params, tokens)
 
 
-def _kda_gap(cfg, params, tokens, reference=_kda_reference):
-    """The widest gap between the logits through the cache (chunks of 6
-    up to position 36: blocks of the recurrence cut short and a chunk
-    boundary inside the convolution's reach; then single steps) and the
-    reference's full forward pass, in deviations of its logits."""
-    got = _chunks_then_steps(cfg, params, tokens, 6, 36)
-    want = np.asarray(reference(params, jnp.asarray(tokens)))
-    assert got.shape == want.shape == (tokens.size, KDA_SZ["vocab_size"])
-    return np.abs(got - want).max() / want.std()
+@functools.cache
+def _through_the_cache(seed, n):
+    """``n`` seeded tokens and the program's logits of them through the
+    cache (chunks of 6 up to position 36: blocks of the recurrence cut
+    short and a chunk boundary inside the convolution's reach; then
+    single steps), made once for every reference they are held to."""
+    tokens = np.random.default_rng(seed).integers(0, KDA_SZ["vocab_size"], n)
+    cfg = KDA_FAMILY.model_config(KDA_SZ, key_block=8)
+    return tokens, served_model.chunks_then_steps(cfg, _kda_params(), tokens,
+                                                  6, 36)
 
 
 def test_two_layer_kinds_through_the_cache_match_the_reference():
@@ -78,17 +78,18 @@ def test_two_layer_kinds_through_the_cache_match_the_reference():
     the order of the sums (the chunked form's triangular solve against
     the token loop, absorbed against expanded)."""
     params = _kda_params()
-    tokens = np.random.default_rng(8).integers(0, KDA_SZ["vocab_size"], 52)
+    tokens, got = _through_the_cache(8, 52)
     cfg = KDA_FAMILY.model_config(KDA_SZ, key_block=8)
     assert cfg.layer_types == ("kda", "kda", "latent", "kda", "kda")
     assert (cfg.state_layers, cfg.latent_layers, cfg.n_dense_layers,
             cfg.n_group, cfg.topk_group, cfg.head_gate, cfg.q_lora_rank) \
         == (4, 1, 1, 4, 2, True, None)
-    assert _kda_gap(cfg, params, tokens) < TOL
+    want = _kda_reference(params, tokens)
+    assert served_model.gap(got, want) < TOL
     # the training layout: one call of all 52 tokens, four blocks of 16
-    got = mla_moe.MlaMoe(cfg).apply({"params": params}, tokens[None])[0]
-    want = np.asarray(_kda_reference(params, jnp.asarray(tokens)))
-    assert np.abs(np.asarray(got) - want).max() < TOL * want.std()
+    assert served_model.gap(_forward(cfg, params, tokens), want) < TOL
+    assert served_model.padding_moves(KDA_REF, KDA_SZ, params, tokens) \
+        < 0.25 * TOL
     # a block of the chunked form stays inside float32 at this bound
     from bluefog_tpu.models import kda
 
@@ -105,8 +106,7 @@ def test_every_part_of_the_two_kind_model_is_seen_by_the_tolerance(
     the convolution, the latent layer's gate a head, the choice inside
     groups, and both norms of the recurrent mixer."""
     params = _kda_params()
-    tokens = np.random.default_rng(9).integers(0, KDA_SZ["vocab_size"], 40)
-    cfg = KDA_FAMILY.model_config(KDA_SZ, key_block=8)
+    tokens, got = _through_the_cache(9, 40)
     sz, p = KDA_SZ, params
     if part == "delta term":
         # S_t = Diag(alpha) S + beta k v^T: no (I - beta k k^T)
@@ -141,34 +141,21 @@ def test_every_part_of_the_two_kind_model_is_seen_by_the_tolerance(
         # a gate of one half everywhere; a norm's scale of two
         node[key] = jnp.zeros_like(node[key]) if key == "kernel" \
             else 2.0 * node[key]
-    spoiled = lambda pp, t: KDA_REF.logits(p, t, sz)
-    assert _kda_gap(cfg, params, tokens, spoiled) > 50 * TOL, part
+    # traced here, under the patch (``served_model.reference`` keeps its
+    # trace)
+    spoiled = jax.jit(lambda p, t: KDA_REF.logits(p, t, sz))
+    assert served_model.gap(got, np.asarray(spoiled(p, jnp.asarray(tokens)))) \
+        > 50 * TOL, part
 
 
 def test_the_four_shares_add_up_under_the_group_limit():
     """With the choice inside groups: the routed parts of the shares
     (0, 8), (8, 8), (16, 8), (24, 8), a group each, plus the shared
     expert once, are the uncut layer of the reference."""
-    params = _kda_params()
-    moe = params["layer_1"]["moe"]
-    m = jax.random.normal(jax.random.PRNGKey(5), (1, 24, 64), jnp.float32)
-    want = KDA_REF.swiglu(m[0], moe["shared"], KDA_REF.mm_highest) \
-        + KDA_REF.routed_part(m[0], moe, KDA_SZ, KDA_REF.mm_highest)
-    cfg = KDA_FAMILY.model_config(KDA_SZ)
-    shared = experts.SwiGLU(cfg, KDA_SZ["moe_intermediate_size"]).apply(
-        {"params": moe["shared"]}, m)[0]
-    total = np.asarray(shared, np.float64)
-    parts = []
-    for first in range(0, 32, 8):
-        share = dict(moe, **{k: moe[k][first:first + 8]
-                             for k in ("w1", "w3", "w2")})
-        layer = experts.ExpertLayer(dataclasses.replace(
-            cfg, experts_held=(first, 8)))
-        parts.append(np.asarray(layer.apply({"params": share}, m)[0]
-                                - shared, np.float64))
-        total += parts[-1]
-    assert np.abs(total - np.asarray(want)).max() \
-        < TOL * np.asarray(want).std()
+    moe = _kda_params()["layer_1"]["moe"]
+    m, want, shared, parts = _shares(KDA_REF, KDA_FAMILY, KDA_SZ, moe, 8)
+    assert len(parts) == 4
+    assert np.abs(shared + sum(parts) - want).max() < TOL * want.std()
     # a token's experts lie in 2 of the 4 groups: every token leaves at
     # least two shares' routed parts at exactly nothing
     idle = sum((np.abs(part).max(-1) == 0).astype(int) for part in parts)
@@ -246,22 +233,12 @@ def test_the_engine_serves_two_layer_kinds_with_slots_freed_and_reused():
     params = _kda_params(seed=2)
     rng = np.random.default_rng(10)
     lengths, budgets = (27, 9, 33, 5, 1), (6, 9, 4, 12, 5)
-    eng = ServingEngine({"params": params},
-                        KDA_FAMILY.model_config(KDA_SZ, key_block=8),
-                        capacity=2, max_len=72, prefill_chunk=4)
-    reqs = [eng.submit(Request(rng.integers(0, 128, n), b))
-            for n, b in zip(lengths, budgets)]
-    eng.run()
-    assert all(r.state == "completed" for r in reqs)
+    eng, reqs = served_model.serve(
+        KDA_FAMILY.model_config(KDA_SZ, key_block=8), params,
+        [rng.integers(0, 128, n) for n in lengths], budgets)
     for r in reqs:
-        seq = np.zeros((48,), np.int32)
-        seq[:r.output().size - 1] = r.output()[:-1]
-        want = np.asarray(_kda_reference(params, jnp.asarray(seq)))[
-            :r.output().size - 1]
-        p, g = r.prompt.size, len(r.tokens)
-        rows = want[p - 1:p - 1 + g]
-        gap = rows.max(-1) - rows[np.arange(g), np.asarray(r.tokens)]
-        assert gap.max() < TOL * want.std(), (p, g, gap.max())
+        served_model.assert_served_is_the_references_greedy(
+            KDA_REF, KDA_SZ, params, r, TOL)
     kinds = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(
             eng.pool.cache)[0]:
@@ -294,14 +271,10 @@ def test_the_engine_serves_the_kernels_tokens_and_counts_what_it_reads():
     served, read = {}, {}
     for attn in ("xla", "pallas"):
         reg = MetricsRegistry()
-        eng = ServingEngine({"params": params},
-                            KDA_FAMILY.model_config(sz, key_block=8),
-                            capacity=3, max_len=24, prefill_chunk=4,
-                            decode_attn=attn, registry=reg)
+        eng, reqs = served_model.serve(
+            KDA_FAMILY.model_config(sz, key_block=8), params, prompts,
+            budgets, capacity=3, max_len=24, decode_attn=attn, registry=reg)
         assert eng.cfg.decode_attn == attn and eng.cfg.state_layers == 2
-        reqs = [eng.submit(Request(p, b)) for p, b in zip(prompts, budgets)]
-        eng.run()
-        assert all(r.state == "completed" for r in reqs)
         served[attn] = [list(r.tokens) for r in reqs]
         count = lambda name: reg.counter(name, "").value
         assert count("bf_serving_state_steps_total") == 2 * sum(budgets)

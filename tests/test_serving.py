@@ -9,6 +9,7 @@ reuse, EOS retirement, deadline cancellation, pool-full backpressure,
 metrics, and timeline spans.
 """
 
+import functools
 import json
 
 import numpy as np
@@ -17,46 +18,20 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from bluefog_tpu import models
 from bluefog_tpu.models import llama_generate
 from bluefog_tpu.serving import (FifoScheduler, Request, RequestRejected,
                                  ServingEngine, SlotPool)
+from served_model import (VirtualClock, one_shot as _one_shot,
+                          tiny_engine as _engine, tiny_llama as _setup)
 
 pytestmark = pytest.mark.serving
 
 MAX_LEN = 48
 
 
-class VirtualClock:
-    """Deterministic engine clock: tests advance time explicitly, so
-    deadline behavior and latency percentiles are reproducible."""
-
-    def __init__(self):
-        self.t = 0.0
-
-    def __call__(self):
-        return self.t
-
-    def advance(self, dt):
-        self.t += dt
-
-
-def _setup(**cfg_overrides):
-    cfg = models.LlamaConfig.tiny(dtype=jnp.float32, **cfg_overrides)
-    variables = models.Llama(cfg).init(jax.random.PRNGKey(1),
-                                       jnp.zeros((2, 4), jnp.int32))
-    return cfg, variables
-
-
-def _one_shot(variables, cfg, prompt, n, **kw):
-    out = llama_generate(variables, cfg, jnp.asarray(prompt[None]), n,
-                         max_len=MAX_LEN, **kw)
-    return np.asarray(out)[0]
-
-
-def _prompts(sizes, seed=0):
+def _prompts(sizes, seed=0, vocab=256):
     rs = np.random.RandomState(seed)
-    return [rs.randint(0, 256, (n,)).astype(np.int32) for n in sizes]
+    return [rs.randint(0, vocab, (n,)).astype(np.int32) for n in sizes]
 
 
 def test_staggered_arrivals_match_one_shot():
@@ -66,8 +41,7 @@ def test_staggered_arrivals_match_one_shot():
     cfg, variables = _setup()
     prompts = _prompts((5, 9, 3, 1))
     budgets = [6, 4, 8, 5]
-    eng = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
-                        prefill_chunk=4)
+    eng = _engine(variables, cfg)
     reqs = [Request(p, b) for p, b in zip(prompts, budgets)]
     eng.submit(reqs[0])
     eng.step()
@@ -88,8 +62,7 @@ def test_scan_layers_layout_served():
     carries a [n_layers] cache axis — slots stack outside it)."""
     cfg, variables = _setup(scan_layers=True)
     prompts = _prompts((4, 6))
-    eng = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
-                        prefill_chunk=3)
+    eng = _engine(variables, cfg, prefill_chunk=3)
     reqs = [eng.submit(Request(p, 5)) for p in prompts]
     eng.run()
     for r, p in zip(reqs, prompts):
@@ -103,8 +76,7 @@ def test_slot_reuse_is_invisible():
     trace)."""
     cfg, variables = _setup()
     prompts = _prompts((7, 5), seed=3)
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=4)
+    eng = _engine(variables, cfg, capacity=1)
     r0 = eng.submit(Request(prompts[0], 6))
     eng.step()  # r0 admitted into slot 0, mid-flight
     r1 = eng.submit(Request(prompts[1], 6))
@@ -125,8 +97,7 @@ def test_eos_retires_slot_and_truncates():
     full = _one_shot(variables, cfg, prompt, 10)
     eos = int(full[prompt.size + 3])  # forces a stop after 4 tokens
     assert eos not in full[prompt.size:prompt.size + 3]
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=4)
+    eng = _engine(variables, cfg, capacity=1)
     r0 = eng.submit(Request(prompt, 10, eos_id=eos))
     r1 = eng.submit(Request(prompt, 2))  # waits for r0's slot
     eng.run()
@@ -147,8 +118,7 @@ def test_decode_horizon_invariant():
     eos = int(full[prompts[0].size + 2])
 
     def serve(horizon):
-        eng = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
-                            prefill_chunk=4, decode_horizon=horizon)
+        eng = _engine(variables, cfg, decode_horizon=horizon)
         reqs = [Request(prompts[0], 10, eos_id=eos)] + \
             [Request(p, b) for p, b in zip(prompts[1:], budgets[1:])]
         eng.submit(reqs[0])
@@ -352,8 +322,7 @@ def test_temperature_sampling_deterministic_and_in_range():
     prompts = _prompts((5, 6), seed=7)
 
     def serve(reqs, capacity):
-        eng = ServingEngine(variables, cfg, capacity=capacity,
-                            max_len=MAX_LEN, prefill_chunk=4)
+        eng = _engine(variables, cfg, capacity=capacity)
         for r in reqs:
             eng.submit(r)
         eng.run()
@@ -382,8 +351,7 @@ def test_deadline_cancels_running_and_queued():
     cfg, variables = _setup()
     clock = VirtualClock()
     prompts = _prompts((4, 4), seed=2)
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=4, clock=clock)
+    eng = _engine(variables, cfg, capacity=1, clock=clock)
     # r0 runs but can never finish 20 tokens by t=2.0 (1s per step;
     # its first decode program is dispatched at t=0 and read at t=1)
     r0 = eng.submit(Request(prompts[0], 20, deadline=2.0))
@@ -414,8 +382,7 @@ def test_deadline_cancels_mid_prefill():
     long_prompt, short_prompt = _prompts((17, 4), seed=5)
     # chunk=2 -> prompt[:-1] needs 8 chunks at 1 chunk/step: the
     # deadline at t=2.5 lands mid-prefill (1 s per step)
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=2, clock=clock)
+    eng = _engine(variables, cfg, capacity=1, prefill_chunk=2, clock=clock)
     r0 = eng.submit(Request(long_prompt, 8, deadline=2.5))
     r1 = eng.submit(Request(short_prompt, 3))
     saw_prefill = False
@@ -439,8 +406,7 @@ def test_deadline_cancels_mid_prefill():
 def test_explicit_cancellation():
     cfg, variables = _setup()
     prompts = _prompts((4, 4), seed=4)
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=8)
+    eng = _engine(variables, cfg, capacity=1, prefill_chunk=8)
     r0 = eng.submit(Request(prompts[0], 20))
     r1 = eng.submit(Request(prompts[1], 3))
     eng.step()
@@ -456,8 +422,7 @@ def test_pool_full_rejects_with_queue_depth():
     immediate RequestRejected carrying the queue depth."""
     cfg, variables = _setup()
     (prompt,) = _prompts((4,))
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=8, max_queue=2)
+    eng = _engine(variables, cfg, capacity=1, prefill_chunk=8, max_queue=2)
     eng.submit(Request(prompt, 4))
     eng.step()  # occupy the slot
     eng.submit(Request(prompt, 4))
@@ -474,8 +439,7 @@ def test_pool_full_rejects_with_queue_depth():
 def test_submit_validates_slot_capacity():
     cfg, variables = _setup()
     (prompt,) = _prompts((40,))
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=8)
+    eng = _engine(variables, cfg, capacity=1, prefill_chunk=8)
     big = Request(prompt, MAX_LEN)
     with pytest.raises(ValueError, match="cache positions"):
         eng.submit(big)
@@ -491,8 +455,7 @@ def test_submit_validates_slot_capacity():
     # (an overrunning dynamic_update_slice start would CLAMP, silently
     # corrupting near-max_len prompts)
     with pytest.raises(ValueError, match="divide max_len"):
-        ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                      prefill_chunk=32)
+        _engine(variables, cfg, capacity=1, prefill_chunk=32)
 
 
 def test_prompt_filling_the_slot_is_exact():
@@ -501,8 +464,7 @@ def test_prompt_filling_the_slot_is_exact():
     token-exact — no chunk window crosses max_len."""
     cfg, variables = _setup()
     (prompt,) = _prompts((MAX_LEN - 6,), seed=12)  # 42 tokens, 6 budget
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=8)
+    eng = _engine(variables, cfg, capacity=1, prefill_chunk=8)
     r = eng.submit(Request(prompt, 6))
     eng.run()
     np.testing.assert_array_equal(
@@ -517,9 +479,7 @@ def test_quantized_interop_matches_one_shot():
     cfg, variables = _setup()
     qvars = quantize_llama_params(variables)
     prompts = _prompts((5, 7), seed=6)
-    eng = ServingEngine(qvars, cfg, capacity=2, max_len=MAX_LEN,
-                        prefill_chunk=4, kv_quant="int8",
-                        weight_quant="int8")
+    eng = _engine(qvars, cfg, kv_quant="int8", weight_quant="int8")
     reqs = [eng.submit(Request(p, 5)) for p in prompts]
     eng.run()
     for r, p in zip(reqs, prompts):
@@ -573,8 +533,7 @@ def test_metrics_and_timeline_spans(tmp_path):
     path = str(tmp_path / "serve_tl")
     timeline.start_timeline(path)
     try:
-        eng = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
-                            prefill_chunk=4, clock=clock)
+        eng = _engine(variables, cfg, clock=clock)
         reqs = [eng.submit(Request(p, 4))
                 for p in _prompts((5, 6), seed=8)]
         while eng.step():
@@ -634,8 +593,7 @@ def test_every_engine_step_holds_its_phases_in_order(budget):
     from bluefog_tpu import observe
 
     cfg, variables = _setup()
-    eng = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
-                        prefill_chunk=4, prefill_budget=budget)
+    eng = _engine(variables, cfg, prefill_budget=budget)
     tracer = observe.get_tracer()
     tracer.clear()
     reqs = [Request(p, b) for p, b in
@@ -714,8 +672,7 @@ def test_engine_counters_equal_what_the_requests_imply():
     cfg, variables = _setup()
     clock = VirtualClock()
     reg = MetricsRegistry()
-    eng = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
-                        prefill_chunk=4, clock=clock, registry=reg)
+    eng = _engine(variables, cfg, clock=clock, registry=reg)
     sizes, budgets = (11, 2, 7, 1, 6), (4, 6, 2, 3, 5)
     reqs = [eng.submit(Request(p, b))
             for p, b in zip(_prompts(sizes, seed=13), budgets)]
@@ -805,8 +762,7 @@ def test_no_recompiles_across_arrival_patterns():
                                             _prefill_chunk_prog)
 
     cfg, variables = _setup()
-    eng = ServingEngine(variables, cfg, capacity=2, max_len=MAX_LEN,
-                        prefill_chunk=4)
+    eng = _engine(variables, cfg)
     reqs = [eng.submit(Request(p, 3)) for p in _prompts((5, 9), seed=9)]
     eng.run()
     pre = _prefill_chunk_prog._cache_size()
@@ -866,12 +822,14 @@ def test_serving_bench_smoke(tmp_path):
 # a model with a STATE leaf (serving/protocol.py): recurrent layers
 # beside a latent one, through the same engine
 # ------------------------------------------------------------------ #
-STATE_LEN = 48
+STATE_LEN = MAX_LEN      # the file's one engine shape (``_engine``)
 
 
+@functools.cache
 def _state_model(seed=3):
     """A tiny decoder of two recurrent layers around a latent one
-    (``models/mla_moe.py`` with ``layer_types``), float32."""
+    (``models/mla_moe.py`` with ``layer_types``), float32; its weights
+    drawn by one program, once."""
     from bluefog_tpu.models import mla_moe
 
     cfg = mla_moe.MlaMoeConfig(
@@ -882,20 +840,17 @@ def _state_model(seed=3):
         n_group=2, topk_group=1, score_func="sigmoid",
         expert_hidden_dim=16, initializer_range=0.3, dtype=jnp.float32,
         key_block=8)
-    variables = mla_moe.MlaMoe(cfg).init(jax.random.PRNGKey(seed),
-                                         jnp.zeros((1, 4), jnp.int32))
+    variables = jax.jit(mla_moe.MlaMoe(cfg).init)(
+        jax.random.PRNGKey(seed), jnp.zeros((1, 4), jnp.int32))
     return cfg, {"params": variables["params"]}
 
 
-def _state_prompts(sizes, seed=0):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(0, 64, (n,)).astype(np.int32) for n in sizes]
+_state_prompts = functools.partial(_prompts, vocab=64)
 
 
 def _alone(variables, cfg, prompt, budget, **kw):
     """The request's stream from an engine nothing else has touched."""
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=STATE_LEN,
-                        prefill_chunk=4, **kw)
+    eng = _engine(variables, cfg, capacity=1, **kw)
     req = eng.submit(Request(prompt, budget))
     eng.run()
     assert req.state == "completed"
@@ -918,8 +873,7 @@ def test_a_state_leaf_of_a_slot_freed_and_taken_again(zero_on_free):
     cfg, variables = _state_model()
     prompts = _state_prompts((9, 1, 14), seed=2)
     budgets = (5, 6, 4)
-    eng = ServingEngine(variables, cfg, capacity=1, max_len=STATE_LEN,
-                        prefill_chunk=4, zero_on_free=zero_on_free)
+    eng = _engine(variables, cfg, capacity=1, zero_on_free=zero_on_free)
     assert len(eng.pool.state_leaves) == 4       # two leaves a kda layer
     reqs = [eng.submit(Request(p, b)) for p, b in zip(prompts, budgets)]
     eng.run()
@@ -940,8 +894,7 @@ def test_a_padded_chunk_tail_leaves_a_state_leaf_alone(chunk):
     convolution."""
     cfg, variables = _state_model()
     prompts = _state_prompts((7, 17, 12), seed=4)
-    eng = ServingEngine(variables, cfg, capacity=2, max_len=STATE_LEN,
-                        prefill_chunk=chunk)
+    eng = _engine(variables, cfg, prefill_chunk=chunk)
     reqs = [eng.submit(Request(p, 6)) for p in prompts]
     eng.run()
     for r, p in zip(reqs, prompts):
@@ -955,8 +908,7 @@ def test_a_slot_that_sits_a_decode_step_out_keeps_its_state():
     both streams are those of an engine to themselves."""
     cfg, variables = _state_model()
     prompts = _state_prompts((3, 22), seed=6)
-    eng = ServingEngine(variables, cfg, capacity=2, max_len=STATE_LEN,
-                        prefill_chunk=4)
+    eng = _engine(variables, cfg)
     r0, r1 = Request(prompts[0], 16), Request(prompts[1], 5)
     mid = []
 
@@ -979,8 +931,7 @@ def test_an_overrun_step_on_a_state_leaf_is_never_observed():
     free_run = _alone(variables, cfg, prompts[0], 12)
     eos = free_run[4]
     stop = free_run.index(eos)
-    eng = ServingEngine(variables, cfg, capacity=2, max_len=STATE_LEN,
-                        prefill_chunk=4)
+    eng = _engine(variables, cfg)
     r0 = Request(prompts[0], 12, eos_id=eos)
     r1, r2 = Request(prompts[1], 14), Request(prompts[2], 7)
     _drive(eng, {0: [r0, r1], 2: [r2]})
@@ -998,21 +949,18 @@ def test_a_prefix_cache_and_the_speculative_step_refuse_a_state_leaf():
     with pytest.raises(ValueError, match=r"state_conv.*recurrent state"):
         seq_axes(cfg, STATE_LEN)
     with pytest.raises(ValueError, match="state leaf"):
-        ServingEngine(variables, cfg, capacity=1, max_len=STATE_LEN,
-                      prefill_chunk=4, prefix_cache=True)
+        _engine(variables, cfg, capacity=1, prefix_cache=True)
     with pytest.raises(ValueError, match="state leaf"):
         SlotPool(cfg, 1, STATE_LEN, prefix=PrefixCache(4, 1 << 20))
     with pytest.raises(ValueError,
                        match=r"state_conv.*does not roll back"):
-        ServingEngine(variables, cfg, capacity=1, max_len=STATE_LEN,
-                      prefill_chunk=4, speculative=SpeculativeConfig(
-                          variables=variables, cfg=cfg, lookahead=2))
+        _engine(variables, cfg, capacity=1, speculative=SpeculativeConfig(
+            variables=variables, cfg=cfg, lookahead=2))
     # a draft with a state leaf under a target without one is refused too
     dense, dense_vars = _setup(vocab_size=64)
     with pytest.raises(ValueError, match="does not roll back"):
-        ServingEngine(dense_vars, dense, capacity=1, max_len=STATE_LEN,
-                      prefill_chunk=4, speculative=SpeculativeConfig(
-                          variables=variables, cfg=cfg, lookahead=2))
+        _engine(dense_vars, dense, capacity=1, speculative=SpeculativeConfig(
+            variables=variables, cfg=cfg, lookahead=2))
 
 
 def test_the_pool_and_the_counters_report_the_state():
@@ -1020,8 +968,7 @@ def test_the_pool_and_the_counters_report_the_state():
 
     cfg, variables = _state_model()
     reg = MetricsRegistry()
-    eng = ServingEngine(variables, cfg, capacity=3, max_len=STATE_LEN,
-                        prefill_chunk=4, registry=reg)
+    eng = _engine(variables, cfg, capacity=3, registry=reg)
     # a kda layer a slot: 2 heads x 8 x 8 float32 and 3 x 48 inputs
     per_slot = 2 * (2 * 8 * 8 * 4 + 3 * 48 * 4)
     assert eng.pool.cache_bytes() == {
@@ -1050,8 +997,7 @@ def test_the_pool_and_the_counters_report_the_state():
     # a model without a state leaf sets and counts none of it
     plain = MetricsRegistry()
     dense, dense_vars = _setup()
-    eng = ServingEngine(dense_vars, dense, capacity=1, max_len=MAX_LEN,
-                        prefill_chunk=4, registry=plain)
+    eng = _engine(dense_vars, dense, capacity=1, registry=plain)
     eng.submit(Request(_prompts((6,))[0], 2))
     eng.run()
     assert not any("state" in name or "groups" in name

@@ -27,10 +27,6 @@ Contracts under test:
 import numpy as np
 import pytest
 
-import jax
-import jax.numpy as jnp
-
-from bluefog_tpu import models
 from bluefog_tpu.observe.registry import MetricsRegistry
 from bluefog_tpu.resilience import ServingFault, ServingFaultPlan
 from bluefog_tpu.resilience.faults import (REPLICA_DEATH, REPLICA_STALL,
@@ -42,28 +38,11 @@ from bluefog_tpu.serving import (FaultyReplica, FleetRouter,
                                  seeded_backoff)
 from bluefog_tpu.serving.engine import (_decode_step_prog,
                                         _prefill_chunk_prog)
+from served_model import VirtualClock as _Clock, tiny_llama as _setup
 
 pytestmark = pytest.mark.chaos_serving
 
 MAX_LEN = 48
-
-
-def _setup(**cfg_overrides):
-    cfg = models.LlamaConfig.tiny(dtype=jnp.float32, **cfg_overrides)
-    variables = models.Llama(cfg).init(jax.random.PRNGKey(1),
-                                       jnp.zeros((2, 4), jnp.int32))
-    return cfg, variables
-
-
-class _Clock:
-    def __init__(self):
-        self.t = 0.0
-
-    def __call__(self):
-        return self.t
-
-    def advance(self, dt):
-        self.t += dt
 
 
 def _engine(variables, cfg, clock, prefix=None, **kw):

@@ -7,11 +7,10 @@ that moves once a step."""
 import logging
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bluefog_tpu import models
+import served_model
 from bluefog_tpu.logging_util import get_logger
 from bluefog_tpu.observe import tracer as obs_tracer
 from bluefog_tpu.observe.registry import MetricsRegistry
@@ -27,10 +26,7 @@ DISPATCHERS = ("decode_dispatch", "prefill_chunk")
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = models.LlamaConfig.tiny(dtype=jnp.float32)
-    variables = models.Llama(cfg).init(jax.random.PRNGKey(1),
-                                       jnp.zeros((2, 4), jnp.int32))
-    return cfg, variables
+    return served_model.tiny_llama()
 
 
 def engine_of(model, **kw):
