@@ -27,6 +27,7 @@ from bluefog_tpu.models.llama import (
     vocab_parallel_xent,
 )
 from bluefog_tpu.models.generate import init_cache, llama_generate
+from bluefog_tpu.models.hybrid_ssm import HybridSsm, HybridSsmConfig
 from bluefog_tpu.models.quant import quantize_llama_params
 from bluefog_tpu.models.vit import ViT, ViTConfig, ViT_B16, ViT_S16
 
@@ -45,6 +46,8 @@ __all__ = [
     "ResNet152",
     "Llama",
     "LlamaConfig",
+    "HybridSsm",
+    "HybridSsmConfig",
     "llama_param_specs",
     "llama_pp_loss_fn",
     "chunked_xent",

@@ -4,8 +4,9 @@ imports a model class or asks a model's name.
 A model is served through its CONFIG: a frozen, hashable dataclass (it
 is the static argument of the engine's resident programs) with the
 methods of :class:`ServedModel`.  ``models.llama.LlamaConfig``,
-``models.afmoe.AfmoeConfig``, ``models.mla_moe.MlaMoeConfig`` and
-``models.looped.LoopedConfig`` implement it.
+``models.afmoe.AfmoeConfig``, ``models.mla_moe.MlaMoeConfig``,
+``models.looped.LoopedConfig`` and ``models.hybrid_ssm.HybridSsmConfig``
+implement it.
 
 The cache of one sequence is a tree of leaves per layer, and the pool
 stacks whatever it is given (``SlotPool``: ``[capacity, *leaf]``).  The
@@ -44,7 +45,11 @@ serving layer reads a leaf's KIND off its name (:func:`leaf_kind`):
   program one ahead) lands in a state nobody reads again.  Restoring
   rows does not restore a state and an index rolled back does not roll
   one back: a prefix cache and the speculative step refuse a model
-  that declares one.
+  that declares one.  A layer MAY hold ``state_*`` leaves BESIDE full
+  leaves under its one ``cache_index`` (``models/hybrid_ssm.py``: a
+  state-space mixer and attention on one normed input): each leaf keeps
+  its own kind's rule, the pool's gauges count both, and the refusals
+  above stand.
 * anything else: ``max_len`` positions along one axis ("full"),
   whatever a position holds: a key or a value of every head, or one
   latent that all heads share.

@@ -84,6 +84,10 @@ MODULES = [
     ("bluefog_tpu.models.looped",
      "a dense stack that runs several times over shared weights, a "
      "cache for every pass: one rolled program over stacked leaves"),
+    ("bluefog_tpu.models.hybrid_ssm",
+     "a Mamba-2 state-space mixer and grouped-query attention side by "
+     "side in every layer: a single-token step and a block form of the "
+     "scan, a recurrent state beside the keys and values"),
     ("bluefog_tpu.serving.protocol",
      "what the serving layer needs of a model (config methods, cache "
      "leaf kinds)"),
