@@ -69,6 +69,15 @@ def load_module(root: Path, kind: str, name: str):
     return module
 
 
+def twin_of(file: str, name: str):
+    """The ``reduce`` of ``layer_metrics/<name>.py`` beside the reader
+    ``file``: a per-layer metric has ONE ``moves``, so a quantity read in
+    cells that report different end-to-end metrics has a name for each,
+    and the second name's file is this one line."""
+    return load_module(Path(file).resolve().parents[2], "layer_metrics",
+                       name).reduce
+
+
 def load_benchmark(root: Path = ROOT) -> dict:
     """The parsed file, with every name, unit and cross-reference
     checked (the driver checks them again; a fault should show here

@@ -27,8 +27,10 @@ PR that added them), every function here returns ``None``.  Off the
 chip every reader built on this module reads nothing (``on_chip``): the
 times would be a CPU's, and a traced line of the tests' CPU runs holds
 what it held.  Span metrics are taken over the traced stretch
-(``pb.trace_window``), counters over the whole process.  Interval
-arithmetic is ``harness/trace.py``'s.
+(``pb.trace_window``).  A counter is read over the whole process
+(``counter_value``) where it is set against another counter, and over
+the traced stretch (``counter_delta``) where it is set against the
+stretch's device time.  Interval arithmetic is ``harness/trace.py``'s.
 """
 
 from __future__ import annotations
@@ -550,5 +552,93 @@ def registry_metric(name: str, **labels):
 
 
 def counter_value(name: str, **labels):
+    """The counter as it stands: its count over the whole process."""
     metric = registry_metric(name, **labels)
     return None if metric is None else float(metric.value)
+
+
+DECODE_STEPS = "bf_serving_decode_steps_total"
+
+
+def _key(name: str, labels: dict):
+    return name, tuple(sorted((k, str(v)) for k, v in labels.items()))
+
+
+def registry_values() -> dict:
+    """``{(name, ((label, value), ...)): value}`` of every counter and
+    gauge of the program's registry, as they stand."""
+    from bluefog_tpu.observe import get_registry
+
+    return {_key(name, labels): float(metric.value)
+            for name, kind, _, labels, metric in get_registry().collect()
+            if kind != "histogram"}
+
+
+class CounterWindow:
+    """The program's counters at the two edges of the traced stretch:
+    ``runners/serve.py:trace_hooks`` calls ``open`` when the profiler
+    has started and ``close`` just before it stops, and a run hands the
+    object to the readers as ``ctx["counter_window"]``.
+
+    The engine dispatches one decode program ahead of the one it blocks
+    on, so the host's counters lead the device by one step at either
+    edge: over the 250-450 steps of a stretch that is under half a
+    percent, and it is left."""
+
+    def __init__(self, opened=None, closed=None):
+        self.opened, self.closed = opened, closed
+
+    def open(self) -> None:
+        self.opened = registry_values()
+
+    def close(self) -> None:
+        self.closed = registry_values()
+
+    def delta(self, name: str, **labels):
+        """What ``name{labels}`` grew by over the stretch; None where no
+        stretch was traced or nothing had published the counter by its
+        end."""
+        key = _key(name, labels)
+        if self.opened is None or self.closed is None \
+                or key not in self.closed:
+            return None
+        return self.closed[key] - self.opened.get(key, 0.0)
+
+
+def counter_delta(ctx: dict, name: str, **labels):
+    """The counter's growth over the traced stretch of the run whose
+    ``ctx`` this is; None where the run traced none (``CounterWindow``).
+    What a reader divides by the stretch's device time is counted here:
+    a flash crowd's lull holds half the decoding slots a step that the
+    process's mean does."""
+    window = ctx.get("counter_window")
+    return None if window is None else window.delta(name, **labels)
+
+
+def stretch_and_process(ctx: dict, per: str, name: str, **labels):
+    """``(over the traced stretch, over the process)`` of the counter
+    ``name{labels}`` a count of ``per``; either None where it cannot be
+    formed."""
+    def ratio(a, b):
+        return a / b if a is not None and b else None
+
+    return (ratio(counter_delta(ctx, name, **labels),
+                  counter_delta(ctx, per)),
+            ratio(counter_value(name, **labels), counter_value(per)))
+
+
+def decode_slots(ctx: dict):
+    """Decoding slots a decode step ``(in the traced stretch, over the
+    process)``."""
+    return stretch_and_process(ctx, DECODE_STEPS,
+                               "bf_serving_decode_slots_total")
+
+
+def slots_line(ctx: dict) -> str:
+    """Both figures of ``decode_slots`` in words, for a reader's printed
+    line: a log then shows by itself where the stretch is not the
+    process."""
+    here, process = (
+        "no" if v is None else f"{v:.1f}" for v in decode_slots(ctx))
+    return (f"{here} decoding slots a step in the traced stretch, "
+            f"{process} over the process")

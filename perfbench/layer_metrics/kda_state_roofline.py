@@ -5,9 +5,11 @@ every recurrent layer read once and written once, whatever implements
 the step) over the HBM peak, over the device time a decode step under
 ``bf.attn.kda_state`` (``harness/decode_scopes.py``).  The decoding
 slots times recurrent layers a step are ``bf_serving_state_steps_total``
-over ``bf_serving_decode_steps_total``, over the whole process (a
-counter has no window).  Nothing off the chip, for a reference that
-states no such bytes, or where the program counts or writes neither."""
+over ``bf_serving_decode_steps_total``, both counted in the traced
+stretch that the device time is taken over
+(``program_trace.counter_delta``).  Nothing off the chip, for a
+reference that states no such bytes, or where the program counts or
+writes neither."""
 
 from perfbench.harness import decode_scopes, program_trace as pt
 from perfbench.harness.peaks import share_pct
@@ -20,15 +22,15 @@ def reduce(trace, spans, ctx):
     if not pt.on_chip() or not ctx.get("peaks") \
             or not hasattr(ref, "kda_step_bytes"):
         return None
-    steps = pt.counter_value("bf_serving_decode_steps_total")
-    state = pt.counter_value("bf_serving_state_steps_total")
+    state, _ = pt.stretch_and_process(
+        ctx, pt.DECODE_STEPS, "bf_serving_state_steps_total")
     ms = decode_scopes.scope_ms(__file__, trace, SCOPE)
-    if not steps or not state or not ms:
+    if not state or not ms:
         return None
-    nbytes = ref.kda_step_bytes(ctx["sizes"], state / steps)
-    print(f"[kda_state_roofline] {state / steps:.1f} decoding slots x "
-          f"recurrent layers a step: {nbytes / 1e6:.1f} MB a step at the "
-          f"least; {ms:.3f} ms a step under {SCOPE}: "
-          f"{nbytes / ms / 1e6:.1f} GB/s", flush=True)
+    nbytes = ref.kda_step_bytes(ctx["sizes"], state)
+    print(f"[kda_state_roofline] {pt.slots_line(ctx)}; {state:.1f} "
+          f"decoding slots x recurrent layers a step there: "
+          f"{nbytes / 1e6:.1f} MB a step at the least; {ms:.3f} ms a step "
+          f"under {SCOPE}: {nbytes / ms / 1e6:.1f} GB/s", flush=True)
     return share_pct(nbytes / ctx["peaks"]["hbm_bytes_per_s"], 1e-3 * ms,
                      "kda_state_roofline")

@@ -8,7 +8,9 @@ and print one JSON object as the last line of standard output.  With
 ``--trace 0`` its ``metrics`` are the cell's end-to-end metrics; with
 ``--trace 1`` they are its per-layer metrics, read by
 ``layer_metrics/<name>.py`` from the profiler's trace and the
-benchmark's spans.  Everything else worth reading goes on earlier lines.
+benchmark's spans.  Its last key, ``checked``, holds each number the
+output check compared beside its limit, and standard error ends with the
+same.  Everything else worth reading goes on earlier lines.
 """
 
 from __future__ import annotations
@@ -19,6 +21,7 @@ T_START = time.perf_counter()  # process start, as near as Python gives it
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -53,6 +56,14 @@ def layer_metrics(cell, trace, spans, ctx) -> dict:
             out[entry["name"]] = {"value": float(value),
                                   "unit": entry["unit"]}
     return out
+
+
+def checked(result) -> dict:
+    """Each number the runner's check compared, beside its limit (a
+    reading that is no number, nothing having been served: ``null``)."""
+    return {name: {"value": value if math.isfinite(value) else None,
+                   "limit": limit}
+            for name, (value, limit) in result["checked"].items()}
 
 
 def main(argv=None, t_start: float = T_START, root=None) -> int:
@@ -111,6 +122,12 @@ def main(argv=None, t_start: float = T_START, root=None) -> int:
             name: {"value": float(result["end_to_end"][name]),
                    "unit": unit} for name, unit in units.items()}
     record["device"] = dev_rec
+    # what decided ``correct``, last on the line and last on standard
+    # error: the driver keeps the end of both of a run that is not
+    record["checked"] = checked(result)
+    for name, pair in record["checked"].items():
+        print(f"perfbench check: {name} = {pair['value']} (limit "
+              f"{pair['limit']})", file=sys.stderr, flush=True)
     print(json.dumps(record), flush=True)
     return 0
 

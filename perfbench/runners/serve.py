@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from perfbench.harness import arrivals, clocks, device as dev
+from perfbench.harness import arrivals, clocks, device as dev, program_trace
 from perfbench.harness.peaks import peaks_for
 from perfbench.runners.train import key_from_seed
 
@@ -110,6 +110,7 @@ class Trial:
         self.done_at = nan.copy()
         self.token_times = [[] for _ in range(n)]
         self.rejected = 0
+        self.fired = []           # the window's time at which each hook ran
         self.live_tokens = []     # (time, cache positions in use)
         self.queue_depth = []     # (time, requests waiting)
         self.step_spans = []      # (start, end) of each engine.step()
@@ -143,7 +144,10 @@ def open_loop(server: Server, requests, due, seconds: float,
     ``[(t, fn)]`` called once when the window's clock passes ``t``; the
     window's clock stands still while ``fn`` runs (starting or stopping
     the profiler stalls the loop for seconds: arrivals pause with it, so
-    no backlog is made that the traffic does not hold)."""
+    no backlog is made that the traffic does not hold).  A hook whose
+    time has not come when the loop ends runs then, in order: a schedule
+    that drains before ``seconds`` closes its trace at the drain, and
+    the engine is not kept idling for a tail that no span would own."""
     engine, spans = server.engine, server.spans
     n = len(requests)
     trial = Trial(n)
@@ -160,13 +164,19 @@ def open_loop(server: Server, requests, due, seconds: float,
             return clocks.now() - start - self.paused
 
     clock = Clock()
-    while True:
-        now = clock.now()
-        while hooks and now >= hooks[0][0]:
+
+    def fire(every: bool = False):
+        """Run the queued hooks whose time has come (``every``: all that
+        are left), the window's clock paused."""
+        while hooks and (every or clock.now() >= hooks[0][0]):
+            trial.fired.append(clock.now())
             began = clocks.now()
             hooks.pop(0)[1]()
             clock.paused += clocks.now() - began
-            now = clock.now()
+
+    while True:
+        fire()
+        now = clock.now()
         if now > seconds + drain_s:
             break
         with spans.span("pb.submit"):
@@ -209,6 +219,10 @@ def open_loop(server: Server, requests, due, seconds: float,
         else:
             with spans.span("pb.idle_wait"):
                 time.sleep(max(0.0, due[nxt] - clock.now()))
+    if hooks:
+        say(f"the loop ended at {clock.now():.3f} s of the window with "
+            f"{len(hooks)} hook(s) not yet due: run now")
+    fire(every=True)
     return trial
 
 
@@ -310,6 +324,49 @@ def check_sample(requests, seed: int, k: int):
 
 
 # ------------------------------------------------------------------ #
+# the traced stretch
+# ------------------------------------------------------------------ #
+def trace_hooks(trace_dir: str, seconds: float):
+    """``(hooks, counters)`` of a traced window: ``open_loop``'s hooks
+    that start the profiler ``TRACE_SECONDS`` (half the window, where it
+    is shorter) before ``seconds`` and stop it at ``seconds``, or when
+    the loop ends; and the ``CounterWindow`` that holds the program's
+    counters as they stood at those two edges, for the readers that
+    divide a count by the stretch's device time."""
+    t_on = max(0.0, seconds - min(TRACE_SECONDS, seconds / 2))
+    counters = program_trace.CounterWindow()
+    window = []
+
+    def start():
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        # made once the profiler runs: an annotation made before it
+        # records nothing
+        window.append(jax.profiler.TraceAnnotation("pb.trace_window"))
+        window[0].__enter__()
+        counters.open()
+
+    def stop():
+        counters.close()
+        window[0].__exit__(None, None, None)
+        jax.profiler.stop_trace()
+
+    return [(t_on, start), (seconds, stop)], counters
+
+
+def mean_live_tokens(trial: Trial):
+    """Mean cache positions in use after the engine steps of the traced
+    stretch, from when ``trace_hooks``' first hook ran to when its
+    second did (of the whole trial where no hook ran), or None."""
+    lo, hi = (trial.fired[0], trial.fired[-1]) if trial.fired \
+        else (-np.inf, np.inf)
+    live = [n for t, n in trial.live_tokens if lo <= t <= hi]
+    return float(np.mean(live)) if live else None
+
+
+# ------------------------------------------------------------------ #
 def run(cell, seed: int, seconds: float, trace: bool, devices, spans,
         t_start: float, trace_dir: str):
     traffic = cell.traffic
@@ -327,24 +384,8 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, spans,
     say("set-up spans: " + ", ".join(
         f"{name[3:]} {e - s:.1f} s" for name, s, e in spans.records
         if name.startswith("pb.compile.")))
-    hooks, t_on, window_ann = [], None, []
-    if trace:
-        t_on = max(0.0, seconds - min(TRACE_SECONDS, seconds / 2))
-
-        def start():
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0
-            options.host_tracer_level = 2
-            jax.profiler.start_trace(trace_dir, profiler_options=options)
-            window_ann.append(jax.profiler.TraceAnnotation(
-                "pb.trace_window"))
-            window_ann[0].__enter__()
-
-        def stop():
-            window_ann[0].__exit__(None, None, None)
-            jax.profiler.stop_trace()
-
-        hooks = [(t_on, start), (seconds, stop)]
+    hooks, counters = trace_hooks(trace_dir, seconds) if trace \
+        else ([], None)
     setup_s = clocks.now() - t_start
     trial = drive(server, requests, due, seconds, traffic["drain_s"],
                   hooks)
@@ -388,12 +429,11 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, spans,
         e2e["ttft_p95_ms"] = clocks.percentile(stats["ttft_ms"], 95)
     if stats["itl_ms"].size:
         e2e["itl_p95_ms"] = clocks.percentile(stats["itl_ms"], 95)
-    live = [n for t, n in trial.live_tokens
-            if t_on is None or t_on <= t <= seconds]
     return {
         "correct": bool(widest <= limit and stats["failed"] == 0
                         and not grew),
         "attempted": stats["attempted"], "failed": stats["failed"],
+        "checked": {"logit_gap": (widest, limit)},
         "end_to_end": e2e, "program_bytes": peak,
         "ctx": {
             "peaks": peaks_for(devices[0].device_kind)
@@ -402,6 +442,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, spans,
             "reference": cell.reference(), "serve": stats,
             "engine_steps": [(s, e) for s, e in trial.step_spans
                              if e <= seconds],
-            "live_tokens_mean": float(np.mean(live)) if live else None,
+            "live_tokens_mean": mean_live_tokens(trial),
+            "counter_window": counters,
         },
     }

@@ -30,7 +30,6 @@ answer, so that no mix may ask for more; here the rows are the mix's
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -38,8 +37,9 @@ from perfbench.harness import clocks, device as dev
 from perfbench.harness.peaks import peaks_for
 from perfbench.runners.serve import (TRACE_SECONDS, Server,  # noqa: F401
                                      check_sample, drive, key_from_seed,
-                                     make_requests, reference_program, say,
-                                     schedule, summarize)
+                                     make_requests, mean_live_tokens,
+                                     reference_program, say, schedule,
+                                     summarize, trace_hooks)
 
 
 # ------------------------------------------------------------------ #
@@ -112,24 +112,8 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, spans,
     say("set-up spans: " + ", ".join(
         f"{name[3:]} {e - s:.1f} s" for name, s, e in spans.records
         if name.startswith("pb.compile.")))
-    hooks, t_on, window_ann = [], None, []
-    if trace:
-        t_on = max(0.0, seconds - min(TRACE_SECONDS, seconds / 2))
-
-        def start():
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0
-            options.host_tracer_level = 2
-            jax.profiler.start_trace(trace_dir, profiler_options=options)
-            window_ann.append(jax.profiler.TraceAnnotation(
-                "pb.trace_window"))
-            window_ann[0].__enter__()
-
-        def stop():
-            window_ann[0].__exit__(None, None, None)
-            jax.profiler.stop_trace()
-
-        hooks = [(t_on, start), (seconds, stop)]
+    hooks, counters = trace_hooks(trace_dir, seconds) if trace \
+        else ([], None)
     setup_s = clocks.now() - t_start
     trial = drive(server, requests, due, seconds, traffic["drain_s"],
                   hooks)
@@ -170,11 +154,10 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, spans,
         e2e["ttft_p95_ms"] = clocks.percentile(stats["ttft_ms"], 95)
     if stats["itl_ms"].size:
         e2e["itl_p95_ms"] = clocks.percentile(stats["itl_ms"], 95)
-    live = [n for t, n in trial.live_tokens
-            if t_on is None or t_on <= t <= seconds]
     return {
         "correct": bool(inside and stats["failed"] == 0 and not grew),
         "attempted": stats["attempted"], "failed": stats["failed"],
+        "checked": numbers,
         "end_to_end": e2e, "program_bytes": peak,
         "ctx": {
             "peaks": peaks_for(devices[0].device_kind)
@@ -183,6 +166,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, spans,
             "reference": cell.reference(), "serve": stats,
             "engine_steps": [(s, e) for s, e in trial.step_spans
                              if e <= seconds],
-            "live_tokens_mean": float(np.mean(live)) if live else None,
+            "live_tokens_mean": mean_live_tokens(trial),
+            "counter_window": counters,
         },
     }

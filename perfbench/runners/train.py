@@ -397,7 +397,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, spans,
     return {
         "correct": bool(ok and kernel_ok and finite
                         and not compiled_in_window),
-        "attempted": steps, "failed": 0,
+        "attempted": steps, "failed": 0, "checked": numbers,
         "end_to_end": {"setup_s": setup_s, "train_rate_per_chip": rate},
         "program_bytes": need,
         "ctx": {

@@ -106,6 +106,12 @@ def add_cell(root, name, config, traffic_name, traffic, chips=1):
         "chips": chips, "why": "test"})
     kind = "serve" if traffic["runner"] == "serve" else "train"
     for m in bench["end_to_end"] + bench["per_layer"]:
+        # a tiny serve cell reports ``ttft_p95_ms`` end to end, so it
+        # reads under the first names, not those of a cell that holds no
+        # bound on its first-token tail
+        if m["name"].endswith(".no_ttft_bound") \
+                or m["name"] == "first_token_p95_ms":
+            continue
         if "workloads" in m and any(
                 kind in w for w in m["workloads"]) and (
                     "4chip" not in "".join(m["workloads"])
@@ -114,6 +120,62 @@ def add_cell(root, name, config, traffic_name, traffic, chips=1):
     with open(path, "w") as fh:
         json.dump(bench, fh)
     return name
+
+
+def labelled(name: str, **labels):
+    """The key of ``name{labels}`` in ``counter_window``'s and
+    ``readers_on_the_chip``'s dictionaries (a bare name: no label)."""
+    return name, tuple(sorted((k, str(v)) for k, v in labels.items()))
+
+
+def _keyed(counts: dict) -> dict:
+    return {k if isinstance(k, tuple) else labelled(k): v
+            for k, v in counts.items()}
+
+
+def counter_window(grew: dict, before: float = 1000.0):
+    """A ``program_trace.CounterWindow`` whose counters stood at
+    ``before`` when the stretch opened and grew by ``grew`` in it."""
+    from perfbench.harness import program_trace as pt
+
+    grew = _keyed(grew)
+    return pt.CounterWindow(dict.fromkeys(grew, before),
+                            {k: before + v for k, v in grew.items()})
+
+
+class ReaderRun:
+    """What ``program_trace.for_run`` hands a reader, of a trace built
+    by hand."""
+
+    def __init__(self, tf_ops):
+        self.tf_ops, self.kept = tf_ops, {}
+
+    def keep(self, key, make):
+        if key not in self.kept:
+            self.kept[key] = make()
+        return self.kept[key]
+
+
+def readers_on_the_chip(monkeypatch, tf_ops, process: dict,
+                        state_bytes_per_slot=None):
+    """The readers' look for the chip answered yes, a hand-made trace's
+    run behind ``for_run``, ``process`` as the registry's counters and,
+    where given, the gauge of a slot's state."""
+    from perfbench.harness import program_trace as pt
+
+    class Gauge:
+        value = state_bytes_per_slot
+
+    run, process = ReaderRun(tf_ops), _keyed(process)
+    monkeypatch.setattr(pt, "on_chip", lambda: True)
+    monkeypatch.setattr(pt, "for_run", lambda f: run)
+    monkeypatch.setattr(
+        pt, "counter_value",
+        lambda name, **labels: process.get(labelled(name, **labels)))
+    monkeypatch.setattr(
+        pt, "registry_metric", lambda name, **labels: Gauge()
+        if name == "bf_serving_state_bytes_per_slot"
+        and state_bytes_per_slot else None)
 
 
 @pytest.fixture

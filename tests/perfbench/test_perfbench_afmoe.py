@@ -16,7 +16,8 @@ import pytest
 from perfbench.harness import clocks, decode_scopes, loader, trace as tr
 from perfbench.harness import program_trace as pt
 
-from conftest import REPO, add_cell
+from conftest import (REPO, add_cell, counter_window, labelled,
+                      readers_on_the_chip)
 
 CELL = "trinity-large-serve-mixed-len"
 MS = 1e6
@@ -320,6 +321,67 @@ def test_the_counter_metrics(monkeypatch):
     gauges.clear()      # a program that sets no such gauge
     assert cell.layer_metric("kv_reserved_mib_per_slot").reduce(
         None, None, ctx) is None
+
+
+ATTENDED = "bf_serving_attended_positions_total"
+
+
+def step_counts(steps, hit, window, full, slots):
+    """Counters of ``steps`` decode steps over 4 expert layers: ``hit``
+    held experts a layer a step, ``window`` + ``full`` attended
+    positions and ``slots`` decoding slots a step."""
+    return {"bf_serving_decode_steps_total": steps,
+            "bf_serving_decode_slots_total": slots * steps,
+            "bf_moe_layer_steps_total": 4.0 * steps,
+            "bf_moe_experts_hit_total": hit * 4 * steps,
+            labelled(ATTENDED, kind="window"): window * steps,
+            labelled(ATTENDED, kind="full"): full * steps}
+
+
+def test_the_decode_steps_roofline_counts_in_the_traced_stretch(
+        monkeypatch, capsys):
+    """The stretch's steps hit 9.5 experts a layer and attend 100,000
+    positions where the process's mean is 19 and 200,000.  The reader
+    divides the stretch's calls, so it takes the stretch's counts; the
+    process's would read past 100% and raise on a program that is
+    right."""
+    trace, tf_ops = hand_trace()
+    cell = loader.load_cell(CELL, REPO)
+    sz = cell.family().sizes(cell.config, "serve")
+    ref = cell.reference()
+    reader = cell.layer_metric("moe_decode_step_roofline")
+    stretch = step_counts(50.0, 9.5, 60_000.0, 40_000.0, 3.0)
+    process = step_counts(900.0, 19.0, 120_000.0, 80_000.0, 6.0)
+    readers_on_the_chip(monkeypatch, tf_ops, process)
+    ctx = {"serve": {}, "traffic": cell.traffic, "sizes": sz,
+           "reference": ref, "peaks": {"hbm_bytes_per_s": 819e9},
+           "counter_window": counter_window(stretch)}
+    # the two whole calls of the hand-made trace take 20 ms each
+    nbytes = ref.moe_decode_step_bytes(sz, 9.5, 100_000.0)
+    assert reader.reduce(trace, None, ctx) == pytest.approx(
+        100 * nbytes / 819e9 / 20e-3)
+    out = capsys.readouterr().out
+    assert "3.0 decoding slots a step in the traced stretch, 6.0 over " \
+        "the process" in out
+    assert "9.50 held experts hit a layer a step and 100000 attended " \
+        "positions a step there (19.00 and 200000 over the process)" in out
+    # calls at which the stretch's bytes are 70% of the peak: the
+    # process's counts pass 100%
+    seconds = nbytes / 819e9 / 0.7
+    monkeypatch.setattr(reader.tr, "module_calls",
+                        lambda trace, pattern: [seconds] * 3)
+    assert reader.reduce(trace, None, ctx) == pytest.approx(70.0)
+    from perfbench.harness.peaks import share_pct
+    with pytest.raises(ValueError, match="cannot be right"):
+        share_pct(ref.moe_decode_step_bytes(sz, 19.0, 200_000.0) / 819e9,
+                  seconds, "the process's counts")
+    # a program that counts no expert, and a run that traced no stretch
+    bare = {k: v for k, v in stretch.items()
+            if not str(k).startswith("bf_moe")}
+    assert reader.reduce(trace, None, dict(
+        ctx, counter_window=counter_window(bare))) is None
+    assert reader.reduce(trace, None,
+                         dict(ctx, counter_window=None)) is None
 
 
 # ------------------------------------------------------------------ #

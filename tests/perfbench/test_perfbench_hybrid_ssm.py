@@ -19,7 +19,8 @@ import pytest
 from perfbench.harness import loader
 from perfbench.harness import program_trace as pt, trace as tr
 
-from conftest import REPO, add_cell
+from conftest import (REPO, add_cell, counter_window,
+                      readers_on_the_chip)
 
 CELL, STEADY = "falcon-h1-34b-serve-chat-bursts", "mistral7b-serve-steady"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -540,33 +541,15 @@ def test_the_new_readers_on_a_run_with_scopes_and_counters(monkeypatch,
                                                            capsys):
     trace, tf_ops = hand_trace()
     cell = loader.load_cell(CELL, REPO)
-    monkeypatch.setattr(pt, "on_chip", lambda: True)
-
-    class Run:
-        kept = {}
-
-        def keep(self, key, make):
-            if key not in self.kept:
-                self.kept[key] = make()
-            return self.kept[key]
-
-    Run.tf_ops = tf_ops
-    run = Run()
-    monkeypatch.setattr(pt, "for_run", lambda f: run)
-    # 40 decoding slots x 6 layers a step
+    # 40 decoding slots x 6 layers a step, in the stretch as over the
+    # process
     counters = {"bf_serving_state_steps_total": 240.0 * 50,
-                "bf_serving_decode_steps_total": 50.0}
-    monkeypatch.setattr(pt, "counter_value",
-                        lambda name, **labels: counters.get(name))
-
-    class Gauge:
-        value = 25_350_144
-
-    monkeypatch.setattr(
-        pt, "registry_metric", lambda name, **labels: Gauge()
-        if name == "bf_serving_state_bytes_per_slot" else None)
+                "bf_serving_decode_steps_total": 50.0,
+                "bf_serving_decode_slots_total": 40.0 * 50}
+    readers_on_the_chip(monkeypatch, tf_ops, counters, 25_350_144)
     ctx = dict(_ctx(cell), peaks={"hbm_bytes_per_s": 819e9,
-                                  "bf16_flops_per_s": 197e12})
+                                  "bf16_flops_per_s": 197e12},
+               counter_window=counter_window(counters))
     read = lambda name: cell.layer_metric(name).reduce(trace, None, ctx)
     # a decode step: (2 + 3 + 1 + 5) / 2; a chunk: (2 + 8 + 2 + 12) / 2
     assert read("attn_scope_ms.ssd") == pytest.approx(5.5)
@@ -583,7 +566,45 @@ def test_the_new_readers_on_a_run_with_scopes_and_counters(monkeypatch,
     out = capsys.readouterr().out
     assert "bf.attn.ssd_state 4.000" in out and "hbm bound" in out
     assert "bf.attn.ssd_chunk 10.000" in out
+    assert "40.0 decoding slots a step in the traced stretch, 40.0 over " \
+        "the process" in out
     # a time under the scope too short for the bytes is refused, not capped
-    counters["bf_serving_state_steps_total"] = 240.0 * 50 * 100
+    ctx["counter_window"] = counter_window(
+        dict(counters, bf_serving_state_steps_total=240.0 * 50 * 100))
     with pytest.raises(ValueError, match="cannot be right"):
         read("ssd_state_roofline")
+    # and a run that traced no stretch has nothing to divide
+    assert cell.layer_metric("ssd_state_roofline").reduce(
+        trace, None, dict(ctx, counter_window=None)) is None
+
+
+def test_the_state_roofline_counts_in_the_traced_stretch(monkeypatch,
+                                                         capsys):
+    """A flash crowd's lull: the stretch decodes 20 slots a step where
+    the process's mean is 40.  The reader divides the stretch's device
+    time, so it takes the stretch's slots; the process's would read
+    twice the share, past 100%, and raise on a program that is right."""
+    trace, tf_ops = hand_trace()
+    cell = loader.load_cell(CELL, REPO)
+    process = {"bf_serving_state_steps_total": 240.0 * 900,
+               "bf_serving_decode_steps_total": 900.0,
+               "bf_serving_decode_slots_total": 40.0 * 900}
+    stretch = {"bf_serving_state_steps_total": 120.0 * 50,
+               "bf_serving_decode_steps_total": 50.0,
+               "bf_serving_decode_slots_total": 20.0 * 50}
+    readers_on_the_chip(monkeypatch, tf_ops, process)
+    # the step's device time at which 120 slot-layers are 70% of the peak
+    ms = 120 * 8 * MIB / 819e9 / 0.7 * 1e3
+    monkeypatch.setattr(
+        cell.layer_metric("ssd_state_roofline").decode_scopes, "scope_ms",
+        lambda f, t, scope: ms)
+    ctx = dict(_ctx(cell), peaks={"hbm_bytes_per_s": 819e9},
+               counter_window=counter_window(stretch))
+    assert cell.layer_metric("ssd_state_roofline").reduce(
+        trace, None, ctx) == pytest.approx(70.0)
+    assert "20.0 decoding slots a step in the traced stretch, 40.0 over " \
+        "the process" in capsys.readouterr().out
+    from perfbench.harness.peaks import share_pct
+    with pytest.raises(ValueError, match="140.00% of the peak"):
+        share_pct(cell.reference().ssd_step_bytes(ctx["sizes"], 240.0)
+                  / 819e9, 1e-3 * ms, "the process's occupancy")

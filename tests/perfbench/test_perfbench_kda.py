@@ -19,7 +19,8 @@ import pytest
 from perfbench.harness import loader
 from perfbench.harness import program_trace as pt, trace as tr
 
-from conftest import REPO, add_cell
+from conftest import (REPO, add_cell, counter_window,
+                      readers_on_the_chip)
 
 CELL, XING = "ling3-flash-serve-doc-reasoning", "xing4-serve-long-answer"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -295,8 +296,9 @@ def test_a_tiny_cell_goes_through_the_command_and_is_correct(
                         lambda: "/cache")
     add_cell(bench_copy, "cell", TINY_KDA, "tiny-kda-serve", TINY_MIX)
     # ``add_cell`` knows the ``serve`` runner by name and takes any other
-    # for a training cell: list the copy's cell where the four-stream
-    # cell, which this runner serves too, is listed
+    # for a training cell: list the copy's cell where this family's own
+    # cell, which this runner serves too, is listed (the four-stream cell
+    # stood here until PR 48 took ``ttft_p95_ms`` from its end-to-end list)
     path = os.path.join(bench_copy, "BENCHMARK.json")
     with open(path) as fh:
         bench = json.load(fh)
@@ -304,7 +306,7 @@ def test_a_tiny_cell_goes_through_the_command_and_is_correct(
         cells = m.get("workloads")
         if cells is not None:
             cells[:] = [c for c in cells if c != "cell"] \
-                + (["cell"] if XING in cells else [])
+                + (["cell"] if CELL in cells else [])
     with open(path, "w") as fh:
         json.dump(bench, fh)
     rc = pbrun.main(["--workload", "cell", "--seed", str(2 ** 31 + 38),
@@ -506,32 +508,15 @@ def test_the_new_readers_on_a_run_with_scopes_and_counters(monkeypatch,
                                                            capsys):
     trace, tf_ops = hand_trace()
     cell = loader.load_cell(CELL, REPO)
-    monkeypatch.setattr(pt, "on_chip", lambda: True)
-
-    class Run:
-        kept = {}
-
-        def keep(self, key, make):
-            if key not in self.kept:
-                self.kept[key] = make()
-            return self.kept[key]
-
-    Run.tf_ops = tf_ops
-    run = Run()
-    monkeypatch.setattr(pt, "for_run", lambda f: run)
+    # 24 decoding slots x 7 recurrent layers a step, in the stretch as
+    # over the process
     counters = {"bf_serving_state_steps_total": 168.0 * 50,
-                "bf_serving_decode_steps_total": 50.0}
-    monkeypatch.setattr(pt, "counter_value",
-                        lambda name, **labels: counters.get(name))
-
-    class Gauge:
-        value = 13_025_280
-
-    monkeypatch.setattr(
-        pt, "registry_metric", lambda name, **labels: Gauge()
-        if name == "bf_serving_state_bytes_per_slot" else None)
+                "bf_serving_decode_steps_total": 50.0,
+                "bf_serving_decode_slots_total": 24.0 * 50}
+    readers_on_the_chip(monkeypatch, tf_ops, counters, 13_025_280)
     ctx = dict(_ctx(cell), peaks={"hbm_bytes_per_s": 819e9,
-                                  "bf16_flops_per_s": 197e12})
+                                  "bf16_flops_per_s": 197e12},
+               counter_window=counter_window(counters))
     read = lambda name: cell.layer_metric(name).reduce(trace, None, ctx)
     # a decode step: (2 + 3 + 1 + 2 + 5) / 2; a chunk: (2 + 8 + 12 + 2) / 2
     assert read("attn_scope_ms.kda") == pytest.approx(6.5)
@@ -548,7 +533,45 @@ def test_the_new_readers_on_a_run_with_scopes_and_counters(monkeypatch,
     out = capsys.readouterr().out
     assert "bf.attn.kda_state 4.000" in out and "hbm bound" in out
     assert "the reference states 13025280" in out
+    assert "24.0 decoding slots a step in the traced stretch, 24.0 over " \
+        "the process" in out
     # a time under the scope too short for the bytes is refused, not capped
-    counters["bf_serving_state_steps_total"] = 168.0 * 50 * 100
+    ctx["counter_window"] = counter_window(
+        dict(counters, bf_serving_state_steps_total=168.0 * 50 * 100))
     with pytest.raises(ValueError, match="cannot be right"):
         read("kda_state_roofline")
+    # and a run that traced no stretch has nothing to divide
+    assert cell.layer_metric("kda_state_roofline").reduce(
+        trace, None, dict(ctx, counter_window=None)) is None
+
+
+def test_the_state_roofline_counts_in_the_traced_stretch(monkeypatch,
+                                                         capsys):
+    """The stretch decodes 12 slots a step where the process's mean is
+    24.  The reader divides the stretch's device time, so it takes the
+    stretch's slots; the process's would read twice the share, past
+    100%, and raise on a program that is right."""
+    trace, tf_ops = hand_trace()
+    cell = loader.load_cell(CELL, REPO)
+    process = {"bf_serving_state_steps_total": 168.0 * 900,
+               "bf_serving_decode_steps_total": 900.0,
+               "bf_serving_decode_slots_total": 24.0 * 900}
+    stretch = {"bf_serving_state_steps_total": 84.0 * 50,
+               "bf_serving_decode_steps_total": 50.0,
+               "bf_serving_decode_slots_total": 12.0 * 50}
+    readers_on_the_chip(monkeypatch, tf_ops, process)
+    ref = cell.reference()
+    ctx = dict(_ctx(cell), peaks={"hbm_bytes_per_s": 819e9},
+               counter_window=counter_window(stretch))
+    # the step's device time at which 84 slot-layers are 70% of the peak
+    ms = ref.kda_step_bytes(ctx["sizes"], 84.0) / 819e9 / 0.7 * 1e3
+    reader = cell.layer_metric("kda_state_roofline")
+    monkeypatch.setattr(reader.decode_scopes, "scope_ms",
+                        lambda f, t, scope: ms)
+    assert reader.reduce(trace, None, ctx) == pytest.approx(70.0)
+    assert "12.0 decoding slots a step in the traced stretch, 24.0 over " \
+        "the process" in capsys.readouterr().out
+    from perfbench.harness.peaks import share_pct
+    with pytest.raises(ValueError, match="140.00% of the peak"):
+        share_pct(ref.kda_step_bytes(ctx["sizes"], 168.0) / 819e9,
+                  1e-3 * ms, "the process's occupancy")

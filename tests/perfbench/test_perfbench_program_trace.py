@@ -386,7 +386,8 @@ def test_the_counters_come_through_the_command_with_the_gate_open(
     """A traced run of the tiny serve cell through ``run.main``, the
     readers' look for the chip answered yes: the counters, which are
     exact on any device, are on the result line with what the
-    registry holds."""
+    registry holds (the batch of a decode step: with what it gained in
+    the traced stretch)."""
     import json
 
     import jax
@@ -396,11 +397,13 @@ def test_the_counters_come_through_the_command_with_the_gate_open(
 
     from conftest import TINY_DECODER, TINY_TRAFFIC, add_cell
 
-    ms = 1e6
+    # the device's events lie a day after anything the CPU's profile holds
+    ms, day = 1e6, 86400e9
     synthetic = tr.Trace(
-        [tr.DeviceTrace(0, [("fusion.1", 1 * ms, 9 * ms)],
-                        [("jit__decode_step_prog(5)", 1 * ms, 9 * ms)])],
-        [("pb.trace_window", 0.0, 10 * ms)])
+        [tr.DeviceTrace(0, [("fusion.1", day + 1 * ms, day + 9 * ms)],
+                        [("jit__decode_step_prog(5)", day + 1 * ms,
+                          day + 9 * ms)])],
+        [("pb.trace_window", day, day + 10 * ms)])
     monkeypatch.setattr(config, "configure_compilation_cache",
                         lambda: "/cache")
     monkeypatch.setattr(tr, "find_xplane", lambda d: d)
@@ -408,23 +411,31 @@ def test_the_counters_come_through_the_command_with_the_gate_open(
     monkeypatch.setattr(pt, "on_chip", lambda: True)
     traffic = TINY_TRAFFIC["tiny-serve"]
     add_cell(bench_copy, "cell", TINY_DECODER, "tiny-serve", traffic)
-    try:
-        assert pbrun.main(["--workload", "cell", "--seed", str(2**31 + 7),
-                           "--seconds", "1.0", "--trace", "1"],
-                          root=bench_copy) == 0
-    finally:
-        # the tiny schedule can end before the window does, and the
-        # runner then leaves its profiler session open
-        try:
-            jax.profiler.stop_trace()
-        except RuntimeError:
-            pass
+    windows = []
+
+    class Kept(pt.CounterWindow):
+        def __init__(self):
+            super().__init__()
+            windows.append(self)
+
+    monkeypatch.setattr(pt, "CounterWindow", Kept)
+    assert pbrun.main(["--workload", "cell", "--seed", str(2**31 + 7),
+                       "--seconds", "1.0", "--trace", "1"],
+                      root=bench_copy) == 0
+    # the tiny schedule can end before the window does: the runner has
+    # closed its profiler session all the same
+    with pytest.raises(RuntimeError):
+        jax.profiler.stop_trace()
     got = json.loads(capsys.readouterr().out.strip().splitlines()[-1])[
         "metrics"]
     value = pt.counter_value
+    # the batch of a decode step is counted in the traced stretch
+    stretch, = windows
     assert got["decode_slots_per_step"]["value"] == pytest.approx(
-        value("bf_serving_decode_slots_total")
-        / value("bf_serving_decode_steps_total"))
+        stretch.delta("bf_serving_decode_slots_total")
+        / stretch.delta("bf_serving_decode_steps_total"))
+    assert 0 < stretch.delta("bf_serving_decode_steps_total") \
+        < value("bf_serving_decode_steps_total")
     assert 1.0 <= got["decode_slots_per_step"]["value"] <= \
         traffic["engine"]["capacity"]
     assert got["prefill_pad_pct"]["value"] == pytest.approx(100 * (
@@ -436,8 +447,8 @@ def test_the_counters_come_through_the_command_with_the_gate_open(
     assert got["setup_trace_s"]["value"] == value(
         "bf_compile_seconds_total", stage="trace")
     assert "compile_cache_misses" in got
-    # the time-valued readers open the CPU's own profile, which has no
-    # engine step inside the synthetic window
+    # the time-valued readers open the CPU's own profile (the runner
+    # closed it), which has no engine step inside the synthetic window
     assert not any(k.startswith(("engine_phase_ms", "engine_idle_ms"))
                    for k in got)
 

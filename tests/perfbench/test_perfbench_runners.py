@@ -52,6 +52,11 @@ def check_record(rec, metrics, count=1):
     assert rec["device"]["count"] == count
     assert set(rec["device"]) >= {"platform", "kind", "count",
                                   "memory_peak_bytes"}
+    # each number compared beside its limit, last on the line
+    assert list(rec)[-1] == "checked" and rec["checked"]
+    for pair in rec["checked"].values():
+        assert set(pair) == {"value", "limit"}
+        assert 0 <= pair["value"] <= pair["limit"]
 
 
 CELLS = {
@@ -126,12 +131,15 @@ def test_the_serving_cell_prints_the_contract_line(main, on_cpu, capsys,
     add_cell(main.root, "cell", TINY_DECODER, "tiny-serve",
              TINY_TRAFFIC["tiny-serve"])
     assert main("cell", seconds=1.0) == 0
-    out = capsys.readouterr().out
+    out, err = capsys.readouterr()
     rec = json.loads(out.strip().splitlines()[-1])
     check_record(rec, {"setup_s", "serve_tokens_per_s", "ttft_p95_ms",
                        "itl_p95_ms"})
     assert rec["attempted"] == 20
     assert "check: logit_gap" in out and "(limit" in out
+    assert set(rec["checked"]) == {"logit_gap"}
+    assert err.strip().splitlines()[-1].startswith(
+        "perfbench check: logit_gap = ")
 
 
 def synthetic_trace(module: str):

@@ -500,15 +500,17 @@ CELLS = ["mistral7b-train-1chip", "resnet50-train-1chip",
          "mistral7b-serve-saturated", "xing4-serve-long-answer",
          "mistral7b-train-allreduce-4chip"]
 # per_layer as the commit before this PR left it: each name with its
-# cells, a digit a cell of CELLS
+# cells, a digit a cell of CELLS (but that PR 48 took cell 7, whose
+# first-token tail holds no bound, from the six lists that move
+# ``ttft_p95_ms``: ``test_perfbench_mhc.py`` holds where it reads now)
 BEFORE = [
     ("compile_s", "012345678"), ("step_ms.train", "0138"),
     ("train_mfu_pct", "0138"), ("flash_attention_roofline", "038"),
     ("exchange_ms", "3"), ("exchange_exposed_ms", "3"),
     ("device_idle_pct.train", "0138"), ("engine_step_ms", "2457"),
-    ("queue_wait_p95_ms", "2457"), ("decode_step_device_ms", "2457"),
+    ("queue_wait_p95_ms", "245"), ("decode_step_device_ms", "2457"),
     ("decode_step_roofline", "2"), ("device_idle_pct.serve", "24567"),
-    ("loadgen_late_p95_ms", "2457"), ("engine_phase_ms.admit", "2457"),
+    ("loadgen_late_p95_ms", "245"), ("engine_phase_ms.admit", "245"),
     ("engine_phase_ms.prefill", "2457"),
     ("engine_phase_ms.decode_inputs", "2457"),
     ("engine_phase_ms.decode_dispatch", "2457"),
@@ -517,8 +519,8 @@ BEFORE = [
     ("engine_idle_ms.decode_inputs", "24567"),
     ("engine_idle_ms.decode_dispatch", "24567"),
     ("engine_idle_ms.token_fetch", "24567"), ("engine_idle_ms.emit", "24567"),
-    ("device_launches_per_step", "2457"), ("queue_wait_prog_p95_ms", "2457"),
-    ("decode_slots_per_step", "2457"), ("prefill_pad_pct", "2457"),
+    ("device_launches_per_step", "2457"), ("queue_wait_prog_p95_ms", "245"),
+    ("decode_slots_per_step", "2457"), ("prefill_pad_pct", "245"),
     ("train_dispatch_ms", "0138"), ("train_scope_ms.forward", "0138"),
     ("train_scope_ms.backward", "0138"), ("setup_trace_s", "012345678"),
     ("setup_lower_s", "012345678"), ("setup_backend_compile_s", "012345678"),
@@ -527,7 +529,7 @@ BEFORE = [
     ("moe_decode_step_roofline", "457"), ("moe_held_share_pct", "457"),
     ("kv_reserved_mib_per_slot", "457"), ("decode_cache_streamed_pct", "2"),
     ("prefill_chunk_device_ms", "2457"), ("moe_tile_fill_pct", "457"),
-    ("attn_scope_ms.latent", "57"), ("chunk_attn_ms.latent", "57"),
+    ("attn_scope_ms.latent", "57"), ("chunk_attn_ms.latent", "5"),
     ("latent_cache_bytes_per_token", "57"), ("chunk_attn_ms.window", "4"),
     ("chunk_attn_ms.full", "4"), ("chunk_cache_streamed_pct", "4"),
     ("hc_scope_ms.decode", "7"), ("hc_scope_ms.chunk", "7"),
